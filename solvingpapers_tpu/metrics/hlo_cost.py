@@ -35,7 +35,8 @@ Conventions (shared with the collective ledger, documented here once):
   reconcile on simple programs (pinned in tests/test_hlo_cost.py):
   elementwise/transcendental ops count one flop per output element,
   `dot` counts ``2 * output_elems * contraction_size`` (contraction
-  parsed from the operand shape + `lhs_contracting_dims`), `reduce`
+  parsed from the first operand's shape — printed inline, or looked up
+  by the operand's name — + `lhs_contracting_dims`), `reduce`
   counts its input elements, and pure data movement (gather, scatter,
   slice, broadcast, copy, bitcast, parameter, ...) counts zero. A
   `fusion` op's flops live on the INNER ops of its fused computation
@@ -147,12 +148,29 @@ def classify_op(op: str) -> str:
     return _CATEGORY_OF.get(op, "other")
 
 
-def _dot_flops(line: str, tail: str, out_elems: int) -> int:
+_OPERAND_NAME_RE = re.compile(r"\s*%?(?P<name>[^\s,()]+)")
+
+
+def _first_operand(tail: str, shapes: dict[str, str]):
+    """Shape atom (a `_SHAPE_RE` match) of an op's FIRST operand. Older
+    HLO text prints operands with their shapes (``dot(f32[8,4]{1,0}
+    %a, ...)``); jaxlib 0.9 prints bare names (``dot(%a, %b)``), so the
+    name is resolved through `shapes`, the module's name -> output-shape
+    table. None when neither is there (minimized dumps)."""
+    inline = _SHAPE_RE.match(tail.lstrip())
+    if inline is not None:
+        return inline
+    name = _OPERAND_NAME_RE.match(tail)
+    if name is None:
+        return None
+    return _SHAPE_RE.search(shapes.get(name.group("name"), ""))
+
+
+def _dot_flops(line: str, lhs, out_elems: int) -> int:
     """``2 * output_elems * contraction_size`` with the contraction
-    parsed from the first operand's shape atom + lhs_contracting_dims;
-    falls back to ``2 * output_elems`` when either is absent (elided
-    operand shapes in minimized dumps)."""
-    lhs = _SHAPE_RE.search(tail)
+    parsed from the first operand's shape atom `lhs` +
+    lhs_contracting_dims; falls back to ``2 * output_elems`` when
+    either is absent (elided operand shapes in minimized dumps)."""
     contract = _CONTRACT_RE.search(line)
     if lhs is None or contract is None:
         return 2 * out_elems
@@ -190,6 +208,7 @@ def parse_hlo_costs(hlo_text: str, top_k: int = 5) -> dict:
     total_flops = 0
     total_bytes = 0
     in_entry = False
+    shapes: dict[str, str] = {}  # op name -> its output shape text
     for line in hlo_text.splitlines():
         stripped = line.strip()
         if stripped.endswith("{") and " = " not in stripped:
@@ -202,6 +221,7 @@ def parse_hlo_costs(hlo_text: str, top_k: int = 5) -> dict:
         if m is None:
             continue
         op = m.group("op")
+        shapes[m.group("name")] = m.group("out")
         if op == "parameter" and not in_entry:
             # a sub-computation's parameter aliases an operand the
             # caller already counted — skipping it keeps the bytes
@@ -211,9 +231,9 @@ def parse_hlo_costs(hlo_text: str, top_k: int = 5) -> dict:
         out_elems, out_bytes = _shape_elems_bytes(out)
         tail = line[m.end():]
         if op in ("dot", "convolution"):
-            flops = _dot_flops(line, tail, out_elems)
+            flops = _dot_flops(line, _first_operand(tail, shapes), out_elems)
         elif op in ("reduce", "reduce-window"):
-            first = _SHAPE_RE.search(tail)
+            first = _first_operand(tail, shapes)
             flops = (
                 _atom_elems_bytes(first.group("dt"), first.group("dims"))[0]
                 if first is not None else out_elems

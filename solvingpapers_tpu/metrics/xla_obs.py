@@ -532,6 +532,16 @@ class CompileRegistry:
                 }
         return out
 
+    def hlo_texts(self, program: str) -> list[str]:
+        """Compiled HLO text of every signature `program` has run through
+        this registry — the executables that actually ran, so a caller can
+        check what the compiler put in them (a Mosaic kernel's
+        `tpu_custom_call`, a mesh's collectives)."""
+        with self._lock:
+            st = self._programs.get(program)
+            sigs = list(st.signatures.values()) if st is not None else []
+        return [s.exe.compiled.as_text() for s in sigs]
+
     def max_temp_bytes(self) -> int:
         """Largest per-program XLA temp allocation seen — the scratch the
         ledger adds on top of live pools for the projected peak."""

@@ -180,8 +180,7 @@ class DSV3Pipe:
             # init runs inside shard_map (blocks trace the context ring); a
             # constant dummy is axis-invariant and would clash with the
             # ring's varying carries under the vma checker
-            if hasattr(jax.lax, "pcast"):  # no-op without vma typing
-                dummy = jax.lax.pcast(dummy, ("context",), to="varying")
+            dummy = jax.lax.pcast(dummy, ("context",), to="varying")
 
         from solvingpapers_tpu.models.staged import interleaved_storage_order
 

@@ -143,8 +143,7 @@ class GPTPipe:
             # init runs inside shard_map (the blocks trace the context
             # ring); a constant dummy is axis-invariant and would clash
             # with the ring's varying carries under the vma checker
-            if hasattr(jax.lax, "pcast"):  # no-op without vma typing
-                dummy = jax.lax.pcast(dummy, ("context",), to="varying")
+            dummy = jax.lax.pcast(dummy, ("context",), to="varying")
 
         def stage_init(key):
             blocks = {}

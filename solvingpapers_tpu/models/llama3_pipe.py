@@ -117,8 +117,7 @@ class LlamaPipe:
             cfg.compute_dtype,
         )
         if cfg.context_parallel:
-            if hasattr(jax.lax, "pcast"):  # no-op without vma typing
-                dummy = jax.lax.pcast(dummy, ("context",), to="varying")
+            dummy = jax.lax.pcast(dummy, ("context",), to="varying")
         from solvingpapers_tpu.models.staged import interleaved_storage_order
 
         stacked = init_stage_stack(

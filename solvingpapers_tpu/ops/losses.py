@@ -123,8 +123,7 @@ def _chunked_nll_sum_count(
     # under shard_map with vma tracking, the carry must match the body
     # output's varying axes (the logits are shard-varying on CP paths)
     zero = jnp.float32(0.0)
-    _typeof = getattr(jax, "typeof", None)  # absent pre-vma jax: no tracking
-    vma = tuple(getattr(_typeof(flat), "vma", ()) or ()) if _typeof else ()
+    vma = tuple(jax.typeof(flat).vma)
     if vma:
         zero = jax.lax.pcast(zero, vma, to="varying")
     (tot, num), _ = jax.lax.scan(body, (zero, zero), (flat, lab))

@@ -54,12 +54,8 @@ def aux_free_bias_update(
 def _psum_axes(x: jax.Array, axis_names) -> tuple:
     """Restrict a psum to the axes `x` actually varies over — under the
     shard_map vma checker a psum over an invariant axis is a type error
-    (e.g. CP x PP meshes where 'data' has size 1); without vma tracking
-    the full tuple is kept (the extra psums are numeric no-ops)."""
-    _typeof = getattr(jax, "typeof", None)  # absent pre-vma jax: no tracking
-    vma = getattr(_typeof(x), "vma", None) if _typeof else None
-    if vma is None:
-        return tuple(axis_names)
+    (e.g. CP x PP meshes where 'data' has size 1)."""
+    vma = jax.typeof(x).vma
     return tuple(a for a in axis_names if a in vma)
 
 

@@ -38,8 +38,7 @@ def initialize(
         return True
     # NOTE: do not touch jax.process_count()/jax.devices() here — any such
     # call initializes the local XLA backend and forecloses distributed init
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None and is_init():
+    if jax.distributed.is_initialized():
         _initialized = True
         return True
     coordinator_address = coordinator_address or os.environ.get(

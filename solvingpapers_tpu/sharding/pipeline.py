@@ -24,34 +24,19 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 
 def vma_axes(x) -> frozenset:
-    """Varying-manual-axes of `x` under the jax-0.9 vma checker, or an
-    empty set on jax versions without `jax.typeof` (no vma tracking — and
-    every pcast in the schedules is gated on a nonempty result, so the
-    schedules degrade to plain SPMD semantics there)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return frozenset(getattr(typeof(x), "vma", ()) or ())
+    """Varying-manual-axes of `x` under the shard_map vma checker (empty
+    outside shard_map and for axis-invariant values; every pcast in the
+    schedules is gated on a nonempty result)."""
+    return frozenset(jax.typeof(x).vma)
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map where it exists (passing `check_vma` when given);
-    the legacy jax.experimental.shard_map with the rep checker off
-    elsewhere (the legacy checker predates the vma typing the
-    schedules' pcasts target, and check_rep=False matches the
-    check_vma=False semantics the schedules are written for). THE
-    jax-version shim for every shard_map in this repo — exported from
-    `solvingpapers_tpu.sharding`; new multi-device code should route
-    through it rather than calling jax.shard_map directly."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    return legacy(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """`jax.shard_map`, passing `check_vma` only when given (None keeps
+    jax's default). The one spelling every shard_map in this repo goes
+    through — exported from `solvingpapers_tpu.sharding`."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 # short internal aliases (the schedule bodies below use them heavily)

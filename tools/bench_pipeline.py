@@ -12,7 +12,8 @@ step-time trend vs m validates the schedule's amortization shape, while
 the analytic fraction is the hardware-independent number. Run with
 JAX_PLATFORMS=cpu and XLA_FLAGS=--xla_force_host_platform_device_count=8
 (tests/conftest.py's recipe), or let this script set them via a subprocess
-re-exec (default when the attached platform has <8 devices).
+re-exec (the default unless the environment already provisions 8
+virtual CPU devices; decided without touching JAX in the parent).
 
 Usage: python tools/bench_pipeline.py [--stages 4] [--batch 32]
 """
@@ -21,7 +22,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import subprocess
 import sys
@@ -30,6 +30,10 @@ import time
 
 def _body(n_stages: int, batch: int) -> None:
     import jax
+
+    from solvingpapers_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     import numpy as np
 
     from solvingpapers_tpu.data.batches import lm_batch_iterator
@@ -230,6 +234,10 @@ def _mesh_obs_overhead_body(n_steps: int = 24) -> None:
     import jax
     import numpy as np
 
+    from solvingpapers_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     from solvingpapers_tpu.data.batches import lm_batch_iterator
     from solvingpapers_tpu.models.gpt_pipe import GPTPipe, GPTPipeConfig
     from solvingpapers_tpu.sharding import (
@@ -291,39 +299,33 @@ def main() -> int:
                    help="run only the paired ABBA mesh-obs overhead arm")
     args = p.parse_args()
 
-    import jax
+    here = pathlib.Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(here))
+    from solvingpapers_tpu.hostenv import virtual_cpu_devices, virtual_cpu_env
 
-    if len(jax.devices()) >= 8:
+    # decided from the environment, not from jax.devices(): a parent that
+    # initialised JAX would hold the accelerator while the child runs
+    if virtual_cpu_devices() >= 8:
         if args.mesh_obs:
             _mesh_obs_overhead_body()
         else:
             _body(args.stages, args.batch)
         return 0
     # re-exec on the virtual CPU mesh (same recipe as __graft_entry__)
-    import re
-
-    env = dict(os.environ)
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   env.get("XLA_FLAGS", ""))
-    env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    here = pathlib.Path(__file__).resolve().parent.parent
     if args.mesh_obs:
         snippet = (
-            "import jax; jax.config.update('jax_platforms', 'cpu'); "
             f"import sys; sys.path.insert(0, {str(here)!r}); "
             "from tools.bench_pipeline import _mesh_obs_overhead_body; "
             "_mesh_obs_overhead_body()"
         )
     else:
         snippet = (
-            "import jax; jax.config.update('jax_platforms', 'cpu'); "
             f"import sys; sys.path.insert(0, {str(here)!r}); "
             "from tools.bench_pipeline import _body; "
             f"_body({args.stages}, {args.batch})"
         )
-    proc = subprocess.run([sys.executable, "-c", snippet], env=env,
-                          cwd=str(here))
+    proc = subprocess.run([sys.executable, "-c", snippet],
+                          env=virtual_cpu_env(8), cwd=str(here))
     return proc.returncode
 
 

@@ -4,7 +4,7 @@ Thin wrapper over `python -m solvingpapers_tpu.cli serve-bench` (one
 parser, one call site — the two entry points cannot drift) that defaults
 --config to llama3_shakespeare and --out to BENCH_serve.json, keeping the
 artifact in the same {metric, value, unit, vs_baseline, detail} shape as
-the BENCH_r0*.json scorecards so the serving trajectory stays comparable
+the training scorecard so the serving trajectory stays comparable
 across rounds.
 
 Usage: python tools/bench_serve.py [--config llama3_shakespeare]

@@ -93,6 +93,10 @@ def run_variant(name: str, steps: int) -> dict:
 
 
 def main() -> None:
+    from solvingpapers_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("variants", nargs="*", default=None)
     ap.add_argument("--steps", type=int, default=3000)

@@ -205,6 +205,10 @@ REFERENCE = {  # the reference's recorded numbers these workloads mirror
 
 
 def main() -> int:
+    from solvingpapers_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     p = argparse.ArgumentParser()
     p.add_argument("--round", type=int, default=None)
     p.add_argument("--fast", action="store_true")

@@ -100,6 +100,10 @@ def drain_while_live(router, rid, max_new, thread, deadline_s=120.0):
 
 
 def main() -> int:
+    from solvingpapers_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--trace-out", default="fleet_trace.json")
     ap.add_argument("--timeseries-out", default="fleet_timeseries.json")
