@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The quickest proof that solvingpapers_tpu still starts on the chip.
 
-    python chip_smoke.py            # one TPU chip: device, train, train_flash, serve
+    python chip_smoke.py            # one chip: device, train, train_flash, profile, serve
     python chip_smoke.py --chips 4  # four chips: sharded vs one-device training only
 
 One process drives the package's normal entry points at the registry width
@@ -13,7 +13,10 @@ latent per layer) — a `Trainer` built the way `cli train` builds it,
 the Pallas flash-MLA path through `dsv3_long` (16,384-token context), and a
 `ServeEngine` behind the `ApiServer` the way `cli serve` assembles them,
 answering HTTP requests that are checked against an uncached float32
-full-prefix forward. Weights are random (from `--seed`), and so are the
+full-prefix forward. The `profile` phase trains `dsv3_long` under
+`TrainConfig.profile_dir` and holds the trace to what the trainer promises:
+whole steps, the loop's annotations, and a layer for the device's time.
+Weights are random (from `--seed`), and so are the
 token ids the runs train on: the file is generated here, not shipped.
 
 There is no CPU mode: a device that is not a TPU fails the `device` phase,
@@ -56,6 +59,10 @@ SERVE_LOGIT_MARGIN = 0.05
 # sharded vs one-device loss, same seed, batches and dropout masks: only
 # the bf16 reduction order differs
 SHARDED_LOSS_TOL = 2e-2
+# the steps `profile_phase` traces, counted from the start of `fit`
+PROFILE_STEPS = (4, 9)
+# the top-level operations of a step against the step's own duration
+PROFILE_SUM_TOL = 0.01
 # prompt lengths of the concurrent requests: two prefill buckets of 32
 PROMPT_LENGTHS = (12, 20, 28, 40, 52, 60)
 MAX_NEW_TOKENS = 32
@@ -243,6 +250,86 @@ def flash_train_phase(cfg, jsonl_path: str) -> dict:
             "train_flash: no tpu_custom_call in the compiled train step — "
             "the flash kernel did not run as a Mosaic kernel")
     return out
+
+
+def profile_phase(cfg, workdir: str) -> dict:
+    """Train under `TrainConfig.profile_dir` and read the trace back: the
+    window holds exactly `profile_steps[1] - profile_steps[0]` executions
+    of the train step, the loop's annotations are on the host plane of the
+    same file, `device_scopes.json` lies beside it, a loop's body runs
+    inside the loop's own event, and the top-level operations of a step add
+    up to the step."""
+    import collections
+    import glob
+
+    from jax.profiler import ProfileData
+
+    start, stop = cfg.train.profile_steps
+    _, _, rows = run_training(cfg, os.path.join(workdir, "profile.jsonl"))
+    prof = cfg.train.profile_dir
+    with open(os.path.join(prof, "device_scopes.json")) as f:
+        scopes = json.load(f)["jit_train_step"]
+    found = sorted(glob.glob(
+        os.path.join(prof, "plugins", "profile", "*", "*.xplane.pb")))
+    require(len(found) == 1, f"profile: expected one trace, found {found}")
+    planes = {p.name: p for p in ProfileData.from_file(found[0]).planes}
+    require("/device:TPU:0" in planes,
+            f"profile: no /device:TPU:0 plane among {sorted(planes)}")
+    lines = {ln.name: list(ln.events) for ln in planes["/device:TPU:0"].lines}
+    steps = [e for e in lines.get("XLA Modules", [])
+             if e.name.startswith("jit_train_step(")]
+    require(len(steps) == stop - start,
+            f"profile: {len(steps)} executions of jit_train_step in the "
+            f"trace, profile_steps={start, stop} promises {stop - start}")
+    host = collections.Counter(
+        e.name for ln in planes["/host:CPU"].lines for e in ln.events)
+    for name in ("train", "data_wait", "train_dispatch"):
+        require(host[name] == stop - start,
+                f"profile: {host[name]} host annotations {name!r}, "
+                f"expected {stop - start}")
+    require(host["log_fetch"] >= 1, "profile: no log_fetch annotation")
+
+    def name_of(e):
+        return e.name.split(" = ", 1)[0].strip().lstrip("%")
+
+    ops = sorted(lines["XLA Ops"], key=lambda e: e.start_ns)
+    lo, hi = steps[0].start_ns, steps[-1].start_ns + steps[-1].duration_ns
+    ops = [e for e in ops if lo <= e.start_ns < hi]
+    unknown = sorted({name_of(e) for e in ops} - set(scopes))
+    require(not unknown, f"profile: operations the map lacks: {unknown[:8]}")
+    layer_ms, inside, parent_end, nested_outside = {}, 0, 0, []
+    for e in ops:
+        layer, _, top_level = scopes[name_of(e)]
+        if top_level:
+            key = layer or "unscoped"
+            layer_ms[key] = layer_ms.get(key, 0.0) + e.duration_ns / 1e6
+            parent_end = max(parent_end, e.start_ns + e.duration_ns)
+        else:
+            inside += 1
+            # starts and durations are rounded to the nanosecond each
+            over_ns = e.start_ns + e.duration_ns - parent_end
+            if over_ns > 2:
+                nested_outside.append((name_of(e), over_ns))
+    require(not nested_outside,
+            "profile: operations of a loop's or branch's body that end "
+            f"(ns) after every top-level event so far: {nested_outside[:8]}")
+    n = stop - start
+    step_ms = sum(e.duration_ns for e in steps) / 1e6 / n
+    top_ms = sum(layer_ms.values()) / n
+    require(abs(top_ms - step_ms) <= PROFILE_SUM_TOL * step_ms,
+            f"profile: top-level operations take {top_ms:.3f} ms a step, "
+            f"the step {step_ms:.3f} ms")
+    return {
+        "config": cfg.name, "profile_steps": [start, stop],
+        "train_step_executions": len(steps), "step_ms": step_ms,
+        "top_level_ms": top_ms, "events_inside_loops": inside,
+        "layer_ms": {k: v / n for k, v in sorted(layer_ms.items())},
+        "host_annotations": {k: host[k] for k in (
+            "train", "data_wait", "train_dispatch", "log_fetch")},
+        "trace_bytes": os.path.getsize(found[0]),
+        "data_wait_ms": rows[-1].get("data_wait_ms"),
+        "host_loop_ms": rows[-1].get("host_loop_ms"),
+    }
 
 
 def flash_dropout_check(seed: int) -> dict:
@@ -596,6 +683,11 @@ def main(argv=None) -> int:
             emit("train_flash", **flash_train_phase(
                 long_cfg, os.path.join(work, "flash.jsonl")),
                 **flash_dropout_check(args.seed))
+            prof_cfg = tokens_config(
+                LONG, tokens, steps=PROFILE_STEPS[1] + 3,
+                profile_dir=os.path.join(work, "profile"),
+                profile_steps=PROFILE_STEPS, **common)
+            emit("profile", **profile_phase(prof_cfg, work))
             emit("serve", **serve_phase(cfg, args.seed))
     emit("done", seconds=round(time.perf_counter() - t0, 2))
     print(json.dumps({"ok": True, "device": dev}), flush=True)

@@ -290,6 +290,7 @@ def _fwd(q3, k3, v3, seed, n_heads, n_kv, scale, causal, block_q, block_k,
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="flash_mla_fwd",
     )(q3, k3, v3, seed)
 
 
@@ -498,6 +499,7 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="flash_mla_bwd_dq",
     )(q3, k3r, v3r, do, lse, delta, seed)
 
     def q_index(i, kb, jb):
@@ -540,6 +542,7 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="flash_mla_bwd_dkv",
     )(q3, k3r, v3r, do, lse, delta, seed)
 
     return dq, dk_r, dv_r

@@ -43,13 +43,26 @@ Conventions (shared with the collective ledger, documented here once):
   (which the scan also walks); the fusion line itself contributes only
   its output bytes — the buffer the fusion materializes.
 
-Nothing here imports jax: the input is a string, so the parser is unit-
-testable on crafted HLO and usable offline on `obs_hlo_dir` dumps.
+The same line scan also maps each instruction to the LAYER of the program
+that emitted it (`device_scopes`): the train step wraps its layers in
+`jax.named_scope`s from a fixed vocabulary (`LAYER_SCOPES`) and names its
+Pallas kernels (`KERNEL_SCOPES`), both reach the optimised HLO as
+`metadata={op_name=...}`, and the profiler's `XLA Ops` events carry the
+instruction's name but not its metadata. `program_scopes` compiles a
+program the `Trainer` registered and returns that map, so a device trace
+can be summed by layer.
+
+Nothing here imports jax while the module is imported: the parsers take
+a string, so they are unit-testable on crafted HLO and usable offline on
+`obs_hlo_dir` dumps; `register_program`/`program_scopes` import it when
+called.
 """
 
 from __future__ import annotations
 
+import contextlib
 import re
+from typing import NamedTuple
 
 # category order is the display order everywhere (statusz, trace
 # summary, README table) — the paged-tax story first, remainder last
@@ -97,11 +110,43 @@ _ZERO_FLOP_OPS = frozenset({
 # "%name = <output shape(s)> <op>(" — defining occurrences only, the
 # parse_hlo_collectives discipline: operand references live inside the
 # parens of another op's definition and never follow " = ".
-_DEF_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[^\s=]+)\s*=\s*"
-    r"(?P<out>\([^)]*\)|\S+)\s+"
-    r"(?P<op>[a-z][a-z0-9\-]*)\("
-)
+_DEF_HEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[^\s=]+)\s*=\s*")
+_DEF_OP_RE = re.compile(r"\s+(?P<op>[a-z][a-z0-9\-]*)\(")
+
+
+class _Def(NamedTuple):
+    """One defining line: `%name = out op(` and where its operands start."""
+
+    name: str
+    out: str
+    op: str
+    end: int
+
+
+def _match_def(line: str) -> _Def | None:
+    head = _DEF_HEAD_RE.match(line)
+    if head is None:
+        return None
+    start = head.end()
+    if line.startswith("(", start):
+        # a tuple shape; TPU layouts nest parentheses inside it
+        # ("{2,1,0:T(8,128)(2,1)S(1)}"), so count them
+        depth = 0
+        for stop in range(start, len(line)):
+            depth += (line[stop] == "(") - (line[stop] == ")")
+            if depth == 0:
+                break
+        else:
+            return None
+        stop += 1
+    else:
+        stop = start
+        while stop < len(line) and not line[stop].isspace():
+            stop += 1
+    op = _DEF_OP_RE.match(line, stop)
+    if op is None:
+        return None
+    return _Def(head.group("name"), line[start:stop], op.group("op"), op.end())
 
 _SHAPE_RE = re.compile(
     r"(?P<dt>[a-z]\d*[a-z0-9]*|pred)\[(?P<dims>[\d,]*)\]"
@@ -117,6 +162,8 @@ _DTYPE_BYTES = {
 
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{(?P<dims>[\d,]*)\}")
 _OP_NAME_RE = re.compile(r'op_name="(?P<src>[^"]*)"')
+_OPERAND_NAMES_RE = re.compile(r"%([^\s,()]+)")
+_CALLS_RE = re.compile(r"\bcalls=%?(?P<comp>[^\s,}]+)")
 
 
 def _atom_elems_bytes(dt: str, dims: str) -> tuple[int, int]:
@@ -186,6 +233,27 @@ def _dot_flops(line: str, lhs, out_elems: int) -> int:
     return 2 * out_elems * k
 
 
+def _scan_defs(hlo_text: str):
+    """(in_entry, computation, `_Def`, line) for each defining line of an
+    HLO module's text — the one line scan `parse_hlo_costs` and
+    `device_scopes` share. `computation` is the name of the computation
+    the line sits in."""
+    in_entry = False
+    comp = ""
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if stripped.endswith("{") and " = " not in stripped:
+            # a computation header ("%fused_computation (...) -> ... {",
+            # "ENTRY %main (...) {", while/reduce region bodies)
+            in_entry = stripped.startswith("ENTRY")
+            words = stripped.split()
+            comp = words[1 if in_entry else 0].lstrip("%") if words else ""
+            continue
+        m = _match_def(line)
+        if m is not None:
+            yield in_entry, comp, m, line
+
+
 def parse_hlo_costs(hlo_text: str, top_k: int = 5) -> dict:
     """Scan an HLO module's text into the per-op-category cost ledger.
 
@@ -207,29 +275,18 @@ def parse_hlo_costs(hlo_text: str, top_k: int = 5) -> dict:
     total_ops = 0
     total_flops = 0
     total_bytes = 0
-    in_entry = False
     shapes: dict[str, str] = {}  # op name -> its output shape text
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        if stripped.endswith("{") and " = " not in stripped:
-            # a computation header ("%fused_computation (...) -> ... {",
-            # "ENTRY %main (...) {", while/reduce region bodies): only
-            # the entry computation's parameters are argument traffic
-            in_entry = stripped.startswith("ENTRY")
-            continue
-        m = _DEF_RE.match(line)
-        if m is None:
-            continue
-        op = m.group("op")
-        shapes[m.group("name")] = m.group("out")
+    for in_entry, _, m, line in _scan_defs(hlo_text):
+        # only the entry computation's parameters are argument traffic
+        op = m.op
+        shapes[m.name] = m.out
         if op == "parameter" and not in_entry:
             # a sub-computation's parameter aliases an operand the
             # caller already counted — skipping it keeps the bytes
             # total an operand+output traffic proxy, not double counts
             continue
-        out = m.group("out")
-        out_elems, out_bytes = _shape_elems_bytes(out)
-        tail = line[m.end():]
+        out_elems, out_bytes = _shape_elems_bytes(m.out)
+        tail = line[m.end:]
         if op in ("dot", "convolution"):
             flops = _dot_flops(line, _first_operand(tail, shapes), out_elems)
         elif op in ("reduce", "reduce-window"):
@@ -251,7 +308,7 @@ def parse_hlo_costs(hlo_text: str, top_k: int = 5) -> dict:
         total_flops += flops
         total_bytes += out_bytes
         entry = {
-            "name": m.group("name"),
+            "name": m.name,
             "op": op,
             "category": cat,
             "flops": flops,
@@ -321,3 +378,165 @@ def format_anatomy(anatomy: dict) -> str:
                     + (f"  [{src}]" if src else "")
                 )
     return "\n".join(lines)
+
+
+# ------------------------------------------------------- layers of a program
+
+# The layer scopes of the train step, one `jax.named_scope` at each layer
+# boundary (models/deepseekv3.py, ops/moe.py, ops/losses.py,
+# train/engine.py). Single tokens that no Flax module is named.
+LAYER_SCOPES = (
+    "L_embed",
+    "L_attn_proj",
+    "L_attn_core",
+    "L_moe_gate",
+    "L_moe_dispatch",
+    "L_moe_experts",
+    "L_moe_combine",
+    "L_moe_shared",
+    "L_moe_stats",
+    "L_loss_head",
+    "L_optimizer",
+)
+# `name=` of the three `pallas_call`s of kernels/flash_attention.py
+KERNEL_SCOPES = ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
+
+_SCOPE_RE = re.compile(
+    r"(?<![A-Za-z0-9_])(" + "|".join(LAYER_SCOPES + KERNEL_SCOPES)
+    + r")(?![A-Za-z0-9_])"
+)
+
+
+class DeviceScope(NamedTuple):
+    """Where one HLO instruction comes from in the program."""
+
+    layer: str | None  # innermost of LAYER_SCOPES / KERNEL_SCOPES
+    pass_: str  # "fwd", "bwd" or "remat"
+    top_level: bool  # defined in the ENTRY computation
+
+
+def device_scopes(hlo_text: str) -> dict[str, DeviceScope]:
+    """{instruction name: DeviceScope} of a compiled program's text.
+
+    The layer is the innermost vocabulary token of the instruction's own
+    `op_name` (`jit(train_step)/transpose(jvp(DeepSeekV3))/layer_0/moe/
+    L_moe_combine/tec,ecd->td/dot_general` -> `L_moe_combine`; a
+    `tpu_custom_call` carries its kernel's name there, inside the layer
+    that called it). A fusion's line holds its root's metadata; where it
+    holds none the fusion takes the last `op_name` of the computation it
+    calls. An instruction the compiler made, with no metadata at all (a
+    layout copy, a reduce split in two, a prefetch into fast memory),
+    takes the layer and pass of its first operand that has a layer, else
+    of the first instruction that uses it; next to a kernel that is the
+    layer the kernel is called in, not the kernel's name, which stays
+    with the kernel's own call. The pass is `remat` under
+    `rematted_computation` (the forward that `jax.checkpoint` runs again),
+    `bwd` under `transpose(`, else `fwd`.
+
+    `top_level`: the profiler records a `while`, a `conditional` or a
+    `call` as one event and the instructions of its body as events inside
+    it, so device time is summed over top-level instructions only.
+    Parameters and constants run nothing and are left out."""
+    out: dict[str, DeviceScope] = {}
+    lends: dict[str, DeviceScope] = {}  # what a neighbour inherits
+    last_path: dict[str, str] = {}  # computation -> its last op_name
+    bare: dict[str, list[str]] = {}  # no metadata, no layer -> operands
+    for in_entry, comp, m, line in _scan_defs(hlo_text):
+        if m.op in ("parameter", "constant"):
+            continue
+        src = _OP_NAME_RE.search(line)
+        path = src.group("src") if src is not None else ""
+        if path:
+            last_path[comp] = path
+        elif m.op == "fusion":
+            called = _CALLS_RE.search(line)
+            if called is not None:
+                path = last_path.get(called.group("comp"), "")
+        found = _SCOPE_RE.findall(path)
+        if "rematted_computation" in path:
+            pass_ = "remat"
+        elif "transpose(" in path:
+            pass_ = "bwd"
+        else:
+            pass_ = "fwd"
+        scope = DeviceScope(found[-1] if found else None, pass_, in_entry)
+        lend = scope._replace(layer=next(
+            (t for t in reversed(found) if t in LAYER_SCOPES), None
+        ))
+        operands = _OPERAND_NAMES_RE.findall(
+            line[m.end:].split(")", 1)[0]
+        )
+        if not path:
+            for name in operands:
+                if name in lends and lends[name].layer is not None:
+                    scope = lend = lends[name]._replace(top_level=in_entry)
+                    break
+            else:
+                bare[m.name] = operands
+        out[m.name], lends[m.name] = scope, lend
+        if lend.layer is not None:
+            # hand the layer up to what the compiler made to feed this
+            todo = [o for o in operands if o in bare]
+            while todo:
+                name = todo.pop()
+                todo += [o for o in bare.pop(name, ()) if o in bare]
+                out[name] = lends[name] = lend._replace(
+                    top_level=out[name].top_level
+                )
+    return out
+
+
+# program name as the profiler shows it ("jit_train_step") -> (the jitted
+# function, the abstract arguments of its first dispatch). Filled by
+# `Trainer`; holds shapes, no device buffer.
+_PROGRAMS: dict[str, tuple] = {}
+_SCOPES: dict[str, dict[str, DeviceScope]] = {}  # name -> its map, once made
+
+
+def register_program(name: str, jitted, args: tuple) -> None:
+    """Remember how to compile `jitted` again: `args` are the arguments of
+    a dispatch, kept as `ShapeDtypeStruct`s with their shardings."""
+    import jax
+
+    _PROGRAMS[name] = (jitted, jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=getattr(a, "sharding", None)
+        ),
+        args,
+    ))
+    _SCOPES.pop(name, None)
+
+
+@contextlib.contextmanager
+def _persistent_cache_off():
+    """Compile without JAX's persistent compilation cache. Its key leaves
+    metadata out (`jax_compilation_cache_include_metadata_in_key` is
+    False), so after a change that only moves a scope a hit returns an
+    executable whose text still holds the old `op_name`s
+    (tests/test_device_scopes.py pins it)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def program_scopes(name: str) -> dict[str, DeviceScope] | None:
+    """`device_scopes` of the registered program `name`, from a compile of
+    its current lowering (tens of seconds for a train step: call it after
+    the measured work, never inside it). None for a name no `Trainer` of
+    this process has dispatched. Kept until the name is registered again."""
+    if name not in _PROGRAMS:
+        return None
+    if name not in _SCOPES:
+        jitted, args = _PROGRAMS[name]
+        with _persistent_cache_off():
+            text = jitted.lower(*args).compile().as_text()
+        _SCOPES[name] = device_scopes(text)
+    return _SCOPES[name]

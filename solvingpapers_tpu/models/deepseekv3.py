@@ -176,192 +176,195 @@ class MLA(nn.Module):
                 getattr(cache, "c_prompt", jnp.zeros((1, 0, 1))).shape[1], s,
             )
 
-        latent = nn.Dense(
-            lat, use_bias=False, dtype=cfg.compute_dtype, name="w_dkv"
-        )(x)  # (B, S, L)
-        init = nn.initializers.normal(0.02)
-        w_q = self.param("w_q", init, (cfg.dim, n, hd))
-        w_k = self.param("w_k", init, (lat, n, hd))
-        w_v = self.param("w_v", init, (lat, n, hd))
+        with jax.named_scope("L_attn_proj"):
+            latent = nn.Dense(
+                lat, use_bias=False, dtype=cfg.compute_dtype, name="w_dkv"
+            )(x)  # (B, S, L)
+            init = nn.initializers.normal(0.02)
+            w_q = self.param("w_q", init, (cfg.dim, n, hd))
+            w_k = self.param("w_k", init, (lat, n, hd))
+            w_v = self.param("w_v", init, (lat, n, hd))
 
-        dt = cfg.compute_dtype
-        q = jnp.einsum("bsd,dnh->bsnh", x.astype(dt), w_q.astype(dt))
-        # absorbed query: project q into latent space once, score vs latents
-        q_lat = jnp.einsum("bsnh,lnh->bsnl", q, w_k.astype(dt))
+            dt = cfg.compute_dtype
+            q = jnp.einsum("bsd,dnh->bsnh", x.astype(dt), w_q.astype(dt))
+            # absorbed query: project q into latent space once, score vs latents
+            q_lat = jnp.einsum("bsnh,lnh->bsnl", q, w_k.astype(dt))
 
-        R = cfg.rope_dim
-        if R:
-            # decoupled RoPE (real DSV3; see DeepSeekV3Config.rope_dim): the
-            # rotary halves concatenate onto the latent score so the cache,
-            # ring and flash paths below all operate on (L+R)-wide vectors
-            cos, sin = ops.precompute_rope(R, cfg.block_size, cfg.rope_theta)
-            w_qr = self.param("w_qr", init, (cfg.dim, n, R))
-            q_rope = jnp.einsum("bsd,dnr->bsnr", x.astype(dt), w_qr.astype(dt))
-            q_rope = ops.apply_rope(q_rope, cos, sin, positions=positions)
-            k_rope = nn.Dense(R, use_bias=False, dtype=dt, name="w_kr")(x)
-            k_rope = ops.apply_rope(
-                k_rope[:, :, None, :], cos, sin, positions=positions
-            )[:, :, 0]
-            q_lat = jnp.concatenate([q_lat, q_rope.astype(dt)], axis=-1)
-            latent = jnp.concatenate(
-                [latent.astype(dt), k_rope.astype(dt)], axis=-1
-            )
-        scale = (hd + R) ** -0.5 if R else hd**-0.5
-
-        if cp_cache and s > 1:
-            # CP PREFILL: this shard's contiguous prompt chunk exactly fills
-            # its c_prompt slice — written in place, no resharding — and
-            # attention falls through to the ring path below (cross-shard
-            # causality is the ring's job, cache slots play no part yet)
-            cache = cache.replace(
-                c_prompt=latent.astype(cache.c_prompt.dtype)
-            )
-        if cp_cache and s == 1:
-            # CP DECODE STEP: the token is replicated across the context
-            # axis; its latent lands in the replicated tail, shard-local
-            # logsumexp partials over the sharded prompt chunk (+ tail on
-            # the last shard only, counted once) combine with one pmax +
-            # two psums — the 32k+ prompt cache never moves off its shard.
-            from solvingpapers_tpu.infer.cache import cp_cache_partial_softmax
-            from solvingpapers_tpu.ops.attention import BIG_NEG
-
-            cp_size = jax.lax.psum(1, "context")
-            idx = jax.lax.axis_index("context")
-            s0_glob = cache.c_prompt.shape[1] * cp_size
-            tail_len = cache.c_tail.shape[1]
-            pos = positions[0, 0]
-            cache = cache.replace(
-                c_tail=jax.lax.dynamic_update_slice(
-                    cache.c_tail, latent.astype(cache.c_tail.dtype),
-                    (0, pos - s0_glob, 0),
+            R = cfg.rope_dim
+            if R:
+                # decoupled RoPE (real DSV3; see DeepSeekV3Config.rope_dim): the
+                # rotary halves concatenate onto the latent score so the cache,
+                # ring and flash paths below all operate on (L+R)-wide vectors
+                cos, sin = ops.precompute_rope(R, cfg.block_size, cfg.rope_theta)
+                w_qr = self.param("w_qr", init, (cfg.dim, n, R))
+                q_rope = jnp.einsum("bsd,dnr->bsnr", x.astype(dt), w_qr.astype(dt))
+                q_rope = ops.apply_rope(q_rope, cos, sin, positions=positions)
+                k_rope = nn.Dense(R, use_bias=False, dtype=dt, name="w_kr")(x)
+                k_rope = ops.apply_rope(
+                    k_rope[:, :, None, :], cos, sin, positions=positions
+                )[:, :, 0]
+                q_lat = jnp.concatenate([q_lat, q_rope.astype(dt)], axis=-1)
+                latent = jnp.concatenate(
+                    [latent.astype(dt), k_rope.astype(dt)], axis=-1
                 )
-            )
-            q32 = q_lat.astype(jnp.float32) * scale
-            # every prompt slot precedes pos (pos >= s0_glob): no mask
-            scores_p = jnp.einsum(
-                "bsnl,btl->bnst", q32, cache.c_prompt.astype(jnp.float32)
-            )
-            scores_t = jnp.einsum(
-                "bsnl,btl->bnst", q32, cache.c_tail.astype(jnp.float32)
-            )
-            tail_pos = s0_glob + jnp.arange(tail_len)
-            mask_t = (tail_pos[None, None, None, :] <= pos) & (
-                idx == cp_size - 1
-            )
-            scores_t = jnp.where(mask_t, scores_t, BIG_NEG)
-            vals = jnp.concatenate([cache.c_prompt, cache.c_tail], axis=1)
-            ctx = cp_cache_partial_softmax(
-                scores_p, scores_t, vals, "context"
-            ).astype(dt)
-        elif cfg.context_parallel and (cache is None or s > 1):
-            # ring over the latent stream (k = v = latents, one shared kv
-            # head): long-context CP for the flagship family. The same
-            # latent-space algebra as the dense path — decompression by
-            # w_v happens after the ring, on the local ctx shard.
-            from solvingpapers_tpu.sharding.ring_attention import (
-                ring_attention_local,
-                ring_flash_attention_local,
-            )
+            scale = (hd + R) ** -0.5 if R else hd**-0.5
 
-            from solvingpapers_tpu.kernels.flash_attention import (
-                is_tpu_backend,
-            )
-
-            drop_active = cfg.attn_dropout > 0.0 and not deterministic
-            if drop_active and not (cfg.use_flash and is_tpu_backend()):
-                raise NotImplementedError(
-                    "attention-prob dropout under context_parallel MLA "
-                    "requires the ring-flash path on real TPU (per-chunk "
-                    "in-kernel masks); set attn_dropout=0.0 or use_flash"
+        with jax.named_scope("L_attn_core"):
+            if cp_cache and s > 1:
+                # CP PREFILL: this shard's contiguous prompt chunk exactly fills
+                # its c_prompt slice — written in place, no resharding — and
+                # attention falls through to the ring path below (cross-shard
+                # causality is the ring's job, cache slots play no part yet)
+                cache = cache.replace(
+                    c_prompt=latent.astype(cache.c_prompt.dtype)
                 )
-            c_kv = latent.astype(dt)[:, :, None, :]  # (B, S_loc, 1, L)
-            if cfg.use_flash:
-                kwargs = {}
-                if drop_active:
-                    kwargs = dict(
-                        dropout_rate=cfg.attn_dropout,
-                        dropout_seed=jax.random.randint(
-                            self.make_rng("dropout"), (), 0,
-                            jnp.iinfo(jnp.int32).max,
-                        ),
+            if cp_cache and s == 1:
+                # CP DECODE STEP: the token is replicated across the context
+                # axis; its latent lands in the replicated tail, shard-local
+                # logsumexp partials over the sharded prompt chunk (+ tail on
+                # the last shard only, counted once) combine with one pmax +
+                # two psums — the 32k+ prompt cache never moves off its shard.
+                from solvingpapers_tpu.infer.cache import cp_cache_partial_softmax
+                from solvingpapers_tpu.ops.attention import BIG_NEG
+
+                cp_size = jax.lax.psum(1, "context")
+                idx = jax.lax.axis_index("context")
+                s0_glob = cache.c_prompt.shape[1] * cp_size
+                tail_len = cache.c_tail.shape[1]
+                pos = positions[0, 0]
+                cache = cache.replace(
+                    c_tail=jax.lax.dynamic_update_slice(
+                        cache.c_tail, latent.astype(cache.c_tail.dtype),
+                        (0, pos - s0_glob, 0),
                     )
-                ctx = ring_flash_attention_local(
-                    q_lat, c_kv, c_kv, "context", causal=True, scale=scale,
-                    **kwargs,
+                )
+                q32 = q_lat.astype(jnp.float32) * scale
+                # every prompt slot precedes pos (pos >= s0_glob): no mask
+                scores_p = jnp.einsum(
+                    "bsnl,btl->bnst", q32, cache.c_prompt.astype(jnp.float32)
+                )
+                scores_t = jnp.einsum(
+                    "bsnl,btl->bnst", q32, cache.c_tail.astype(jnp.float32)
+                )
+                tail_pos = s0_glob + jnp.arange(tail_len)
+                mask_t = (tail_pos[None, None, None, :] <= pos) & (
+                    idx == cp_size - 1
+                )
+                scores_t = jnp.where(mask_t, scores_t, BIG_NEG)
+                vals = jnp.concatenate([cache.c_prompt, cache.c_tail], axis=1)
+                ctx = cp_cache_partial_softmax(
+                    scores_p, scores_t, vals, "context"
                 ).astype(dt)
-            else:
-                ctx = ring_attention_local(
-                    q_lat, c_kv, c_kv, "context", causal=True, scale=scale
-                ).astype(dt)
-        elif cache is None and cfg.use_flash:
-            # absorbed-query MLA *is* MQA over the latent stream: scores are
-            # q_lat . c and the context is probs @ c, i.e. attention with
-            # k = v = c and one shared kv head — so the Pallas flash kernel
-            # serves MLA directly (head_dim = latent_dim), giving the
-            # flagship family the same long-context memory profile as the
-            # GQA models (no (S, S) probs in HBM). Cached decode keeps the
-            # dense einsum path (per-step scores are (1, t), already small).
-            from solvingpapers_tpu.models.layers import apply_flash_attention
+            elif cfg.context_parallel and (cache is None or s > 1):
+                # ring over the latent stream (k = v = latents, one shared kv
+                # head): long-context CP for the flagship family. The same
+                # latent-space algebra as the dense path — decompression by
+                # w_v happens after the ring, on the local ctx shard.
+                from solvingpapers_tpu.sharding.ring_attention import (
+                    ring_attention_local,
+                    ring_flash_attention_local,
+                )
 
-            c_kv = latent.astype(dt)[:, :, None, :]  # (B, S, 1, L)
-            ctx = apply_flash_attention(
-                self, q_lat, c_kv, c_kv, causal=True, scale=scale,
-                dropout_rate=cfg.attn_dropout, deterministic=deterministic,
-            ).astype(dt)
-        elif cache is not None and attend_len is not None:
-            # PREFILL: this chunk occupies cache slots [attend_len - S,
-            # attend_len) with every earlier slot written, so attention is
-            # end-aligned causal over a STATIC slice of the latent cache —
-            # no (S, max_len) score tensor (16k-prompt prefill fits HBM).
-            cache = update_latent_cache(cache, latent, positions[0, 0])
-            c_att = jax.lax.slice_in_dim(cache.c, 0, attend_len, axis=1)
-            c_kv = c_att[:, :, None, :]  # (B, attend_len, 1, L[+R])
-            if cfg.use_flash:
+                from solvingpapers_tpu.kernels.flash_attention import (
+                    is_tpu_backend,
+                )
+
+                drop_active = cfg.attn_dropout > 0.0 and not deterministic
+                if drop_active and not (cfg.use_flash and is_tpu_backend()):
+                    raise NotImplementedError(
+                        "attention-prob dropout under context_parallel MLA "
+                        "requires the ring-flash path on real TPU (per-chunk "
+                        "in-kernel masks); set attn_dropout=0.0 or use_flash"
+                    )
+                c_kv = latent.astype(dt)[:, :, None, :]  # (B, S_loc, 1, L)
+                if cfg.use_flash:
+                    kwargs = {}
+                    if drop_active:
+                        kwargs = dict(
+                            dropout_rate=cfg.attn_dropout,
+                            dropout_seed=jax.random.randint(
+                                self.make_rng("dropout"), (), 0,
+                                jnp.iinfo(jnp.int32).max,
+                            ),
+                        )
+                    ctx = ring_flash_attention_local(
+                        q_lat, c_kv, c_kv, "context", causal=True, scale=scale,
+                        **kwargs,
+                    ).astype(dt)
+                else:
+                    ctx = ring_attention_local(
+                        q_lat, c_kv, c_kv, "context", causal=True, scale=scale
+                    ).astype(dt)
+            elif cache is None and cfg.use_flash:
+                # absorbed-query MLA *is* MQA over the latent stream: scores are
+                # q_lat . c and the context is probs @ c, i.e. attention with
+                # k = v = c and one shared kv head — so the Pallas flash kernel
+                # serves MLA directly (head_dim = latent_dim), giving the
+                # flagship family the same long-context memory profile as the
+                # GQA models (no (S, S) probs in HBM). Cached decode keeps the
+                # dense einsum path (per-step scores are (1, t), already small).
                 from solvingpapers_tpu.models.layers import apply_flash_attention
 
+                c_kv = latent.astype(dt)[:, :, None, :]  # (B, S, 1, L)
                 ctx = apply_flash_attention(
                     self, q_lat, c_kv, c_kv, causal=True, scale=scale,
+                    dropout_rate=cfg.attn_dropout, deterministic=deterministic,
                 ).astype(dt)
-            else:
-                ctx = ops.dot_product_attention(
-                    q_lat, c_kv, c_kv, causal=True, scale=scale
-                ).astype(dt)
-        else:
-            if cache is not None:
+            elif cache is not None and attend_len is not None:
+                # PREFILL: this chunk occupies cache slots [attend_len - S,
+                # attend_len) with every earlier slot written, so attention is
+                # end-aligned causal over a STATIC slice of the latent cache —
+                # no (S, max_len) score tensor (16k-prompt prefill fits HBM).
                 cache = update_latent_cache(cache, latent, positions[0, 0])
-                c_full = cache.c
-                kv_idx = jnp.arange(cache.max_len)
-                mask = kv_idx[None, None, None, :] <= positions[:, None, :, None]
+                c_att = jax.lax.slice_in_dim(cache.c, 0, attend_len, axis=1)
+                c_kv = c_att[:, :, None, :]  # (B, attend_len, 1, L[+R])
+                if cfg.use_flash:
+                    from solvingpapers_tpu.models.layers import apply_flash_attention
+
+                    ctx = apply_flash_attention(
+                        self, q_lat, c_kv, c_kv, causal=True, scale=scale,
+                    ).astype(dt)
+                else:
+                    ctx = ops.dot_product_attention(
+                        q_lat, c_kv, c_kv, causal=True, scale=scale
+                    ).astype(dt)
             else:
-                c_full = latent
-                q_idx = jnp.arange(s)
-                mask = (q_idx[None, :, None] >= q_idx[None, None, :])[:, None]
+                if cache is not None:
+                    cache = update_latent_cache(cache, latent, positions[0, 0])
+                    c_full = cache.c
+                    kv_idx = jnp.arange(cache.max_len)
+                    mask = kv_idx[None, None, None, :] <= positions[:, None, :, None]
+                else:
+                    c_full = latent
+                    q_idx = jnp.arange(s)
+                    mask = (q_idx[None, :, None] >= q_idx[None, None, :])[:, None]
 
-            scores = (
-                jnp.einsum("bsnl,btl->bnst", q_lat, c_full.astype(dt)).astype(
-                    jnp.float32
+                scores = (
+                    jnp.einsum("bsnl,btl->bnst", q_lat, c_full.astype(dt)).astype(
+                        jnp.float32
+                    )
+                    * scale
                 )
-                * scale
-            )
-            scores = jnp.where(mask, scores, ops.attention.BIG_NEG)
-            probs = jax.nn.softmax(scores, axis=-1)
-            if cfg.attn_dropout > 0.0 and not deterministic:
-                keep = jax.random.bernoulli(
-                    self.make_rng("dropout"), 1.0 - cfg.attn_dropout, probs.shape
-                )
-                probs = probs * keep / (1.0 - cfg.attn_dropout)
-            probs = probs.astype(dt)
-            ctx = jnp.einsum("bnst,btl->bsnl", probs, c_full.astype(dt))
+                scores = jnp.where(mask, scores, ops.attention.BIG_NEG)
+                probs = jax.nn.softmax(scores, axis=-1)
+                if cfg.attn_dropout > 0.0 and not deterministic:
+                    keep = jax.random.bernoulli(
+                        self.make_rng("dropout"), 1.0 - cfg.attn_dropout, probs.shape
+                    )
+                    probs = probs * keep / (1.0 - cfg.attn_dropout)
+                probs = probs.astype(dt)
+                ctx = jnp.einsum("bnst,btl->bsnl", probs, c_full.astype(dt))
 
-        if R:
-            # the rotary tail of cat(latent, k_rope) is score-only; values
-            # decompress from the latent part alone
-            ctx = ctx[..., :lat]
-        out = jnp.einsum("bsnl,lnh->bsnh", ctx, w_v.astype(dt))
-        out = out.reshape(b, s, n * hd)
-        out = nn.Dense(cfg.dim, use_bias=False, dtype=dt, name="out")(out)
-        if cfg.attn_dropout > 0.0:
-            out = nn.Dropout(cfg.attn_dropout)(out, deterministic=deterministic)
+        with jax.named_scope("L_attn_proj"):
+            if R:
+                # the rotary tail of cat(latent, k_rope) is score-only; values
+                # decompress from the latent part alone
+                ctx = ctx[..., :lat]
+            out = jnp.einsum("bsnl,lnh->bsnh", ctx, w_v.astype(dt))
+            out = out.reshape(b, s, n * hd)
+            out = nn.Dense(cfg.dim, use_bias=False, dtype=dt, name="out")(out)
+            if cfg.attn_dropout > 0.0:
+                out = nn.Dropout(cfg.attn_dropout)(out, deterministic=deterministic)
         return out, cache
 
 
@@ -384,31 +387,34 @@ class MoELayer(nn.Module):
         h = cfg.expert_hidden
         e = cfg.n_experts
         dt = cfg.compute_dtype
-        xt = x.reshape(b * s, d).astype(dt)
-
-        gate_logits = nn.Dense(
-            e, use_bias=False, dtype=jnp.float32, name="gate"
-        )(xt.astype(jnp.float32))
-        if cfg.noisy_topk:
-            # layer created unconditionally so init (deterministic) still
-            # builds its params; noise applied only in train mode
-            noise_scale = jax.nn.softplus(
-                nn.Dense(e, use_bias=False, dtype=jnp.float32, name="noise")(
-                    xt.astype(jnp.float32)
+        with jax.named_scope("L_moe_gate"):
+            xt = x.reshape(b * s, d).astype(dt)
+            gate_logits = nn.Dense(
+                e, use_bias=False, dtype=jnp.float32, name="gate"
+            )(xt.astype(jnp.float32))
+            if cfg.noisy_topk:
+                # layer created unconditionally so init (deterministic) still
+                # builds its params; noise applied only in train mode
+                noise_scale = jax.nn.softplus(
+                    nn.Dense(
+                        e, use_bias=False, dtype=jnp.float32, name="noise"
+                    )(xt.astype(jnp.float32))
                 )
+                if not deterministic:
+                    gate_logits = gate_logits + noise_scale * jax.random.normal(
+                        self.make_rng("dropout"), gate_logits.shape
+                    )
+            bias = self.variable(
+                "moe_state", "routing_bias",
+                lambda: jnp.zeros((e,), jnp.float32),
             )
-            if not deterministic:
-                gate_logits = gate_logits + noise_scale * jax.random.normal(
-                    self.make_rng("dropout"), gate_logits.shape
-                )
-
-        bias = self.variable(
-            "moe_state", "routing_bias", lambda: jnp.zeros((e,), jnp.float32)
-        )
-        biased = gate_logits + bias.value if cfg.use_aux_free else gate_logits
-        # reference detail: both selection AND softmax weights use the biased
-        # logits (cell 23 scatters top_k_values of the biased tensor)
-        probs = ops.moe.topk_gate_probs(biased, cfg.top_experts)
+            biased = (
+                gate_logits + bias.value if cfg.use_aux_free else gate_logits
+            )
+            # reference detail: both selection AND softmax weights use the
+            # biased logits (cell 23 scatters top_k_values of the biased
+            # tensor)
+            probs = ops.moe.topk_gate_probs(biased, cfg.top_experts)
 
         init = nn.initializers.normal(0.02)
         w1 = self.param("w1", init, (e, d, h))
@@ -498,10 +504,11 @@ class MoELayer(nn.Module):
                 out = ops.moe.moe_dispatch_combine(xt, probs, expert_fn, cap)
 
         if cfg.use_shared_expert:
-            out = out + GLUFFN(
-                dim=d, hidden_dim=h, activation=ops.swish, dtype=dt,
-                name="shared_expert",
-            )(xt)
+            with jax.named_scope("L_moe_shared"):
+                out = out + GLUFFN(
+                    dim=d, hidden_dim=h, activation=ops.swish, dtype=dt,
+                    name="shared_expert",
+                )(xt)
 
         # one load reduction (+ one cross-shard collective under CP) shared
         # by the bias update and the sown stats. probs_g: along a ZeRO'd
@@ -534,13 +541,15 @@ class MoELayer(nn.Module):
             # stop_gradient the stats below use): f_e = selection fraction
             # scaled by E/k, P_e = mean softmax gate prob over ALL experts.
             # dsv3_loss_fn reads the sown value and adds weight * mean.
-            sel_frac = jnp.mean((probs > 0.0).astype(jnp.float32), axis=0)
-            f = sel_frac * (e / cfg.top_experts)
-            p_full = jnp.mean(
-                jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1),
-                axis=0,
-            )
-            self.sow("moe_metrics", "balance_loss", jnp.sum(f * p_full))
+            with jax.named_scope("L_moe_stats"):
+                sel_frac = jnp.mean((probs > 0.0).astype(jnp.float32), axis=0)
+                f = sel_frac * (e / cfg.top_experts)
+                p_full = jnp.mean(
+                    jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1),
+                    axis=0,
+                )
+                balance = jnp.sum(f * p_full)
+            self.sow("moe_metrics", "balance_loss", balance)
 
         if self.is_mutable_collection("moe_metrics"):
             # load-balance observability (SURVEY.md hard part #1): sown per
@@ -566,9 +575,11 @@ class MoELayer(nn.Module):
                     ),
                 )
             )
-            stats["bias_norm"] = jnp.linalg.norm(bias.value)
+            with jax.named_scope("L_moe_stats"):
+                stats["bias_norm"] = jnp.linalg.norm(bias.value)
             self.sow("moe_metrics", "stats", stats)
-        return out.reshape(b, s, d).astype(x.dtype)
+        with jax.named_scope("L_moe_combine"):
+            return out.reshape(b, s, d).astype(x.dtype)
 
 
 class DSV3DecoderLayer(nn.Module):
@@ -580,18 +591,24 @@ class DSV3DecoderLayer(nn.Module):
     def __call__(self, x, positions=None, cache=None, deterministic=True,
                  attend_len=None):
         cfg = self.cfg
+        # the block's norms and residual adds go to the layer scope next
+        # to them, so that no device time of the step is without one
+        with jax.named_scope("L_attn_proj"):
+            h = RMSNorm(eps=cfg.norm_eps, name="norm1")(x)
         h, cache = MLA(cfg, name="mla")(
-            RMSNorm(eps=cfg.norm_eps, name="norm1")(x),
+            h,
             positions=positions,
             cache=cache,
             deterministic=deterministic,
             attend_len=attend_len,
         )
-        x = x + h
-        x = x + MoELayer(cfg, name="moe")(
-            RMSNorm(eps=cfg.norm_eps, name="norm2")(x),
-            deterministic=deterministic,
-        )
+        with jax.named_scope("L_attn_proj"):
+            x = x + h
+        with jax.named_scope("L_moe_gate"):
+            h = RMSNorm(eps=cfg.norm_eps, name="norm2")(x)
+        h = MoELayer(cfg, name="moe")(h, deterministic=deterministic)
+        with jax.named_scope("L_moe_combine"):
+            x = x + h
         return x, cache
 
 
@@ -634,13 +651,14 @@ class DeepSeekV3(nn.Module):
             cfg.vocab_size, cfg.dim, dtype=cfg.compute_dtype,
             embedding_init=nn.initializers.normal(0.02), name="tok_emb",
         )
-        pe = ops.sinusoidal_position_encoding(cfg.block_size, cfg.dim)
         # no input dropout: the reference's forward goes embedding -> PE ->
         # decoder directly (cell 33); dropout appears only after the layer
         # stack (cell 31)
-        x = embed(tokens) + cfg.pe_scale * jnp.take(pe, positions, axis=0).astype(
-            cfg.compute_dtype
-        )
+        with jax.named_scope("L_embed"):
+            pe = ops.sinusoidal_position_encoding(cfg.block_size, cfg.dim)
+            x = embed(tokens) + cfg.pe_scale * jnp.take(
+                pe, positions, axis=0
+            ).astype(cfg.compute_dtype)
 
         new_caches = [] if caches is not None else None
         layer_cls = maybe_remat(DSV3DecoderLayer, cfg.remat, caches)
@@ -655,11 +673,14 @@ class DeepSeekV3(nn.Module):
             if new_caches is not None:
                 new_caches.append(c)
 
-        if cfg.dropout > 0.0:
-            x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
-        x = 2.0 * cfg.n_layers**-0.5 * x  # deepseek depth scaling (cell 31)
-        x = RMSNorm(eps=cfg.norm_eps, name="norm_f")(x)
-        logits = embed.attend(x.astype(cfg.compute_dtype))  # weight-tied head
+        with jax.named_scope("L_loss_head"):
+            if cfg.dropout > 0.0:
+                x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
+            # deepseek depth scaling (cell 31)
+            x = 2.0 * cfg.n_layers**-0.5 * x
+            x = RMSNorm(eps=cfg.norm_eps, name="norm_f")(x)
+            # weight-tied head
+            logits = embed.attend(x.astype(cfg.compute_dtype))
 
         if not (return_mtp and cfg.mtp_heads > 0):
             if return_hidden:

@@ -26,6 +26,7 @@ _AUTO_CHUNK_ELEMENTS = 2**28
 _AUTO_CHUNK_ROWS = 8192
 
 
+@jax.named_scope("L_loss_head")
 def cross_entropy(
     logits: jax.Array,
     labels: jax.Array,
@@ -174,6 +175,7 @@ def vae_loss(
     return bce + kl, bce, kl
 
 
+@jax.named_scope("L_loss_head")
 def mtp_loss(
     logits: jax.Array,
     tokens: jax.Array,

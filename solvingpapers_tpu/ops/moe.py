@@ -27,12 +27,14 @@ def topk_gate_probs(gate_logits: jax.Array, k: int) -> jax.Array:
     """(T, E) logits -> (T, E) probs: softmax over the top-k entries per row,
     zero elsewhere (deepseekv3 cell 23's masked-scatter softmax; computed in
     float32)."""
-    logits32 = gate_logits.astype(jnp.float32)
-    kth = jax.lax.top_k(logits32, k)[0][..., -1:]
-    masked = jnp.where(logits32 >= kth, logits32, BIG_NEG)
-    return jax.nn.softmax(masked, axis=-1)
+    with jax.named_scope("L_moe_gate"):
+        logits32 = gate_logits.astype(jnp.float32)
+        kth = jax.lax.top_k(logits32, k)[0][..., -1:]
+        masked = jnp.where(logits32 >= kth, logits32, BIG_NEG)
+        return jax.nn.softmax(masked, axis=-1)
 
 
+@jax.named_scope("L_moe_stats")
 def aux_free_bias_update(
     probs: jax.Array, bias: jax.Array, rate: float, axis_names=None, ci=None
 ) -> jax.Array:
@@ -59,6 +61,7 @@ def _psum_axes(x: jax.Array, axis_names) -> tuple:
     return tuple(a for a in axis_names if a in vma)
 
 
+@jax.named_scope("L_moe_stats")
 def expert_load(probs: jax.Array, axis_names=None) -> jax.Array:
     """(E,) routed probability mass per expert under stop_gradient,
     psum'd over `axis_names` when inside shard_map."""
@@ -89,6 +92,28 @@ def _dispatch_slots(probs: jax.Array, capacity: int):
     return sel, pos, keep
 
 
+def _one_hot_dispatch(x: jax.Array, probs: jax.Array, capacity: int):
+    """The (T, E, C) one-hot of kept (token, expert) pairs and the tokens
+    gathered through it into (E, C, D) expert slots."""
+    with jax.named_scope("L_moe_gate"):
+        _, pos, keep = _dispatch_slots(probs, capacity)
+        # dropped/unselected tokens index the sentinel `capacity`, which
+        # one_hot encodes as an all-zero row — no extra masking needed
+        dispatch = jax.nn.one_hot(
+            jnp.where(keep, pos, capacity), capacity, dtype=x.dtype
+        )
+    with jax.named_scope("L_moe_dispatch"):
+        xe = jnp.einsum("tec,td->ecd", dispatch, x)
+    return dispatch, xe
+
+
+def _weighted_combine(dispatch: jax.Array, probs: jax.Array, ye: jax.Array):
+    """Expert outputs (E, C, D) back to (T, D), weighted by the gate."""
+    with jax.named_scope("L_moe_combine"):
+        combine = dispatch * probs[..., None].astype(dispatch.dtype)
+        return jnp.einsum("tec,ecd->td", combine, ye)
+
+
 def moe_dispatch_combine(
     x: jax.Array,
     probs: jax.Array,
@@ -102,18 +127,13 @@ def moe_dispatch_combine(
     probability mass contributes nothing) — set capacity_factor high enough
     that drops are rare; the dense path below is drop-free.
     """
-    sel, pos, keep = _dispatch_slots(probs, capacity)
-    # (T, E, C); dropped/unselected tokens index the sentinel `capacity`,
-    # which one_hot encodes as an all-zero row — no extra masking needed
-    dispatch = jax.nn.one_hot(
-        jnp.where(keep, pos, capacity), capacity, dtype=x.dtype
-    )
-    xe = jnp.einsum("tec,td->ecd", dispatch, x)
-    ye = expert_fn(xe)
-    combine = dispatch * probs[..., None].astype(x.dtype)
-    return jnp.einsum("tec,ecd->td", combine, ye)
+    dispatch, xe = _one_hot_dispatch(x, probs, capacity)
+    with jax.named_scope("L_moe_experts"):
+        ye = expert_fn(xe)
+    return _weighted_combine(dispatch, probs, ye)
 
 
+@jax.named_scope("L_moe_stats")
 def dispatch_drop_fraction(
     probs: jax.Array, capacity: int, axis_names=None
 ) -> jax.Array:
@@ -134,6 +154,7 @@ def dispatch_drop_fraction(
     return (routed - kept) / jnp.maximum(routed, 1.0)
 
 
+@jax.named_scope("L_moe_stats")
 def load_balance_stats(
     probs: jax.Array, axis_names=None, ci=None
 ) -> dict[str, jax.Array]:
@@ -177,7 +198,8 @@ def moe_expert_sliced_combine(
     partial = moe_dispatch_combine(
         x, probs_local, lambda xe: expert_fn(xe, start), capacity
     )
-    return jax.lax.psum(partial, axis_name)
+    with jax.named_scope("L_moe_combine"):
+        return jax.lax.psum(partial, axis_name)
 
 
 def moe_all_to_all_combine(
@@ -221,24 +243,22 @@ def moe_all_to_all_combine(
     e_local = e // ep
     start = jax.lax.axis_index(axis_name) * e_local
 
-    sel, pos, keep = _dispatch_slots(probs, capacity)
-    dispatch = jax.nn.one_hot(
-        jnp.where(keep, pos, capacity), capacity, dtype=x.dtype
-    )  # (T, E, C)
-    xe = jnp.einsum("tec,td->ecd", dispatch, x)  # (E, C, D) — my tokens
+    dispatch, xe = _one_hot_dispatch(x, probs, capacity)  # my tokens
     # ship: split the expert dim across members, concat received blocks
     # along the slot dim (source-member order) -> (E/ep, ep*C, D)
-    xe = jax.lax.all_to_all(
-        xe, axis_name, split_axis=0, concat_axis=1, tiled=True
-    )
-    ye = expert_fn(xe, start)  # (E/ep, ep*C, D) through MY experts
+    with jax.named_scope("L_moe_dispatch"):
+        xe = jax.lax.all_to_all(
+            xe, axis_name, split_axis=0, concat_axis=1, tiled=True
+        )
+    with jax.named_scope("L_moe_experts"):
+        ye = expert_fn(xe, start)  # (E/ep, ep*C, D) through MY experts
     # ship back: split the slot dim by destination member, concat along the
     # expert dim -> (E, C, D) with exactly my original slot layout
-    ye = jax.lax.all_to_all(
-        ye, axis_name, split_axis=1, concat_axis=0, tiled=True
-    )
-    combine = dispatch * probs[..., None].astype(x.dtype)
-    return jnp.einsum("tec,ecd->td", combine, ye)
+    with jax.named_scope("L_moe_combine"):
+        ye = jax.lax.all_to_all(
+            ye, axis_name, split_axis=1, concat_axis=0, tiled=True
+        )
+    return _weighted_combine(dispatch, probs, ye)
 
 
 def ep_comm_elements(
@@ -271,5 +291,7 @@ def moe_dense_combine(x: jax.Array, probs: jax.Array, expert_fn_all) -> jax.Arra
     `expert_fn_all((T, D)) -> (E, T, D)`. Exact semantics of the reference's
     per-expert loop; costs E/k times the dispatch path's FLOPs.
     """
-    ye = expert_fn_all(x)  # (E, T, D)
-    return jnp.einsum("te,etd->td", probs.astype(x.dtype), ye)
+    with jax.named_scope("L_moe_experts"):
+        ye = expert_fn_all(x)  # (E, T, D)
+    with jax.named_scope("L_moe_combine"):
+        return jnp.einsum("te,etd->td", probs.astype(x.dtype), ye)

@@ -16,7 +16,9 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import json
 import math
+import os
 import time
 from typing import Any, Callable, Iterator
 
@@ -28,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.checkpoint import CheckpointManager
-from solvingpapers_tpu.metrics import ConsoleWriter, MetricsWriter
+from solvingpapers_tpu.metrics import ConsoleWriter, MetricsWriter, hlo_cost
 from solvingpapers_tpu.sharding import (
     LM_RULES,
     MeshConfig,
@@ -43,6 +45,8 @@ from solvingpapers_tpu.train.state import TrainState
 
 # loss_fn(model, params, batch, rng, model_state, train) -> (loss, aux, new_model_state)
 LossFn = Callable[..., tuple[jax.Array, dict, Any]]
+
+_NULL_SCOPE = contextlib.nullcontext()
 
 def _pp_param_spec(path, _leaf) -> P:
     """shard_map in_spec for pipeline-parallel params: the stage-stacked
@@ -88,8 +92,16 @@ class TrainConfig:
     scan_steps: int = 1
     # aux subsystems (SURVEY.md §5)
     debug_nans: bool = False  # jax_debug_nans: fail fast at the faulting op
-    profile_dir: str | None = None  # jax.profiler trace output (TensorBoard)
-    profile_steps: tuple[int, int] = (10, 15)  # [start, stop) steps to trace
+    # jax.profiler trace of the steps [start, stop) counted from where
+    # fit() starts: the device is fenced before the trace starts and before
+    # it stops, so the window holds exactly stop - start whole executions
+    # of the train step; the loop's spans (data_wait, train_dispatch,
+    # log_fetch, eval, callback, checkpoint) are TraceAnnotations on the
+    # host plane of the same file, each step under a StepTraceAnnotation
+    # "train"; `device_scopes.json` beside the trace maps the programs'
+    # instructions to the layers of metrics/hlo_cost.LAYER_SCOPES.
+    profile_dir: str | None = None
+    profile_steps: tuple[int, int] = (10, 15)
     # flight recorder (metrics/trace.py): record data-wait / step / eval /
     # checkpoint / callback spans on a "train" track and export a Chrome
     # trace-event JSON here when fit() ends (also on exceptions — the
@@ -207,11 +219,18 @@ class Trainer:
         self._ledger = None
         self._mesh_obs = None
         self._status = None
+        # programs already made known to metrics/hlo_cost.py
+        self._known_programs: set[str] = set()
 
     def _dispatch(self, name: str, jitted, state, batch):
         """Run a jitted step, through the compile registry when the
         observatory is on (signature = the batch's leaf shapes; the
-        state's shapes are fixed after init) — one branch when off."""
+        state's shapes are fixed after init) — one branch when off. A
+        program's first dispatch leaves its abstract arguments with
+        `hlo_cost.register_program`, under the name a profile shows."""
+        if name not in self._known_programs:
+            self._known_programs.add(name)
+            hlo_cost.register_program(f"jit_{name}", jitted, (state, batch))
         if self._registry is None:
             return jitted(state, batch)
         key = tuple(
@@ -791,12 +810,14 @@ class Trainer:
                 (loss, (aux, new_ms)), grads = jax.value_and_grad(
                     loss_wrap, has_aux=True
                 )(state.params)
-            grad_norm = optax.global_norm(grads)
-            new_state = state.apply_gradients(grads, new_ms)
+            with jax.named_scope("L_optimizer"):
+                grad_norm = optax.global_norm(grads)
+                new_state = state.apply_gradients(grads, new_ms)
+                lr = self.schedule(state.step)
             metrics = {
                 "train_loss": loss,
                 "grad_norm": grad_norm,
-                "lr": self.schedule(state.step),
+                "lr": lr,
                 **{f"train_{k}": v for k, v in aux.items()},
             }
             return new_state, metrics
@@ -877,19 +898,70 @@ class Trainer:
             recorder = FlightRecorder()
             t_fit0 = recorder.clock()
 
-        def _next(it):
-            if recorder is None:
-                return next(it)
-            with recorder.span("data_wait", "train", "train"):
-                return next(it)
+        # with a profile or the recorder asked for, every section the loop
+        # spends host time in is a jax.profiler.TraceAnnotation, so that it
+        # lands in the profiler's own file on the device operations' clock
+        annotate = bool(cfg.profile_dir or cfg.trace_path)
+
+        @contextlib.contextmanager
+        def _annotated(name, kw):
+            with jax.profiler.TraceAnnotation(name, **kw):
+                if recorder is None:
+                    yield
+                else:
+                    with recorder.span(name, "train", "train", **kw):
+                        yield
 
         def _span(name, **kw):
-            """Recorder span, or a no-op context when tracing is off —
-            one `with` per instrumented section instead of a duplicated
-            traced/untraced call at every site."""
-            if recorder is None:
-                return contextlib.nullcontext()
-            return recorder.span(name, "train", "train", **kw)
+            """The one instrumented-section helper: a profiler annotation
+            (and the recorder's span beside it) when tracing is on, a
+            shared no-op context when it is off — always one branch."""
+            return _annotated(name, kw) if annotate else _NULL_SCOPE
+
+        def _step_scope(step_num):
+            if annotate:
+                return jax.profiler.StepTraceAnnotation(
+                    "train", step_num=step_num
+                )
+            return _NULL_SCOPE
+
+        # host seconds since the last logged row: waiting for the batch
+        # iterator, and blocked on the device (the fetch at the log cadence
+        # and the fences before eval, callbacks and checkpoints)
+        wait_s = 0.0
+        blocked_s = 0.0
+
+        def _next(it):
+            nonlocal wait_s
+            t0 = time.perf_counter()
+            with _span("data_wait"):
+                batch = next(it)
+            wait_s += time.perf_counter() - t0
+            return batch
+
+        def _fence():
+            """Wait for the steps in flight (not counted as loop time)."""
+            nonlocal blocked_s
+            t0 = time.perf_counter()
+            jax.device_get(metrics["train_loss"])
+            blocked_s += time.perf_counter() - t0
+
+        def _stop_profile() -> float:
+            """Close the profile between two steps of the device and leave
+            the programs' layer map beside it; returns the seconds spent
+            (the map costs a compile of each program that ran)."""
+            t0 = time.perf_counter()
+            jax.block_until_ready(state)
+            jax.profiler.stop_trace()
+            scopes = {
+                f"jit_{name}": hlo_cost.program_scopes(f"jit_{name}")
+                for name in sorted(self._known_programs)
+            }
+            with open(
+                os.path.join(cfg.profile_dir, "device_scopes.json"), "w"
+            ) as f:
+                json.dump(scopes, f)
+            return time.perf_counter() - t0
 
         if state is None:
             first = _next(batch_iter)
@@ -1068,39 +1140,71 @@ class Trainer:
                 # inside one scan window, checking start first would open
                 # and immediately close an empty trace in the same iteration
                 if profiling and step - start_step >= cfg.profile_steps[1]:
-                    jax.profiler.stop_trace()
+                    t_prev += _stop_profile()
                     profiling = False
                     profile_stopped = True
                 if cfg.profile_dir and not profiling and not profile_stopped \
                         and step - start_step >= cfg.profile_steps[0]:
-                    jax.profiler.start_trace(cfg.profile_dir)
+                    # the host runs steps ahead of the device: fence, so
+                    # the trace starts between two steps
+                    t_prof = time.perf_counter()
+                    jax.block_until_ready(state)
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0  # the loop's spans suffice
+                    jax.profiler.start_trace(cfg.profile_dir,
+                                             profiler_options=opts)
                     profiling = True
-                if kk == 1:
-                    batch = first if (first is not None and step == start_step) \
-                        else _next(batch_iter)
+                    t_prev += time.perf_counter() - t_prof
+                with _step_scope(step):
+                    window = []
                     if first is not None and step == start_step:
+                        window.append(first)
                         first = None
+                    while len(window) < kk:
+                        window.append(_next(batch_iter))
+                    if kk == 1:
+                        batch = window[0]
+                        name, jitted = "train_step", self._train_step
+                    else:
+                        # device arrays (e.g. lm_batch_iterator's on-device
+                        # crops) stack with jnp — np.stack would force K
+                        # synchronous D2H pulls per window; host arrays
+                        # stack on host so the window ships as ONE transfer
+                        batch = jax.tree.map(
+                            lambda *xs: (
+                                jnp.stack(xs) if isinstance(xs[0], jax.Array)
+                                else np.stack(xs)
+                            ),
+                            *window,
+                        )
+                        name, jitted = (
+                            "train_step_scan", self._train_step_scan
+                        )
                     exclude_compile = (
-                        scan_k > 1 and not tail_warmed and step != start_step
+                        kk == 1 and scan_k > 1 and not tail_warmed
+                        and step != start_step
                     )
                     if exclude_compile:
                         # first single-step call of a scan-windowed run (the
                         # ragged tail or a resume re-align): _train_step has
                         # not been traced yet, so fence and keep its compile
                         # out of the step timing, like eval/checkpoint
-                        jax.device_get(metrics["train_loss"])
+                        _fence()
                         t_tail = time.perf_counter()
                     t_span = _obs_clock() if _fenced else 0.0
-                    state, metrics = self._dispatch(
-                        "train_step", self._train_step, state, batch
-                    )
+                    with _span("train_dispatch"):
+                        state, metrics = self._dispatch(
+                            name, jitted, state, batch
+                        )
                     if _fenced:
+                        t_block = time.perf_counter()
                         jax.block_until_ready(metrics)
+                        blocked_s += time.perf_counter() - t_block
                         d_span = _obs_clock() - t_span
                         compiled = step == start_step
                         if recorder is not None:
                             recorder.complete("step", "train", "train",
-                                              ts=t_span, dur=d_span, steps=1,
+                                              ts=t_span, dur=d_span, steps=kk,
                                               compiled=int(compiled))
                         if not compiled:
                             # goodput's numerator counts TRAINING time;
@@ -1111,7 +1215,9 @@ class Trainer:
                             # honestly read as low goodput)
                             step_span_total += d_span
                             if self._mesh_obs is not None:
-                                self._mesh_obs.observe_step(t_span, d_span)
+                                self._mesh_obs.observe_step(
+                                    t_span, d_span, steps=kk
+                                )
                     if exclude_compile:
                         jax.device_get(metrics["train_loss"])
                         t_prev += time.perf_counter() - t_tail
@@ -1119,41 +1225,8 @@ class Trainer:
                         # next log row's denominator too (else step_time /
                         # tokens_per_sec overstate by the excluded step)
                         excluded_steps += 1
-                    tail_warmed = True
-                else:
-                    window = []
-                    if first is not None and step == start_step:
-                        window.append(first)
-                        first = None
-                    while len(window) < kk:
-                        window.append(_next(batch_iter))
-                    # device arrays (e.g. lm_batch_iterator's on-device
-                    # crops) stack with jnp — np.stack would force K
-                    # synchronous D2H pulls per window; host arrays stack
-                    # on host so the window ships as ONE transfer
-                    batch = jax.tree.map(
-                        lambda *xs: (jnp.stack(xs) if isinstance(xs[0], jax.Array)
-                                     else np.stack(xs)),
-                        *window,
-                    )
-                    t_span = _obs_clock() if _fenced else 0.0
-                    state, metrics = self._dispatch(
-                        "train_step_scan", self._train_step_scan, state, batch
-                    )
-                    if _fenced:
-                        jax.block_until_ready(metrics)
-                        d_span = _obs_clock() - t_span
-                        compiled = step == start_step
-                        if recorder is not None:
-                            recorder.complete("step", "train", "train",
-                                              ts=t_span, dur=d_span, steps=kk,
-                                              compiled=int(compiled))
-                        if not compiled:  # see the kk == 1 branch
-                            step_span_total += d_span
-                            if self._mesh_obs is not None:
-                                self._mesh_obs.observe_step(
-                                    t_span, d_span, steps=kk
-                                )
+                    if kk == 1:
+                        tail_warmed = True
                 if step == start_step:
                     # fence the first step so compile time never pollutes
                     # step_time/tokens_per_sec/MFU metrics; the timed window
@@ -1167,6 +1240,7 @@ class Trainer:
                         self._probe_pipeline_stages(state, batch)
                     t_prev = time.perf_counter()
                     last_log_step = end
+                    wait_s = blocked_s = 0.0
 
                 run_eval = (
                     cfg.eval_every > 0 and eval_iter_fn
@@ -1183,7 +1257,7 @@ class Trainer:
                     # misattributed to eval and subtracted from the step
                     # timing (the source of impossible tokens/sec spikes on
                     # eval-aligned log rows)
-                    jax.device_get(metrics["train_loss"])
+                    _fence()
                 if run_eval:
                     t_eval = time.perf_counter()
                     with _span("eval", step=end):
@@ -1200,16 +1274,28 @@ class Trainer:
                     t_prev += time.perf_counter() - t_cb
 
                 if end % max(cfg.log_every, 1) == 0 or end == cfg.steps:
-                    metrics = jax.device_get(metrics)  # blocks; also fences timing
+                    # the one place the loop blocks; also fences timing
+                    t_fetch = time.perf_counter()
+                    with _span("log_fetch", step=end):
+                        metrics = jax.device_get(metrics)
                     if step == start_step:
                         # the compile step is excluded from the timed window;
                         # report its metrics without timing-derived fields
                         pass
                     else:
                         now = time.perf_counter()
-                        dt = (now - t_prev) / max(
-                            end - last_log_step - excluded_steps, 1
-                        )
+                        n_timed = max(end - last_log_step - excluded_steps, 1)
+                        wall = now - t_prev
+                        dt = wall / n_timed
+                        blocked_s += now - t_fetch
+                        # where the host's share of the loop went, a step:
+                        # in next() of the batch iterator, and everywhere
+                        # else that is not waiting for the device
+                        metrics["data_wait_ms"] = 1e3 * wait_s / n_timed
+                        metrics["host_loop_ms"] = 1e3 * max(
+                            wall - wait_s - blocked_s, 0.0
+                        ) / n_timed
+                        wait_s = blocked_s = 0.0
                         t_prev = now
                         last_log_step = end
                         excluded_steps = 0
@@ -1245,7 +1331,7 @@ class Trainer:
                         and end % ckpt.save_every == 0:
                     # keep the save (fence + D2H snapshot; the disk write is
                     # already async) out of step timing, like eval/callbacks
-                    jax.device_get(metrics["train_loss"])
+                    _fence()
                     t_save = time.perf_counter()
                     with _span("checkpoint", step=end):
                         ckpt.maybe_save(end, _pure_state(state))
@@ -1257,6 +1343,9 @@ class Trainer:
             if ckpt is not None:
                 final_step = int(jax.device_get(state.step))
                 ckpt.maybe_save(final_step, _pure_state(state), force=True)
+            if profiling:  # fit ended inside the profile window
+                _stop_profile()
+                profiling = False
         finally:
             if self._status is not None:
                 self._status.close()

@@ -63,6 +63,7 @@ def dsv3_init_fn(model, rngs, batch):
     return variables["params"], {"moe_state": variables["moe_state"]}
 
 
+@jax.named_scope("L_moe_stats")
 def _aggregate_moe_metrics(collection) -> dict:
     """Mean each sown per-layer MoE stat (models/deepseekv3.py MoELayer)
     into one train-metric scalar: moe_load_entropy, moe_load_max_fraction,
@@ -123,10 +124,12 @@ def dsv3_loss_fn(model, params, batch, rng, model_state, train):
         logits, mtp_logits = out, None
 
     main = ops.cross_entropy(logits, batch["y"])
-    aux = {"perplexity": jnp.exp(main), **moe_metrics}
+    with jax.named_scope("L_loss_head"):
+        aux = {"perplexity": jnp.exp(main), **moe_metrics}
     loss = main
     if balance_terms:
-        bal = jnp.mean(jnp.stack(balance_terms))
+        with jax.named_scope("L_moe_stats"):
+            bal = jnp.mean(jnp.stack(balance_terms))
         aux["balance_loss"] = bal
         loss = loss + cfg.balance_loss_weight * bal
     if mtp_logits is not None:

@@ -83,6 +83,19 @@ def test_flash_phase_runs_but_cannot_pass_interpreted(token_file, tmp_path):
         chip_smoke.flash_train_phase(cfg, str(tmp_path / "flash.jsonl"))
 
 
+def test_profile_phase_runs_but_finds_no_device_plane(token_file, tmp_path):
+    """On the CPU the profiled steps run and the trainer leaves the trace
+    and its layer map; the phase then fails at the look for a TPU plane."""
+    prof = tmp_path / "profile"
+    cfg = tiny_config(chip_smoke.FLAGSHIP, token_file, steps=6,
+                      model_overrides=dict(block_size=64),
+                      profile_dir=str(prof), profile_steps=(2, 4))
+    with pytest.raises(chip_smoke.SmokeFailure, match="/device:TPU:0"):
+        chip_smoke.profile_phase(cfg, str(tmp_path))
+    assert (prof / "device_scopes.json").exists()
+    assert list(prof.glob("plugins/profile/*/*.xplane.pb"))
+
+
 def test_flash_dropout_check_needs_the_hardware_prng():
     with pytest.raises(ValueError, match="hardware PRNG"):
         chip_smoke.flash_dropout_check(0)
