@@ -420,7 +420,7 @@ def device_scopes(hlo_text: str) -> dict[str, DeviceScope]:
 
     The layer is the innermost vocabulary token of the instruction's own
     `op_name` (`jit(train_step)/transpose(jvp(DeepSeekV3))/layer_0/moe/
-    L_moe_combine/tec,ecd->td/dot_general` -> `L_moe_combine`; a
+    L_moe_combine/jit(_take)/gather` -> `L_moe_combine`; a
     `tpu_custom_call` carries its kernel's name there, inside the layer
     that called it). A fusion's line holds its root's metadata; where it
     holds none the fusion takes the last `op_name` of the computation it

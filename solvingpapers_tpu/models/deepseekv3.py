@@ -19,7 +19,7 @@ TPU-first divergences (documented per SURVEY.md hard part #2):
   * One latent per layer shared by all heads with per-head decompression
     (the paper's MLA); the reference gives each head its own W_dkv and
     threads one growing cache through heads AND layers (cell 27 quirk).
-  * MoE dispatch is static-shape one-hot einsums over expert capacity slots
+  * MoE dispatch is static-shape row gathers into expert capacity slots
     (ops/moe.py), not a python loop; expert weights are stacked (E, ...)
     so the `expert` mesh axis shards them (EP via GSPMD all_to_all).
   * MTP is computed for all positions in parallel, not a per-position

@@ -7,15 +7,21 @@ experts, weighted expert combine, shared expert, and the no-grad bias update
 `bias += rate * sign(mean(load) - load)`.
 
 TPU-first: the reference's python loop over experts with boolean gather/
-scatter becomes static-shape one-hot einsum dispatch (tokens -> expert
-capacity slots) so the whole layer is three MXU einsums; a dense
-all-experts path is kept as the numerics reference (exact — no capacity
-drops) and for tiny configs. Expert weights are stacked (E, ...) arrays so
-an `expert` mesh axis shards them directly and GSPMD inserts the
-all_to_alls (SURVEY.md §2.3 EP row).
+scatter becomes static-shape dispatch into (E, C, D) expert capacity slots.
+The slot assignment is two small integer maps (token -> its slots, slot ->
+its token) and rows move through them by gather, forward and backward: the
+maps are one partial permutation read from both ends, so no scatter and no
+product with a (T, E, C) one-hot is needed, and the MXU runs the experts'
+einsums only. A dense all-experts path is kept as the numerics reference
+(exact — no capacity drops) and for tiny configs. Expert weights are
+stacked (E, ...) arrays so an `expert` mesh axis shards them directly and
+GSPMD inserts the all_to_alls (SURVEY.md §2.3 EP row).
 """
 
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -92,26 +98,130 @@ def _dispatch_slots(probs: jax.Array, capacity: int):
     return sel, pos, keep
 
 
-def _one_hot_dispatch(x: jax.Array, probs: jax.Array, capacity: int):
-    """The (T, E, C) one-hot of kept (token, expert) pairs and the tokens
-    gathered through it into (E, C, D) expert slots."""
+def _vary_alike(*xs: jax.Array) -> tuple:
+    """Inside shard_map, widen every argument's varying axes to their union
+    (outside it, nothing): a `custom_vjp` rule returns cotangents of its
+    inputs' own types, so the cast, whose transpose is the psum, has to
+    happen before the call and not inside the rule."""
+    axes = frozenset().union(*(jax.typeof(x).vma for x in xs))
+
+    def widen(x):
+        missing = tuple(axes - jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return tuple(widen(x) for x in xs)
+
+
+def _take_rows(rows: jax.Array, idx: jax.Array) -> jax.Array:
+    """`rows[idx]` along axis 0; the sentinel `len(rows)` reads a zero row."""
+    return jnp.take(rows, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _slot_rows(slots: jax.Array, tok_pos: jax.Array) -> list[jax.Array]:
+    """For each expert, in float32, the (T, D) rows of its (C, D) slots that
+    the tokens' kept pairs point at, zeros for the other tokens. A gather
+    an expert, not one through a flat (E*C, D) view: XLA's row gather on
+    the v5e costs 3.4 ns a row from an operand of up to 98,304 rows and
+    four times that from one of 131,072 (PERF.md, PR 26)."""
+    return [
+        _take_rows(slots[e], tok_pos[:, e]).astype(jnp.float32)
+        for e in range(slots.shape[0])
+    ]
+
+
+class _Routes(NamedTuple):
+    """The slot assignment as index maps. The kept (token, expert) pairs
+    and the filled slots are one partial permutation read from both ends,
+    so rows move by gather in either direction, forward and backward."""
+
+    tok_pos: jax.Array  # (T, E) slot of a kept pair within its expert, else C
+    slot_tok: jax.Array  # (E, C) token held by the slot, else T
+    slot_w: jax.Array  # (E, C) gate weight of the slot's pair, float32
+
+
+def _routes(probs: jax.Array, capacity: int) -> _Routes:
+    t = probs.shape[0]
     with jax.named_scope("L_moe_gate"):
+        probs = jax.lax.stop_gradient(probs).astype(jnp.float32)
         _, pos, keep = _dispatch_slots(probs, capacity)
-        # dropped/unselected tokens index the sentinel `capacity`, which
-        # one_hot encodes as an all-zero row — no extra masking needed
-        dispatch = jax.nn.one_hot(
-            jnp.where(keep, pos, capacity), capacity, dtype=x.dtype
-        )
+        # `pos` counts an expert's tokens in token order, so its kept token
+        # ids in ascending order ARE its slots in order; every other token
+        # sorts behind them as the sentinel T
+        tok = jnp.where(keep.T, jnp.arange(t, dtype=jnp.int32), t)
+        slot_tok, slot_w = jax.lax.sort((tok, probs.T), dimension=1, num_keys=1)
+        if capacity <= t:
+            slot_tok, slot_w = slot_tok[:, :capacity], slot_w[:, :capacity]
+        else:
+            pad = ((0, 0), (0, capacity - t))
+            slot_tok = jnp.pad(slot_tok, pad, constant_values=t)
+            slot_w = jnp.pad(slot_w, pad)
+    return _Routes(jnp.where(keep, pos, capacity), slot_tok, slot_w)
+
+
+@jax.custom_vjp
+def _dispatch_rows(x, tok_pos, slot_tok):
+    return _take_rows(x, slot_tok)
+
+
+def _dispatch_rows_fwd(x, tok_pos, slot_tok):
+    return _take_rows(x, slot_tok), tok_pos
+
+
+def _dispatch_rows_bwd(tok_pos, dxe):
+    # each token reads back the slots it was copied to: the transpose of a
+    # gather through a partial permutation is the gather through its inverse
+    dx = functools.reduce(jnp.add, _slot_rows(dxe, tok_pos))
+    return dx.astype(dxe.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ye, probs, tok_pos, slot_tok, slot_w):
+    probs = probs.astype(jnp.float32)
+    out = functools.reduce(jnp.add, [
+        rows * probs[:, e, None]
+        for e, rows in enumerate(_slot_rows(ye, tok_pos))
+    ])
+    return out.astype(ye.dtype)
+
+
+def _combine_rows_fwd(ye, probs, tok_pos, slot_tok, slot_w):
+    out = _combine_rows(ye, probs, tok_pos, slot_tok, slot_w)
+    return out, (ye, probs, tok_pos, slot_tok, slot_w)
+
+
+def _combine_rows_bwd(res, dout):
+    ye, probs, tok_pos, slot_tok, slot_w = res
+    dye = _take_rows(dout, slot_tok).astype(jnp.float32) * slot_w[..., None]
+    dout = dout.astype(jnp.float32)
+    dprobs = jnp.stack(
+        [jnp.sum(rows * dout, axis=-1) for rows in _slot_rows(ye, tok_pos)],
+        axis=1,
+    )
+    return dye.astype(ye.dtype), dprobs.astype(probs.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _dispatch(x: jax.Array, probs: jax.Array, capacity: int):
+    """The index maps of the kept (token, expert) pairs and the tokens
+    gathered through them into (E, C, D) expert slots; an empty slot holds
+    zeros."""
+    routes = _routes(probs, capacity)
     with jax.named_scope("L_moe_dispatch"):
-        xe = jnp.einsum("tec,td->ecd", dispatch, x)
-    return dispatch, xe
+        xe = _dispatch_rows(*_vary_alike(x, routes.tok_pos, routes.slot_tok))
+    return routes, xe
 
 
-def _weighted_combine(dispatch: jax.Array, probs: jax.Array, ye: jax.Array):
-    """Expert outputs (E, C, D) back to (T, D), weighted by the gate."""
+def _weighted_combine(routes: _Routes, probs: jax.Array, ye: jax.Array):
+    """Expert outputs (E, C, D) back to (T, D): every token gathers the
+    slots of its kept pairs and sums them weighted by the gate, in
+    float32, cast once to the experts' dtype."""
     with jax.named_scope("L_moe_combine"):
-        combine = dispatch * probs[..., None].astype(dispatch.dtype)
-        return jnp.einsum("tec,ecd->td", combine, ye)
+        return _combine_rows(*_vary_alike(ye, probs, *routes))
 
 
 def moe_dispatch_combine(
@@ -127,10 +237,10 @@ def moe_dispatch_combine(
     probability mass contributes nothing) — set capacity_factor high enough
     that drops are rare; the dense path below is drop-free.
     """
-    dispatch, xe = _one_hot_dispatch(x, probs, capacity)
+    routes, xe = _dispatch(x, probs, capacity)
     with jax.named_scope("L_moe_experts"):
         ye = expert_fn(xe)
-    return _weighted_combine(dispatch, probs, ye)
+    return _weighted_combine(routes, probs, ye)
 
 
 @jax.named_scope("L_moe_stats")
@@ -217,9 +327,9 @@ def moe_all_to_all_combine(
     Contract (differs from moe_expert_sliced_combine, which replicates
     tokens): `x` (T_local, D) / `probs` (T_local, E) are this member's
     TOKEN SHARD over `axis_name`; expert weights are sharded over the same
-    axis. Each member one-hot-dispatches its local tokens into per-expert
-    capacity slots (E, C, D), one tiled `all_to_all` ships each expert's
-    slot block to the member that owns it — landing as (E/ep, ep*C, D),
+    axis. Each member gathers its local tokens into per-expert capacity
+    slots (E, C, D) through the slot maps, one tiled `all_to_all` ships
+    each expert's slot block to its owner — landing as (E/ep, ep*C, D),
     slot blocks ordered by source member — the local expert matmul runs via
     ``expert_fn((E/ep, ep*C, D), start)`` (same `start` slicing convention
     as the sliced op), a second `all_to_all` ships results back to the
@@ -243,7 +353,7 @@ def moe_all_to_all_combine(
     e_local = e // ep
     start = jax.lax.axis_index(axis_name) * e_local
 
-    dispatch, xe = _one_hot_dispatch(x, probs, capacity)  # my tokens
+    routes, xe = _dispatch(x, probs, capacity)  # my tokens
     # ship: split the expert dim across members, concat received blocks
     # along the slot dim (source-member order) -> (E/ep, ep*C, D)
     with jax.named_scope("L_moe_dispatch"):
@@ -258,7 +368,7 @@ def moe_all_to_all_combine(
         ye = jax.lax.all_to_all(
             ye, axis_name, split_axis=1, concat_axis=0, tiled=True
         )
-    return _weighted_combine(dispatch, probs, ye)
+    return _weighted_combine(routes, probs, ye)
 
 
 def ep_comm_elements(

@@ -78,6 +78,76 @@ def test_dispatch_equals_dense_when_capacity_ample():
     )
 
 
+def one_hot_dispatch_combine(x, probs, expert_fn, capacity):
+    """The plain reference of `moe_dispatch_combine`: rows moved by products
+    with the (T, E, C) one-hot of the kept (token, expert) pairs, float32."""
+    _, pos, keep = ops.moe._dispatch_slots(probs, capacity)
+    dispatch = jax.nn.one_hot(
+        jnp.where(keep, pos, capacity), capacity, dtype=jnp.float32
+    )
+    xe = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))
+    ye = expert_fn(xe.astype(x.dtype)).astype(jnp.float32)
+    out = jnp.einsum("tec,ecd->td", dispatch * probs[..., None], ye)
+    return out.astype(x.dtype)
+
+
+# name -> (tokens, capacity factor, a row of tied logits, dtype, tolerance)
+DISPATCH_CASES = {
+    "capacity_ample": (256, 4.0, False, jnp.float32, 1e-5),
+    "capacity_binding": (256, 0.5, False, jnp.float32, 1e-5),
+    "tied_row": (256, 2.0, True, jnp.float32, 1e-5),
+    "decode_size": (8, 1.0, False, jnp.float32, 1e-5),
+    "more_slots_than_tokens": (4, 1.0, False, jnp.float32, 1e-5),
+    "bf16": (256, 2.0, False, jnp.bfloat16, 3e-2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH_CASES))
+def test_dispatch_by_index_matches_one_hot_reference(case):
+    t, factor, tied, dtype, tol = DISPATCH_CASES[case]
+    e, k, d, h = 8, 2, 16, 24
+    keys = jax.random.split(jax.random.key(11), 4)
+    x = jax.random.normal(keys[0], (t, d), dtype)
+    logits = jax.random.normal(keys[1], (t, e))
+    if tied:  # `probs > 0` selects all eight experts of this token
+        logits = logits.at[3].set(0.25)
+    w1 = (jax.random.normal(keys[2], (e, d, h)) * 0.3).astype(dtype)
+    w3 = (jax.random.normal(keys[3], (e, h, d)) * 0.3).astype(dtype)
+    cap = ops.moe.expert_capacity(t, e, k, factor)
+    probs = ops.moe.topk_gate_probs(logits, k)
+    kept = np.asarray(ops.moe._dispatch_slots(probs, cap)[2])
+    if case == "capacity_binding":
+        assert ((probs > 0).sum(0) > cap).sum() >= 3  # drops in several experts
+    if tied:
+        assert int((probs[3] > 0).sum()) == e
+    if case in ("decode_size", "more_slots_than_tokens"):
+        assert cap == 8
+
+    def loss(impl):
+        def f(x, logits, w1, w3):
+            def expert_fn(xe):
+                hid = jnp.tanh(jnp.einsum("ecd,edh->ech", xe, w1))
+                return jnp.einsum("ech,ehd->ecd", hid, w3)
+
+            out = impl(x, ops.moe.topk_gate_probs(logits, k), expert_fn, cap)
+            mix = jnp.cos(jnp.arange(t * d, dtype=jnp.float32)).reshape(t, d)
+            return jnp.sum(out.astype(jnp.float32) * mix), out
+
+        return jax.value_and_grad(f, argnums=(0, 1, 2, 3), has_aux=True)
+
+    (_, out), grads = loss(ops.moe.moe_dispatch_combine)(x, logits, w1, w3)
+    (_, want), want_grads = loss(one_hot_dispatch_combine)(x, logits, w1, w3)
+    assert out.dtype == dtype
+    for got, ref in zip((out, *grads), (want, *want_grads)):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        assert np.abs(ref).max() > 1e-3  # nothing compared is all zeros
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol * np.abs(ref).max())
+    # a token no expert kept comes back as zeros, as through the one-hot
+    lost = ~kept.any(axis=1)
+    assert lost.any() == (case == "capacity_binding")
+    assert not np.asarray(out, np.float32)[lost].any()
+
+
 def test_moe_dense_and_dispatch_model_agree():
     import dataclasses
 

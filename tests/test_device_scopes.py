@@ -91,6 +91,54 @@ def test_train_step_names_its_layers(name):
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
 
 
+@pytest.mark.parametrize("name", ["dense", "remat"])
+def test_moe_rows_move_by_gather_forward_and_backward(name):
+    """Dispatch and combine of the compiled step move rows through index
+    maps: no product that contracts over tokens or slots, nothing shaped
+    (T, E, C), no scatter in the backward, and the backward rules'
+    gathers carry their layer."""
+    # T, E, C, D and the experts' hidden size are five different numbers
+    sizes = dict(n_experts=4, top_experts=2, capacity_factor=1.5)
+    trainer, batch = dsv3_trainer(**STEPS[name][0], **sizes)
+    cfg = trainer.model.cfg
+    t, e = batch["x"].size, cfg.n_experts
+    c = ops.moe.expert_capacity(t, e, cfg.top_experts, cfg.capacity_factor)
+    assert len({t, e, c, cfg.dim, cfg.expert_hidden}) == 5
+    state = trainer.init_state(batch)
+    trainer._build_steps()
+    text = trainer._train_step.lower(state, batch).compile().as_text()
+    scopes = hlo_cost.device_scopes(text)
+    defs = {m.name: (m, line) for _, _, m, line in hlo_cost._scan_defs(text)}
+    dims_of = lambda shape: [  # noqa: E731  (each atom's dims, 1s left out)
+        sorted(int(d) for d in atom.group("dims").split(",") if d and d != "1")
+        for atom in hlo_cost._SHAPE_RE.finditer(shape)]
+    routed = {n: s for n, s in scopes.items()
+              if s.layer in ("L_moe_dispatch", "L_moe_combine")}
+    assert {s.pass_ for s in routed.values()} == STEPS[name][2]
+    for n in routed:
+        m, line = defs[n]
+        assert m.op != "scatter", line
+        operands = hlo_cost._OPERAND_NAMES_RE.findall(
+            line[m.end:].split(")", 1)[0])
+        for shape in [m.out] + [defs[o][0].out for o in operands if o in defs]:
+            assert sorted((t, e, c)) not in dims_of(shape), line
+        if m.op in ("dot", "convolution"):
+            lhs = hlo_cost._first_operand(
+                line[m.end:], {k: d.out for k, (d, _) in defs.items()})
+            lhs_dims = [int(d) for d in lhs.group("dims").split(",")]
+            contracted = {lhs_dims[int(i)] for i in hlo_cost._CONTRACT_RE
+                          .search(line).group("dims").split(",") if i}
+            assert not contracted & {t, c}, line
+    # every gather of the step has a layer, and the backward's are there:
+    # dx in the dispatch's rule, dye and the rows of dprobs in the combine's
+    gathers = {n: scopes[n] for n, (m, _) in defs.items() if m.op == "gather"}
+    assert all(s.layer is not None for s in gathers.values()), gathers
+    bwd = collections.Counter(
+        s.layer for s in gathers.values() if s.pass_ == "bwd")
+    assert bwd["L_moe_dispatch"] >= cfg.n_layers
+    assert bwd["L_moe_combine"] >= cfg.n_layers
+
+
 def test_program_scopes_knows_only_registered_programs():
     assert hlo_cost.program_scopes("jit_nobody_dispatched_this") is None
 
