@@ -404,6 +404,12 @@ def _serve_model(args, *, quiet_random_init: bool = False):
               "export the stage-stacked params to the dense family first",
               file=sys.stderr)
         return 2
+    if cfg.model_family == "qwen3next":
+        print("serving is unsupported for the qwen3next family: its Gated "
+              "DeltaNet layers keep recurrent state, and no cache manager "
+              "here holds that yet (ROADMAP R-M7); `cli train` runs it",
+              file=sys.stderr)
+        return 2
     if getattr(cfg.model, "context_parallel", False):
         # params are replicated at rest: serve the dense twin, exactly
         # like cmd_sample's single-chip path

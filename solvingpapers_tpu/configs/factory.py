@@ -30,6 +30,10 @@ def build_model(cfg: RunConfig):
         from solvingpapers_tpu.models.deepseekv3 import DeepSeekV3
 
         return DeepSeekV3(cfg.model)
+    if fam == "qwen3next":
+        from solvingpapers_tpu.models.qwen3next import Qwen3Next
+
+        return Qwen3Next(cfg.model)
     if fam == "gpt_pipe":
         from solvingpapers_tpu.models.gpt_pipe import GPTPipe
 
@@ -74,7 +78,9 @@ def loss_fn_for(cfg: RunConfig):
         reconstruction_loss_fn,
         vae_loss_fn,
     )
-    from solvingpapers_tpu.train.objectives import dsv3_loss_fn
+    from solvingpapers_tpu.train.objectives import (
+        dsv3_loss_fn, qwen3next_loss_fn,
+    )
 
     return {
         "gpt": lm_loss_fn,
@@ -84,6 +90,7 @@ def loss_fn_for(cfg: RunConfig):
         "gemma": lm_loss_fn,
         "deepseekv3": dsv3_loss_fn,
         "dsv3_pipe": dsv3_loss_fn,
+        "qwen3next": qwen3next_loss_fn,
         "vit": classification_loss_fn,
         "alexnet": classification_loss_fn,
         "kd": classification_loss_fn,

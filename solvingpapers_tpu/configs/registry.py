@@ -13,7 +13,7 @@ from solvingpapers_tpu.train.optim import OptimizerConfig
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     name: str
-    model_family: str  # gpt | llama3 | gemma | deepseekv3 | vit | alexnet | ae | vae | kd
+    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | vit | alexnet | ae | vae | kd
     model: Any
     train: TrainConfig
     data: dict = dataclasses.field(default_factory=dict)
@@ -633,6 +633,44 @@ def _dsv3_long() -> RunConfig:
               "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
         notes="beyond-reference: 64x the reference's maximum context for "
               "its own flagship architecture, one chip",
+    )
+
+
+@register("qwen3next_80b_a3b")
+def _qwen3next_80b_a3b() -> RunConfig:
+    """Qwen3-Next-80B-A3B-Instruct at its published size
+    (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct config.json): 48
+    layers, three Gated DeltaNet to one gated attention, hidden 2048, 512
+    experts of width 512 with 10 a token and a shared one, vocabulary
+    151,936. Far more than one chip holds (80B parameters): what runs is a
+    cut of it, one expert-parallel rank's share of a few layers
+    (benchmarks/configs/qwen3next_ep16.json sets `num_hidden_layers`,
+    `num_experts`, the experts held, and `vocab_size`; the router keeps its
+    512 outputs). Training only: no decode cache holds recurrent state yet.
+
+    The job (assumed, the source states none): one sequence of 16,384
+    tokens a step, AdamW 3e-4 beta=(0.9, 0.95) wd 0.1 clip 1.0, 100 steps
+    of warm-up -> cosine to 0.1*max; capacity factor 2; remat a layer."""
+    from solvingpapers_tpu.models.qwen3next import Qwen3NextConfig
+
+    return RunConfig(
+        name="qwen3next_80b_a3b",
+        model_family="qwen3next",
+        model=Qwen3NextConfig(),
+        train=TrainConfig(
+            steps=10_000, batch_size=1, log_every=50, eval_every=500,
+            eval_batches=4, ckpt_every=1000,
+            optimizer=OptimizerConfig(
+                name="adamw", max_lr=3e-4, warmup_steps=100,
+                total_steps=10_000, b1=0.9, b2=0.95, weight_decay=0.1,
+                grad_clip=1.0,
+            ),
+            tokens_per_step=16_384,
+        ),
+        data={"kind": "bpe", "path": None, "block_size": 16_384,
+              "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
+        notes="published widths; run through a cut (experts held, layers, "
+              "vocabulary slice), see benchmarks/configs/qwen3next_ep16.json",
     )
 
 

@@ -383,12 +383,17 @@ def format_anatomy(anatomy: dict) -> str:
 # ------------------------------------------------------- layers of a program
 
 # The layer scopes of the train step, one `jax.named_scope` at each layer
-# boundary (models/deepseekv3.py, ops/moe.py, ops/losses.py,
-# train/engine.py). Single tokens that no Flax module is named.
+# boundary (models/deepseekv3.py, models/qwen3next.py, ops/moe.py,
+# ops/losses.py, train/engine.py). Single tokens that no Flax module is
+# named. `L_gdn_*` are a Gated DeltaNet layer's: its projections, norms and
+# gate; its causal convolution; the chunked gated delta rule.
 LAYER_SCOPES = (
     "L_embed",
     "L_attn_proj",
     "L_attn_core",
+    "L_gdn_proj",
+    "L_gdn_conv",
+    "L_gdn_core",
     "L_moe_gate",
     "L_moe_dispatch",
     "L_moe_experts",

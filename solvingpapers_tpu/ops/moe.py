@@ -117,15 +117,25 @@ def _take_rows(rows: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.take(rows, idx, axis=0, mode="fill", fill_value=0)
 
 
-def _slot_rows(slots: jax.Array, tok_pos: jax.Array) -> list[jax.Array]:
-    """For each expert, in float32, the (T, D) rows of its (C, D) slots that
-    the tokens' kept pairs point at, zeros for the other tokens. A gather
-    an expert, not one through a flat (E*C, D) view: XLA's row gather on
-    the v5e costs 3.4 ns a row from an operand of up to 98,304 rows and
-    four times that from one of 131,072 (PERF.md, PR 26)."""
+def _token_rows(slots: jax.Array, tok_map: jax.Array, flat: bool) -> list[jax.Array]:
+    """The token side of the slot assignment: for each column of `tok_map`,
+    in float32, the (T, D) rows of the (E, C, D) slots that the tokens' kept
+    pairs point at, zeros for the other tokens.
+
+    `flat` False: `tok_map` is (T, E), a pair's slot within its expert
+    (else C), a gather an expert: it has room for every expert of a row of
+    tied logits (`topk_gate_probs` selects them all) and the `shard_map`
+    paths slice it by column. On the v5e it is the slower of the two for
+    DeepSeekV3 as well (PERF.md, PR 28; ROADMAP S1b).
+    `flat` True: `tok_map` is (T, k), the flat slot e*C + pos of each of a
+    token's k routed pairs (else E*C): k gathers of T rows however many
+    experts are held, for a device that holds a share of a wide router's
+    experts."""
+    if flat:
+        slots = slots.reshape(-1, slots.shape[-1])
     return [
-        _take_rows(slots[e], tok_pos[:, e]).astype(jnp.float32)
-        for e in range(slots.shape[0])
+        _take_rows(slots if flat else slots[j], tok_map[:, j]).astype(jnp.float32)
+        for j in range(tok_map.shape[1])
     ]
 
 
@@ -158,49 +168,52 @@ def _routes(probs: jax.Array, capacity: int) -> _Routes:
     return _Routes(jnp.where(keep, pos, capacity), slot_tok, slot_w)
 
 
-@jax.custom_vjp
-def _dispatch_rows(x, tok_pos, slot_tok):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, tok_map, slot_tok, flat):
     return _take_rows(x, slot_tok)
 
 
-def _dispatch_rows_fwd(x, tok_pos, slot_tok):
-    return _take_rows(x, slot_tok), tok_pos
+def _dispatch_rows_fwd(x, tok_map, slot_tok, flat):
+    return _take_rows(x, slot_tok), tok_map
 
 
-def _dispatch_rows_bwd(tok_pos, dxe):
+def _dispatch_rows_bwd(flat, tok_map, dxe):
     # each token reads back the slots it was copied to: the transpose of a
     # gather through a partial permutation is the gather through its inverse
-    dx = functools.reduce(jnp.add, _slot_rows(dxe, tok_pos))
+    dx = functools.reduce(jnp.add, _token_rows(dxe, tok_map, flat))
     return dx.astype(dxe.dtype), None, None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
-@jax.custom_vjp
-def _combine_rows(ye, probs, tok_pos, slot_tok, slot_w):
-    probs = probs.astype(jnp.float32)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine_rows(ye, w, tok_map, slot_tok, slot_w, flat):
+    """`w` holds a weight for each column of `tok_map`: the (T, E) gate
+    probabilities, or the (T, k) weights of a token's routed pairs."""
+    w = w.astype(jnp.float32)
     out = functools.reduce(jnp.add, [
-        rows * probs[:, e, None]
-        for e, rows in enumerate(_slot_rows(ye, tok_pos))
+        rows * w[:, j, None]
+        for j, rows in enumerate(_token_rows(ye, tok_map, flat))
     ])
     return out.astype(ye.dtype)
 
 
-def _combine_rows_fwd(ye, probs, tok_pos, slot_tok, slot_w):
-    out = _combine_rows(ye, probs, tok_pos, slot_tok, slot_w)
-    return out, (ye, probs, tok_pos, slot_tok, slot_w)
+def _combine_rows_fwd(ye, w, tok_map, slot_tok, slot_w, flat):
+    out = _combine_rows(ye, w, tok_map, slot_tok, slot_w, flat)
+    return out, (ye, w, tok_map, slot_tok, slot_w)
 
 
-def _combine_rows_bwd(res, dout):
-    ye, probs, tok_pos, slot_tok, slot_w = res
+def _combine_rows_bwd(flat, res, dout):
+    ye, w, tok_map, slot_tok, slot_w = res
     dye = _take_rows(dout, slot_tok).astype(jnp.float32) * slot_w[..., None]
     dout = dout.astype(jnp.float32)
-    dprobs = jnp.stack(
-        [jnp.sum(rows * dout, axis=-1) for rows in _slot_rows(ye, tok_pos)],
+    dw = jnp.stack(
+        [jnp.sum(rows * dout, axis=-1)
+         for rows in _token_rows(ye, tok_map, flat)],
         axis=1,
     )
-    return dye.astype(ye.dtype), dprobs.astype(probs.dtype), None, None, None
+    return dye.astype(ye.dtype), dw.astype(w.dtype), None, None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -212,7 +225,8 @@ def _dispatch(x: jax.Array, probs: jax.Array, capacity: int):
     zeros."""
     routes = _routes(probs, capacity)
     with jax.named_scope("L_moe_dispatch"):
-        xe = _dispatch_rows(*_vary_alike(x, routes.tok_pos, routes.slot_tok))
+        xe = _dispatch_rows(
+            *_vary_alike(x, routes.tok_pos, routes.slot_tok), False)
     return routes, xe
 
 
@@ -221,7 +235,7 @@ def _weighted_combine(routes: _Routes, probs: jax.Array, ye: jax.Array):
     slots of its kept pairs and sums them weighted by the gate, in
     float32, cast once to the experts' dtype."""
     with jax.named_scope("L_moe_combine"):
-        return _combine_rows(*_vary_alike(ye, probs, *routes))
+        return _combine_rows(*_vary_alike(ye, probs, *routes), False)
 
 
 def moe_dispatch_combine(
@@ -241,6 +255,92 @@ def moe_dispatch_combine(
     with jax.named_scope("L_moe_experts"):
         ye = expert_fn(xe)
     return _weighted_combine(routes, probs, ye)
+
+
+def topk_renorm_weights(
+    logits: jax.Array, k: int, renorm: bool = True
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Softmax over ALL experts in float32, the k largest a token, their
+    weights divided by their sum when `renorm` (the published
+    `norm_topk_prob`). Returns (weights (T, k), expert ids (T, k), the full
+    softmax (T, E)): the routed pairs themselves, not a (T, E) map, since a
+    router may be far wider than the experts a device holds."""
+    with jax.named_scope("L_moe_gate"):
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        top_w, top_idx = jax.lax.top_k(probs, k)
+        if renorm:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        return top_w, top_idx, probs
+
+
+def held_pair_probs(
+    pair_w: jax.Array, pair_idx: jax.Array, first: int, held: int
+) -> jax.Array:
+    """The routed pairs that fall on the experts [first, first + held) as a
+    (T, held) map of their weights, zero elsewhere: what `_dispatch_slots`
+    and the drop counter read, so that one rule decides what is dropped
+    whether a device holds every expert or a share."""
+    with jax.named_scope("L_moe_gate"):
+        local = pair_idx - first  # (T, k)
+        hit = local[..., None] == jnp.arange(held, dtype=local.dtype)
+        return jnp.sum(
+            jnp.where(hit, pair_w.astype(jnp.float32)[..., None], 0.0), axis=1
+        )
+
+
+class _PairRoutes(NamedTuple):
+    """The slot assignment of a device that holds a share of the experts.
+    The token side follows the routed pairs, k a token, and not the experts
+    held (`_token_rows` with `flat`)."""
+
+    pair_slot: jax.Array  # (T, k) flat slot e*C + pos of a kept pair, else E*C
+    slot_tok: jax.Array  # (E, C) token held by the slot, else T
+    slot_w: jax.Array  # (E, C) weight of the slot's pair, float32
+
+
+def _pair_routes(
+    pair_w: jax.Array, pair_idx: jax.Array, first: int, held: int, capacity: int
+) -> tuple[_PairRoutes, jax.Array]:
+    """(the maps, the (T, held) weights they were made from)."""
+    probs = held_pair_probs(jax.lax.stop_gradient(pair_w), pair_idx, first, held)
+    routes = _routes(probs, capacity)
+    with jax.named_scope("L_moe_gate"):
+        local = pair_idx - first
+        on_held = (local >= 0) & (local < held)
+        local = jnp.clip(local, 0, held - 1)
+        pos = jnp.take_along_axis(routes.tok_pos, local, axis=1)
+        pair_slot = jnp.where(
+            on_held & (pos < capacity), local * capacity + pos, held * capacity
+        ).astype(jnp.int32)
+    return _PairRoutes(pair_slot, routes.slot_tok, routes.slot_w), probs
+
+
+def moe_held_dispatch_combine(
+    x: jax.Array,
+    pair_w: jax.Array,
+    pair_idx: jax.Array,
+    expert_fn,
+    capacity: int,
+    first: int,
+    held: int,
+) -> tuple[jax.Array, jax.Array]:
+    """One expert-parallel rank's part of an MoE layer, with no exchange:
+    of the routed pairs `(pair_w, pair_idx)` (T, k) over ALL experts, those
+    on the experts [first, first + held) go through the same slots as
+    `moe_dispatch_combine` (`_dispatch_slots` decides what is dropped),
+    `expert_fn((held, C, D)) -> (held, C, D)` runs, and each token sums its
+    kept pairs. What the other experts would add is left out. Returns (the
+    partial output (T, D), the (T, held) weights of the pairs routed here,
+    for the drop counter)."""
+    routes, probs = _pair_routes(pair_w, pair_idx, first, held, capacity)
+    with jax.named_scope("L_moe_dispatch"):
+        xe = _dispatch_rows(
+            *_vary_alike(x, routes.pair_slot, routes.slot_tok), True)
+    with jax.named_scope("L_moe_experts"):
+        ye = expert_fn(xe)
+    with jax.named_scope("L_moe_combine"):
+        out = _combine_rows(*_vary_alike(ye, pair_w, *routes), True)
+    return out, probs
 
 
 @jax.named_scope("L_moe_stats")

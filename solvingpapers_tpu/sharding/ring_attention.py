@@ -376,8 +376,8 @@ def ring_flash_attention_local(
     # seq-adaptive auto like flash_attention: an 8k+ CP shard gets the
     # long-sequence tile (the 16k sweep's 1.5-2x backward win applies to
     # each ring chunk too)
-    bq = _pick_block_q(s_loc, auto_block(s_loc, block_q))
-    bk = _pick_block(s_loc, auto_block(s_loc, block_k))
+    bq = _pick_block_q(s_loc, auto_block(s_loc, block_q, h))
+    bk = _pick_block(s_loc, auto_block(s_loc, block_k, h))
 
     q3 = q.transpose(0, 2, 1, 3).reshape(b * n, s_loc, h)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * n_kv, s_loc, h)

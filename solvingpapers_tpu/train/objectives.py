@@ -164,6 +164,37 @@ def dsv3_loss_fn(model, params, batch, rng, model_state, train):
     return loss, aux, new_ms
 
 
+def qwen3next_loss_fn(model, params, batch, rng, model_state, train):
+    """Qwen3-Next objective: next-token cross-entropy plus
+    `router_aux_loss_coef` times the family's load-balancing loss, E *
+    sum_e F_e P_e with F the share of tokens that chose an expert and P its
+    mean router probability, both over the tokens of all layers together.
+    The MoE's counters (`moe_drop_fraction`, `moe_held_pair_fraction`, the
+    load statistics) ride along as the DeepSeekV3 family's do."""
+    cfg = model.cfg
+    variables = {"params": params}
+    if not train:
+        logits, _ = model.apply(variables, batch["x"])
+        main = ops.cross_entropy(logits, batch["y"])
+        return main, {"perplexity": jnp.exp(main)}, model_state
+    (logits, _), mutated = model.apply(
+        variables, batch["x"], mutable=["moe_metrics"]
+    )
+    raw = mutated.get("moe_metrics", {})
+    main = ops.cross_entropy(logits, batch["y"])
+    with jax.named_scope("L_loss_head"):
+        aux = {"perplexity": jnp.exp(main), **_aggregate_moe_metrics(raw)}
+    with jax.named_scope("L_moe_stats"):
+        is_balance = lambda x: isinstance(x, dict) and "chosen" in x  # noqa: E731
+        layers = [b for b in jax.tree.leaves(raw, is_leaf=is_balance)
+                  if is_balance(b)]
+        chosen = jnp.mean(jnp.stack([b["chosen"] for b in layers]), axis=0)
+        prob = jnp.mean(jnp.stack([b["prob"] for b in layers]), axis=0)
+        balance = cfg.router_experts * jnp.sum(chosen * prob)
+    aux["balance_loss"] = balance
+    return main + cfg.router_aux_loss_coef * balance, aux, model_state
+
+
 def make_kd_loss_fn(teacher_model, teacher_params, temperature=7.0, alpha=0.3):
     """Distillation objective with a frozen teacher (kd.py:48-68, 110-142).
 

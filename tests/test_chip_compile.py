@@ -59,6 +59,10 @@ SHAPES = {
     "llama_gqa_1k": (8, 1024, 16, 8, 64, 0.0),
     # a ragged prefill chunk: no 128-divisible block, one q block
     "ragged_2016": (1, 2016, 8, 1, 128, 0.0),
+    # qwen3next's gated attention: 16 q heads on 2 kv heads of width 256.
+    # With the long-sequence 1024-tiles the compiler refuses the backward-dq
+    # kernel here (VMEM), so `auto_block` keeps 512 past width 128
+    "qwen3next_gqa_16k": (1, 16_384, 16, 2, 256, 0.0),
 }
 
 
@@ -101,6 +105,46 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
         *_abstract_qkv(shape, one_chip)
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _abstract_gdn(one_chip, seq=16_384):
+    """qwen3next_ep16's Gated DeltaNet core: 16 key heads of 128 serving 32
+    value heads of 128, one sequence of 16,384, bfloat16 q/k/v."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    return (sds((1, seq, 16, 128), jnp.bfloat16),
+            sds((1, seq, 16, 128), jnp.bfloat16),
+            sds((1, seq, 32, 128), jnp.bfloat16),
+            sds((1, seq, 32), jnp.float32), sds((1, seq, 32), jnp.float32))
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_gated_delta_rule_compiles_for_v5e(one_chip, backward):
+    """The chunked gated delta rule at the cell's shape: a scan over
+    segments whose body scans over chunks, and no loop over single tokens
+    (no `while` of 16,384 trips: the trip counts are the 8 segments' and
+    the 32 chunks' of a segment)."""
+    from solvingpapers_tpu.ops.gated_delta import gated_delta_rule
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gated_delta_rule(q, k, v, g, beta).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else loss
+    args = _abstract_gdn(one_chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert " while(" in compiled.as_text()
+
+    def scan_lengths(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn.params["length"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scan_lengths(sub)
+
+    trips = set(scan_lengths(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert trips == {8, 32}, trips
+    # a segment's backward keeps a segment's intermediates, not 16k tokens'
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
 
 
 def test_described_chip_is_in_the_peak_tables(topo):

@@ -1,0 +1,153 @@
+"""The gated delta rule: the chunked form of the timed path against the
+rule token by token (`gated_delta_rule_recurrent`), values and gradients,
+in float32 and with bfloat16 inputs, at lengths that are and are not
+multiples of the chunk and of the segment; the halving inverse; the causal
+convolution and the gated norm with their own backward rules."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from solvingpapers_tpu.ops import gated_delta as gd
+
+pytestmark = pytest.mark.fast
+
+B, HK, HV, DK, DV = 2, 2, 4, 16, 8
+CHUNK, SEGMENT = 16, 32
+
+
+def inputs(seq, dtype, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    q = jax.random.normal(ks[0], (B, seq, HK, DK)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, seq, HK, DK)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, seq, HV, DV)).astype(dtype)
+    # decays from heads that forget in a token to heads that hardly do
+    a = jax.random.uniform(ks[3], (HV,), minval=1e-3, maxval=16.0)
+    g = -a * jax.nn.softplus(jax.random.normal(ks[4], (B, seq, HV)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, seq, HV)))
+    return q, k, v, g, beta
+
+
+@jax.jit
+def chunked(*args):
+    return gd.gated_delta_rule(*args, chunk=CHUNK, segment=SEGMENT)
+
+
+recurrent = jax.jit(gd.gated_delta_rule_recurrent)
+
+
+# one chunk; a ragged chunk; whole segments; segments and a ragged tail
+LENGTHS = [16, 23, 64, 75]
+
+
+@pytest.mark.parametrize("seq", LENGTHS)
+def test_chunked_rule_matches_the_recurrence_float32(seq):
+    args = inputs(seq, jnp.float32)
+    want = recurrent(*args)
+    got = chunked(*args)
+    assert got.shape == want.shape == (B, seq, HV, DV)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("seq", LENGTHS)
+def test_chunked_rule_gradients_match_the_recurrence_float32(seq):
+    args = inputs(seq, jnp.float32)
+    mix = jax.random.normal(jax.random.key(9), (B, seq, HV, DV))
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * mix)  # noqa: E731
+    got = jax.jit(jax.grad(loss(chunked), argnums=(0, 1, 2, 3, 4)))(*args)
+    want = jax.jit(jax.grad(loss(recurrent), argnums=(0, 1, 2, 3, 4)))(*args)
+    for name, a, b in zip("qkvgb", got, want):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=2e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("seq", [23, 64])
+def test_chunked_rule_with_bfloat16_inputs(seq):
+    """bfloat16 q, k, v (the chip's dtype): the result is bfloat16 and lies
+    within bfloat16's rounding of the float32 recurrence on the same
+    (rounded) inputs; so do the gradients."""
+    args = inputs(seq, jnp.bfloat16)
+    as32 = tuple(a.astype(jnp.float32) for a in args)
+    want = recurrent(*as32)
+    got = chunked(*args)
+    assert got.dtype == jnp.bfloat16
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 0.03 * scale
+    mix = jax.random.normal(jax.random.key(9), (B, seq, HV, DV))
+    g_got = jax.jit(jax.grad(
+        lambda *a: jnp.sum(chunked(*a).astype(jnp.float32) * mix),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    g_want = jax.jit(jax.grad(lambda *a: jnp.sum(recurrent(*a) * mix),
+                              argnums=(0, 1, 2, 3, 4)))(*as32)
+    for name, a, b in zip("qkvgb", g_got, g_want):
+        gap = float(jnp.linalg.norm(a.astype(jnp.float32) - b))
+        assert gap < 0.03 * float(jnp.linalg.norm(b)), (name, gap)
+
+
+def test_repeated_keys_cost_the_inverse_no_precision():
+    """Every key of a chunk the same and beta near one: powers of the
+    system's matrix grow past float32 before they vanish, forward
+    substitution in blocks does not care."""
+    seq = 64
+    q, k, v, g, beta = inputs(seq, jnp.float32)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    beta = jnp.full_like(beta, 0.99)
+    g = jnp.full_like(g, -1e-3)
+    want = recurrent(q, k, v, g, beta)
+    got = jax.jit(lambda *a: gd.gated_delta_rule(*a, chunk=64))(q, k, v, g, beta)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-4)
+
+
+def test_unit_lower_inverse_and_its_backward():
+    m = jnp.tril(jax.random.normal(jax.random.key(0), (3, 16, 16)), -1)
+    m = m + jnp.eye(16)
+    inv = gd._unit_lower_inverse(m)
+    np.testing.assert_allclose(inv @ m, jnp.broadcast_to(jnp.eye(16), m.shape),
+                               atol=1e-5)
+    mix = jax.random.normal(jax.random.key(1), m.shape)
+    got = jax.grad(lambda a: jnp.sum(gd._unit_lower_inverse(a) * mix))(m)
+    want = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(a) * mix))(m)
+    np.testing.assert_allclose(got, want, atol=1e-4 * float(jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("silu", [False, True])
+def test_causal_depthwise_conv_and_its_backward(silu):
+    x = jax.random.normal(jax.random.key(0), (2, 11, 6))
+    w = jax.random.normal(jax.random.key(1), (4, 6))
+
+    def plain(x, w):
+        xp = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
+        y = sum(xp[:, j:j + 11] * w[j] for j in range(4))
+        return y * jax.nn.sigmoid(y) if silu else y
+
+    conv = lambda x, w: gd.causal_depthwise_conv(x, w, silu)  # noqa: E731
+    np.testing.assert_allclose(conv(x, w), plain(x, w), atol=1e-6)
+    if not silu:
+        # causal: the first output sees the first input alone, through w[-1]
+        np.testing.assert_allclose(conv(x, w)[:, 0], x[:, 0] * w[3], atol=1e-6)
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda x, w: jnp.sum(jnp.sin(fn(x, w))), argnums=(0, 1))(x, w)
+    for a, b in zip(grads(conv), grads(plain)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_gated_rms_norm_and_its_backward():
+    o = jax.random.normal(jax.random.key(0), (2, 5, 3, 8))
+    z = jax.random.normal(jax.random.key(1), (2, 5, 3, 8))
+    w = 1.0 + 0.1 * jax.random.normal(jax.random.key(2), (8,))
+
+    def plain(o, z, w):
+        n = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + 1e-6) * w
+        return n * z * jax.nn.sigmoid(z)
+
+    np.testing.assert_allclose(gd.gated_rms_norm(o, z, w, 1e-6),
+                               plain(o, z, w), atol=1e-6)
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda o, z, w: jnp.sum(jnp.sin(fn(o, z, w))), argnums=(0, 1, 2)
+    )(o, z, w)
+    got = grads(lambda o, z, w: gd.gated_rms_norm(o, z, w, 1e-6))
+    for a, b in zip(got, grads(plain)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    assert gd.gated_rms_norm(o.astype(jnp.bfloat16), z.astype(jnp.bfloat16),
+                             w, 1e-6).dtype == jnp.bfloat16
