@@ -107,44 +107,60 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def _abstract_gdn(one_chip, seq=16_384):
+def _abstract_gdn(one_chip, dtype, seq=16_384):
     """qwen3next_ep16's Gated DeltaNet core: 16 key heads of 128 serving 32
-    value heads of 128, one sequence of 16,384, bfloat16 q/k/v."""
+    value heads of 128, one sequence of 16,384."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    return (sds((1, seq, 16, 128), jnp.bfloat16),
-            sds((1, seq, 16, 128), jnp.bfloat16),
-            sds((1, seq, 32, 128), jnp.bfloat16),
+    return (sds((1, seq, 16, 128), dtype), sds((1, seq, 16, 128), dtype),
+            sds((1, seq, 32, 128), dtype),
             sds((1, seq, 32), jnp.float32), sds((1, seq, 32), jnp.float32))
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-def test_gated_delta_rule_compiles_for_v5e(one_chip, backward):
-    """The chunked gated delta rule at the cell's shape: a scan over
-    segments whose body scans over chunks, and no loop over single tokens
-    (no `while` of 16,384 trips: the trip counts are the 8 segments' and
-    the 32 chunks' of a segment)."""
-    from solvingpapers_tpu.ops.gated_delta import gated_delta_rule
+def test_gated_delta_rule_compiles_for_v5e(one_chip, backward, dtype):
+    """The gated delta rule's kernels at the cell's shape (bfloat16 as the
+    cell runs it, and float32): Mosaic takes their blocks and their VMEM,
+    the rule is a kernel call forward (the one that also writes the
+    entering states, under differentiation) and one backward, and no
+    `while` is left of it."""
+    from solvingpapers_tpu.kernels.gated_delta import gated_delta_rule
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(gated_delta_rule(q, k, v, g, beta).astype(jnp.float32))
+        # interpret=False: the default would ask jax.devices(), the CPU here
+        return jnp.sum(gated_delta_rule(
+            q, k, v, g, beta, chunk=64, interpret=False).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else loss
-    args = _abstract_gdn(one_chip)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert " while(" in compiled.as_text()
+    compiled = jax.jit(fn).lower(*_abstract_gdn(one_chip, dtype)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + backward
+    assert " while(" not in text
+    # besides gradients and cotangents of the arguments' size, the entering
+    # states (256 chunks x 32 heads x 128 x 128): 0.27 GB in bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
 
-    def scan_lengths(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "scan":
-                yield eqn.params["length"]
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from scan_lengths(sub)
 
-    trips = set(scan_lengths(jax.make_jaxpr(fn)(*args).jaxpr))
-    assert trips == {8, 32}, trips
-    # a segment's backward keeps a segment's intermediates, not 16k tokens'
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+def test_gated_delta_rule_pads_narrow_heads_for_v5e(one_chip):
+    """Widths that are no multiple of the 128 lanes (and one value head a
+    key head, a ragged length) are padded, not refused."""
+    from solvingpapers_tpu.kernels.gated_delta import gated_delta_rule
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    args = (sds((2, 300, 4, 64), jnp.bfloat16), sds((2, 300, 4, 64), jnp.bfloat16),
+            sds((2, 300, 4, 96), jnp.bfloat16),
+            sds((2, 300, 4), jnp.float32), sds((2, 300, 4), jnp.float32))
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gated_delta_rule(
+            q, k, v, g, beta, chunk=64, interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
 
 
 def test_described_chip_is_in_the_peak_tables(topo):

@@ -187,6 +187,8 @@ ENTRY %main (a: f32[4]) -> f32[4] {
   %copy.8 = f32[4]{0} copy(%fusion.7)
   %bitcast.10 = f32[4]{0} bitcast(%a)
   %k.9 = f32[4]{0} custom-call(%bitcast.10, %copy.8), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/transpose(jvp(M))/jvp(M)/checkpoint/rematted_computation/layer_0/mla/L_attn_core/flash_mla_fwd/pallas_call"}
+  %bitcast.12 = f32[4]{0} bitcast(%a)
+  %k.11 = f32[4]{0} custom-call(%bitcast.12), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/transpose(jvp(M))/layer_1/mixer/L_gdn_core/gated_delta_bwd/pallas_call"}
   %while.4 = (s32[], f32[4]{0}) while(%tuple.0), condition=%cond, body=%body, metadata={op_name="jit(f)/transpose(jvp(L_loss_head))/while"}
   %add.5 = f32[4]{0} add(%a, %a), metadata={op_name="jit(f)/add"}
   ROOT %mul.6 = f32[4]{0} multiply(%add.5, %add.5), metadata={op_name="jit(f)/L_optimizer/mul"}
@@ -207,6 +209,10 @@ ENTRY %main (a: f32[4]) -> f32[4] {
     # what the compiler made to feed a kernel: the kernel's layer, since
     # the kernel's name is for the kernel's own time
     ("bitcast.10", ("L_attn_core", "remat", True)),
+    # a kernel whose name is no vocabulary (the gated delta rule's): its
+    # time is its layer's, as `gdn_core_ms` reads it
+    ("k.11", ("L_gdn_core", "bwd", True)),
+    ("bitcast.12", ("L_gdn_core", "bwd", True)),
     ("while.4", ("L_loss_head", "bwd", True)),
     ("exp.2", ("L_loss_head", "bwd", False)),
     ("add.5", (None, "fwd", True)),

@@ -1,85 +1,90 @@
-"""The gated delta rule: the chunked form of the timed path against the
-rule token by token (`gated_delta_rule_recurrent`), values and gradients,
-in float32 and with bfloat16 inputs, at lengths that are and are not
-multiples of the chunk and of the segment; the halving inverse; the causal
-convolution and the gated norm with their own backward rules."""
+"""The gated delta rule: the kernels of the timed path (interpreted here)
+against the rule token by token (`gated_delta_rule_recurrent`), values and
+all five gradients, in float32 and with bfloat16 inputs, at lengths that
+are and are not multiples of a chunk and of a grid step's tile, one and two
+value heads a key head; the causal convolution and the gated norm with
+their own backward rules."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from solvingpapers_tpu.kernels import gated_delta as kernel
 from solvingpapers_tpu.ops import gated_delta as gd
 
 pytestmark = pytest.mark.fast
 
-B, HK, HV, DK, DV = 2, 2, 4, 16, 8
-CHUNK, SEGMENT = 16, 32
+B, HK, DK, DV = 2, 2, 16, 8
+CHUNK = 16  # a grid step holds kernel.CHUNKS_A_STEP = 4 of them: 64 tokens
 
 
-def inputs(seq, dtype, seed=0):
+def inputs(seq, dtype, hv=4, seed=0):
     ks = jax.random.split(jax.random.key(seed), 6)
     q = jax.random.normal(ks[0], (B, seq, HK, DK)).astype(dtype)
     k = jax.random.normal(ks[1], (B, seq, HK, DK)).astype(dtype)
-    v = jax.random.normal(ks[2], (B, seq, HV, DV)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, seq, hv, DV)).astype(dtype)
     # decays from heads that forget in a token to heads that hardly do
-    a = jax.random.uniform(ks[3], (HV,), minval=1e-3, maxval=16.0)
-    g = -a * jax.nn.softplus(jax.random.normal(ks[4], (B, seq, HV)) + 1.0)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, seq, HV)))
+    a = jax.random.uniform(ks[3], (hv,), minval=1e-3, maxval=16.0)
+    g = -a * jax.nn.softplus(jax.random.normal(ks[4], (B, seq, hv)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, seq, hv)))
     return q, k, v, g, beta
 
 
 @jax.jit
 def chunked(*args):
-    return gd.gated_delta_rule(*args, chunk=CHUNK, segment=SEGMENT)
+    return gd.gated_delta_rule(*args, chunk=CHUNK)
 
 
 recurrent = jax.jit(gd.gated_delta_rule_recurrent)
 
 
-# one chunk; a ragged chunk; whole segments; segments and a ragged tail
-LENGTHS = [16, 23, 64, 75]
+def grads(fn, args, mix):
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * mix),
+        argnums=(0, 1, 2, 3, 4)))(*args)
 
 
-@pytest.mark.parametrize("seq", LENGTHS)
-def test_chunked_rule_matches_the_recurrence_float32(seq):
-    args = inputs(seq, jnp.float32)
+# (tokens, value heads): one chunk; a ragged chunk; one whole tile; whole
+# tiles and a ragged one (the carries in VMEM cross two grid steps, forward
+# and backward); the same with one value head a key head
+CASES = [(16, 4), (23, 4), (64, 4), (150, 4), (16, 2), (75, 2)]
+
+
+@pytest.mark.parametrize("seq, hv", CASES)
+def test_chunked_rule_matches_the_recurrence_float32(seq, hv):
+    args = inputs(seq, jnp.float32, hv)
     want = recurrent(*args)
     got = chunked(*args)
-    assert got.shape == want.shape == (B, seq, HV, DV)
+    assert got.shape == want.shape == (B, seq, hv, DV)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
 
 
-@pytest.mark.parametrize("seq", LENGTHS)
-def test_chunked_rule_gradients_match_the_recurrence_float32(seq):
-    args = inputs(seq, jnp.float32)
-    mix = jax.random.normal(jax.random.key(9), (B, seq, HV, DV))
-    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * mix)  # noqa: E731
-    got = jax.jit(jax.grad(loss(chunked), argnums=(0, 1, 2, 3, 4)))(*args)
-    want = jax.jit(jax.grad(loss(recurrent), argnums=(0, 1, 2, 3, 4)))(*args)
+@pytest.mark.parametrize("seq, hv", CASES)
+def test_chunked_rule_gradients_match_the_recurrence_float32(seq, hv):
+    args = inputs(seq, jnp.float32, hv)
+    mix = jax.random.normal(jax.random.key(9), (B, seq, hv, DV))
+    got, want = grads(chunked, args, mix), grads(recurrent, args, mix)
     for name, a, b in zip("qkvgb", got, want):
         scale = float(jnp.max(jnp.abs(b)))
         np.testing.assert_allclose(a, b, atol=2e-5 * scale, err_msg=name)
 
 
-@pytest.mark.parametrize("seq", [23, 64])
-def test_chunked_rule_with_bfloat16_inputs(seq):
+@pytest.mark.parametrize("seq, hv", [(23, 4), (64, 4), (150, 2)])
+def test_chunked_rule_with_bfloat16_inputs(seq, hv):
     """bfloat16 q, k, v (the chip's dtype): the result is bfloat16 and lies
     within bfloat16's rounding of the float32 recurrence on the same
-    (rounded) inputs; so do the gradients."""
-    args = inputs(seq, jnp.bfloat16)
+    (rounded) inputs; so do the gradients, q's, k's and v's bfloat16 too."""
+    args = inputs(seq, jnp.bfloat16, hv)
     as32 = tuple(a.astype(jnp.float32) for a in args)
     want = recurrent(*as32)
     got = chunked(*args)
     assert got.dtype == jnp.bfloat16
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 0.03 * scale
-    mix = jax.random.normal(jax.random.key(9), (B, seq, HV, DV))
-    g_got = jax.jit(jax.grad(
-        lambda *a: jnp.sum(chunked(*a).astype(jnp.float32) * mix),
-        argnums=(0, 1, 2, 3, 4)))(*args)
-    g_want = jax.jit(jax.grad(lambda *a: jnp.sum(recurrent(*a) * mix),
-                              argnums=(0, 1, 2, 3, 4)))(*as32)
+    mix = jax.random.normal(jax.random.key(9), (B, seq, hv, DV))
+    g_got, g_want = grads(chunked, args, mix), grads(recurrent, as32, mix)
+    assert [a.dtype for a in g_got] == [a.dtype for a in args]
     for name, a, b in zip("qkvgb", g_got, g_want):
         gap = float(jnp.linalg.norm(a.astype(jnp.float32) - b))
         assert gap < 0.03 * float(jnp.linalg.norm(b)), (name, gap)
@@ -99,16 +104,35 @@ def test_repeated_keys_cost_the_inverse_no_precision():
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-4)
 
 
-def test_unit_lower_inverse_and_its_backward():
-    m = jnp.tril(jax.random.normal(jax.random.key(0), (3, 16, 16)), -1)
-    m = m + jnp.eye(16)
-    inv = gd._unit_lower_inverse(m)
-    np.testing.assert_allclose(inv @ m, jnp.broadcast_to(jnp.eye(16), m.shape),
-                               atol=1e-5)
+@pytest.mark.parametrize("grp", [1, 2])
+def test_packed_unit_lower_inverse_and_its_backward(grp):
+    """`grp` unit lower-triangular 16 x 16 matrices side by side along the
+    lanes: each is inverted by itself, and the backward rule (from the
+    inverse alone) is the derivative of the inverse."""
+    c = 16
+    m = jnp.tril(jax.random.normal(jax.random.key(0), (3, grp, c, c)), -1)
+    pack = lambda x: jnp.moveaxis(x, 1, 2).reshape(3, c, grp * c)  # noqa: E731
+    inv = kernel._unit_lower_inverse(pack(m), c, grp)
+    want = jnp.linalg.inv(m + jnp.eye(c))
+    np.testing.assert_allclose(
+        inv, pack(want), atol=1e-5 * float(jnp.max(jnp.abs(want))))
     mix = jax.random.normal(jax.random.key(1), m.shape)
-    got = jax.grad(lambda a: jnp.sum(gd._unit_lower_inverse(a) * mix))(m)
-    want = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(a) * mix))(m)
-    np.testing.assert_allclose(got, want, atol=1e-4 * float(jnp.max(jnp.abs(want))))
+    got = jax.grad(lambda a: jnp.sum(
+        kernel._unit_lower_inverse(pack(a), c, grp) * pack(mix)))(m)
+    want = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(a + jnp.eye(c)) * mix))(m)
+    # a gradient of the places above the diagonal too; A has none there
+    np.testing.assert_allclose(
+        jnp.tril(got, -1), jnp.tril(want, -1),
+        atol=1e-4 * float(jnp.max(jnp.abs(want))))
+
+
+def test_rule_refuses_heads_and_chunks_it_cannot_pack():
+    q, k, v, g, beta = inputs(16, jnp.float32, hv=3)
+    with pytest.raises(ValueError, match="3 value heads over 2"):
+        gd.gated_delta_rule(q, k, v, g, beta)
+    q, k, v, g, beta = inputs(16, jnp.float32)
+    with pytest.raises(ValueError, match="power of two"):
+        gd.gated_delta_rule(q, k, v, g, beta, chunk=24)
 
 
 @pytest.mark.parametrize("silu", [False, True])

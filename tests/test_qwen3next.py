@@ -15,6 +15,7 @@
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -300,10 +301,18 @@ def test_train_step_names_the_new_layers(monkeypatch):
             "L_optimizer", "L_embed"} <= layers
     for layer in ("L_gdn_proj", "L_gdn_conv", "L_gdn_core"):
         assert {s.pass_ for s in top if s.layer == layer} >= {"bwd", "remat"}
-    # the scan over chunks is one top-level `while` of the rule's own scope
-    whiles = [s for n, s in scopes.items()
-              if n.startswith("while") and s.top_level]
-    assert "L_gdn_core" in {s.layer for s in whiles}
+    # the rule's kernels carry a `name=` that is no layer: their time stays
+    # the rule's own scope's, the forward kernel's in the step and again
+    # in the layer's remat, the backward kernel's in the backward pass
+    seen = {}
+    for m in re.finditer(
+            r"%?([\w.\-]+) = [^\n]*op_name=\"[^\"]*(gated_delta_(?:fwd|bwd))", text):
+        s = scopes[m.group(1)]
+        assert s.layer == "L_gdn_core", m.group(0)
+        if s.top_level:
+            seen.setdefault(m.group(2), set()).add(s.pass_)
+    assert seen == {"gated_delta_fwd": {"fwd", "remat"},
+                    "gated_delta_bwd": {"bwd"}}
     covered = sum(s.layer is not None for s in top) / len(top)
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
 
