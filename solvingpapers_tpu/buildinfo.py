@@ -2,10 +2,7 @@
 
 A scraped replica is anonymous without this — ROADMAP item 2's
 per-replica `/statusz` aggregation needs to know WHICH build and WHICH
-jax it is talking to before any of its numbers mean anything, and the
-bench provenance stamp (serve/bench.py) needs the same facts so a
-BENCH_serve.json entry stays identifiable after a rebase. One module so
-the two surfaces cannot drift.
+jax it is talking to before any of its numbers mean anything.
 
 `build_info()` is cheap after the first call (git sha and versions are
 cached; only uptime is live) and never raises: a missing git binary, a

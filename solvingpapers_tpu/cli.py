@@ -1,7 +1,7 @@
 """CLI entrypoints — `python -m solvingpapers_tpu.cli <cmd>`.
 
-Replaces the reference's notebook cells with commands (BASELINE.json north
-star: "every notebook's train() cell becomes a CLI entrypoint"):
+Replaces the reference's notebook cells with commands (every notebook's
+train() cell becomes a CLI entrypoint):
 
     cli list
     cli train  --config gpt_shakespeare [--steps N] [--data-path f.txt]
@@ -14,8 +14,6 @@ star: "every notebook's train() cell becomes a CLI entrypoint"):
     cli replay --config gpt_shakespeare --journal serve.jsonl
                [--config-overrides kv_quant=int8] [--out report.json]
                — config-canary divergence gate (exit 2 on divergence)
-    cli serve-bench --config llama3_shakespeare [--trace] [--http]
-    cli kernel-bench [--config gpt_shakespeare] [--out BENCH_kernels.json]
     cli trace-summary serve_trace.json [--top 10]
 """
 
@@ -702,319 +700,9 @@ def cmd_replay(args) -> int:
     return 0
 
 
-def cmd_serve_bench(args) -> int:
-    """Continuous-batching engine vs sequential one-shot generate on a
-    synthetic Poisson arrival stream — or, with --shared-prefix, prefix
-    cache on vs off over K shared system prompts, or, with --sampling,
-    a per-request SamplingParams mix vs all-greedy on the same trace,
-    or, with --paged, the paged KV pool vs the lane pool (throughput,
-    equal-HBM capacity, zero-copy prefix TTFT) (serve/bench.py); prints
-    the BENCH-shaped JSON and optionally writes it to --out."""
-    if args.checkpoint_dir or args.data_path:
-        print(
-            "serve-bench benchmarks scheduling throughput on random-init "
-            "params; --checkpoint-dir/--data-path are not consumed",
-            file=sys.stderr,
-        )
-        return 2
-    if sum((args.shared_prefix, args.sampling, args.paged, args.http,
-            args.speculative, args.slo, args.chaos, args.journal,
-            args.fleet, args.replay, args.kv_quant is not None)) > 1:
-        print("--shared-prefix, --sampling, --paged, --http, "
-              "--speculative, --slo, --chaos, --journal, --fleet, "
-              "--replay and --kv-quant are separate workloads; pick "
-              "one per run",
-              file=sys.stderr)
-        return 2
-    from solvingpapers_tpu.serve.bench import (
-        bench_provenance,
-        run_chaos_bench,
-        run_fleet_bench,
-        run_http_bench,
-        run_journal_bench,
-        run_paged_bench,
-        run_prefix_bench,
-        run_quant_bench,
-        run_replay_bench,
-        run_sampling_bench,
-        run_serve_bench,
-        run_slo_bench,
-        run_spec_bench,
-    )
-
-    max_new = args.max_new_tokens
-    if max_new is None:
-        max_new = 4 if args.shared_prefix else 64
-    decode_block = args.decode_block
-    if decode_block is None:
-        decode_block = 4 if args.shared_prefix else 16
-    n_requests = args.requests
-    if n_requests is None:
-        n_requests = 48 if args.shared_prefix else 32
-    # shared flags with per-workload defaults (None sentinel, so an
-    # EXPLICIT value always wins — even one that matches another
-    # workload's default)
-    n_slots = args.slots
-    if n_slots is None:
-        n_slots = 4 if args.chaos else 8
-    mean_ia = args.mean_interarrival
-    if mean_ia is None:
-        mean_ia = 0.15 if args.chaos else 0.001
-    prompt_lens = args.prompt_lens
-    if prompt_lens is None:
-        # --speculative defaults to gpt_tiny_long (256 positions):
-        # streams must be long enough for drafts to find history
-        prompt_lens = [24, 32, 40, 48] if args.speculative \
-            else [16, 32, 48, 64]
-    trace_kwargs = dict(
-        trace=args.trace,
-        trace_out=args.trace_out if args.trace else None,
-        trace_dump=args.trace_dump if args.trace else None,
-        obs=args.obs,
-        status_port=args.status_port,
-        status_hold_s=args.status_hold_s,
-    )
-    if args.obs_hlo_dir:
-        if any((args.shared_prefix, args.sampling, args.paged, args.http,
-                args.speculative, args.slo, args.chaos, args.journal,
-                args.fleet, args.replay, args.kv_quant is not None)):
-            # say so instead of silently dropping the flag — a user
-            # waiting on dumps should not debug an empty directory
-            print("--obs-hlo-dir only dumps from the Poisson workload's "
-                  "probe engine; ignoring it for this workload (use "
-                  "ServeConfig.obs_hlo_dir directly elsewhere)",
-                  file=sys.stderr)
-        else:
-            # Poisson workload: the probe engine is the one that dumps
-            trace_kwargs["obs_hlo_dir"] = args.obs_hlo_dir
-    if args.replay:
-        result = run_replay_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=args.max_new_tokens or 48,
-            decode_block=args.decode_block or 8,
-            prompt_lens=tuple(prompt_lens),
-            train_steps=args.replay_train_steps,
-            seed=args.seed,
-            page_size=args.page_size,
-            kv_quant_block=args.kv_quant_block,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.kv_quant:
-        result = run_quant_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            page_size=args.page_size,
-            kv_quant_block=args.kv_quant_block,
-            train_steps=args.quant_train_steps,
-            seed=args.seed,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.speculative:
-        result = run_spec_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=args.max_new_tokens or 160,
-            decode_block=args.decode_block or 8,
-            spec_k=args.spec_k,
-            spec_rounds=args.spec_rounds,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            train_steps=args.spec_train_steps,
-            seed=args.seed,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.chaos:
-        result = run_chaos_bench(
-            config=args.config,
-            n_requests=args.requests or 48,
-            n_slots=n_slots,
-            max_new=args.max_new_tokens or 48,
-            decode_block=args.decode_block or 8,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            seed=args.seed,
-            stall_s=args.chaos_stall,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.fleet:
-        result = run_fleet_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            n_replicas=args.fleet_replicas,
-            seed=args.seed,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-            trace_out=args.trace_out if args.trace else None,
-        )
-    elif args.journal:
-        result = run_journal_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            seed=args.seed,
-            kill_step=args.journal_kill_step,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.slo:
-        result = run_slo_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            seed=args.seed,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.http:
-        result = run_http_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            seed=args.seed,
-        )
-    elif args.paged:
-        result = run_paged_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            n_prefixes=args.n_prefixes,
-            prefix_requests=args.prefix_requests,
-            suffix_len=args.suffix_len,
-            page_size=args.page_size,
-            seed=args.seed,
-            status_port=args.status_port,
-            status_hold_s=args.status_hold_s,
-        )
-    elif args.sampling:
-        result = run_sampling_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            seed=args.seed,
-            **trace_kwargs,
-        )
-    elif args.shared_prefix:
-        result = run_prefix_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            n_prefixes=args.n_prefixes,
-            prefix_len=args.prefix_len,
-            suffix_len=args.suffix_len,
-            mean_interarrival_s=mean_ia,
-            prefix_page=args.prefix_page,
-            seed=args.seed,
-            **trace_kwargs,
-        )
-    else:
-        result = run_serve_bench(
-            config=args.config,
-            n_requests=n_requests,
-            n_slots=n_slots,
-            max_new=max_new,
-            decode_block=decode_block,
-            prompt_lens=tuple(prompt_lens),
-            mean_interarrival_s=mean_ia,
-            seed=args.seed,
-            skip_sequential=args.skip_sequential,
-            **trace_kwargs,
-        )
-    # identity stamp (schema v2): ONE clock reading injected here — the
-    # single place entries are written — so every entry is attributable
-    # to a git sha / jax / host after any future rebase
-    import time as _time
-
-    result = {**bench_provenance(timestamp=_time.time()), **result}
-    line = json.dumps(result)
-    print(line)
-    if args.out:
-        with open(args.out, "a" if args.append else "w") as f:
-            f.write(line + "\n")
-        verb = "appended to" if args.append else "wrote"
-        print(f"[serve-bench] {verb} {args.out}", file=sys.stderr)
-    return 0
-
-
-def cmd_kernel_bench(args) -> int:
-    """Microbench the serving stack's hot inner ops in isolation over
-    the full (pool layout x kv_quant) grid and print/write one
-    BENCH_kernels.json entry per grid cell (serve/kernel_bench.py)."""
-    from solvingpapers_tpu.serve.bench import bench_provenance
-    from solvingpapers_tpu.serve.kernel_bench import run_kernel_bench
-
-    entries = run_kernel_bench(
-        config=args.config,
-        n_slots=args.slots,
-        max_len=args.max_len,
-        page_size=args.page_size,
-        quant_block=args.kv_quant_block,
-        sample_cap=args.sample_cap,
-        spec_k=args.spec_k,
-        decode_block=args.decode_block,
-        reps=args.reps,
-        seed=args.seed,
-    )
-    # one provenance stamp per RUN (the serve-bench discipline: the
-    # timestamp is injected at the single write site, so the grid's
-    # four entries share one clock reading and one git sha)
-    import time as _time
-
-    prov = bench_provenance(timestamp=_time.time())
-    lines = [json.dumps({**prov, **e}) for e in entries]
-    for line in lines:
-        print(line)
-    if args.out:
-        with open(args.out, "a" if args.append else "w") as f:
-            for line in lines:
-                f.write(line + "\n")
-        verb = "appended to" if args.append else "wrote"
-        print(f"[kernel-bench] {verb} {args.out} "
-              f"({len(lines)} entries)", file=sys.stderr)
-    return 0
-
-
 def cmd_trace_summary(args) -> int:
     """Rebuild per-request timelines from a Chrome trace-event JSON the
-    flight recorder exported (`serve-bench --trace`,
+    flight recorder exported (`serve --trace --trace-out`,
     `engine.trace.export_chrome`, or TrainConfig.trace_path) and print
     phase breakdowns plus the slowest requests (metrics/trace.py)."""
     import os
@@ -1060,9 +748,8 @@ def cmd_trace_summary(args) -> int:
     if getattr(args, "fleet", False) and "fleet" not in summary:
         print(
             f"{args.trace} holds no fleet events: --fleet expects the "
-            "stitched export (FleetRouter.export_chrome_fleet, "
-            "`serve --replicas N --trace --trace-out`, or "
-            "`serve-bench --fleet --trace-out`); this looks like a "
+            "stitched export (FleetRouter.export_chrome_fleet or "
+            "`serve --replicas N --trace --trace-out`); this looks like a "
             "single-engine trace — rerun without --fleet",
             file=sys.stderr,
         )
@@ -1244,279 +931,6 @@ def main(argv=None) -> int:
     )
     p_sample.add_argument("--seed", type=int, default=0)
 
-    p_serve = sub.add_parser("serve-bench")
-    _add_common(p_serve)
-    p_serve.add_argument("--requests", type=int, default=None,
-                         help="default 32 (48 with --shared-prefix)")
-    p_serve.add_argument("--slots", type=int, default=None,
-                         help="default 8 (4 with --chaos, whose ladder "
-                              "arm needs deliberate overload)")
-    p_serve.add_argument("--max-new-tokens", type=int, default=None,
-                         help="default 64 (4 with --shared-prefix, whose "
-                              "TTFT story is prefill-bound)")
-    p_serve.add_argument("--decode-block", type=int, default=None,
-                         help="default 16 (4 with --shared-prefix)")
-    p_serve.add_argument("--prompt-lens", type=int, nargs="+",
-                         default=None,
-                         help="prompt-length cycle (bounded set => bounded "
-                              "compiles in both arms); default "
-                              "16 32 48 64 (24 32 40 48 with "
-                              "--speculative)")
-    p_serve.add_argument("--mean-interarrival", type=float, default=None,
-                         help="Poisson arrival mean gap in seconds; "
-                              "default 0.001 (0.15 with --chaos — "
-                              "admissions must keep arriving while the "
-                              "ladder is up for shedding to be "
-                              "observable)")
-    p_serve.add_argument("--seed", type=int, default=0)
-    p_serve.add_argument("--skip-sequential", action="store_true",
-                         help="only run the engine arm")
-    p_serve.add_argument("--shared-prefix", action="store_true",
-                         help="shared-prefix workload instead: N requests "
-                              "over K distinct system prompts, prefix "
-                              "cache on vs off (serve/bench.py "
-                              "run_prefix_bench)")
-    p_serve.add_argument("--sampling", action="store_true",
-                         help="sampling workload instead: the same Poisson "
-                              "trace decoded all-greedy vs with a "
-                              "per-request temperature/top-p/top-k/min-p "
-                              "mix (serve/bench.py run_sampling_bench)")
-    p_serve.add_argument("--http", action="store_true",
-                         help="HTTP soak workload instead: the Poisson "
-                              "trace as N concurrent SSE clients through "
-                              "the OpenAI front door, ABBA-paired against "
-                              "direct engine.submit — req/s, client-side "
-                              "TTFT, p99 ITL and http_overhead_pct "
-                              "(serve/bench.py run_http_bench)")
-    p_serve.add_argument("--paged", action="store_true",
-                         help="paged-KV-pool workload instead: ABBA-paired "
-                              "paged vs lane pool on the Poisson trace, a "
-                              "capacity arm at equal HBM (2x slots, "
-                              "lane-equivalent page budget), and a "
-                              "shared-prefix arm with zero-copy page "
-                              "sharing (serve/bench.py run_paged_bench)")
-    p_serve.add_argument("--speculative", action="store_true",
-                         help="speculative-decoding workload instead: "
-                              "ABBA-paired spec-on (n-gram drafter) vs "
-                              "spec-off delivered tokens/sec on a "
-                              "briefly-trained model, with a greedy "
-                              "token-exactness check and a temperature-"
-                              "2.0 zero-acceptance adversarial arm "
-                              "(serve/bench.py run_spec_bench; defaults "
-                              "max-new-tokens 160, decode-block 8)")
-    p_serve.add_argument("--slo", action="store_true",
-                         help="SLO-observatory workload instead: the "
-                              "Poisson trace with per-request SLO "
-                              "classes (interactive/standard/batch "
-                              "cycle) through an slo_targets-enabled "
-                              "engine, ABBA-paired against the plain "
-                              "engine — slo_overhead_pct (<= 2%% "
-                              "budget), per-class attainment, burn "
-                              "rates and goodput_tokens_per_s "
-                              "(serve/bench.py run_slo_bench)")
-    p_serve.add_argument("--chaos", action="store_true",
-                         help="fault-tolerance soak instead: one seeded "
-                              "fault schedule (NaN/Inf slot poisons, "
-                              "synthetic XlaRuntimeError + OOM, a step "
-                              "stall) over the Poisson trace through a "
-                              "fault-free reference, a ladder-off chaos "
-                              "arm (streams_survived, survivor token-"
-                              "exactness, fault_recovery_s, zero-leak "
-                              "drain) and a ladder-on arm (goodput with "
-                              "degradation on vs off), plus the ABBA-"
-                              "paired armed-but-quiet fault_overhead_pct "
-                              "(serve/bench.py run_chaos_bench)")
-    p_serve.add_argument("--journal", action="store_true",
-                         help="durability workload instead: ABBA-paired "
-                              "journal-on vs journal-off req/s on the "
-                              "Poisson trace (journal_overhead_pct, "
-                              "<= 2%% budget — fsync batched per step) "
-                              "plus a kill-and-recover arm: abandon the "
-                              "engine mid-decode, replay the journal "
-                              "through a fresh one, and record "
-                              "recovery_wall_s / recovered_requests / "
-                              "recovered_token_exact (serve/bench.py "
-                              "run_journal_bench)")
-    p_serve.add_argument("--fleet", action="store_true",
-                         help="fleet workload instead: the Poisson trace "
-                              "through a multi-replica FleetRouter — "
-                              "router_overhead_pct (ABBA-paired 1-replica "
-                              "router vs bare engine, pure routing tax), "
-                              "fleet token-exactness vs a single-engine "
-                              "reference, and a mid-decode drain arm: "
-                              "drain replica r0 with streams live, adopt "
-                              "them on the peer, record migration_wall_s "
-                              "/ migrated_streams / migrated_token_exact "
-                              "and zero-leak on BOTH replicas "
-                              "(serve/bench.py run_fleet_bench)")
-    p_serve.add_argument("--fleet-replicas", type=int, default=2,
-                         help="[--fleet] replica count for the exactness "
-                              "and drain arms (the overhead arm is "
-                              "always 1 replica, like-for-like)")
-    p_serve.add_argument("--journal-kill-step", type=int, default=None,
-                         help="[--journal] engine step at which the "
-                              "kill-and-recover arm abandons the first "
-                              "engine (default: a mid-decode point "
-                              "derived from the workload)")
-    p_serve.add_argument("--chaos-stall", type=float, default=0.75,
-                         help="[--chaos] injected step-stall seconds; "
-                              "the watchdog deadline is set BELOW it "
-                              "(max(0.25, 0.75x)) so the stall "
-                              "deterministically trips the fire path")
-    p_serve.add_argument("--replay", action="store_true",
-                         help="replay-observatory workload instead: "
-                              "journal a seeded greedy+seeded-sampling "
-                              "workload on a briefly-trained model, "
-                              "replay it through serve/replay.py "
-                              "against (a) the identical config on "
-                              "BOTH pool layouts — replay_byte_exact, "
-                              "the never-flip CI gate — and (b) an "
-                              "int8-kv candidate — "
-                              "replay_agreement_rate, the graded "
-                              "teacher-forced score (serve/bench.py "
-                              "run_replay_bench; defaults config "
-                              "gpt_tiny_long via tools/bench_serve.py)")
-    p_serve.add_argument("--replay-train-steps", type=int, default=150,
-                         help="[--replay] brief training steps before "
-                              "journaling (int8 agreement on random "
-                              "init measures argmax tie-breaking, not "
-                              "quantization quality; 0 = random init)")
-    p_serve.add_argument("--kv-quant", default=None, choices=["int8"],
-                         help="quantized-KV workload instead: int8 cache "
-                              "storage vs exact on a briefly-trained "
-                              "model — greedy-token agreement (teacher-"
-                              "forced, the >= 0.99 CI gate), ABBA-paired "
-                              "like-for-like Poisson overhead, and a "
-                              "capacity arm booking slots at the f32 "
-                              "paged pool's resident byte budget "
-                              "(serve/bench.py run_quant_bench; defaults "
-                              "config gpt_tiny_long)")
-    p_serve.add_argument("--kv-quant-block", type=int, default=16,
-                         help="[--kv-quant] lane-pool absmax-scale block "
-                              "length in tokens "
-                              "(ServeConfig.kv_quant_block; the paged "
-                              "pool always scales per page)")
-    p_serve.add_argument("--quant-train-steps", type=int, default=200,
-                         help="[--kv-quant] brief training steps before "
-                              "benching (agreement on a random-init "
-                              "model measures argmax tie-breaking over "
-                              "near-uniform logits, not quantization "
-                              "quality; 0 = random init)")
-    p_serve.add_argument("--spec-k", type=int, default=16,
-                         help="[--speculative] draft tokens per round "
-                              "(ServeConfig.spec_k)")
-    p_serve.add_argument("--spec-rounds", type=int, default=6,
-                         help="[--speculative] draft-verify rounds per "
-                              "decode call (ServeConfig.spec_rounds)")
-    p_serve.add_argument("--spec-train-steps", type=int, default=300,
-                         help="[--speculative] brief training steps on "
-                              "the synthetic corpus before benching "
-                              "(draft quality is the mechanism under "
-                              "test; 0 = random init, all-reject "
-                              "regime)")
-    p_serve.add_argument("--page-size", type=int, default=16,
-                         help="[--paged] tokens per KV page "
-                              "(ServeConfig.page_size)")
-    p_serve.add_argument("--prefix-requests", type=int, default=None,
-                         help="[--paged] request count for the "
-                              "shared-prefix sub-arm (default 48, the "
-                              "committed measurement regime; CI smokes "
-                              "pass a small value)")
-    p_serve.add_argument("--n-prefixes", type=int, default=4,
-                         help="[--shared-prefix] distinct system prompts K")
-    p_serve.add_argument("--prefix-len", type=int, default=None,
-                         help="[--shared-prefix] shared stem length "
-                              "(default: stretch to the model's position "
-                              "budget, page-aligned)")
-    p_serve.add_argument("--suffix-len", type=int, default=8,
-                         help="[--shared-prefix] unique tail length")
-    p_serve.add_argument("--prefix-page", type=int, default=16,
-                         help="[--shared-prefix] radix-tree page size")
-    p_serve.add_argument("--out", default=None,
-                         help="also write the JSON result here "
-                              "(tools/bench_serve.py default: BENCH_serve.json)")
-    p_serve.add_argument("--append", action="store_true",
-                         help="append to --out instead of overwriting "
-                              "(BENCH_serve.json is JSON-lines: one entry "
-                              "per workload)")
-    p_serve.add_argument("--trace", action="store_true",
-                         help="run one extra arm with the flight recorder "
-                              "on and record trace_overhead_pct (tracing-on "
-                              "vs tracing-off req/s on the same arrival "
-                              "trace) in the result detail")
-    p_serve.add_argument("--trace-out", default="serve_trace.json",
-                         help="[--trace] write the traced arm's Chrome "
-                              "trace-event JSON here (open in Perfetto or "
-                              "feed `cli trace-summary`)")
-    p_serve.add_argument("--trace-dump", default=None,
-                         help="[--trace] anomaly-dump JSONL path "
-                              "(ServeConfig.trace_dump_path): timeouts, "
-                              "reject bursts, and slow steps append the "
-                              "last ring events + a metrics snapshot")
-    p_serve.add_argument("--obs", action="store_true",
-                         help="run one extra paired arm with the compile "
-                              "& memory observatory on "
-                              "(ServeConfig.xla_obs) and record "
-                              "obs_overhead_pct (enabled-vs-disabled "
-                              "req/s, < 2%% budget); compile_time_s and "
-                              "peak_hbm_bytes are recorded per entry "
-                              "regardless, from the warm-phase probe")
-    p_serve.add_argument("--status-port", type=int, default=None,
-                         help="serve /healthz /metrics /statusz from the "
-                              "observatory probe engine for the duration "
-                              "of the bench (0 = ephemeral port, printed "
-                              "to stderr)")
-    p_serve.add_argument("--status-hold-s", type=float, default=0.0,
-                         help="[--status-port] keep the status endpoint "
-                              "up this many seconds after the arms "
-                              "finish (CI curl window)")
-    p_serve.add_argument("--obs-hlo-dir", default=None,
-                         help="dump each compiled program's HLO text "
-                              "here from the observatory probe engine "
-                              "(ServeConfig.obs_hlo_dir: one file per "
-                              "signature, atomic writes) so the anatomy "
-                              "ledger's claims can be diffed offline; "
-                              "Poisson workload only")
-
-    p_kern = sub.add_parser(
-        "kernel-bench",
-        help="fenced min-of-reps microbenchmarks of the serving stack's "
-             "hot inner ops — gather/scatter/quant-roundtrip/splice/"
-             "sample/spec-verify over the (pool layout x kv_quant) grid "
-             "(serve/kernel_bench.py; tools/bench_kernels.py defaults "
-             "--out BENCH_kernels.json)",
-    )
-    p_kern.add_argument("--config", default="gpt_shakespeare",
-                        help="registered decoder config whose cache "
-                             "shapes the ops are benched at (default "
-                             "gpt_shakespeare — the paged bench's "
-                             "model)")
-    p_kern.add_argument("--slots", type=int, default=8)
-    p_kern.add_argument("--max-len", type=int, default=256,
-                        help="lane length in tokens (rounded down to "
-                             "the page/quant-block grain and the "
-                             "model's position budget)")
-    p_kern.add_argument("--page-size", type=int, default=16)
-    p_kern.add_argument("--kv-quant-block", type=int, default=16)
-    p_kern.add_argument("--sample-cap", type=int, default=64)
-    p_kern.add_argument("--spec-k", type=int, default=4,
-                        help="draft width of the speculative 1+k verify "
-                             "window op")
-    p_kern.add_argument("--decode-block", type=int, default=16,
-                        help="recorded knob: sets the decomposition's "
-                             "scatter multiplier — the paged decode "
-                             "program runs (decode_block-1)//page_size "
-                             "+ 2 write-back windows per call")
-    p_kern.add_argument("--reps", type=int, default=5,
-                        help="fenced repetitions per op (min is kept)")
-    p_kern.add_argument("--seed", type=int, default=0)
-    p_kern.add_argument("--out", default=None,
-                        help="also write the JSON-lines entries here "
-                             "(tools/bench_kernels.py default: "
-                             "BENCH_kernels.json)")
-    p_kern.add_argument("--append", action="store_true",
-                        help="append to --out instead of overwriting")
-
     p_srv = sub.add_parser("serve")
     _add_common(p_srv)
     p_srv.add_argument("--port", type=int, default=8000,
@@ -1538,8 +952,8 @@ def main(argv=None) -> int:
                        help="hold the KV pool as symmetric int8 with "
                             "per-block absmax scales (~half the resident "
                             "KV bytes vs bf16, a quarter vs f32; output "
-                            "quality gated by the bench's measured "
-                            "greedy-agreement rate, not exactness)")
+                            "is close to the exact pool's, not equal: "
+                            "`cli replay` scores the agreement)")
     p_srv.add_argument("--kv-quant-block", type=int, default=16,
                        help="[--kv-quant] lane-pool scale block length "
                             "in tokens (must divide max-len; the paged "
@@ -1705,7 +1119,7 @@ def main(argv=None) -> int:
     p_tsum = sub.add_parser("trace-summary")
     p_tsum.add_argument("trace",
                         help="Chrome trace-event JSON exported by the "
-                             "flight recorder (serve-bench --trace-out, "
+                             "flight recorder (serve --trace --trace-out, "
                              "engine.trace.export_chrome, "
                              "TrainConfig.trace_path)")
     p_tsum.add_argument("--top", type=int, default=5,
@@ -1730,9 +1144,7 @@ def main(argv=None) -> int:
     from solvingpapers_tpu.compile_cache import configure_compile_cache
 
     configure_compile_cache()
-    # kernel-bench skips _apply_platform: it takes no _add_common flags
-    # (no data/checkpoint plumbing) — set JAX_PLATFORMS in the env
-    if args.cmd not in ("list", "trace-summary", "kernel-bench"):
+    if args.cmd not in ("list", "trace-summary"):
         # before any command code touches jax (see _apply_platform docstring)
         _apply_platform(args)
     return {
@@ -1741,8 +1153,6 @@ def main(argv=None) -> int:
         "sample": cmd_sample,
         "serve": cmd_serve,
         "replay": cmd_replay,
-        "serve-bench": cmd_serve_bench,
-        "kernel-bench": cmd_kernel_bench,
         "trace-summary": cmd_trace_summary,
         "eval": cmd_eval,
         "export": cmd_export,

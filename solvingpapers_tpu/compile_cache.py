@@ -1,7 +1,7 @@
 """Where this checkout keeps JAX's persistent compilation cache.
 
-Entry points (`cli.main`, `chip_smoke.py`, `bench.py`, the `tools/`
-scripts) call `configure_compile_cache()` once, before their first
+Entry points (`cli.main`, `chip_smoke.py`, `benchmarks/run.py`, the
+`tools/` scripts) call `configure_compile_cache()` once, before their first
 compilation. The directory is part of the cache key, so it must be the
 same in every process: either the one `JAX_COMPILATION_CACHE_DIR`
 names — JAX reads that variable itself, so nothing is set here — or

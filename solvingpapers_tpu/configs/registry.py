@@ -610,7 +610,7 @@ def _dsv3_long() -> RunConfig:
     DeepSeekV3 (MLA + MoE) at 16,384-token context on a single chip via
     flash-MLA (absorbed-query attention through the Pallas kernel; the
     dense einsum path cannot even compile at this length) + per-layer
-    remat. Measured 433 ms/step / 38k tok/s on 1x v5e (BENCHMARKS.md)."""
+    remat. Benchmark cell `dsv3_long.train_16k` (PERF.md)."""
     from solvingpapers_tpu.models.deepseekv3 import DeepSeekV3Config
 
     return RunConfig(

@@ -2,7 +2,7 @@
 
 A process that has initialised a JAX backend holds the accelerator, and a
 child it then starts cannot have it. So the scripts that need an N-device
-CPU mesh (`__graft_entry__.dryrun_multichip`, `tools/bench_pipeline.py`)
+CPU mesh (`__graft_entry__.dryrun_multichip`)
 decide whether to re-exec from `JAX_PLATFORMS` and `XLA_FLAGS` — which is
 all JAX itself will look at — and never ask `jax.devices()` first.
 Nothing here imports jax.

@@ -25,9 +25,9 @@ q-blocks, kv-blocks) with the kv axis 'arbitrary' (sequential) and the
 online-softmax state carried in VMEM scratch — so VMEM holds only
 O(block_q x block_k) tiles regardless of sequence length. (The earlier 2-D
 formulation kept full-length K/V rows in VMEM and hit the 16 MB scoped-vmem
-ceiling at seq 16k; this one trains 350M at 16k on a single v5e chip —
-32k+ is HBM-bound there and is the job of context parallelism, see
-BENCHMARKS.md.)
+ceiling at seq 16k. What one chip's HBM cannot hold at all is the job
+of context parallelism: ring attention or Ulysses resharding, in
+`sharding/`.)
 """
 
 from __future__ import annotations

@@ -48,9 +48,8 @@ Everything is opt-in (`ServeConfig.xla_obs` / `TrainConfig.xla_obs`);
 with it off the engines never import this module and every hook site is
 a single `is not None` branch. With it on, program calls are fenced
 (`block_until_ready`) so run seconds are device-true — the same
-observability-mode contract as flight-recorder tracing, held to the
-same paired-bench overhead budget (`obs_overhead_pct` in
-BENCH_serve.json).
+observability-mode contract as flight-recorder tracing; what the
+fences cost is not measured on the chip.
 """
 
 from __future__ import annotations

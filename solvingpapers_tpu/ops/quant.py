@@ -14,8 +14,8 @@ granularity so one outlier cannot flatten a whole lane:
 * 3-D leaves (MLA latents): one f32 scale per ``(batch, time-block)``.
   Per-channel scales would cost 4 bytes per `block` int8 entries (25%
   at block 16 — enough to push the latent pool past the 0.6x byte
-  budget), so latents take the coarser per-block scalar and the quality
-  gate (greedy-agreement rate, serve/bench.py) measures the cost.
+  budget), so latents take the coarser per-block scalar; what that costs
+  in greedy agreement is not measured on the chip (`cli replay` scores it).
 
 Scale semantics: ``scale = absmax / 127`` over the block, so the
 block's max-magnitude entry maps to exactly +-127 and every entry obeys

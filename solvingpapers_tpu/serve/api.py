@@ -226,35 +226,41 @@ class EngineLoop:
         host-side via `engine.force_drain` — no further device work."""
         if not self._thread.is_alive():
             return
+        # through `_locked`, as every other caller off the loop's thread:
+        # taken bare, the lock is re-won by the loop step after step and
+        # close() waits the streams out instead of cancelling them
         deadline = time.monotonic() + drain_timeout_s
         while time.monotonic() < deadline:
-            with self.lock:
-                if not self.engine.has_work():
-                    break
+            if not self._locked(self.engine.has_work):
+                break
             time.sleep(0.01)
-        with self.lock:
-            for r in list(self.engine._slot_req):
-                if r is not None:
-                    self.engine.cancel(r)
-            for r in list(self.engine.scheduler.queue):
-                self.engine.cancel(r)
-            # one bounded drain pass finishes the cancelled streams
-            # (cancels resolve at the next block boundary); capped on
-            # BOTH steps and wall clock — a step stalled past the cap
-            # falls through to the host-side force drain below
-            steps = 0
-            cancel_deadline = time.monotonic() + min(
-                5.0, max(1.0, drain_timeout_s)
-            )
-            while (self.engine.has_work() and steps < 64
-                   and time.monotonic() < cancel_deadline):
-                self.engine.step()
-                steps += 1
-            if self.engine.has_work():
-                self.engine.force_drain("cancelled")
+        self._locked(lambda: self._cancel_and_drain(drain_timeout_s))
         self._stop.set()
         self._wake.set()
         self._thread.join(timeout=5)
+
+    def _cancel_and_drain(self, drain_timeout_s: float) -> None:
+        """Under the lock: cancel every request, step the cancels to their
+        block boundary within a wall cap, force-finish what is left."""
+        for r in list(self.engine._slot_req):
+            if r is not None:
+                self.engine.cancel(r)
+        for r in list(self.engine.scheduler.queue):
+            self.engine.cancel(r)
+        # one bounded drain pass finishes the cancelled streams
+        # (cancels resolve at the next block boundary); capped on
+        # BOTH steps and wall clock — a step stalled past the cap
+        # falls through to the host-side force drain below
+        steps = 0
+        cancel_deadline = time.monotonic() + min(
+            5.0, max(1.0, drain_timeout_s)
+        )
+        while (self.engine.has_work() and steps < 64
+               and time.monotonic() < cancel_deadline):
+            self.engine.step()
+            steps += 1
+        if self.engine.has_work():
+            self.engine.force_drain("cancelled")
 
 
 class _Stream:

@@ -40,7 +40,7 @@ decouples from max_seq, the scheduler admits on a PAGE budget (free
 pages must cover prompt + a decode reservation), and a stream that
 outgrows the pool is preempted — pages freed, request requeued at the
 head, KV recomputed on resume (token streams unchanged). The lane pool
-stays the default and the bench baseline (`serve-bench --paged`).
+stays the default.
 
 Cross-request prefix reuse (`serve/prefix_cache.py`, opt-in via
 `ServeConfig.prefix_cache` — see its docstring for the cost model):
@@ -243,8 +243,8 @@ class ServeConfig:
     consecutive rejections, or a step exceeding `trace_slow_step_factor`
     x the rolling median step time append the last `trace_dump_events`
     events + a `ServeMetrics.snapshot()` to that JSONL file. With
-    `trace` off every hook site is one `is None` branch (< 2% req/s on
-    the Poisson bench — BENCH_serve.json `trace_overhead_pct`).
+    `trace` off every hook site is one `is None` branch; what it costs
+    when on is not measured on the chip.
 
     Profiler (`profile_dir`): opens a `jax.profiler.trace` window around
     engine steps [`profile_steps[0]`, `profile_steps[1]`) with
@@ -265,7 +265,7 @@ class ServeConfig:
     Greedy streams are token-exact with the cache on or off. Opt-in:
     every admission pays a match + snapshot copy and the tree holds up
     to `prefix_cache_bytes` of HBM, which is pure overhead on traffic
-    with no shared prefixes (~10% req/s on the Poisson bench) — turn it
+    with no shared prefixes (not measured on the chip) — turn it
     on when prompts share stems (system prompts, few-shot, multi-turn).
     """
 
@@ -277,8 +277,8 @@ class ServeConfig:
     # style): one physical pool of `page_budget` fixed-size KV pages +
     # per-slot page tables instead of contiguous max_len lanes. HBM is
     # booked per PAGE actually needed, so slot count decouples from
-    # max_len (more concurrent slots at equal HBM — the bench's
-    # --paged arm measures it), and the prefix cache shares pages
+    # max_len (more concurrent slots at equal HBM), and the prefix
+    # cache shares pages
     # zero-copy by refcount (a full-page hit dispatches NO device
     # program). Admission moves from slot-count to page-budget
     # accounting: a request is admitted while free pages cover its
@@ -302,14 +302,13 @@ class ServeConfig:
     # the pool holds symmetric int8 payload + per-block f32 absmax
     # scales instead of the compute dtype — roughly HALF the resident KV
     # bytes (vs bf16; a quarter vs f32), i.e. ~2x the servable slots or
-    # context at the same HBM budget (the serve-bench --kv-quant
-    # capacity arm measures it). The jitted programs dequantize on read
+    # context at the same HBM budget. The jitted programs dequantize on read
     # (gather/extract sites materialize the familiar compute-dtype lane
     # view — models serve unmodified) and quantize on write (store/
     # scatter sites requantize only the blocks/pages the step wrote).
-    # Output quality is gated on MEASUREMENT, not exactness: the bench
-    # records a greedy-token agreement rate vs the full-precision pool
-    # per BENCH_serve.json entry (>= 0.99 is the CI gate).
+    # Output is close to the exact pool's, not equal: `cli replay
+    # --config-overrides kv_quant=int8` scores the greedy-token agreement
+    # of recorded streams (not measured on the chip).
     #   kv_quant        None = exact storage (today's pools, untouched
     #                   code paths); "int8" = quantized payload + scale
     #                   sidecar in BOTH pool layouts. The prefix cache
@@ -531,9 +530,9 @@ class ServeConfig:
     # projected decode-step peak vs device capacity, warning before the
     # projection exceeds it. Gauges ride ServeMetrics.snapshot() as
     # compile/* + mem/* + roofline/* keys. Observability mode: program
-    # calls are fenced for device-true run seconds (same contract and
-    # paired-bench budget as `trace` — BENCH_serve.json
-    # `obs_overhead_pct`); off = None registry, one branch per call site.
+    # calls are fenced for device-true run seconds (same contract as
+    # `trace`; cost not measured on the chip); off = None registry, one
+    # branch per call site.
     # The registry also parses every compiled program's HLO text into
     # the per-op-category anatomy ledger (metrics/hlo_cost.py —
     # gather/scatter/dot/convert/... flops + output-shape bytes, top-k
@@ -664,9 +663,7 @@ def _prefill_program(model, padded, chunk, start, cap, variables, caches,
 
     `prompt` is (padded,) right-padded; `ctl = [slot, length, step,
     top_k, seed, need_lp, *allow_row]` is the host's packed int control
-    word (one transfer instead of many — the host loop's dispatch
-    overhead is the serving bottleneck on small models, see
-    tools/bench_serve.py), where `length` is the real token count, so
+    word (one transfer instead of many), where `length` is the real token count, so
     one compiled program serves every prompt in the bucket.
     `allow_row` is the (cap,) grammar allow-list for the FIRST sampled
     token (-1-padded; all -1 = unconstrained — see serve/grammar.py). `samp = [temperature, top_p, min_p]` is
@@ -1750,9 +1747,8 @@ class ServeEngine:
                 storm_window_s=cfg.obs_storm_window_s,
                 clock=smetrics.now,
                 # the per-op anatomy ledger rides the observatory: the
-                # parse is compile-time-only, and the armed steady-state
-                # cost is held to the same paired-bench <= 2% budget
-                # (BENCH_serve.json anatomy_overhead_pct)
+                # parse is compile-time-only (steady-state cost not
+                # measured on the chip)
                 anatomy=True,
                 hlo_dir=cfg.obs_hlo_dir,
             )

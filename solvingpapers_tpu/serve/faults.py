@@ -239,7 +239,7 @@ class FaultPlan:
         if isinstance(plan, FaultPlan):
             # each engine replays the schedule from visit 0: a shared
             # plan object must not leak one engine's counters into the
-            # next (bench arms reuse one config)
+            # next (a fleet's replicas share one config)
             return cls(plan.specs)
         return cls(plan)
 

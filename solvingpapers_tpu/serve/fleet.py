@@ -67,8 +67,7 @@ door closes a ``"migrated"`` stream WITHOUT a terminal chunk, the
 client reconnects with its Last-Event-ID cursor, and the cursor
 resolves on the peer through the same recovered-set path a crash
 restart uses — zero dropped streams, byte-identical transcripts
-(pinned in tests/test_fleet.py; measured in BENCH_serve.json's
-``serve_fleet_migrated_streams`` entry). ``"migrated"`` is excluded
+(pinned in tests/test_fleet.py). ``"migrated"`` is excluded
 from SLO accounting on the drained replica (serve/slo.py) — the
 adopting replica owns the latency outcome.
 """

@@ -8,8 +8,7 @@ applies that at lane granularity — one `max_seq` lane per slot, HBM
 booked for the worst case. `PagedKVPool` (second half of this module)
 is the full vLLM-PagedAttention layout: one physical pool of fixed-size
 KV pages, per-slot page tables, and refcounted zero-copy prefix sharing
-(`ServeConfig.paged`); the lane pool remains the default and the paired
-baseline the bench measures the paged pool against.
+(`ServeConfig.paged`); the lane pool remains the default.
 
 The pool is carved out of the existing cache machinery unchanged: the
 pooled pytrees come from ``model.init_caches(n_slots, max_len)``

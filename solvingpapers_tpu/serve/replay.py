@@ -22,14 +22,11 @@ Two comparison modes, applied per stream by replayability class:
   `first_divergence` token offset. Unseeded stochastic streams fold
   the engine step counter (serve/sampling.py) — they are re-served for
   load realism but excluded from byte accounting.
-* **teacher-forced agreement** — the quant bench's cut-replay
-  machinery (PR 10) generalized to arbitrary recorded streams: each
+* **teacher-forced agreement** — over arbitrary recorded streams: each
   byte-comparable stream is cut every `cut_stride` positions and the
   prefix re-served through the candidate for exactly ONE token.
-  Greedy cuts submit the prefix as a plain prompt (PR 10's cut
-  verbatim — the measurement `run_quant_bench`'s >= 0.99
-  `greedy_agreement_rate` band is calibrated on; argmax needs no seed
-  pinning). Seeded cuts ride `ServeEngine.replay_submit`'s
+  Greedy cuts submit the prefix as a plain prompt (argmax needs no
+  seed pinning). Seeded cuts ride `ServeEngine.replay_submit`'s
   committed-prefix path, which pins the recorded seed chain
   (admission re-prefills prompt + committed[:-1], discards the
   resampled token, and the next draw lands at sample index
@@ -440,8 +437,7 @@ class ReplayHarness:
         # number (argmax agreement is robust to small logit error);
         # seeded cuts re-draw through the pinned seed chain, where a
         # lossy candidate flips tokens far more readily — disclosed
-        # separately so the graded score stays comparable to the
-        # --kv-quant bench's greedy_agreement_rate precedent
+        # separately
         by_kind = {"greedy": [0, 0], "seeded": [0, 0]}  # [total, match]
         if cut_stride:
             cuts = []  # (expected token, entry, params, offset, kind)
@@ -466,9 +462,6 @@ class ReplayHarness:
                         max_tokens=None)
                 try:
                     if kind == "greedy":
-                        # PR 10's plain-prompt cut verbatim — the
-                        # measurement run_quant_bench's >= 0.99
-                        # greedy_agreement_rate band is calibrated on:
                         # the teacher-forced prefix rides the prefill
                         # path and argmax needs no seed pinning
                         h = eng.replay_submit(

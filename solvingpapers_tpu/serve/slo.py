@@ -30,8 +30,7 @@ and the substrate the DistServe-style disaggregated phase (ROADMAP item
   waiting for). Exposed as `serve/goodput_tokens[_per_s]`.
 
 Pure host-side bookkeeping on the finish path — no device work, no new
-program shapes; the serve-bench ``--slo`` arm holds the whole observatory
-(SLO tracking + histogram backend) to the PR-4/5 <= 2% paired budget.
+program shapes; its cost is not measured on the chip.
 """
 
 from __future__ import annotations

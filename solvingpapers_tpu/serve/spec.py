@@ -57,8 +57,7 @@ chunk width for nothing. The controller tracks an acceptance EMA per
 engine and drops the engine back to the plain block program while the
 EMA is below `spec_min_rate`, probing speculation again every
 `spec_probe_every` steps — bounding the zero-acceptance overhead to the
-occasional probe (the `serve-bench --speculative` adversarial arm
-measures it against a <= 10% budget).
+occasional probe (not measured on the chip).
 """
 
 from __future__ import annotations

@@ -501,7 +501,7 @@ class Trainer:
         """Loss AND grads via the 1F1B schedule (TrainConfig.pp_schedule
         = "1f1b"): the model's f1b_value_and_grad runs inside shard_map —
         per-microbatch backwards interleaved with forwards, live
-        activations bounded by pipe depth (BENCHMARKS.md PP memory table)
+        activations bounded by pipe depth
         — so the engine consumes grads directly instead of wrapping the
         forward in jax.value_and_grad."""
         self._reject_axes(

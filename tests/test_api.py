@@ -752,10 +752,16 @@ def test_server_close_bounded_under_injected_stall(gpt_tiny):
     srv, eng = _fault_server(gpt_tiny, plan, drain_timeout_s=0.2)
     req = srv.loop.submit(np.asarray(list(range(8)), np.int32),
                           max_new_tokens=64)
-    time.sleep(0.2)  # let the loop start stepping (and stalling)
+    # let the loop start stepping (and stalling): past its first decode
+    # block the programs are compiled, so close() is timed against the
+    # stalls and not against XLA compiling in the loop's thread
+    waited = time.monotonic() + 120
+    while len(req.tokens) < 2 and time.monotonic() < waited:
+        time.sleep(0.05)
+    assert len(req.tokens) >= 2 and not req.done
     t0 = time.monotonic()
     srv.close()
     took = time.monotonic() - t0
     assert took < 6.0, f"close took {took:.1f}s — unbounded shutdown"
-    assert req.done
+    assert req.done and req.finish_reason == "cancelled"
     assert_no_leaks(eng)

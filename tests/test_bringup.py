@@ -1,9 +1,7 @@
 """What the chip bring-up added around the entry points: the placeable
-compile cache, re-exec decisions that never touch JAX, and a bench.py that
-cannot look clean when it is not."""
+compile cache and re-exec decisions that never touch JAX."""
 
 import importlib.util
-import json
 import os
 import pathlib
 import subprocess
@@ -117,41 +115,3 @@ def test_dryrun_reexec_is_decided_without_touching_jax(monkeypatch):
     monkeypatch.setattr(subprocess, "run", fake_run)
     graft.dryrun_multichip(4)
     assert len(calls) == 1 and hostenv.virtual_cpu_devices(calls[0]) == 4
-
-
-# ----------------------------------------------------------------- bench.py
-
-
-def test_bench_refuses_a_device_that_is_not_a_tpu(cache_dir_restored):
-    bench = _load("bench")
-    with pytest.raises(SystemExit, match="measures the TPU"):
-        bench.main()
-
-
-ROWS = ("bench_gpt_train", "bench_350m_mfu", "bench_flash_mla_16k",
-        "bench_decode", "bench_decode_16k_prefill",
-        "bench_speculative_decode", "bench_dropout_identity")
-
-
-def _boom():
-    raise RuntimeError("row exploded")
-
-
-@pytest.mark.parametrize("raising,code", [
-    ((), 0),
-    (("bench_flash_mla_16k",), 1),
-], ids=["clean", "row_raises"])
-def test_bench_exit_code_reports_a_row_that_raised(
-        monkeypatch, capsys, cache_dir_restored, raising, code):
-    bench = _load("bench")
-    monkeypatch.setattr(bench, "_require_tpu", lambda: None)
-    for row in ROWS:
-        monkeypatch.setattr(
-            bench, row,
-            _boom if row in raising else (lambda: {"tokens_per_sec": 1.0}),
-        )
-    assert bench.main() == code
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert len(out["scorecard"]) == len(ROWS) and out["value"] == 1.0
-    failed = [r["name"] for r in out["scorecard"] if "error" in r]
-    assert failed == (["flash_mla_16k_step"] if raising else [])

@@ -53,7 +53,7 @@ def one_chip(topo):
 SHAPES = {
     # dsv3_long: absorbed-query MLA is MQA over the latent stream
     "mla_16k": (1, 16_384, 8, 1, 128, 0.0),
-    # bench.py's GPT row: one 256-wide head, in-kernel dropout
+    # gpt_shakespeare's attention: one 256-wide head, in-kernel dropout
     "gpt_dropout": (128, 256, 1, 1, 256, 0.1),
     # the 342M llama3 study point: GQA 16 q heads over 8 kv heads
     "llama_gqa_1k": (8, 1024, 16, 8, 64, 0.0),

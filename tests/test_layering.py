@@ -1,0 +1,149 @@
+"""The package's import graph, read with `ast` (nothing here imports JAX or
+the package).
+
+  * each sub-package imports only from the sub-packages below it: the
+    allowed sets are the arrows as they stand, and the two arrows that
+    point the wrong way are named by file, so that a third cannot appear
+    unnoticed and a paid debt must be struck here;
+  * nothing under `solvingpapers_tpu/` imports what stands beside it
+    (the benchmark, the tools, the tests, the chip check);
+  * every `solvingpapers_tpu` module that the benchmark imports exists,
+    and binds the names the benchmark takes from it: a deletion cannot
+    break the benchmark before the chip says so.
+"""
+
+import ast
+import pathlib
+
+import pytest
+
+pytestmark = pytest.mark.fast
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+PKG = "solvingpapers_tpu"
+
+# sub-package -> what it may import from the package (itself aside)
+ALLOWED = {
+    "kernels": set(),
+    "ops": {"kernels"},
+    "sharding": {"kernels", "ops"},
+    "data": {"native", "sharding"},
+    "checkpoint": set(),
+    "metrics": {"ops", "sharding"},
+    "infer": {"ops", "sharding"},
+    "models": {"infer", "kernels", "ops", "sharding"},
+    "train": {"checkpoint", "metrics", "ops", "sharding"},
+    "serve": {"buildinfo", "infer", "metrics", "models", "ops"},
+    "configs": {"data", "models", "sharding", "train"},
+}
+
+# the arrows that point upwards today (ROADMAP D17, D18): (file, target)
+BACK_ARROWS = {
+    "metrics": {("metrics/timeseries.py", "serve")},
+    "infer": {("infer/speculative.py", "models")},
+}
+
+BESIDE_THE_PACKAGE = {"benchmarks", "tools", "tests", "bench", "chip_smoke"}
+
+
+def imports_of(path: pathlib.Path, package: tuple[str, ...] = ()):
+    """(absolute dotted module, names taken from it) for every import
+    statement of the file, wherever it stands; `package` is the file's own
+    package, which relative imports are resolved against."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                yield alias.name, ()
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - (node.level - 1)] if node.level else ()
+            module = ".".join((*base, *filter(None, [node.module])))
+            yield module, tuple(alias.name for alias in node.names)
+
+
+def package_files(sub: str = ""):
+    """(file, the package it belongs to) under the package or one part of it."""
+    for path in sorted((REPO / PKG / sub).rglob("*.py")):
+        yield path, path.relative_to(REPO).parts[:-1]
+
+
+def package_imports(sub: str):
+    """(file relative to the package, first segment under the package) for
+    every import a sub-package makes of another part of the package."""
+    root = REPO / PKG
+    for path, package in package_files(sub):
+        for module, names in imports_of(path, package):
+            parts = module.split(".")
+            if parts[0] != PKG:
+                continue
+            # `from solvingpapers_tpu import serve` names its target last
+            targets = [parts[1]] if len(parts) > 1 else names
+            for target in targets:
+                if target != sub:
+                    yield path.relative_to(root).as_posix(), target
+
+
+@pytest.mark.parametrize("sub", sorted(ALLOWED))
+def test_subpackage_imports_only_from_below(sub):
+    upwards = {(f, t) for f, t in package_imports(sub) if t not in ALLOWED[sub]}
+    assert upwards == BACK_ARROWS.get(sub, set())
+
+
+def test_package_imports_nothing_that_stands_beside_it():
+    found = [
+        (path.relative_to(REPO).as_posix(), module)
+        for path, package in package_files()
+        for module, _ in imports_of(path, package)
+        if module.split(".")[0] in BESIDE_THE_PACKAGE
+    ]
+    assert found == []
+
+
+def benchmark_imports() -> dict[str, set[str]]:
+    """module -> names, over every file under `benchmarks/`."""
+    taken: dict[str, set[str]] = {}
+    for path in sorted((REPO / "benchmarks").rglob("*.py")):
+        for module, names in imports_of(path):
+            if module.split(".")[0] == PKG:
+                taken.setdefault(module, set()).update(names)
+    return taken
+
+
+def module_file(module: str) -> pathlib.Path | None:
+    stem = REPO.joinpath(*module.split("."))
+    for candidate in (stem.with_suffix(".py"), stem / "__init__.py"):
+        if candidate.is_file():
+            return candidate
+    return None
+
+
+def bound_at_top_level(path: pathlib.Path) -> set[str]:
+    bound = set()
+    for node in ast.parse(path.read_text()).body:
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
+            bound.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            bound.update((a.asname or a.name).split(".")[0] for a in node.names)
+        elif isinstance(node, (ast.Assign, ast.AnnAssign, ast.AugAssign)):
+            targets = node.targets if isinstance(node, ast.Assign) else [node.target]
+            bound.update(n.id for t in targets for n in ast.walk(t)
+                         if isinstance(n, ast.Name))
+    return bound
+
+
+BENCHMARK_IMPORTS = benchmark_imports()
+
+
+def test_the_benchmark_imports_the_package():
+    assert len(BENCHMARK_IMPORTS) >= 10
+
+
+@pytest.mark.parametrize("module", sorted(BENCHMARK_IMPORTS))
+def test_module_the_benchmark_imports_is_in_the_tree(module):
+    path = module_file(module)
+    assert path is not None, f"benchmarks/ imports {module}: no such file"
+    bound = bound_at_top_level(path)
+    missing = {
+        name for name in BENCHMARK_IMPORTS[module]
+        if name not in bound and module_file(f"{module}.{name}") is None
+    }
+    assert not missing, f"benchmarks/ takes {sorted(missing)} from {module}"

@@ -295,8 +295,7 @@ def test_spec_mtp_accepts_on_predictable_stream():
     streams stay exact — the serving twin of
     tests/test_speculative.py's acceptance test. Marked slow (a 150-step
     training fit): tier-1 already gates MTP exactness (the untrained
-    all-reject path above), and trained-draft acceptance is gated by
-    CI's serve-bench speculative smoke; the function-level twin
+    all-reject path above); the function-level twin
     (tests/test_speculative.py) is slow-marked for the same reason."""
     from solvingpapers_tpu.data.batches import lm_batch_iterator
     from solvingpapers_tpu.models.deepseekv3 import (

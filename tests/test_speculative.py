@@ -59,9 +59,7 @@ def test_speculative_accepts_on_predictable_stream():
     live, not just the fallback path. Marked slow (training-fit-backed):
     tier-1 keeps draft-verify token equality at every level (the
     equality tests here, the CLI regression, the serving matrix in
-    tests/test_spec.py), and live-acceptance is gated by CI's
-    serve-bench speculative smoke (acceptance fields + exactness on a
-    trained model)."""
+    tests/test_spec.py)."""
     from solvingpapers_tpu.data.batches import lm_batch_iterator
     from solvingpapers_tpu.train import OptimizerConfig, TrainConfig, Trainer
     from solvingpapers_tpu.train.objectives import dsv3_init_fn, dsv3_loss_fn
@@ -128,8 +126,7 @@ def test_speculative_2draft_beats_single_on_predictable_stream():
     tokens/forward ABOVE the single-draft cap of 2. Marked slow (a
     training fit feeds a PERFORMANCE acceptance): 2-draft token
     equality stays tier-1 (`test_speculative_2draft_equals_plain_greedy`
-    + the full-context edge), and the live-speedup contract is gated by
-    CI's serve-bench speculative smoke."""
+    + the full-context edge)."""
     from solvingpapers_tpu.data.batches import lm_batch_iterator
     from solvingpapers_tpu.train import OptimizerConfig, TrainConfig, Trainer
     from solvingpapers_tpu.train.objectives import dsv3_init_fn, dsv3_loss_fn

@@ -1,4 +1,4 @@
-"""Reproducible 350M llama3 single-chip scaling study (BENCHMARKS.md).
+"""Reproducible 350M llama3 single-chip scaling study.
 
 Measures steady-state training step time / tokens-per-sec / MFU for the
 342M-param llama3 config (dim 1024, 24 layers, 16 q / 8 kv heads, seq 1024,
@@ -9,8 +9,7 @@ stops only after the device has finished.
 Usage: python tools/scale_350m.py [--bs 8] [--flash 1] [--remat 0]
        [--block-q N] [--block-k N] [--steps 20] [--seq 1024]
        [--profile-dir DIR]
---block-q/--block-k default to the kernel's DEFAULT_BLOCK (512; pass 128
-to reproduce the pre-sweep rows in BENCHMARKS.md). Timing mirrors bench.py:
+--block-q/--block-k default to the kernel's DEFAULT_BLOCK (512). Timing:
 long warmup to fill the dispatch queue, then best of 3 windows, each fenced
 by a device_get.
 """
@@ -92,8 +91,8 @@ def main() -> None:
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
     trainer._build_steps()
 
-    # compile + warmup long enough to fill the dispatch queue (bench.py's
-    # methodology), fenced by a value fetch
+    # compile + warmup long enough to fill the dispatch queue, fenced by
+    # a value fetch
     for _ in range(10):
         state, m = trainer._train_step(state, next(it))
     _ = float(jax.device_get(m["train_loss"]))

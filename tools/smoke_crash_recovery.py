@@ -2,8 +2,8 @@
 restart it on the same journal, and assert the recovered completions
 are byte-identical to an uninterrupted reference.
 
-The in-process kill-and-recover arm (`serve-bench --journal`) abandons
-an engine object; this smoke does the real thing — a subprocess
+The in-process recovery tests (tests/test_journal.py) abandon an engine
+object; this smoke does the real thing — a subprocess
 `python -m solvingpapers_tpu.cli serve --journal ...` killed with
 SIGKILL while SSE streams are mid-flight — and drives the full client
 resume protocol: each stream tracks the last ``id: <rid>:<offset>``
