@@ -38,6 +38,7 @@ from solvingpapers_tpu import ops
 from solvingpapers_tpu.infer.cache import (
     CPLatentCache, LatentCache, update_latent_cache,
 )
+from solvingpapers_tpu.kernels import moe_grouped
 from solvingpapers_tpu.models.layers import (
     GLUFFN, RMSNorm, LayerNorm, maybe_remat, swiglu_hidden_dim,
 )
@@ -425,6 +426,8 @@ class MoELayer(nn.Module):
         # dispatches per-member token shards, so its drops are counted from
         # the shard's probs and psum'd over the expert axis too
         drop_probs = drop_axes = None
+        # whether the routed experts run as kernels/moe_grouped.py's kernels
+        grouped = False
 
         if cfg.moe_impl == "dense":
             def expert_fn_all(xt):
@@ -439,10 +442,14 @@ class MoELayer(nn.Module):
                 g = jnp.einsum("ecd,edh->ech", xe, w2s)
                 return jnp.einsum("ech,ehd->ecd", ops.swish(a) * g, w3s)
 
-            def expert_fn(xe):  # (E, C, D) -> (E, C, D)
-                return expert_body(
-                    xe, w1.astype(dt), w2.astype(dt), w3.astype(dt)
-                )
+            def expert_fn(xe, fill):  # (E, C, D), (E,) -> (E, C, D)
+                ws = w1.astype(dt), w2.astype(dt), w3.astype(dt)
+                if grouped:
+                    # the same product over the tiles of rows that hold a
+                    # token; the slots behind an expert's fill are zero
+                    # rows, which give zero rows either way
+                    return moe_grouped.grouped_glu(xe, *ws, fill)
+                return expert_body(xe, *ws)
 
             # under CP/shard_map b*s is the LOCAL token count, so capacity
             # is per-shard — the standard distributed-MoE dispatch
@@ -453,6 +460,10 @@ class MoELayer(nn.Module):
             cap = ops.moe.expert_capacity(
                 b * s, e, cfg.top_experts, cfg.capacity_factor
             )
+            # by what the call can see, no flag: one TPU and whole row
+            # tiles (a train step's thousands of slots, not a decode
+            # call's few); the shard_map paths below keep the einsums
+            grouped = not cfg.context_parallel and moe_grouped.engages(cap, d)
             if cfg.context_parallel:
                 # inside the CP shard_map the 'expert' mesh axis shards
                 # expert COMPUTE, not just storage: the in-step ZeRO gather
@@ -501,7 +512,9 @@ class MoELayer(nn.Module):
                         xt, probs, expert_fn_sliced, cap, axis_name="expert"
                     )
             else:
-                out = ops.moe.moe_dispatch_combine(xt, probs, expert_fn, cap)
+                out = ops.moe.moe_dispatch_combine(
+                    xt, probs, expert_fn, cap, pass_fill=True
+                )
 
         if cfg.use_shared_expert:
             with jax.named_scope("L_moe_shared"):
@@ -574,6 +587,13 @@ class MoELayer(nn.Module):
                         cfg.stats_axes if drop_probs is None else drop_axes
                     ),
                 )
+            )
+            # share of the experts' row tiles that are multiplied: 1 where
+            # the einsums run over every slot (made from another stat, so
+            # that inside a shard_map it varies over the axes they do)
+            stats["live_tile_fraction"] = (
+                ops.moe.live_tile_fraction(probs, cap, moe_grouped.ROW_TILE)
+                if grouped else 1.0 + 0.0 * stats["drop_fraction"]
             )
             with jax.named_scope("L_moe_stats"):
                 stats["bias_norm"] = jnp.linalg.norm(bias.value)

@@ -48,7 +48,7 @@ from solvingpapers_tpu.models.staged import (
 from solvingpapers_tpu.sharding.pipeline import pipeline_local_apply
 
 _STAT_KEYS = ("load_entropy", "load_max_fraction", "drop_fraction",
-              "bias_norm")
+              "live_tile_fraction", "bias_norm")
 
 
 @dataclasses.dataclass(frozen=True)

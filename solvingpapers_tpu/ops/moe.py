@@ -12,10 +12,14 @@ The slot assignment is two small integer maps (token -> its slots, slot ->
 its token) and rows move through them by gather, forward and backward: the
 maps are one partial permutation read from both ends, so no scatter and no
 product with a (T, E, C) one-hot is needed, and the MXU runs the experts'
-einsums only. A dense all-experts path is kept as the numerics reference
-(exact — no capacity drops) and for tiny configs. Expert weights are
-stacked (E, ...) arrays so an `expert` mesh axis shards them directly and
-GSPMD inserts the all_to_alls (SURVEY.md §2.3 EP row).
+products only. An expert's filled slots are a prefix of its C rows, and
+the dispatch hands the E fill counts on (`_Routes.fill`), so that the
+experts' product can skip the rows behind them: on one TPU
+`kernels/moe_grouped.py` does, in tiles of rows; elsewhere the caller's
+einsums run over every slot. A dense all-experts path is kept as the
+numerics reference (exact — no capacity drops) and for tiny configs.
+Expert weights are stacked (E, ...) arrays so an `expert` mesh axis shards
+them directly and GSPMD inserts the all_to_alls (SURVEY.md §2.3 EP row).
 """
 
 from __future__ import annotations
@@ -98,6 +102,15 @@ def _dispatch_slots(probs: jax.Array, capacity: int):
     return sel, pos, keep
 
 
+def _slot_fill(pos: jax.Array, capacity: int) -> jax.Array:
+    """(E,) int32 slots each expert fills: min(its routed pairs, C), the
+    pairs counted by the last row of `pos`. The filled slots are the PREFIX
+    [0, fill) of an expert's C: `pos` numbers its pairs from 0 in token
+    order and `_routes` sorts them into slots in that order, the empty
+    slots behind them."""
+    return jnp.minimum(pos[-1] + 1, capacity)
+
+
 def _vary_alike(*xs: jax.Array) -> tuple:
     """Inside shard_map, widen every argument's varying axes to their union
     (outside it, nothing): a `custom_vjp` rule returns cotangents of its
@@ -147,6 +160,7 @@ class _Routes(NamedTuple):
     tok_pos: jax.Array  # (T, E) slot of a kept pair within its expert, else C
     slot_tok: jax.Array  # (E, C) token held by the slot, else T
     slot_w: jax.Array  # (E, C) gate weight of the slot's pair, float32
+    fill: jax.Array  # (E,) slots filled: slot_tok[e, :fill[e]] < T, the rest T
 
 
 def _routes(probs: jax.Array, capacity: int) -> _Routes:
@@ -165,7 +179,8 @@ def _routes(probs: jax.Array, capacity: int) -> _Routes:
             pad = ((0, 0), (0, capacity - t))
             slot_tok = jnp.pad(slot_tok, pad, constant_values=t)
             slot_w = jnp.pad(slot_w, pad)
-    return _Routes(jnp.where(keep, pos, capacity), slot_tok, slot_w)
+        return _Routes(jnp.where(keep, pos, capacity), slot_tok, slot_w,
+                       _slot_fill(pos, capacity))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -235,7 +250,8 @@ def _weighted_combine(routes: _Routes, probs: jax.Array, ye: jax.Array):
     slots of its kept pairs and sums them weighted by the gate, in
     float32, cast once to the experts' dtype."""
     with jax.named_scope("L_moe_combine"):
-        return _combine_rows(*_vary_alike(ye, probs, *routes), False)
+        return _combine_rows(*_vary_alike(
+            ye, probs, routes.tok_pos, routes.slot_tok, routes.slot_w), False)
 
 
 def moe_dispatch_combine(
@@ -243,9 +259,15 @@ def moe_dispatch_combine(
     probs: jax.Array,
     expert_fn,
     capacity: int,
+    *,
+    pass_fill: bool = False,
 ) -> jax.Array:
     """Static-shape MoE: route (T, D) tokens to (E, C, D) slots, run
     `expert_fn((E, C, D)) -> (E, C, D)`, combine back weighted by probs.
+    With `pass_fill` the call is `expert_fn((E, C, D), fill)`, fill (E,)
+    int32: expert e's tokens are the rows [0, fill[e]) of its slots and the
+    rows behind them are zero, so a product that maps zero rows to zero rows
+    may skip them.
 
     Tokens beyond an expert's capacity are dropped for that expert (their
     probability mass contributes nothing) — set capacity_factor high enough
@@ -253,7 +275,7 @@ def moe_dispatch_combine(
     """
     routes, xe = _dispatch(x, probs, capacity)
     with jax.named_scope("L_moe_experts"):
-        ye = expert_fn(xe)
+        ye = expert_fn(xe, routes.fill) if pass_fill else expert_fn(xe)
     return _weighted_combine(routes, probs, ye)
 
 
@@ -312,6 +334,8 @@ def _pair_routes(
         pair_slot = jnp.where(
             on_held & (pos < capacity), local * capacity + pos, held * capacity
         ).astype(jnp.int32)
+    # `routes.fill` is left behind: `HeldExpertsMoE` runs its einsums over
+    # every slot (320 of 640 rows an expert in its cell: two or three tiles)
     return _PairRoutes(pair_slot, routes.slot_tok, routes.slot_w), probs
 
 
@@ -365,6 +389,17 @@ def dispatch_drop_fraction(
 
 
 @jax.named_scope("L_moe_stats")
+def live_tile_fraction(probs: jax.Array, capacity: int, tile: int) -> jax.Array:
+    """Share of the (E, C) slots' row tiles of `tile` rows that hold a
+    token, from the same slot assignment as the dispatch, under
+    stop_gradient: what a product that skips the tiles behind each expert's
+    fill still multiplies (`kernels/moe_grouped.py`)."""
+    _, pos, _ = _dispatch_slots(jax.lax.stop_gradient(probs), capacity)
+    live = jnp.sum((_slot_fill(pos, capacity) + (tile - 1)) // tile)
+    return live.astype(jnp.float32) / (probs.shape[1] * (capacity // tile))
+
+
+@jax.named_scope("L_moe_stats")
 def load_balance_stats(
     probs: jax.Array, axis_names=None, ci=None
 ) -> dict[str, jax.Array]:
@@ -397,7 +432,9 @@ def moe_expert_sliced_combine(
     convention this op slices probs (contiguous blocks) — and the partial
     combines psum over the axis. No all_to_all needed — token replication
     over 'expert' makes EP a slice + reduce, composing freely with the
-    data/context axes of the same shard_map."""
+    data/context axes of the same shard_map. The experts run over every
+    slot here (no fill count is handed on): a `pallas_call` inside this
+    `shard_map` is not wired."""
     t, e = probs.shape
     ep = jax.lax.psum(1, axis_name)
     if e % ep:
@@ -445,6 +482,10 @@ def moe_all_to_all_combine(
     token count — the standard distributed-MoE semantics, identical to how
     the sliced path decides drops per CP shard. In the drop-free regime the
     result equals `moe_dispatch_combine` over the gathered tokens exactly.
+
+    The (E/ep, ep*C, D) layout holds one filled prefix PER SOURCE MEMBER, so
+    a single fill count an expert would be wrong here: `expert_fn` is handed
+    none and runs over every slot (ROADMAP S1).
     """
     t, e = probs.shape
     ep = jax.lax.psum(1, axis_name)

@@ -163,6 +163,39 @@ def test_gated_delta_rule_pads_narrow_heads_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("capacity", [16_384, 8_192],
+                         ids=["train_64x256", "train_16k"])
+def test_moe_grouped_glu_compiles_for_v5e(one_chip, capacity):
+    """The routed experts' kernels at the two DeepSeekV3 cells' shapes (8
+    experts, 512 wide, hidden 1,365 padded to 1,408, bfloat16): Mosaic takes
+    their blocks and the VMEM they ask for, one call forward and one
+    backward, and each returns three arrays or more (the benchmark's
+    `flash_mla.kind_of` reads a Mosaic call with one or two results as a
+    flash-attention kernel)."""
+    import re
+
+    from solvingpapers_tpu.kernels.moe_grouped import grouped_glu
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    args = (sds((8, capacity, 512)), sds((8, 512, 1365)), sds((8, 512, 1365)),
+            sds((8, 1365, 512)), sds((8,), jnp.int32))
+
+    def loss(xe, w1, w2, w3, fill):
+        # interpret=False: the default would ask jax.devices(), the CPU here
+        return jnp.sum(grouped_glu(
+            xe, w1, w2, w3, fill, interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *args).compile().as_text()
+    calls = [line.split(" custom-call(")[0].split("=", 1)[1]
+             for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2
+    for results in calls:
+        assert len(re.findall(r"\b[a-z]+\d+\[", results)) >= 3, results
+
+
 def test_described_chip_is_in_the_peak_tables(topo):
     """`metrics/mfu.py` and `metrics/mesh_obs.py` key their peak tables by
     `device_kind`; the v5e's must resolve to its published peaks, not to

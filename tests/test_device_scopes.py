@@ -139,6 +139,55 @@ def test_moe_rows_move_by_gather_forward_and_backward(name):
     assert bwd["L_moe_combine"] >= cfg.n_layers
 
 
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_grouped_expert_kernels_keep_the_experts_scope(monkeypatch):
+    """On one TPU the routed experts run as `kernels/moe_grouped.py`'s two
+    kernels (here through the interpreter, steered as the chip would): what
+    they lower to stays `L_moe_experts`' in all three passes, since their
+    `name=` is no scope of the vocabulary, so `moe_experts_ms` keeps reading
+    them; and each call returns three arrays or more, because the
+    benchmark's `benchmarks/kernels/flash_mla.kind_of` reads any Mosaic
+    call with one or two results as a flash-attention kernel."""
+    from solvingpapers_tpu.kernels import moe_grouped
+
+    monkeypatch.setattr(moe_grouped, "ROW_TILE", 8)
+    monkeypatch.setattr(moe_grouped, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    trainer, batch = dsv3_trainer(remat=True, rope_dim=8, dim=128, n_layers=1)
+    state = trainer.init_state(batch)
+    trainer._build_steps()
+    hlo_cost.register_program("jit_train_step", trainer._train_step,
+                              (state, batch))
+    scopes = hlo_cost.program_scopes("jit_train_step")
+    text = trainer._train_step.lower(state, batch).compile().as_text()
+    passes = collections.defaultdict(set)
+    for _, _, m, line in hlo_cost._scan_defs(text):
+        src = hlo_cost._OP_NAME_RE.search(line)
+        if src is None or m.name not in scopes:  # a parameter, a constant
+            continue
+        for kernel in ("moe_glu_fwd", "moe_glu_bwd"):
+            if kernel in src.group("src"):
+                assert scopes[m.name].layer == "L_moe_experts", line
+                passes[kernel].add(scopes[m.name].pass_)
+    assert passes == {"moe_glu_fwd": {"fwd", "remat"}, "moe_glu_bwd": {"bwd"}}
+    assert not set(hlo_cost.LAYER_SCOPES + hlo_cost.KERNEL_SCOPES) & set(passes)
+    calls = list(_pallas_calls(
+        jax.make_jaxpr(trainer._train_step)(state, batch).jaxpr))
+    names = collections.Counter(
+        c.params["name"] for c in calls)
+    assert names == {"moe_glu_fwd": 2, "moe_glu_bwd": 1}, names  # fwd, remat
+    assert all(len(c.outvars) >= 3 for c in calls)
+
+
 def test_program_scopes_knows_only_registered_programs():
     assert hlo_cost.program_scopes("jit_nobody_dispatched_this") is None
 
