@@ -402,10 +402,11 @@ def _serve_model(args, *, quiet_random_init: bool = False):
               "export the stage-stacked params to the dense family first",
               file=sys.stderr)
         return 2
-    if cfg.model_family == "qwen3next":
-        print("serving is unsupported for the qwen3next family: its Gated "
-              "DeltaNet layers keep recurrent state, and no cache manager "
-              "here holds that yet (ROADMAP R-M7); `cli train` runs it",
+    if cfg.model_family in ("qwen3next", "kimi_linear"):
+        print(f"serving is unsupported for the {cfg.model_family} family: "
+              "its linear-attention layers (Gated DeltaNet, Kimi Delta "
+              "Attention) keep recurrent state, and no cache manager here "
+              "holds that yet (ROADMAP R-M7); `cli train` runs it",
               file=sys.stderr)
         return 2
     if getattr(cfg.model, "context_parallel", False):

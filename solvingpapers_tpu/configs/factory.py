@@ -34,6 +34,10 @@ def build_model(cfg: RunConfig):
         from solvingpapers_tpu.models.qwen3next import Qwen3Next
 
         return Qwen3Next(cfg.model)
+    if fam == "kimi_linear":
+        from solvingpapers_tpu.models.kimi_linear import KimiLinear
+
+        return KimiLinear(cfg.model)
     if fam == "gpt_pipe":
         from solvingpapers_tpu.models.gpt_pipe import GPTPipe
 
@@ -79,7 +83,7 @@ def loss_fn_for(cfg: RunConfig):
         vae_loss_fn,
     )
     from solvingpapers_tpu.train.objectives import (
-        dsv3_loss_fn, qwen3next_loss_fn,
+        dsv3_loss_fn, kimi_linear_loss_fn, qwen3next_loss_fn,
     )
 
     return {
@@ -91,6 +95,7 @@ def loss_fn_for(cfg: RunConfig):
         "deepseekv3": dsv3_loss_fn,
         "dsv3_pipe": dsv3_loss_fn,
         "qwen3next": qwen3next_loss_fn,
+        "kimi_linear": kimi_linear_loss_fn,
         "vit": classification_loss_fn,
         "alexnet": classification_loss_fn,
         "kd": classification_loss_fn,

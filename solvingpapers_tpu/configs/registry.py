@@ -13,7 +13,7 @@ from solvingpapers_tpu.train.optim import OptimizerConfig
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     name: str
-    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | vit | alexnet | ae | vae | kd
+    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | vit | alexnet | ae | vae | kd
     model: Any
     train: TrainConfig
     data: dict = dataclasses.field(default_factory=dict)
@@ -671,6 +671,50 @@ def _qwen3next_80b_a3b() -> RunConfig:
               "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
         notes="published widths; run through a cut (experts held, layers, "
               "vocabulary slice), see benchmarks/configs/qwen3next_ep16.json",
+    )
+
+
+@register("kimi_linear_48b_a3b")
+def _kimi_linear_48b_a3b() -> RunConfig:
+    """Kimi-Linear-48B-A3B-Instruct at its published size
+    (huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct config.json):
+    27 layers, Kimi Delta Attention (32 heads of 128, a decay per key
+    channel) three to one with latent attention that carries no positions
+    (keys 192 wide, values 128), hidden 2304, one dense layer of width 9216,
+    then 256 experts of width 1024 with 8 a token behind a sigmoid router
+    and a shared one, vocabulary 163,840. Far more than one chip holds (48B
+    parameters): what runs is a cut of it, one expert-parallel rank's share
+    of a few layers (benchmarks/configs/kimi_linear_ep32.json sets
+    `num_hidden_layers`, `num_experts`, the experts held, and `vocab_size`;
+    the router keeps its 256 outputs, and the layer pattern is read from the
+    published `full_attn_layers` / `kda_layers`). Training only: no decode
+    cache holds recurrent state yet.
+
+    The job (assumed, the source states none): one sequence of 16,384
+    tokens a step, AdamW 3e-4 beta=(0.9, 0.95) wd 0.1 clip 1.0, 100 steps
+    of warm-up -> cosine to 0.1*max; capacity factor 4 (at 2 the first
+    steps dropped up to 2% of the pairs routed to one rank's experts);
+    remat a layer."""
+    from solvingpapers_tpu.models.kimi_linear import KimiLinearConfig
+
+    return RunConfig(
+        name="kimi_linear_48b_a3b",
+        model_family="kimi_linear",
+        model=KimiLinearConfig(),
+        train=TrainConfig(
+            steps=10_000, batch_size=1, log_every=50, eval_every=500,
+            eval_batches=4, ckpt_every=1000,
+            optimizer=OptimizerConfig(
+                name="adamw", max_lr=3e-4, warmup_steps=100,
+                total_steps=10_000, b1=0.9, b2=0.95, weight_decay=0.1,
+                grad_clip=1.0,
+            ),
+            tokens_per_step=16_384,
+        ),
+        data={"kind": "bpe", "path": None, "block_size": 16_384,
+              "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
+        notes="published widths; run through a cut (experts held, layers, "
+              "vocabulary slice), see benchmarks/configs/kimi_linear_ep32.json",
     )
 
 

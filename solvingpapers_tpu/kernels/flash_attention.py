@@ -83,10 +83,14 @@ def auto_block(seq: int, requested: int | None, head_dim: int) -> int:
     (LONG_SEQ_BLOCK past LONG_SEQ, DEFAULT_BLOCK below — the measured
     crossover, see the constants above); an explicit int is honored.
     Shared by flash_attention and the ring-flash per-chunk core so long
-    CP shards get the long-sequence tile too. The long-sequence tile was
-    measured at head widths up to 128; at 256 the backward-dq kernel's
-    1024 x 1024 tiles no longer fit the v5e's VMEM (the compiler refuses
-    them, tests/test_chip_compile.py), so wider heads keep DEFAULT_BLOCK."""
+    CP shards get the long-sequence tile too. `head_dim` is the KEY width
+    (q's and k's; a narrower value width changes nothing here). The
+    long-sequence tile was measured at widths up to 128; at 256 the
+    backward-dq kernel's 1024 x 1024 tiles no longer fit the v5e's VMEM
+    (the compiler refuses them, tests/test_chip_compile.py), so wider
+    heads keep DEFAULT_BLOCK. A width that is no multiple of 128 counts as
+    the next multiple, the lanes its tiles take in VMEM: 192 is 256 there
+    and keeps DEFAULT_BLOCK like 256."""
     if requested is not None:
         if requested <= 0:
             raise ValueError(f"block size must be positive, got {requested}")
@@ -248,9 +252,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
 
 def _fwd(q3, k3, v3, seed, n_heads, n_kv, scale, causal, block_q, block_k,
          dropout_rate, interpret):
-    """q3: (B*N, S, D); k3/v3: (B*Nkv, Skv, D). Returns (o, lse)."""
+    """q3: (B*N, S, D); k3: (B*Nkv, Skv, D); v3: (B*Nkv, Skv, Dv).
+    Returns (o (B*N, S, Dv), lse)."""
     bn, seq_q, d = q3.shape
-    seq_k = k3.shape[1]
+    seq_k, dv = k3.shape[1], v3.shape[2]
     group = n_heads // n_kv
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
@@ -278,21 +283,21 @@ def _fwd(q3, k3, v3, seed, n_heads, n_kv, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
         ],
         out_shape=[
-            _sds((bn, seq_q, d), q3.dtype, q3),
+            _sds((bn, seq_q, dv), q3.dtype, q3),
             _sds((bn, 1, seq_q), jnp.float32, q3),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
@@ -436,15 +441,15 @@ def _flash_fwd(q3, k3, v3, seed, heads, scale, causal, blocks, dropout_rate,
 def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret, res, do):
     q3, k3, v3, seed, o, lse = res
     n_heads, n_kv = heads
-    bn, seq_q, d = q3.shape
+    bn = q3.shape[0]
     seq_k = k3.shape[1]
     group = n_heads // n_kv
 
     if group > 1:  # materialize repeated kv for the backward pass
         bkv = k3.shape[0]
         rep = lambda x: jnp.repeat(  # noqa: E731
-            x.reshape(bkv // n_kv, n_kv, seq_k, d), group, axis=1
-        ).reshape(bn, seq_k, d)
+            x.reshape(bkv // n_kv, n_kv, seq_k, x.shape[2]), group, axis=1
+        ).reshape(bn, seq_k, x.shape[2])
         k3r, v3r = rep(k3), rep(v3)
     else:
         k3r, v3r = k3, v3
@@ -459,9 +464,9 @@ def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret, res, do):
 
     if group > 1:  # reduce repeated-head grads back to kv heads
         b = bn // n_heads
-        fold = lambda x: x.reshape(b, n_kv, group, seq_k, d).sum(axis=2).reshape(  # noqa: E731
-            b * n_kv, seq_k, d
-        )
+        fold = lambda x: x.reshape(  # noqa: E731
+            b, n_kv, group, seq_k, x.shape[2]
+        ).sum(axis=2).reshape(b * n_kv, seq_k, x.shape[2])
         dk_r, dv_r = fold(dk_r), fold(dv_r)
     # seed is integer-typed: no cotangent
     return dq, dk_r.astype(k3.dtype), dv_r.astype(v3.dtype), None
@@ -472,9 +477,9 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
     """dq/dk/dv pallas sweeps for one (q, kv) pair with kv already repeated
     to q heads. Shared by the full backward above and the ring-flash
     backward (sharding/ring_attention.py), which runs it once per rotating
-    kv chunk with the GLOBAL lse/delta."""
+    kv chunk with the GLOBAL lse/delta. v3r and do are Dv wide, as dv is."""
     bn, seq_q, d = q3.shape
-    seq_k = k3r.shape[1]
+    seq_k, dv = k3r.shape[1], v3r.shape[2]
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
     offset = seq_k - seq_q
@@ -494,8 +499,8 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), kv_index_rep),
-            pl.BlockSpec((1, block_k, d), kv_index_rep),
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dv), kv_index_rep),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -528,23 +533,23 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_k, d), lambda i, kb, jb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, jb: (i, kb, 0)),
-            pl.BlockSpec((1, block_q, d), q_index),
+            pl.BlockSpec((1, block_k, dv), lambda i, kb, jb: (i, kb, 0)),
+            pl.BlockSpec((1, block_q, dv), q_index),
             pl.BlockSpec((1, 1, block_q), q_row_index),
             pl.BlockSpec((1, 1, block_q), q_row_index),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda i, kb, jb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, jb: (i, kb, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, kb, jb: (i, kb, 0)),
         ],
         out_shape=[
             _sds((bn, seq_k, d), k3r.dtype, k3r),
-            _sds((bn, seq_k, d), v3r.dtype, k3r),
+            _sds((bn, seq_k, dv), v3r.dtype, k3r),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
@@ -573,7 +578,12 @@ def flash_attention(
     """Flash attention over BSNH tensors (drop-in for ops.dot_product_attention
     when there is no cache/explicit mask).
 
-    q: (B, Sq, N, D); k, v: (B, Skv, Nkv, D) with N % Nkv == 0.
+    q: (B, Sq, N, D); k: (B, Skv, Nkv, D); v: (B, Skv, Nkv, Dv) with
+    N % Nkv == 0; returns (B, Sq, N, Dv). Dv may differ from D (latent
+    attention decompressed: keys [nope | rope] 192 wide, values 128): each
+    operand crosses HBM at its own width, nothing is padded there. In VMEM a
+    width that is no multiple of the 128 lanes takes the next multiple's
+    (192 sits in 256), which is what `auto_block` sizes the tiles by.
     dropout_rate > 0 applies attention-prob dropout INSIDE the kernel
     (masks regenerated from (dropout_seed, block id) in the backward — no
     (S, S) mask tensor ever exists); same Bernoulli semantics as the dense
@@ -602,10 +612,11 @@ def flash_attention(
 
     q3 = q.transpose(0, 2, 1, 3).reshape(b * n_heads, seq_q, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, d)
-    v3 = v.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, d)
+    dv = v.shape[3]
+    v3 = v.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, dv)
     seed = jnp.asarray(dropout_seed, jnp.int32).reshape(1)
     o3 = _flash(
         q3, k3, v3, seed, (n_heads, n_kv), float(scale), bool(causal),
         (block_q, block_k), float(dropout_rate), interpret,
     )
-    return o3.reshape(b, n_heads, seq_q, d).transpose(0, 2, 1, 3)
+    return o3.reshape(b, n_heads, seq_q, dv).transpose(0, 2, 1, 3)

@@ -31,6 +31,14 @@ to the system's backward. It is the derivative of the forward program as
 written, product for product; the inverse's backward needs the inverse
 alone (dM = -T^t dT T^t).
 
+Decay shapes: g is (B, S, Hv), one decay a value head and token; it enters
+the kernels as rows of running sums a head (`rows`, `g_row`), and a chunk's
+system is (k.k) times a C x C matrix of decays. A decay per key channel (B,
+S, H, d_k) does not fit that: the decay then sits inside the contraction
+over the channels, and needs a reference point a sub-block to stay inside
+float32. That rule is `ops/kda.py`, plain XLA today; bringing it into these
+kernels (chosen statically by g's rank) is open (ROADMAP R-M2).
+
 Numerics reference: `ops.gated_delta.gated_delta_rule_recurrent`
 (tests/test_gated_delta.py, interpret mode).
 """
@@ -458,7 +466,8 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int,
                      interpret: bool | None = None):
     """The chunked rule; arguments and result as
-    `ops.gated_delta.gated_delta_rule`, `chunk` a power of two. Any S (the
+    `ops.gated_delta.gated_delta_rule` (g (B, S, Hv): one decay a value
+    head), `chunk` a power of two. Any S (the
     tail of the last grid step is padded with tokens that write nothing)
     and any widths (off the interpreter they are padded with zeros to the
     128 lanes a block of a head needs). `interpret` None: interpret on the

@@ -383,10 +383,13 @@ def format_anatomy(anatomy: dict) -> str:
 # ------------------------------------------------------- layers of a program
 
 # The layer scopes of the train step, one `jax.named_scope` at each layer
-# boundary (models/deepseekv3.py, models/qwen3next.py, ops/moe.py,
-# ops/losses.py, train/engine.py). Single tokens that no Flax module is
-# named. `L_gdn_*` are a Gated DeltaNet layer's: its projections, norms and
-# gate; its causal convolution; the chunked gated delta rule.
+# boundary (models/deepseekv3.py, models/qwen3next.py, models/kimi_linear.py,
+# ops/moe.py, ops/losses.py, train/engine.py). Single tokens that no Flax
+# module is named. `L_gdn_*` are a Gated DeltaNet layer's: its projections,
+# norms and gate; its causal convolution; the chunked gated delta rule.
+# `L_kda_*` are the same three of a Kimi Delta Attention layer (the decay a
+# key channel is made in `L_kda_proj`); `L_dense_ffn` a dense SwiGLU layer
+# with its norm and residual add.
 LAYER_SCOPES = (
     "L_embed",
     "L_attn_proj",
@@ -394,6 +397,10 @@ LAYER_SCOPES = (
     "L_gdn_proj",
     "L_gdn_conv",
     "L_gdn_core",
+    "L_kda_proj",
+    "L_kda_conv",
+    "L_kda_core",
+    "L_dense_ffn",
     "L_moe_gate",
     "L_moe_dispatch",
     "L_moe_experts",

@@ -256,28 +256,50 @@ class GatedDeltaNet(nn.Module):
 
 class HeldExpertsMoE(nn.Module):
     """One expert-parallel rank's MoE layer: routes over all
-    `router_experts`, computes the `num_experts` it holds."""
+    `router_experts`, computes the `held` experts [first_expert,
+    first_expert + held). The routing is the family's: "softmax" scores
+    over all experts, the top_k largest, renormalised when `renorm`, a
+    shared expert behind its own sigmoid gate, and the sums the family's
+    balance loss needs; or "sigmoid" scores, the top_k largest of score +
+    a selection bias that takes no gradient (the parameter `select_bias`),
+    the scores themselves as weights, renormalised when `renorm`, times
+    `scale`, and a shared expert added as it is."""
 
-    cfg: Qwen3NextConfig
+    router_experts: int
+    held: int
+    first_expert: int
+    top_k: int
+    expert_hidden: int
+    shared_hidden: int
+    capacity_factor: float
+    dtype: jnp.dtype
+    scoring: str = "softmax"
+    renorm: bool = True
+    scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.cfg
         b, s, d = x.shape
         t = b * s
-        held, h = cfg.num_experts, cfg.moe_intermediate_size
-        k = cfg.num_experts_per_tok
-        dt = cfg.compute_dtype
+        held, h, k, dt = self.held, self.expert_hidden, self.top_k, self.dtype
+        softmax = self.scoring == "softmax"
         with jax.named_scope("L_moe_gate"):
             x32 = x.reshape(t, d).astype(jnp.float32)
             xt = x32.astype(dt)
             logits = nn.Dense(
-                cfg.router_experts, use_bias=False, dtype=jnp.float32,
+                self.router_experts, use_bias=False, dtype=jnp.float32,
                 precision=HI, kernel_init=_INIT, name="gate",
             )(x32)
-            pair_w, pair_idx, probs = ops.moe.topk_renorm_weights(
-                logits, k, cfg.norm_topk_prob
-            )
+            if softmax:
+                pair_w, pair_idx, probs = ops.moe.topk_renorm_weights(
+                    logits, k, self.renorm
+                )
+            else:
+                bias = self.param("select_bias", _INIT,
+                                  (self.router_experts,))
+                pair_w, pair_idx, probs = ops.moe.topk_sigmoid_weights(
+                    logits, bias, k, self.renorm, self.scale
+                )
         w1 = self.param("w1", _INIT, (held, d, h))
         w2 = self.param("w2", _INIT, (held, d, h))
         w3 = self.param("w3", _INIT, (held, h, d))
@@ -290,35 +312,38 @@ class HeldExpertsMoE(nn.Module):
         # capacity from the layer's whole width: an expert's fair share of
         # the routed pairs is the same whichever device holds it
         cap = ops.moe.expert_capacity(
-            t, cfg.router_experts, k, cfg.capacity_factor
+            t, self.router_experts, k, self.capacity_factor
         )
         out, held_probs = ops.moe.moe_held_dispatch_combine(
-            xt, pair_w, pair_idx, expert_fn, cap, cfg.first_expert, held
+            xt, pair_w, pair_idx, expert_fn, cap, self.first_expert, held
         )
         with jax.named_scope("L_moe_shared"):
             shared = GLUFFN(
-                dim=d, hidden_dim=cfg.shared_expert_intermediate_size,
+                dim=d, hidden_dim=self.shared_hidden,
                 activation=ops.silu, dtype=dt, name="shared_expert",
-            )(xt)
-            share = jax.nn.sigmoid(nn.Dense(
-                1, use_bias=False, dtype=jnp.float32, kernel_init=_INIT,
-                name="shared_gate",
-            )(x32))
-            out = out.astype(jnp.float32) + share * shared.astype(jnp.float32)
+            )(xt).astype(jnp.float32)
+            if softmax:
+                shared = jax.nn.sigmoid(nn.Dense(
+                    1, use_bias=False, dtype=jnp.float32, kernel_init=_INIT,
+                    name="shared_gate",
+                )(x32)) * shared
+            out = out.astype(jnp.float32) + shared
 
         if self.is_mutable_collection("moe_metrics"):
             with jax.named_scope("L_moe_stats"):
-                # the family's balance loss needs, over all experts, the
-                # share of tokens that chose each and its mean probability
+                # over all experts, the share of tokens that chose each
                 chosen = jnp.sum(
-                    pair_idx[..., None] == jnp.arange(cfg.router_experts),
+                    pair_idx[..., None] == jnp.arange(self.router_experts),
                     axis=(0, 1), dtype=jnp.float32) / t
-                self.sow("moe_metrics", "balance", {
-                    "chosen": chosen,
-                    "prob": jnp.mean(probs, axis=0),
-                })
-                on_held = (pair_idx >= cfg.first_expert) & (
-                    pair_idx < cfg.first_expert + held)
+                if softmax:
+                    # with each one's mean probability, what the family's
+                    # balance loss needs
+                    self.sow("moe_metrics", "balance", {
+                        "chosen": chosen,
+                        "prob": jnp.mean(probs, axis=0),
+                    })
+                on_held = (pair_idx >= self.first_expert) & (
+                    pair_idx < self.first_expert + held)
                 stats = ops.moe.load_balance_stats(probs, ci=chosen)
                 stats["held_pair_fraction"] = jnp.mean(
                     on_held.astype(jnp.float32))
@@ -327,6 +352,18 @@ class HeldExpertsMoE(nn.Module):
             self.sow("moe_metrics", "stats", stats)
         with jax.named_scope("L_moe_combine"):
             return out.reshape(b, s, d)
+
+
+def held_moe(cfg: Qwen3NextConfig, name: str | None = None) -> HeldExpertsMoE:
+    """The layer as this family's config words it."""
+    return HeldExpertsMoE(
+        router_experts=cfg.router_experts, held=cfg.num_experts,
+        first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
+        expert_hidden=cfg.moe_intermediate_size,
+        shared_hidden=cfg.shared_expert_intermediate_size,
+        capacity_factor=cfg.capacity_factor, dtype=cfg.compute_dtype,
+        renorm=cfg.norm_topk_prob, name=name,
+    )
 
 
 class MixerBlock(nn.Module):
@@ -360,7 +397,7 @@ class MoEBlock(nn.Module):
     def __call__(self, x):
         with jax.named_scope("L_moe_gate"):
             h = ZeroCenteredRMSNorm(self.cfg.rms_norm_eps, name="post_norm")(x)
-        h = HeldExpertsMoE(self.cfg, name="moe")(h)
+        h = held_moe(self.cfg, name="moe")(h)
         with jax.named_scope("L_moe_combine"):
             return x + h
 

@@ -32,6 +32,7 @@ from solvingpapers_tpu.ops.attention import (
 )
 from solvingpapers_tpu.ops.losses import (
     cross_entropy,
+    head_cross_entropy,
     distillation_loss,
     vae_loss,
     mtp_loss,

@@ -10,6 +10,16 @@ with q and k normalised to unit length a head, q scaled by d_k^-0.5, g <= 0
 the log of the decay and beta in (0, 1) the writing strength, both a value
 head's. Each key head serves `Hv // Hk` value heads.
 
+Decay shapes. Both entries here take g of shape (B, S, Hv): ONE decay a
+value head and token, the same for every key channel (Gated DeltaNet). A
+decay per key channel, g (B, S, H, d_k) (Kimi Delta Attention), is
+`ops/kda.py`'s rule: there the decay sits inside the contraction over the
+channels and a chunk's system is no longer (K K^t) times a decay matrix, so
+it has a chunked form of its own (plain XLA; these kernels do not take it).
+A g that repeats one number over the channels gives this rule back
+(tests/test_kda.py). The causal convolution and the gated norm below serve
+both.
+
 `gated_delta_rule` is the chunked form for the timed path: inside a chunk
 of C tokens the rule is a unit lower-triangular system (I + A) U = beta V -
 diag(beta e^G) K S_0, with A_ij = beta_i e^(G_i - G_j) k_i.k_j below the
@@ -138,8 +148,9 @@ def gated_delta_rule(
     *,
     chunk: int | None = None,
 ) -> jax.Array:
-    """q, k (B, S, Hk, dk); v (B, S, Hv, dv); g, beta (B, S, Hv) float32.
-    Returns o (B, S, Hv, dv) in v's dtype. Products take operands in v's
+    """q, k (B, S, Hk, dk); v (B, S, Hv, dv); g, beta (B, S, Hv) float32
+    (one decay a value head: a decay per key channel goes to
+    `ops.kda.kda_rule`). Returns o (B, S, Hv, dv) in v's dtype. Products take operands in v's
     dtype (bfloat16 on the chip) and add up in float32; the state, the
     decays and the triangular system are float32. Any S: the tail is
     padded with tokens that write nothing. `chunk` defaults to the
@@ -148,6 +159,10 @@ def gated_delta_rule(
     hk, hv = q.shape[2], v.shape[2]
     if hv % hk:
         raise ValueError(f"{hv} value heads over {hk} key heads")
+    if g.shape != v.shape[:3]:
+        raise ValueError(
+            f"g {g.shape} must be one decay a value head, {v.shape[:3]}; a "
+            "decay per key channel is ops.kda.kda_rule's")
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} must be a power of two")
     return kernel.gated_delta_rule(q, k, v, g, beta, chunk=chunk)

@@ -131,6 +131,38 @@ def _chunked_nll_sum_count(
     return tot, num
 
 
+@jax.named_scope("L_loss_head")
+def head_cross_entropy(
+    hidden: jax.Array, kernel: jax.Array, labels: jax.Array,
+    chunk_size: int = 2048,
+) -> jax.Array:
+    """Mean cross-entropy of integer labels under an untied head, head and
+    loss together a chunk of rows at a time: hidden (..., D) in the compute
+    dtype, kernel (D, V) as it is kept (float32), labels (...) int. A
+    chunk's logits (the product in hidden's dtype, as `nn.Dense` gives
+    them, then float32) exist inside a `jax.checkpoint`ed scan body only,
+    so neither the (rows, V) logits nor their cotangent are ever whole in
+    memory (16,384 x 20,480 bfloat16: 640 MB each). The kernel is cast
+    inside the body, so its gradient adds up over the chunks in float32.
+    Rows that `chunk_size` does not divide run as one chunk."""
+    d = hidden.shape[-1]
+    flat, lab = hidden.reshape(-1, d), labels.reshape(-1)
+    n = flat.shape[0]
+    chunk = chunk_size if n % chunk_size == 0 else n
+
+    @jax.checkpoint
+    def body(tot, xs):
+        h, lb = xs
+        lg = jnp.dot(h, kernel.astype(h.dtype)).astype(jnp.float32)
+        picked = jnp.take_along_axis(lg, lb[:, None], axis=-1)[:, 0]
+        return tot + jnp.sum(jax.nn.logsumexp(lg, axis=-1) - picked), None
+
+    tot, _ = jax.lax.scan(
+        body, jnp.float32(0.0),
+        (flat.reshape(-1, chunk, d), lab.reshape(-1, chunk)))
+    return tot / n
+
+
 def distillation_loss(
     student_logits: jax.Array,
     teacher_logits: jax.Array,

@@ -295,6 +295,29 @@ def topk_renorm_weights(
         return top_w, top_idx, probs
 
 
+def topk_sigmoid_weights(
+    logits: jax.Array, select_bias: jax.Array, k: int, renorm: bool = True,
+    scale: float = 1.0,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Sigmoid scores over ALL experts in float32; the k chosen a token are
+    the largest of score + `select_bias` (E,), which steers the choice and
+    nothing else: it takes no gradient, and the weights are the scores
+    themselves, divided by their sum when `renorm` (the published
+    `moe_renormalize`), times `scale` (`routed_scaling_factor`). With one
+    expert group of which one is kept, the group-limited choice some
+    families make is this plain top-k. Returns (weights (T, k), expert ids
+    (T, k), the scores (T, E)), as `topk_renorm_weights` does."""
+    with jax.named_scope("L_moe_gate"):
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, top_idx = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
+            k)
+        top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if renorm:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        return top_w * scale, top_idx, scores
+
+
 def held_pair_probs(
     pair_w: jax.Array, pair_idx: jax.Array, first: int, held: int
 ) -> jax.Array:

@@ -195,6 +195,30 @@ def qwen3next_loss_fn(model, params, batch, rng, model_state, train):
     return main + cfg.router_aux_loss_coef * balance, aux, model_state
 
 
+def kimi_linear_loss_fn(model, params, batch, rng, model_state, train):
+    """Kimi-Linear objective: next-token cross-entropy and nothing else (the
+    source's config states no balance loss; its selection bias is a
+    parameter that takes no gradient). The MoE's counters
+    (`moe_drop_fraction`, `moe_held_pair_fraction`, the load statistics)
+    ride along as the other MoE families' do. Head and loss run together in
+    chunks of rows (`ops.head_cross_entropy`): at 16,384 tokens the whole
+    logits and their cotangent, 640 MB each, were the step's memory peak."""
+    variables = {"params": params}
+    kernel = params["lm_head"]["kernel"]
+    if not train:
+        hidden, _ = model.apply(variables, batch["x"], head=False)
+        main = ops.head_cross_entropy(hidden, kernel, batch["y"])
+        return main, {"perplexity": jnp.exp(main)}, model_state
+    (hidden, _), mutated = model.apply(
+        variables, batch["x"], head=False, mutable=["moe_metrics"]
+    )
+    main = ops.head_cross_entropy(hidden, kernel, batch["y"])
+    with jax.named_scope("L_loss_head"):
+        aux = {"perplexity": jnp.exp(main),
+               **_aggregate_moe_metrics(mutated.get("moe_metrics", {}))}
+    return main, aux, model_state
+
+
 def make_kd_loss_fn(teacher_model, teacher_params, temperature=7.0, alpha=0.3):
     """Distillation objective with a frozen teacher (kd.py:48-68, 110-142).
 

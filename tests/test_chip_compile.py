@@ -107,6 +107,56 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_with_a_value_width_of_its_own_compiles_for_v5e(
+        one_chip, backward):
+    """kimi_linear_ep32's latent attention, decompressed: 32 heads, keys 192
+    wide (128 + 64, no whole number of 128-lane tiles: 256 lanes in VMEM),
+    values 128, one sequence of 16,384. `auto_block` counts 192 as 256 and
+    keeps the 512-tiles; Mosaic takes the blocks of all three kernels."""
+    sds = lambda h, w: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 16_384, h, w), jnp.bfloat16, sharding=one_chip)
+    from solvingpapers_tpu.kernels.flash_attention import auto_block
+
+    assert auto_block(16_384, None, 192) == 512
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5, interpret=False)
+        assert out.shape == (1, 16_384, 32, 128)
+        return jnp.sum(out.astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else loss
+    compiled = jax.jit(fn).lower(
+        sds(32, 192), sds(32, 192), sds(32, 128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (3 if backward
+                                                           else 1)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_kda_rule_compiles_for_v5e(one_chip, backward):
+    """The delta rule with a decay per key channel at the cell's shape (32
+    heads of 128, one sequence of 16,384, bfloat16 q, k, v): plain XLA, a
+    scan over eight rematerialised segments. What it keeps beside its
+    arguments, gradients and cotangents stays a segment's: under 1.5 GiB."""
+    from solvingpapers_tpu.ops.kda import kda_rule
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    wide = (1, 16_384, 32, 128)
+    args = (sds(wide, jnp.bfloat16), sds(wide, jnp.bfloat16),
+            sds(wide, jnp.bfloat16), sds(wide, jnp.float32),
+            sds(wide[:3], jnp.float32))
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda_rule(q, k, v, g, beta).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else loss
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+
+
 def _abstract_gdn(one_chip, dtype, seq=16_384):
     """qwen3next_ep16's Gated DeltaNet core: 16 key heads of 128 serving 32
     value heads of 128, one sequence of 16,384."""

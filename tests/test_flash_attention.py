@@ -252,3 +252,45 @@ def test_trainer_routes_flash_through_sharded_kernel_under_tp(devices, monkeypat
     sharded = run(MeshConfig(data=2, fsdp=1, model=2), devices[:4])
     assert calls["sharded"] > 0, "TP mesh did not route through sharded flash"
     np.testing.assert_allclose(sharded, single, rtol=2e-4, atol=2e-5)
+
+
+# (q heads, kv heads, key width, value width): keys wider than values, as
+# latent attention decompressed has them (192 / 128 at the published size);
+# values wider than keys; grouped queries with unequal widths
+WIDTHS = [pytest.param(4, 4, 48, 32, id="keys_wider"),
+          pytest.param(2, 2, 16, 40, id="values_wider"),
+          pytest.param(4, 2, 24, 16, id="gqa_keys_wider")]
+
+
+def _qkv_two_widths(n, n_kv, dk, dv, s=128):
+    q, k, _ = make_qkv(jax.random.key(3), 2, s, s, n, n_kv, dk)
+    v = jax.random.normal(jax.random.key(4), (2, s, n_kv, dv))
+    return q, k, v
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "bidir"])
+@pytest.mark.parametrize("n,n_kv,dk,dv", WIDTHS)
+def test_value_width_of_its_own_forward_matches_dense(n, n_kv, dk, dv, causal):
+    q, k, v = _qkv_two_widths(n, n_kv, dk, dv)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=32)
+    assert out.shape == (2, 128, n, dv)
+    want = ops.dot_product_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("n,n_kv,dk,dv", WIDTHS)
+def test_value_width_of_its_own_grads_match_dense(n, n_kv, dk, dv):
+    q, k, v = _qkv_two_widths(n, n_kv, dk, dv)
+    mix = jax.random.normal(jax.random.key(5), (2, 128, n, dv))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * mix)
+
+    flash = loss(functools.partial(flash_attention, causal=True, block_q=32,
+                                   block_k=64))
+    dense = loss(functools.partial(ops.dot_product_attention, causal=True))
+    got = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)

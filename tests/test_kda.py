@@ -1,0 +1,150 @@
+"""The delta rule with a decay per key channel (`ops/kda.py`): the chunked
+form of the timed path against the rule token by token, values and all five
+gradients, at random decays and at the strongest the initialisation can
+draw (a channel that forgets everything within a token: the exponent no
+chunked form may take positive), over one chunk, ragged chunks and several
+rematerialised segments; and a decay that repeats one number over the
+channels against the gated delta rule of `ops/gated_delta.py`."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from solvingpapers_tpu.ops import gated_delta as gd
+from solvingpapers_tpu.ops import kda
+
+pytestmark = pytest.mark.fast
+
+B, H, DK, DV = 2, 3, 16, 8
+CHUNK, SUB, SEGMENT = 16, 4, 32
+
+
+def inputs(seq, dtype=jnp.float32, decay="random", seed=0):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    q = jax.random.normal(ks[0], (B, seq, H, DK)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, seq, H, DK)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, seq, H, DV)).astype(dtype)
+    if decay == "random":
+        # heads that forget in a token to heads that hardly do, as A_log =
+        # log U(1e-3, 16) draws them; the channels of a head differ
+        a = jax.random.uniform(ks[3], (H, 1), minval=1e-3, maxval=16.0)
+        pre = jax.random.normal(ks[4], (B, seq, H, DK)) + 1.0
+    else:
+        # the strongest start: every head at exp(A_log) = 16, softplus of
+        # dt_bias 1 plus a large projection: g about -16 * 4 a token, e^-88
+        # passed in two tokens and e^-4000 inside a chunk
+        a = jnp.full((H, 1), 16.0)
+        pre = jax.random.normal(ks[4], (B, seq, H, DK)) + 4.0
+    g = -a * jax.nn.softplus(pre)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, seq, H)))
+    return q, k, v, g, beta
+
+
+@jax.jit
+def chunked(*args):
+    return kda.kda_rule(*args, chunk=CHUNK, sub=SUB, segment=SEGMENT)
+
+
+recurrent = jax.jit(kda.kda_rule_recurrent)
+
+
+def grads(fn, args, mix):
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * mix),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+
+
+# tokens: one chunk; a ragged chunk; one whole segment; segments and a
+# ragged tail (the state crosses rematerialised segments)
+SEQS = [16, 23, 32, 75]
+
+
+@pytest.mark.parametrize("decay", ["random", "strongest"])
+@pytest.mark.parametrize("seq", SEQS)
+def test_chunked_rule_matches_the_recurrence(seq, decay):
+    args = inputs(seq, decay=decay)
+    want = recurrent(*args)
+    got = chunked(*args)
+    assert got.shape == want.shape == (B, seq, H, DV)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("decay", ["random", "strongest"])
+@pytest.mark.parametrize("seq", SEQS)
+def test_chunked_rule_gradients_match_the_recurrence(seq, decay):
+    args = inputs(seq, decay=decay)
+    mix = jax.random.normal(jax.random.key(9), (B, seq, H, DV))
+    got, want = grads(chunked, args, mix), grads(recurrent, args, mix)
+    for name, a, b in zip("qkvgb", got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=2e-5 * scale, err_msg=name)
+
+
+def test_the_product_form_would_overflow_where_this_one_does_not():
+    """What the sub-blocks are for: at the strongest decay (k e^G)(k e^-G)^t
+    needs e^(+4000) inside a chunk, which float32 does not hold."""
+    _, _, _, g, _ = inputs(16, decay="strongest")
+    total = jnp.cumsum(g, axis=1)
+    assert float(jnp.min(total)) < -1000.0
+    assert not bool(jnp.all(jnp.isfinite(jnp.exp(-total))))
+
+
+@pytest.mark.parametrize("seq", [23, 64])
+def test_one_decay_a_head_gives_the_gated_delta_rule_back(seq):
+    """g_t repeated over the key channels is Gated DeltaNet's rule."""
+    q, k, v, g, beta = inputs(seq)
+    g_head = g[..., 0]
+    want = jax.jit(gd.gated_delta_rule_recurrent)(q, k, v, g_head, beta)
+    g_all = jnp.broadcast_to(g_head[..., None], q.shape)
+    np.testing.assert_allclose(recurrent(q, k, v, g_all, beta), want,
+                               atol=2e-6, rtol=1e-5)
+    np.testing.assert_allclose(chunked(q, k, v, g_all, beta), want,
+                               atol=2e-6, rtol=1e-5)
+
+
+def test_chunked_rule_with_bfloat16_inputs():
+    """bfloat16 q, k, v (the chip's dtype): the result is bfloat16 and lies
+    within bfloat16's rounding of the float32 recurrence on the same
+    (rounded) inputs."""
+    args = inputs(75, jnp.bfloat16)
+    want = recurrent(*(a.astype(jnp.float32) for a in args))
+    got = chunked(*args)
+    assert got.dtype == jnp.bfloat16
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(got.astype(jnp.float32), want,
+                               atol=2e-2 * scale)
+
+
+def test_the_rule_refuses_a_decay_a_head():
+    q, k, v, g, beta = inputs(16)
+    with pytest.raises(ValueError, match="decay per key channel"):
+        kda.kda_rule(q, k, v, g[..., 0], beta)
+
+
+def test_decay_made_inside_the_segments_is_the_same_function():
+    """`decay=`: the log decays made from a low-rank input a segment at a
+    time, values and the gradients of what the function closes over."""
+    q, k, v, _, beta = inputs(75)
+    low = jax.random.normal(jax.random.key(3), (B, 75, 5))
+    w = 0.5 * jax.random.normal(jax.random.key(4), (5, H * DK))
+
+    def decay(low, w):
+        return -2.0 * jax.nn.softplus((low @ w).reshape(
+            low.shape[:2] + (H, DK)))
+
+    def whole(low, w):
+        return jnp.sum(chunked(q, k, v, decay(low, w), beta) ** 2)
+
+    def by_segment(low, w):
+        return jnp.sum(kda.kda_rule(
+            q, k, v, low, beta, chunk=CHUNK, sub=SUB, segment=SEGMENT,
+            decay=lambda x: decay(x, w)) ** 2)
+
+    want, g_want = jax.jit(jax.value_and_grad(whole, (0, 1)))(low, w)
+    got, g_got = jax.jit(jax.value_and_grad(by_segment, (0, 1)))(low, w)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))))

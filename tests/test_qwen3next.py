@@ -31,7 +31,7 @@ from solvingpapers_tpu.configs.factory import (
 )
 from solvingpapers_tpu.metrics import hlo_cost
 from solvingpapers_tpu.models.qwen3next import (
-    HeldExpertsMoE, Qwen3Next, Qwen3NextConfig, ZeroCenteredRMSNorm,
+    Qwen3Next, Qwen3NextConfig, ZeroCenteredRMSNorm, held_moe,
     partial_rotary,
 )
 from solvingpapers_tpu.ops import gated_delta
@@ -253,7 +253,7 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer():
 
     def rank_out(r, **kw):
         cfg = dataclasses.replace(cfg0, first_expert=r * held)
-        return jax.jit(HeldExpertsMoE(cfg).apply)(
+        return jax.jit(held_moe(cfg).apply)(
             {"params": rank_params(r, **kw)}, x)
 
     shared = rank_out(0, zero_experts=True)  # what every rank computes alike
