@@ -251,8 +251,8 @@ class KimiDeltaAttention(nn.Module):
             return hid @ w_qkv, fob[..., :dk], fob[..., dk:2 * dk], beta
 
         def decay(f_low):
-            # made a segment at a time inside the rule (`kda_rule`'s
-            # `decay`): the (S, H, dk) float32 array never exists whole
+            # made inside the rule's scope (`kda_rule`'s `decay`): the
+            # (S, H, dk) float32 array lives beside the kernels that read it
             f = (f_low @ w_f).astype(jnp.float32).reshape(
                 f_low.shape[:2] + (h, dk))
             return -jnp.exp(a_log)[:, None] * jax.nn.softplus(f + dt_bias)

@@ -15,7 +15,8 @@ value head and token, the same for every key channel (Gated DeltaNet). A
 decay per key channel, g (B, S, H, d_k) (Kimi Delta Attention), is
 `ops/kda.py`'s rule: there the decay sits inside the contraction over the
 channels and a chunk's system is no longer (K K^t) times a decay matrix, so
-it has a chunked form of its own (plain XLA; these kernels do not take it).
+the kernels make that system another way (sub-blocked pair sums, chosen
+there by g's rank) and share the rest.
 A g that repeats one number over the channels gives this rule back
 (tests/test_kda.py). The causal convolution and the gated norm below serve
 both.

@@ -133,28 +133,51 @@ def test_flash_with_a_value_width_of_its_own_compiles_for_v5e(
                                                            else 1)
 
 
-@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-def test_kda_rule_compiles_for_v5e(one_chip, backward):
-    """The delta rule with a decay per key channel at the cell's shape (32
-    heads of 128, one sequence of 16,384, bfloat16 q, k, v): plain XLA, a
-    scan over eight rematerialised segments. What it keeps beside its
-    arguments, gradients and cotangents stays a segment's: under 1.5 GiB."""
-    from solvingpapers_tpu.ops.kda import kda_rule
+def _compiled_kda(one_chip, qk_shape, dv, backward):
+    """The per-channel rule's kernels compiled for the described chip: q, k
+    (B, S, H, dk) and v (B, S, H, dv) bfloat16, g float32, beta a head."""
+    from solvingpapers_tpu.kernels.gated_delta import gated_delta_rule
 
     sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    wide = (1, 16_384, 32, 128)
-    args = (sds(wide, jnp.bfloat16), sds(wide, jnp.bfloat16),
-            sds(wide, jnp.bfloat16), sds(wide, jnp.float32),
-            sds(wide[:3], jnp.float32))
+    args = (sds(qk_shape, jnp.bfloat16), sds(qk_shape, jnp.bfloat16),
+            sds(qk_shape[:3] + (dv,), jnp.bfloat16),
+            sds(qk_shape, jnp.float32), sds(qk_shape[:3], jnp.float32))
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(kda_rule(q, k, v, g, beta).astype(jnp.float32))
+        # interpret=False: the default would ask jax.devices(), the CPU here
+        return jnp.sum(gated_delta_rule(
+            q, k, v, g, beta, chunk=64, sub=16,
+            interpret=False).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else loss
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_kda_rule_compiles_for_v5e(one_chip, backward):
+    """The delta rule with a decay per key channel at the cell's shape (32
+    heads of 128, one sequence of 16,384, bfloat16 q, k, v, float32 g):
+    Mosaic takes the kernels' blocks and their VMEM (the sub-blocked pair
+    sums, the triangular system and both of their backwards in one grid
+    step), the rule is a kernel call forward and one backward, and no
+    `while` is left of it."""
+    compiled = _compiled_kda(one_chip, (1, 16_384, 32, 128), 128, backward)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + backward
+    assert " while(" not in text
+    # besides the result in float32 and the gradients: the entering states
+    # (64 grid steps x 32 heads x 128 x 128 float32, 0.125 GiB) and beta's
+    # rows; nothing a chunk's system made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2 ** 30
+
+
+def test_kda_rule_pads_narrow_heads_for_v5e(one_chip):
+    """Key and value widths that are no multiple of the 128 lanes and a
+    ragged length are padded, not refused (the padded channels carry a log
+    decay of 0 and a key of 0: they write nothing)."""
+    compiled = _compiled_kda(one_chip, (2, 300, 4, 64), 96, True)
+    assert compiled.as_text().count("tpu_custom_call") == 2
 
 
 def _abstract_gdn(one_chip, dtype, seq=16_384):

@@ -1,23 +1,27 @@
-"""The delta rule with a decay per key channel (`ops/kda.py`): the chunked
-form of the timed path against the rule token by token, values and all five
-gradients, at random decays and at the strongest the initialisation can
-draw (a channel that forgets everything within a token: the exponent no
-chunked form may take positive), over one chunk, ragged chunks and several
-rematerialised segments; and a decay that repeats one number over the
-channels against the gated delta rule of `ops/gated_delta.py`."""
+"""The delta rule with a decay per key channel (`ops/kda.py`, the kernels
+of `kernels/gated_delta.py` interpreted): the chunked form of the timed
+path against the rule token by token, values and all five gradients, at
+random decays and at the strongest the initialisation can draw (a channel
+that forgets everything within a token: the exponent no chunked form may
+take positive), over one chunk, a ragged chunk, whole grid steps and
+several grid steps with the state crossing them; and a decay that repeats
+one number over the channels against the gated delta rule of
+`ops/gated_delta.py`, both as the recurrence and as its kernels."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from solvingpapers_tpu.kernels import gated_delta as kernel
 from solvingpapers_tpu.ops import gated_delta as gd
 from solvingpapers_tpu.ops import kda
 
 pytestmark = pytest.mark.fast
 
 B, H, DK, DV = 2, 3, 16, 8
-CHUNK, SUB, SEGMENT = 16, 4, 32
+CHUNK, SUB = 16, 4
+STEP = CHUNK * kernel.CHUNKS_A_STEP  # tokens a grid step holds
 
 
 def inputs(seq, dtype=jnp.float32, decay="random", seed=0):
@@ -43,7 +47,7 @@ def inputs(seq, dtype=jnp.float32, decay="random", seed=0):
 
 @jax.jit
 def chunked(*args):
-    return kda.kda_rule(*args, chunk=CHUNK, sub=SUB, segment=SEGMENT)
+    return kda.kda_rule(*args, chunk=CHUNK, sub=SUB)
 
 
 recurrent = jax.jit(kda.kda_rule_recurrent)
@@ -55,9 +59,10 @@ def grads(fn, args, mix):
         argnums=(0, 1, 2, 3, 4)))(*args)
 
 
-# tokens: one chunk; a ragged chunk; one whole segment; segments and a
-# ragged tail (the state crosses rematerialised segments)
-SEQS = [16, 23, 32, 75]
+# tokens: one chunk; a ragged chunk (two chunks a grid step, the second
+# padded); one whole grid step; grid steps and a ragged tail (the state
+# crosses them in VMEM, and in the backward dS does, the other way)
+SEQS = [16, 23, STEP, 2 * STEP + 22]
 
 
 @pytest.mark.parametrize("decay", ["random", "strongest"])
@@ -83,6 +88,23 @@ def test_chunked_rule_gradients_match_the_recurrence(seq, decay):
         np.testing.assert_allclose(a, b, atol=2e-5 * scale, err_msg=name)
 
 
+@pytest.mark.parametrize("sub", [1, 2, 8, 16])
+def test_any_sub_block_size_is_the_same_function(sub):
+    """From every pair through a reference point (sub-blocks of one token:
+    only the diagonal is summed channel by channel) to none (one sub-block
+    a chunk: the far product is not made at all)."""
+    rule = jax.jit(lambda *a: kda.kda_rule(*a, chunk=CHUNK, sub=sub))
+    for decay in ("strongest", "random"):
+        args = inputs(STEP + 5, decay=decay, seed=sub)
+        np.testing.assert_allclose(rule(*args), recurrent(*args),
+                                   atol=2e-6, rtol=1e-5)
+    mix = jax.random.normal(jax.random.key(9), (B, STEP + 5, H, DV))
+    for name, a, b in zip("qkvgb", grads(rule, args, mix),
+                          grads(recurrent, args, mix)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=2e-5 * scale, err_msg=name)
+
+
 def test_the_product_form_would_overflow_where_this_one_does_not():
     """What the sub-blocks are for: at the strongest decay (k e^G)(k e^-G)^t
     needs e^(+4000) inside a chunk, which float32 does not hold."""
@@ -94,7 +116,9 @@ def test_the_product_form_would_overflow_where_this_one_does_not():
 
 @pytest.mark.parametrize("seq", [23, 64])
 def test_one_decay_a_head_gives_the_gated_delta_rule_back(seq):
-    """g_t repeated over the key channels is Gated DeltaNet's rule."""
+    """g_t repeated over the key channels is Gated DeltaNet's rule: the
+    recurrences agree, and so do the two branches of the kernels (g's rank
+    picks the sub-blocked system or the decay matrix)."""
     q, k, v, g, beta = inputs(seq)
     g_head = g[..., 0]
     want = jax.jit(gd.gated_delta_rule_recurrent)(q, k, v, g_head, beta)
@@ -103,32 +127,63 @@ def test_one_decay_a_head_gives_the_gated_delta_rule_back(seq):
                                atol=2e-6, rtol=1e-5)
     np.testing.assert_allclose(chunked(q, k, v, g_all, beta), want,
                                atol=2e-6, rtol=1e-5)
+    a_head = jax.jit(lambda *a: gd.gated_delta_rule(*a, chunk=CHUNK))(
+        q, k, v, g_head, beta)
+    np.testing.assert_allclose(chunked(q, k, v, g_all, beta), a_head,
+                               atol=2e-6, rtol=1e-5)
 
 
 def test_chunked_rule_with_bfloat16_inputs():
     """bfloat16 q, k, v (the chip's dtype): the result is bfloat16 and lies
     within bfloat16's rounding of the float32 recurrence on the same
-    (rounded) inputs."""
-    args = inputs(75, jnp.bfloat16)
+    (rounded) inputs; the gradients come in their arguments' dtypes."""
+    args = inputs(2 * STEP + 22, jnp.bfloat16)
     want = recurrent(*(a.astype(jnp.float32) for a in args))
     got = chunked(*args)
     assert got.dtype == jnp.bfloat16
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(got.astype(jnp.float32), want,
                                atol=2e-2 * scale)
+    mix = jax.random.normal(jax.random.key(9), want.shape)
+    got_g = grads(chunked, args, mix)
+    want_g = grads(recurrent, [a.astype(jnp.float32) for a in args], mix)
+    assert [a.dtype for a in got_g] == [a.dtype for a in args]
+    for name, a, b in zip("qkvgb", got_g, want_g):
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b, err_msg=name,
+            atol=4e-2 * float(jnp.max(jnp.abs(b))))
 
 
-def test_the_rule_refuses_a_decay_a_head():
+def test_the_state_is_float32_across_grid_steps():
+    """Under differentiation the forward kernel also writes the float32
+    state that enters each grid step, (B, H, steps, 1, dk, dv): the
+    backward starts each step from it."""
+    args = inputs(3 * STEP)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(chunked(*a)), argnums=0))(*args))
+    assert f"f32[{B},{H},3,1,{DK},{DV}]" in jaxpr
+    assert jaxpr.count("pallas_call") == 2
+    assert "while" not in jaxpr and "scan" not in jaxpr
+
+
+def test_the_rule_refuses_what_it_does_not_compute():
     q, k, v, g, beta = inputs(16)
     with pytest.raises(ValueError, match="decay per key channel"):
         kda.kda_rule(q, k, v, g[..., 0], beta)
+    with pytest.raises(ValueError, match="its own q, k and v"):
+        kda.kda_rule(q, k, jnp.concatenate([v, v], axis=2), g, beta)
+    with pytest.raises(ValueError, match="power of two"):
+        kda.kda_rule(q, k, v, g, beta, chunk=24, sub=4)
+    with pytest.raises(ValueError, match="multiple of sub"):
+        kda.kda_rule(q, k, v, g, beta, chunk=16, sub=3)
 
 
-def test_decay_made_inside_the_segments_is_the_same_function():
-    """`decay=`: the log decays made from a low-rank input a segment at a
-    time, values and the gradients of what the function closes over."""
-    q, k, v, _, beta = inputs(75)
-    low = jax.random.normal(jax.random.key(3), (B, 75, 5))
+def test_decay_made_from_a_low_rank_input_is_the_same_function():
+    """`decay=`: the log decays made inside `kda_rule` from a low-rank
+    input, values and the gradients of what the function closes over."""
+    seq = STEP + 11
+    q, k, v, _, beta = inputs(seq)
+    low = jax.random.normal(jax.random.key(3), (B, seq, 5))
     w = 0.5 * jax.random.normal(jax.random.key(4), (5, H * DK))
 
     def decay(low, w):
@@ -138,13 +193,13 @@ def test_decay_made_inside_the_segments_is_the_same_function():
     def whole(low, w):
         return jnp.sum(chunked(q, k, v, decay(low, w), beta) ** 2)
 
-    def by_segment(low, w):
+    def handed_over(low, w):
         return jnp.sum(kda.kda_rule(
-            q, k, v, low, beta, chunk=CHUNK, sub=SUB, segment=SEGMENT,
+            q, k, v, low, beta, chunk=CHUNK, sub=SUB,
             decay=lambda x: decay(x, w)) ** 2)
 
     want, g_want = jax.jit(jax.value_and_grad(whole, (0, 1)))(low, w)
-    got, g_got = jax.jit(jax.value_and_grad(by_segment, (0, 1)))(low, w)
+    got, g_got = jax.jit(jax.value_and_grad(handed_over, (0, 1)))(low, w)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))))

@@ -18,6 +18,7 @@
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,9 +160,10 @@ def test_loss_and_gradients_match_the_reference_float32(capacity_factor):
 
 
 def test_stages_block_by_block_equal_the_whole_sequence(monkeypatch):
-    """KDA's per-token stages, the dense layer and the rule's segments run
-    in rematerialised blocks of `kda.SEGMENT` tokens: the same function as
-    in one piece, values and gradients."""
+    """KDA's per-token stages and the dense layer run in rematerialised
+    blocks of `kda.SEGMENT` tokens (and the rule in chunks of `kda.CHUNK`,
+    three here, one before): the same function as in one piece, values and
+    gradients."""
     b = batch()
     cfg = tiny(dtype="float32")
     sz, w, tree = seeded(cfg)
@@ -351,6 +353,18 @@ def test_train_step_names_the_new_layers(monkeypatch):
     for layer in ("L_kda_proj", "L_kda_conv", "L_kda_core", "L_dense_ffn"):
         assert {s.pass_ for s in top if s.layer == layer} >= {"bwd", "remat"}
     assert not layers & {"L_gdn_proj", "L_gdn_conv", "L_gdn_core"}
+    # the rule's kernels carry a `name=` that is no layer: their time stays
+    # the rule's own scope's, the forward kernel's in the step and again
+    # in the layer's remat, the backward kernel's in the backward pass
+    seen = {}
+    for m in re.finditer(
+            r"%?([\w.\-]+) = [^\n]*op_name=\"[^\"]*(kda_(?:fwd|bwd))", text):
+        s = scopes[m.group(1)]
+        assert s.layer == "L_kda_core", m.group(0)
+        if s.top_level:
+            seen.setdefault(m.group(2), set()).add(s.pass_)
+    assert seen == {"kda_fwd": {"fwd", "remat"}, "kda_bwd": {"bwd"}}
+    assert "gated_delta_fwd" not in text and "gated_delta_bwd" not in text
     covered = sum(s.layer is not None for s in top) / len(top)
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
 
