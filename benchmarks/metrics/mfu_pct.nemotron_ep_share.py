@@ -1,0 +1,16 @@
+"""Model FLOP/s utilization of one expert-parallel rank's window: tokens/s
+times the operations a token needs ON THIS RANK
+(`kernels/nemotron_h_model.py`: the held experts' share of the routed
+pairs, not top_k a token), over the chip's bf16 peak (`peaks.json`) times
+the chips used. Read in the traced run, as `mfu_pct` is."""
+from benchmarks.kernels.nemotron_h_model import train_flops_per_token
+
+
+def read(obs):
+    sz = obs.get("sizes")
+    if "tokens_per_step" not in obs or not hasattr(sz, "ssm_heads"):
+        return None
+    rate = obs["steps"] * obs["tokens_per_step"] / obs["window_s"]
+    flops = train_flops_per_token(sz, obs["seq_len"])
+    chips = obs["trace"].n_devices if obs.get("trace") else 1
+    return 100.0 * rate * flops / (obs["peaks"]["bf16_flops_per_s"] * chips)

@@ -402,10 +402,10 @@ def _serve_model(args, *, quiet_random_init: bool = False):
               "export the stage-stacked params to the dense family first",
               file=sys.stderr)
         return 2
-    if cfg.model_family in ("qwen3next", "kimi_linear"):
+    if cfg.model_family in ("qwen3next", "kimi_linear", "nemotron_h"):
         print(f"serving is unsupported for the {cfg.model_family} family: "
-              "its linear-attention layers (Gated DeltaNet, Kimi Delta "
-              "Attention) keep recurrent state, and no cache manager here "
+              "its recurrent layers (Gated DeltaNet, Kimi Delta Attention, "
+              "Mamba-2) keep recurrent state, and no cache manager here "
               "holds that yet (ROADMAP R-M7); `cli train` runs it",
               file=sys.stderr)
         return 2

@@ -38,6 +38,10 @@ def build_model(cfg: RunConfig):
         from solvingpapers_tpu.models.kimi_linear import KimiLinear
 
         return KimiLinear(cfg.model)
+    if fam == "nemotron_h":
+        from solvingpapers_tpu.models.nemotron_h import NemotronH
+
+        return NemotronH(cfg.model)
     if fam == "gpt_pipe":
         from solvingpapers_tpu.models.gpt_pipe import GPTPipe
 
@@ -96,6 +100,8 @@ def loss_fn_for(cfg: RunConfig):
         "dsv3_pipe": dsv3_loss_fn,
         "qwen3next": qwen3next_loss_fn,
         "kimi_linear": kimi_linear_loss_fn,
+        # the same objective: cross-entropy alone, head and loss in chunks
+        "nemotron_h": kimi_linear_loss_fn,
         "vit": classification_loss_fn,
         "alexnet": classification_loss_fn,
         "kd": classification_loss_fn,

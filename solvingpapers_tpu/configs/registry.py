@@ -13,7 +13,7 @@ from solvingpapers_tpu.train.optim import OptimizerConfig
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     name: str
-    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | vit | alexnet | ae | vae | kd
+    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | nemotron_h | vit | alexnet | ae | vae | kd
     model: Any
     train: TrainConfig
     data: dict = dataclasses.field(default_factory=dict)
@@ -715,6 +715,52 @@ def _kimi_linear_48b_a3b() -> RunConfig:
               "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
         notes="published widths; run through a cut (experts held, layers, "
               "vocabulary slice), see benchmarks/configs/kimi_linear_ep32.json",
+    )
+
+
+@register("nemotron3_nano_30b_a3b")
+def _nemotron3_nano_30b_a3b() -> RunConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B at its published size
+    (huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 config.json):
+    52 layers, each ONE sub-block of the kind its pattern says: 23 Mamba-2
+    (64 heads of 64, 8 groups, state 128, convolution of 4 with bias), 23
+    MoE (128 ungated squared-ReLU experts of width 1856 with 6 a token
+    behind a sigmoid router, and a shared one of 3712), 6 attention (32
+    heads on 2 of width 128, no positions), hidden 2688, vocabulary
+    131,072. Far more than one chip holds (31.6B parameters): what runs is
+    a cut of it, one expert-parallel rank's share of a few layers
+    (benchmarks/configs/nemotron3_nano_ep16.json sets `num_hidden_layers`,
+    `n_routed_experts`, the experts held, and `vocab_size`; the router
+    keeps its 128 outputs, and the kinds of the layers are read from the
+    published `hybrid_override_pattern`). Training only: no decode cache
+    holds recurrent state yet.
+
+    The job (assumed, the source states none): one sequence of 16,384
+    tokens a step, AdamW 3e-4 beta=(0.9, 0.95) wd 0.1 clip 1.0, 100 steps
+    of warm-up -> cosine to 0.1*max; capacity factor 8 (at 4 the first
+    steps dropped up to 6% of the pairs routed to one rank's experts on two
+    seeds of fourteen); remat a layer."""
+    from solvingpapers_tpu.models.nemotron_h import NemotronHConfig
+
+    return RunConfig(
+        name="nemotron3_nano_30b_a3b",
+        model_family="nemotron_h",
+        model=NemotronHConfig(),
+        train=TrainConfig(
+            steps=10_000, batch_size=1, log_every=50, eval_every=500,
+            eval_batches=4, ckpt_every=1000,
+            optimizer=OptimizerConfig(
+                name="adamw", max_lr=3e-4, warmup_steps=100,
+                total_steps=10_000, b1=0.9, b2=0.95, weight_decay=0.1,
+                grad_clip=1.0,
+            ),
+            tokens_per_step=16_384,
+        ),
+        data={"kind": "bpe", "path": None, "block_size": 16_384,
+              "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
+        notes="published widths; run through a cut (experts held, layers, "
+              "vocabulary slice), see "
+              "benchmarks/configs/nemotron3_nano_ep16.json",
     )
 
 

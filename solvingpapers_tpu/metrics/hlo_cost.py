@@ -400,6 +400,9 @@ LAYER_SCOPES = (
     "L_kda_proj",
     "L_kda_conv",
     "L_kda_core",
+    "L_ssm_proj",
+    "L_ssm_conv",
+    "L_ssm_core",
     "L_dense_ffn",
     "L_moe_gate",
     "L_moe_dispatch",

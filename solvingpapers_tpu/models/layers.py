@@ -412,7 +412,11 @@ def swiglu_hidden_dim(dim: int, multiplier: int = 4) -> int:
 def apply_flash_attention(module, q, k, v, *, causal, scale=None,
                           dropout_rate=0.0, deterministic=True):
     """Flash attention with the framework's dropout policy, shared by every
-    use_flash model (Attention here, DeepSeekV3's MLA): in-kernel prob
+    use_flash model (Attention here, DeepSeekV3's MLA, Qwen3-Next's gated
+    attention at 16 heads on 2 of width 256, Kimi-Linear's latent attention
+    with keys 192 and values 128 wide, Nemotron-H's 32 heads on 2 of width
+    128 with no rotation: q (B, S, n, w), k and v (B, S, n_kv, w), each
+    key-value head serving n / n_kv query heads): in-kernel prob
     dropout on real TPU (same Bernoulli semantics as the dense path; mask
     regenerated in the backward from the seed, never materialized); when
     dropout is active OFF-TPU the dense path runs instead — interpret-mode

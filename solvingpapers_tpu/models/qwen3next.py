@@ -37,13 +37,16 @@ manager that holds recurrent state, ROADMAP R-M7).
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
-from solvingpapers_tpu.models.layers import GLUFFN, apply_flash_attention
+from solvingpapers_tpu.models.layers import (
+    GLUFFN, MLP, apply_flash_attention,
+)
 from solvingpapers_tpu.ops import gated_delta
 
 HI = jax.lax.Precision.HIGHEST
@@ -263,7 +266,10 @@ class HeldExpertsMoE(nn.Module):
     balance loss needs; or "sigmoid" scores, the top_k largest of score +
     a selection bias that takes no gradient (the parameter `select_bias`),
     the scores themselves as weights, renormalised when `renorm`, times
-    `scale`, and a shared expert added as it is."""
+    `scale`, and a shared expert added as it is. An expert, and the shared
+    one, is the gated unit w3 (act(w1 x) * w2 x) or, with `gated` false,
+    the two-matrix w3 act(w1 x) (no `w2`; the shared one a plain `MLP`):
+    the `nemotron_h` family's squared-ReLU experts."""
 
     router_experts: int
     held: int
@@ -276,6 +282,8 @@ class HeldExpertsMoE(nn.Module):
     scoring: str = "softmax"
     renorm: bool = True
     scale: float = 1.0
+    gated: bool = True
+    activation: Callable[[jax.Array], jax.Array] = ops.silu
 
     @nn.compact
     def __call__(self, x):
@@ -301,13 +309,17 @@ class HeldExpertsMoE(nn.Module):
                     logits, bias, k, self.renorm, self.scale
                 )
         w1 = self.param("w1", _INIT, (held, d, h))
-        w2 = self.param("w2", _INIT, (held, d, h))
+        w2 = self.param("w2", _INIT, (held, d, h)) if self.gated else None
         w3 = self.param("w3", _INIT, (held, h, d))
 
         def expert_fn(xe):  # (held, C, D) -> (held, C, D)
             a = jnp.einsum("ecd,edh->ech", xe, w1.astype(dt))
-            g = jnp.einsum("ecd,edh->ech", xe, w2.astype(dt))
-            return jnp.einsum("ech,ehd->ecd", ops.silu(a) * g, w3.astype(dt))
+            if self.gated:
+                g = jnp.einsum("ecd,edh->ech", xe, w2.astype(dt))
+                a = self.activation(a) * g
+            else:
+                a = self.activation(a)
+            return jnp.einsum("ech,ehd->ecd", a, w3.astype(dt))
 
         # capacity from the layer's whole width: an expert's fair share of
         # the routed pairs is the same whichever device holds it
@@ -318,9 +330,9 @@ class HeldExpertsMoE(nn.Module):
             xt, pair_w, pair_idx, expert_fn, cap, self.first_expert, held
         )
         with jax.named_scope("L_moe_shared"):
-            shared = GLUFFN(
-                dim=d, hidden_dim=self.shared_hidden,
-                activation=ops.silu, dtype=dt, name="shared_expert",
+            shared = (GLUFFN if self.gated else MLP)(
+                dim=d, hidden_dim=self.shared_hidden, use_bias=False,
+                activation=self.activation, dtype=dt, name="shared_expert",
             )(xt).astype(jnp.float32)
             if softmax:
                 shared = jax.nn.sigmoid(nn.Dense(

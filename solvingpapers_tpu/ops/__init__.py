@@ -17,6 +17,7 @@ from solvingpapers_tpu.ops.rope import (
 from solvingpapers_tpu.ops import moe
 from solvingpapers_tpu.ops.activations import (
     relu,
+    relu2,
     leaky_relu,
     prelu,
     elu,

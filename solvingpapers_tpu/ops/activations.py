@@ -22,6 +22,12 @@ def relu(x: jax.Array) -> jax.Array:
     return jax.nn.relu(x)
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """Squared ReLU, max(x, 0)^2: the activation of the `nemotron_h`
+    family's ungated feed-forward units (`mlp_hidden_act: "relu2"`)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def leaky_relu(x: jax.Array, negative_slope: float = 0.01) -> jax.Array:
     return jax.nn.leaky_relu(x, negative_slope)
 

@@ -78,7 +78,10 @@ def causal_depthwise_conv(x: jax.Array, w: jax.Array, silu: bool = False):
     multiply-adds, no convolution op (K is 4: an elementwise pass the
     compiler fuses). The backward pass is written the same way and starts
     again from x, so that it too is one pass over the sequence, and neither
-    K arrays of the sequence's size nor the convolution's output are kept."""
+    K arrays of the sequence's size nor the convolution's output are kept.
+    There is no bias here: a caller whose convolution has one (Mamba-2,
+    `models/nemotron_h.py`) calls with `silu` False and applies SiLU(y +
+    bias) itself, under a `jax.checkpoint` of its own."""
     y = _shifted_sum(x, w.astype(x.dtype), back=False)
     return jax.nn.silu(y) if silu else y
 

@@ -196,9 +196,10 @@ def qwen3next_loss_fn(model, params, batch, rng, model_state, train):
 
 
 def kimi_linear_loss_fn(model, params, batch, rng, model_state, train):
-    """Kimi-Linear objective: next-token cross-entropy and nothing else (the
-    source's config states no balance loss; its selection bias is a
-    parameter that takes no gradient). The MoE's counters
+    """Kimi-Linear objective, and the `nemotron_h` family's: next-token
+    cross-entropy and nothing else (neither source's config states a
+    balance loss; the selection bias is a parameter that takes no
+    gradient). The MoE's counters
     (`moe_drop_fraction`, `moe_held_pair_fraction`, the load statistics)
     ride along as the other MoE families' do. Head and loss run together in
     chunks of rows (`ops.head_cross_entropy`): at 16,384 tokens the whole

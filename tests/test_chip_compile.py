@@ -63,6 +63,10 @@ SHAPES = {
     # With the long-sequence 1024-tiles the compiler refuses the backward-dq
     # kernel here (VMEM), so `auto_block` keeps 512 past width 128
     "qwen3next_gqa_16k": (1, 16_384, 16, 2, 256, 0.0),
+    # nemotron3_nano_ep16's attention layer: 32 q heads on 2 kv heads of
+    # width 128, sixteen query heads a key-value head, no rotation; at width
+    # 128 `auto_block` hands all three kernels the 1,024-tiles
+    "nemotron_gqa_32on2_16k": (1, 16_384, 32, 2, 128, 0.0),
 }
 
 

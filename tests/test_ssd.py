@@ -1,0 +1,183 @@
+"""The Mamba-2 state-space recurrence of `ops/ssd.py` on the CPU: the
+chunked form against the rule token by token (`ssd_recurrent`) and against
+its quadratic dual written out here, values and gradients, in float32 and
+with bfloat16 operands; a sequence that is no whole number of chunks; heads
+that share their group's B and C; a step so large that the decay underflows;
+the state handed from one call to the next; the gated norm."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from solvingpapers_tpu.ops import gated_delta, ssd
+
+pytestmark = pytest.mark.fast
+
+B, S, H, P, G, N = 2, 50, 8, 4, 2, 16
+
+
+def inputs(seed=0, seq=S, dtype=jnp.float32, heads=H, groups=G):
+    k = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(k[0], (B, seq, heads, P)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, seq, heads)) - 1.0)
+    a = -jnp.exp(jax.random.uniform(k[2], (heads,), minval=-2.0, maxval=2.7))
+    b = jax.random.normal(k[3], (B, seq, groups, N)).astype(dtype)
+    c = jax.random.normal(k[4], (B, seq, groups, N)).astype(dtype)
+    d = jax.random.normal(k[5], (heads,))
+    return x, dt, a, b, c, d
+
+
+def dual(x, dt, a, b, c, d):
+    """y_t = sum_{s<=t} exp(sum_{r=s+1..t} dt_r a) dt_s (C_t . B_s) x_s + d
+    x_t, the whole (S, S) matrix a head at once."""
+    rep = x.shape[2] // b.shape[2]
+    b, c = jnp.repeat(b, rep, axis=2), jnp.repeat(c, rep, axis=2)
+    cum = jnp.cumsum(dt * a, axis=1)  # (B, S, H)
+    diff = cum[:, :, None] - cum[:, None, :]  # [t, s]
+    keep = jnp.tril(jnp.ones((x.shape[1],) * 2, bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(keep, diff, -jnp.inf))
+    scores = jnp.einsum("bthn,bshn->btsh", c, b, precision="highest")
+    m = scores * decay * dt[:, None]
+    return (jnp.einsum("btsh,bshp->bthp", m, x, precision="highest")
+            + d[:, None] * x)
+
+
+def close(got, want, tol=2e-5):
+    """Within `tol` of the largest entry of `want`."""
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=tol * float(jnp.max(jnp.abs(want))))
+
+
+def objective(fn, **kw):
+    def f(x, dt, a, b, c, d):
+        out = fn(x, dt, a, b, c, d, **kw)
+        y = out[0] if isinstance(out, tuple) else out
+        return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+    return f
+
+
+@pytest.mark.parametrize("chunk, segment", [(8, 16), (16, 16), (8, 64)])
+def test_chunked_equals_recurrent_equals_dual_float32(chunk, segment):
+    """50 tokens: no whole number of chunks of 8 or 16; 8 heads on 2
+    groups."""
+    args = inputs()
+    y_rec, s_rec = ssd.ssd_recurrent(*args)
+    y, s = ssd.ssd_chunked(*args, chunk=chunk, segment=segment)
+    assert y.shape == (B, S, H, P) and s.shape == (B, H, P, N)
+    close(y, y_rec)
+    close(s, s_rec)
+    close(dual(*args), y_rec)
+
+
+def test_gradients_equal_the_recurrent_and_the_dual_ones():
+    args = inputs(seed=1)
+    want = jax.grad(objective(ssd.ssd_recurrent), argnums=range(6))(*args)
+    got = jax.grad(objective(ssd.ssd_chunked, chunk=8, segment=16),
+                   argnums=range(6))(*args)
+    by_dual = jax.grad(objective(dual), argnums=range(6))(*args)
+    for g, w, q in zip(got, want, by_dual):
+        close(g, w)
+        close(q, w)
+
+
+def test_bfloat16_operands_stay_near_the_float32_rule():
+    """Operands in bfloat16, sums, decays and state in float32: values and
+    gradients within bfloat16's rounding of the float32 recurrence on the
+    same (rounded) inputs."""
+    args = inputs(seed=2, dtype=jnp.bfloat16)
+    y, state = ssd.ssd_chunked(*args, chunk=8, segment=16)
+    assert y.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    y_rec, s_rec = ssd.ssd_recurrent(*args)
+    close(y.astype(jnp.float32), y_rec, 2e-2)
+    close(state, s_rec, 2e-2)
+    got = jax.grad(objective(ssd.ssd_chunked, chunk=8, segment=16),
+                   argnums=(0, 1, 3))(*args)
+    want = jax.grad(objective(ssd.ssd_recurrent), argnums=(0, 1, 3))(*args)
+    for g, w in zip(got, want):
+        rel = float(jnp.linalg.norm(g.astype(jnp.float32) - w)
+                    / jnp.linalg.norm(w))
+        assert rel < 3e-2, rel
+
+
+def test_every_head_of_a_group_reads_its_groups_b_and_c():
+    """8 heads on 2 groups equal 8 heads on 8 groups whose B and C repeat
+    their group's rows."""
+    x, dt, a, b, c, d = inputs(seed=3)
+    wide = ssd.ssd_chunked(x, dt, a, jnp.repeat(b, 4, axis=2),
+                           jnp.repeat(c, 4, axis=2), d, chunk=8)[0]
+    close(ssd.ssd_chunked(x, dt, a, b, c, d, chunk=8)[0], wide)
+    with pytest.raises(ValueError, match="heads over"):
+        ssd.ssd_chunked(x, dt, a, b[:, :, :1].repeat(3, 2),
+                        c[:, :, :1].repeat(3, 2), d)
+
+
+def test_a_step_that_underflows_the_decay_gives_zeros_not_nan():
+    """dt a = -4,000 a token: exp underflows, the state forgets everything
+    within one token and what is left is the token's own write."""
+    x, dt, a, b, c, d = inputs(seed=4)
+    dt = jnp.full_like(dt, 250.0)
+    a = jnp.full_like(a, -16.0)
+    y, state = ssd.ssd_chunked(x, dt, a, b, c, None, chunk=8, segment=16)
+    assert bool(jnp.all(jnp.isfinite(y))) and bool(
+        jnp.all(jnp.isfinite(state)))
+    rep = H // G
+    own = dt[..., None] * x * jnp.sum(
+        jnp.repeat(b, rep, 2) * jnp.repeat(c, rep, 2), -1, keepdims=True)
+    close(y, own)
+    grads = jax.grad(objective(ssd.ssd_chunked, chunk=8, segment=16),
+                     argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c, d)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
+
+
+def test_state_handed_from_one_call_to_the_next():
+    args = inputs(seed=5, seq=48)
+    x, dt, a, b, c, d = args
+    whole, s_whole = ssd.ssd_chunked(*args, chunk=8, segment=16)
+    cut = 20  # not a chunk's edge
+    first, s_first = ssd.ssd_chunked(x[:, :cut], dt[:, :cut], a, b[:, :cut],
+                                     c[:, :cut], d, chunk=8, segment=16)
+    rest, s_rest = ssd.ssd_chunked(x[:, cut:], dt[:, cut:], a, b[:, cut:],
+                                   c[:, cut:], d, state=s_first, chunk=8,
+                                   segment=16)
+    close(jnp.concatenate([first, rest], 1), whole)
+    close(s_rest, s_whole)
+    # and one token at a time, as a decode step would
+    state, ys = s_first, []
+    for t in range(cut, cut + 3):
+        state, y_t = ssd.ssd_step(state, x[:, t], dt[:, t], a, b[:, t],
+                                  c[:, t], d)
+        ys.append(y_t)
+    close(jnp.stack(ys, 1), whole[:, cut:cut + 3])
+
+
+def test_shapes_that_do_not_fit_are_refused():
+    x, dt, a, b, c, d = inputs()
+    with pytest.raises(ValueError, match="whole number of chunks"):
+        ssd.ssd_chunked(x, dt, a, b, c, d, chunk=8, segment=20)
+    with pytest.raises(ValueError, match="one step a head"):
+        ssd.ssd_chunked(x, dt[..., :4], a, b, c, d)
+
+
+def test_gate_then_group_norm_is_not_norm_then_gate():
+    """u = y * SiLU(z) normalised over groups of channels, against the
+    DeltaNet families' norm a head with the gate after it: a y whose group
+    holds one loud head tells them apart."""
+    y = jnp.concatenate([jnp.full((1, 3, 4), 10.0), jnp.ones((1, 3, 12))], -1)
+    z = jax.random.normal(jax.random.key(0), (1, 3, 16))
+    w = jnp.linspace(0.5, 1.5, 16)
+    got = ssd.gate_then_group_norm(y, z, w, 2, 1e-5)
+    u = (y * jax.nn.silu(z)).reshape(1, 3, 2, 8)
+    want = (u / jnp.sqrt(jnp.mean(u * u, -1, keepdims=True) + 1e-5)
+            ).reshape(1, 3, 16) * w
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    heads = gated_delta.gated_rms_norm(
+        y.reshape(1, 3, 4, 4), z.reshape(1, 3, 4, 4), jnp.ones(4), 1e-5
+    ).reshape(1, 3, 16) * w
+    assert float(jnp.max(jnp.abs(got - heads))) > 0.1
+    # one group over everything is the plain gated RMS norm
+    one = ssd.gate_then_group_norm(y, z, w, 1, 1e-5)
+    u = y * jax.nn.silu(z)
+    np.testing.assert_allclose(
+        one, u / jnp.sqrt(jnp.mean(u * u, -1, keepdims=True) + 1e-5) * w,
+        atol=1e-6)
