@@ -448,7 +448,8 @@ class MoELayer(nn.Module):
                     # the same product over the tiles of rows that hold a
                     # token; the slots behind an expert's fill are zero
                     # rows, which give zero rows either way
-                    return moe_grouped.grouped_glu(xe, *ws, fill)
+                    return moe_grouped.grouped_glu(
+                        xe, *ws, fill, activation=ops.swish)
                 return expert_body(xe, *ws)
 
             # under CP/shard_map b*s is the LOCAL token count, so capacity

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
+from solvingpapers_tpu.kernels import moe_grouped
 from solvingpapers_tpu.models.layers import (
     GLUFFN, MLP, apply_flash_attention,
 )
@@ -269,7 +270,11 @@ class HeldExpertsMoE(nn.Module):
     `scale`, and a shared expert added as it is. An expert, and the shared
     one, is the gated unit w3 (act(w1 x) * w2 x) or, with `gated` false,
     the two-matrix w3 act(w1 x) (no `w2`; the shared one a plain `MLP`):
-    the `nemotron_h` family's squared-ReLU experts."""
+    the `nemotron_h` family's squared-ReLU experts. On one TPU, at a
+    capacity of whole row tiles, the routed experts' unit runs as the
+    kernels of `kernels/moe_grouped.py` over the tiles of rows that hold a
+    token (`moe_grouped.engages`; the sown `live_tile_fraction` says how
+    many); everywhere else as einsums over every slot."""
 
     router_experts: int
     held: int
@@ -312,7 +317,22 @@ class HeldExpertsMoE(nn.Module):
         w2 = self.param("w2", _INIT, (held, d, h)) if self.gated else None
         w3 = self.param("w3", _INIT, (held, h, d))
 
-        def expert_fn(xe):  # (held, C, D) -> (held, C, D)
+        # capacity from the layer's whole width: an expert's fair share of
+        # the routed pairs is the same whichever device holds it
+        cap = ops.moe.expert_capacity(
+            t, self.router_experts, k, self.capacity_factor
+        )
+        # by what the call can see, no flag: on one TPU, with whole row
+        # tiles, the same unit over the tiles of rows that hold a token
+        # (`kernels/moe_grouped.py`); the slots behind an expert's fill are
+        # zero rows, which give zero rows either way
+        grouped = moe_grouped.engages(cap, d)
+
+        def expert_fn(xe, fill):  # (held, C, D), (held,) -> (held, C, D)
+            if grouped:
+                return moe_grouped.grouped_glu(
+                    xe, w1.astype(dt), w2 if w2 is None else w2.astype(dt),
+                    w3.astype(dt), fill, activation=self.activation)
             a = jnp.einsum("ecd,edh->ech", xe, w1.astype(dt))
             if self.gated:
                 g = jnp.einsum("ecd,edh->ech", xe, w2.astype(dt))
@@ -321,11 +341,6 @@ class HeldExpertsMoE(nn.Module):
                 a = self.activation(a)
             return jnp.einsum("ech,ehd->ecd", a, w3.astype(dt))
 
-        # capacity from the layer's whole width: an expert's fair share of
-        # the routed pairs is the same whichever device holds it
-        cap = ops.moe.expert_capacity(
-            t, self.router_experts, k, self.capacity_factor
-        )
         out, held_probs = ops.moe.moe_held_dispatch_combine(
             xt, pair_w, pair_idx, expert_fn, cap, self.first_expert, held
         )
@@ -361,6 +376,12 @@ class HeldExpertsMoE(nn.Module):
                     on_held.astype(jnp.float32))
             stats["drop_fraction"] = ops.moe.dispatch_drop_fraction(
                 held_probs, cap)
+            # share of the experts' row tiles that are multiplied: 1 where
+            # the einsums run over every slot
+            stats["live_tile_fraction"] = (
+                ops.moe.live_tile_fraction(
+                    held_probs, cap, moe_grouped.ROW_TILE)
+                if grouped else jnp.ones(()))
             self.sow("moe_metrics", "stats", stats)
         with jax.named_scope("L_moe_combine"):
             return out.reshape(b, s, d)

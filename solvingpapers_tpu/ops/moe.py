@@ -13,7 +13,8 @@ its token) and rows move through them by gather, forward and backward: the
 maps are one partial permutation read from both ends, so no scatter and no
 product with a (T, E, C) one-hot is needed, and the MXU runs the experts'
 products only. An expert's filled slots are a prefix of its C rows, and
-the dispatch hands the E fill counts on (`_Routes.fill`), so that the
+the dispatch hands the E fill counts on (`_Routes.fill`: `moe_dispatch_combine`
+with `pass_fill`, `moe_held_dispatch_combine` always), so that the
 experts' product can skip the rows behind them: on one TPU
 `kernels/moe_grouped.py` does, in tiles of rows; elsewhere the caller's
 einsums run over every slot. A dense all-experts path is kept as the
@@ -345,8 +346,9 @@ class _PairRoutes(NamedTuple):
 
 def _pair_routes(
     pair_w: jax.Array, pair_idx: jax.Array, first: int, held: int, capacity: int
-) -> tuple[_PairRoutes, jax.Array]:
-    """(the maps, the (T, held) weights they were made from)."""
+) -> tuple[_PairRoutes, jax.Array, jax.Array]:
+    """(the maps, the (held,) slots each expert fills, the (T, held) weights
+    they were made from)."""
     probs = held_pair_probs(jax.lax.stop_gradient(pair_w), pair_idx, first, held)
     routes = _routes(probs, capacity)
     with jax.named_scope("L_moe_gate"):
@@ -357,9 +359,8 @@ def _pair_routes(
         pair_slot = jnp.where(
             on_held & (pos < capacity), local * capacity + pos, held * capacity
         ).astype(jnp.int32)
-    # `routes.fill` is left behind: `HeldExpertsMoE` runs its einsums over
-    # every slot (320 of 640 rows an expert in its cell: two or three tiles)
-    return _PairRoutes(pair_slot, routes.slot_tok, routes.slot_w), probs
+    return (_PairRoutes(pair_slot, routes.slot_tok, routes.slot_w),
+            routes.fill, probs)
 
 
 def moe_held_dispatch_combine(
@@ -375,16 +376,17 @@ def moe_held_dispatch_combine(
     of the routed pairs `(pair_w, pair_idx)` (T, k) over ALL experts, those
     on the experts [first, first + held) go through the same slots as
     `moe_dispatch_combine` (`_dispatch_slots` decides what is dropped),
-    `expert_fn((held, C, D)) -> (held, C, D)` runs, and each token sums its
-    kept pairs. What the other experts would add is left out. Returns (the
-    partial output (T, D), the (T, held) weights of the pairs routed here,
-    for the drop counter)."""
-    routes, probs = _pair_routes(pair_w, pair_idx, first, held, capacity)
+    `expert_fn((held, C, D), fill) -> (held, C, D)` runs (fill (held,)
+    int32, as `moe_dispatch_combine` hands it on with `pass_fill`), and
+    each token sums its kept pairs. What the other experts would add is
+    left out. Returns (the partial output (T, D), the (T, held) weights of
+    the pairs routed here, for the drop counter)."""
+    routes, fill, probs = _pair_routes(pair_w, pair_idx, first, held, capacity)
     with jax.named_scope("L_moe_dispatch"):
         xe = _dispatch_rows(
             *_vary_alike(x, routes.pair_slot, routes.slot_tok), True)
     with jax.named_scope("L_moe_experts"):
-        ye = expert_fn(xe)
+        ye = expert_fn(xe, fill)
     with jax.named_scope("L_moe_combine"):
         out = _combine_rows(*_vary_alike(ye, pair_w, *routes), True)
     return out, probs

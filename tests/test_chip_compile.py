@@ -240,37 +240,52 @@ def test_gated_delta_rule_pads_narrow_heads_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
-@pytest.mark.parametrize("capacity", [16_384, 8_192],
-                         ids=["train_64x256", "train_16k"])
-def test_moe_grouped_glu_compiles_for_v5e(one_chip, capacity):
-    """The routed experts' kernels at the two DeepSeekV3 cells' shapes (8
-    experts, 512 wide, hidden 1,365 padded to 1,408, bfloat16): Mosaic takes
-    their blocks and the VMEM they ask for, one call forward and one
-    backward, and each returns three arrays or more (the benchmark's
-    `flash_mla.kind_of` reads a Mosaic call with one or two results as a
-    flash-attention kernel)."""
+# (experts, slots an expert, width, hidden width, gated, activation, calls)
+MOE_SHAPES = {
+    # the two DeepSeekV3 cells: hidden 1,365 padded to 1,408; an expert's
+    # weights, sums and gradient blocks fit VMEM: one kernel backward
+    "train_64x256": (8, 16_384, 512, 1365, True, "swish", 2),
+    "train_16k": (8, 8_192, 512, 1365, True, "swish", 2),
+    # the published widths: the backward's sums in blocks of H, dx apart
+    "kimi_linear_ep32": (8, 2_048, 2304, 1024, True, "silu", 3),
+    "nemotron3_nano_ep16": (8, 6_144, 2688, 1856, False, "relu2", 3),
+}
+
+
+@pytest.mark.parametrize("shape", list(MOE_SHAPES))
+def test_moe_grouped_glu_compiles_for_v5e(one_chip, shape):
+    """The routed experts' kernels at the cells' shapes (bfloat16, forward
+    and backward): Mosaic takes their blocks and the VMEM they ask for. At
+    the two DeepSeekV3 cells' one call forward and one backward, and each
+    returns three arrays or more (the benchmark's `flash_mla.kind_of` reads
+    a Mosaic call with one or two results as a flash-attention kernel); at
+    the published widths, gated and not, the backward is two calls."""
     import re
 
+    from solvingpapers_tpu import ops
     from solvingpapers_tpu.kernels.moe_grouped import grouped_glu
 
+    e, c, d, h, gated, act, n_calls = MOE_SHAPES[shape]
     sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    args = (sds((8, capacity, 512)), sds((8, 512, 1365)), sds((8, 512, 1365)),
-            sds((8, 1365, 512)), sds((8,), jnp.int32))
+    args = (sds((e, c, d)), sds((e, d, h)), sds((e, d, h)), sds((e, h, d)),
+            sds((e,), jnp.int32))
 
     def loss(xe, w1, w2, w3, fill):
         # interpret=False: the default would ask jax.devices(), the CPU here
         return jnp.sum(grouped_glu(
-            xe, w1, w2, w3, fill, interpret=False).astype(jnp.float32))
+            xe, w1, w2 if gated else None, w3, fill,
+            activation=getattr(ops, act), interpret=False).astype(jnp.float32))
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-        *args).compile().as_text()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3) if gated else (0, 1, 3))
+                   ).lower(*args).compile().as_text()
     calls = [line.split(" custom-call(")[0].split("=", 1)[1]
              for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
-    assert len(calls) == 2
-    for results in calls:
-        assert len(re.findall(r"\b[a-z]+\d+\[", results)) >= 3, results
+    assert len(calls) == n_calls
+    if n_calls == 2:
+        for results in calls:
+            assert len(re.findall(r"\b[a-z]+\d+\[", results)) >= 3, results
 
 
 def test_described_chip_is_in_the_peak_tables(topo):

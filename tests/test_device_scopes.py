@@ -149,6 +149,29 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
+def _expert_kernel_passes(trainer, state, batch, kernels):
+    """{kernel: the passes of the compiled step in which what it lowers to
+    appears}, every such instruction under `L_moe_experts`; and the names of
+    the step's `pallas_call`s, counted."""
+    hlo_cost.register_program("jit_train_step", trainer._train_step,
+                              (state, batch))
+    scopes = hlo_cost.program_scopes("jit_train_step")
+    text = trainer._train_step.lower(state, batch).compile().as_text()
+    passes = collections.defaultdict(set)
+    for _, _, m, line in hlo_cost._scan_defs(text):
+        src = hlo_cost._OP_NAME_RE.search(line)
+        if src is None or m.name not in scopes:  # a parameter, a constant
+            continue
+        for kernel in kernels:
+            if kernel in src.group("src"):
+                assert scopes[m.name].layer == "L_moe_experts", line
+                passes[kernel].add(scopes[m.name].pass_)
+    assert not set(hlo_cost.LAYER_SCOPES + hlo_cost.KERNEL_SCOPES) & set(passes)
+    calls = list(_pallas_calls(
+        jax.make_jaxpr(trainer._train_step)(state, batch).jaxpr))
+    return dict(passes), calls
+
+
 def test_grouped_expert_kernels_keep_the_experts_scope(monkeypatch):
     """On one TPU the routed experts run as `kernels/moe_grouped.py`'s two
     kernels (here through the interpreter, steered as the chip would): what
@@ -165,27 +188,53 @@ def test_grouped_expert_kernels_keep_the_experts_scope(monkeypatch):
     trainer, batch = dsv3_trainer(remat=True, rope_dim=8, dim=128, n_layers=1)
     state = trainer.init_state(batch)
     trainer._build_steps()
-    hlo_cost.register_program("jit_train_step", trainer._train_step,
-                              (state, batch))
-    scopes = hlo_cost.program_scopes("jit_train_step")
-    text = trainer._train_step.lower(state, batch).compile().as_text()
-    passes = collections.defaultdict(set)
-    for _, _, m, line in hlo_cost._scan_defs(text):
-        src = hlo_cost._OP_NAME_RE.search(line)
-        if src is None or m.name not in scopes:  # a parameter, a constant
-            continue
-        for kernel in ("moe_glu_fwd", "moe_glu_bwd"):
-            if kernel in src.group("src"):
-                assert scopes[m.name].layer == "L_moe_experts", line
-                passes[kernel].add(scopes[m.name].pass_)
+    passes, calls = _expert_kernel_passes(
+        trainer, state, batch, ("moe_glu_fwd", "moe_glu_bwd"))
     assert passes == {"moe_glu_fwd": {"fwd", "remat"}, "moe_glu_bwd": {"bwd"}}
-    assert not set(hlo_cost.LAYER_SCOPES + hlo_cost.KERNEL_SCOPES) & set(passes)
-    calls = list(_pallas_calls(
-        jax.make_jaxpr(trainer._train_step)(state, batch).jaxpr))
-    names = collections.Counter(
-        c.params["name"] for c in calls)
+    names = collections.Counter(c.params["name"] for c in calls)
     assert names == {"moe_glu_fwd": 2, "moe_glu_bwd": 1}, names  # fwd, remat
     assert all(len(c.outvars) >= 3 for c in calls)
+
+
+def test_held_experts_kernels_keep_the_experts_scope(monkeypatch):
+    """The held experts on one steered TPU (a Mamba-2 layer and an MoE layer
+    of the `nemotron_h` family, ungated squared-ReLU experts, `SUMS_VMEM`
+    shrunk so that the backward is the two kernels the published widths
+    take): what all three kernels lower to is `L_moe_experts`', forward,
+    recomputed and backward, and none of it is unscoped."""
+    from solvingpapers_tpu.kernels import moe_grouped
+    from solvingpapers_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+    from solvingpapers_tpu.ops import ssd
+    from solvingpapers_tpu.train.objectives import kimi_linear_loss_fn
+
+    monkeypatch.setattr(moe_grouped, "ROW_TILE", 8)
+    monkeypatch.setattr(moe_grouped, "SUMS_VMEM", 700_000)
+    monkeypatch.setattr(moe_grouped, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    monkeypatch.setattr(ssd, "SEGMENT", 16)
+    cfg = NemotronHConfig(
+        vocab_size=64, block_size=32, hidden_size=128, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        mamba_num_heads=8, mamba_head_dim=4, n_groups=2, ssm_state_size=8,
+        chunk_size=8, n_routed_experts=4, router_experts=16, first_expert=4,
+        num_experts_per_tok=3, moe_intermediate_size=24,
+        moe_shared_expert_intermediate_size=48, use_flash=False,
+        capacity_factor=2.0, dtype="float32", remat=True)
+    assert cfg.hybrid_override_pattern[:2] == "ME"
+    trainer = Trainer(
+        NemotronH(cfg), TrainConfig(steps=2, batch_size=2, log_every=1),
+        loss_fn=kimi_linear_loss_fn, mesh=one_device_mesh())
+    batch = {k: np.zeros((2, cfg.block_size), np.int32) for k in "xy"}
+    state = trainer.init_state(batch)
+    trainer._build_steps()
+    passes, calls = _expert_kernel_passes(
+        trainer, state, batch,
+        ("moe_glu_fwd", "moe_glu_bwd_dw", "moe_glu_bwd_dx"))
+    assert passes == {"moe_glu_fwd": {"fwd", "remat"},
+                      "moe_glu_bwd_dw": {"bwd"}, "moe_glu_bwd_dx": {"bwd"}}
+    names = collections.Counter(c.params["name"] for c in calls)
+    assert names == {"moe_glu_fwd": 2, "moe_glu_bwd_dw": 1,
+                     "moe_glu_bwd_dx": 1}, names  # fwd, remat
 
 
 def test_program_scopes_knows_only_registered_programs():

@@ -1,15 +1,19 @@
-"""The routed experts' grouped GLU kernels (`kernels/moe_grouped.py`) against
-the einsums of `MoELayer.expert_body`, through Pallas' interpreter:
+"""The routed experts' grouped kernels (`kernels/moe_grouped.py`) against
+the einsums of `MoELayer.expert_body` and `HeldExpertsMoE.expert_fn`,
+through Pallas' interpreter, for both units (gated SwiGLU, ungated squared
+ReLU) and both forms of the backward (one kernel; the sums in blocks of H
+and dx in a kernel of its own):
 
-  * forward and the four gradients through `moe_dispatch_combine`, for fills
+  * forward and every gradient through `moe_dispatch_combine`, for fills
     that are empty, one row, not whole tiles, exactly C with drops, and a
     row of tied logits;
   * y and dx exactly zero behind an expert's fill;
   * the invariant the kernels rest on: an expert's filled slots are the
     prefix [0, fill) of its C;
-  * a whole `MoELayer` step with the kernels equals the step with the
+  * a whole `MoELayer` step, and a `HeldExpertsMoE` step of each of the
+    three families that use it, with the kernels equals the step with the
     einsums, and counts its live row tiles;
-  * where the kernels engage.
+  * where the kernels engage, and how the backward is cut into blocks.
 """
 
 import jax
@@ -19,12 +23,22 @@ import pytest
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels import moe_grouped
+from solvingpapers_tpu.models import kimi_linear, nemotron_h, qwen3next
 from solvingpapers_tpu.models.deepseekv3 import DeepSeekV3Config, MoELayer
 
 pytestmark = pytest.mark.fast
 
 TILE = 8
 T, E, K, C, D, H = 32, 5, 2, 16, 16, 40  # H pads to 128 lanes
+# the unit: (the activation, whether there is a gate)
+UNITS = {"swiglu": (ops.swish, True), "relu2": (ops.relu2, False)}
+# the backward: (H, `SUMS_VMEM`): one kernel, or two with the sums in two
+# blocks of the 256 lanes that H = 200 pads to (the budget holds 128 rows of
+# the widest case, three float32 weights of D, and not 256 of the narrowest);
+# and both again at an H of whole lanes, where w1 and w2 go in untransposed
+BACKWARDS = {"one_kernel": (H, None), "h_blocks": (200, 128 * 832),
+             "one_kernel_whole_lanes": (128, None),
+             "h_blocks_whole_lanes": (256, 128 * 832)}
 
 
 @pytest.fixture(autouse=True)
@@ -70,63 +84,97 @@ CASES = ["empty_one_ragged_full", "whole_tiles", "tied_row", "all_to_one",
          "random"]
 
 
-def _weights(dtype=jnp.float32, e=E, d=D, h=H):
+def _weights(unit="swiglu", dtype=jnp.float32, e=E, d=D, h=H):
+    """{"w1", "w3"} and, where the unit has a gate, "w2"."""
     k = jax.random.split(jax.random.key(1), 3)
-    return tuple((jax.random.normal(k[i], s) * 0.3).astype(dtype)
-                 for i, s in enumerate([(e, d, h), (e, d, h), (e, h, d)]))
+    ws = {name: (jax.random.normal(k[i], s) * 0.3).astype(dtype)
+          for i, (name, s) in enumerate(
+              [("w1", (e, d, h)), ("w2", (e, d, h)), ("w3", (e, h, d))])}
+    if not UNITS[unit][1]:
+        del ws["w2"]
+    return ws
 
 
-def _einsums(xe, w1, w2, w3):
-    a = jnp.einsum("ecd,edh->ech", xe, w1)
-    g = jnp.einsum("ecd,edh->ech", xe, w2)
-    return jnp.einsum("ech,ehd->ecd", ops.swish(a) * g, w3)
+def _einsums(unit, xe, ws):
+    a = UNITS[unit][0](jnp.einsum("ecd,edh->ech", xe, ws["w1"]))
+    if "w2" in ws:
+        a = a * jnp.einsum("ecd,edh->ech", xe, ws["w2"])
+    return jnp.einsum("ech,ehd->ecd", a, ws["w3"])
 
 
-def _moe(x, w1, w2, w3, probs, grouped: bool):
+def _grouped(unit, xe, ws, fill):
+    return moe_grouped.grouped_glu(
+        xe, ws["w1"], ws.get("w2"), ws["w3"], fill,
+        activation=UNITS[unit][0])
+
+
+def _set_backward(monkeypatch, backward, unit, dtype) -> int:
+    """H of the case, `SUMS_VMEM` shrunk where it asks for blocks."""
+    h, budget = BACKWARDS[backward]
+    if budget is not None:
+        monkeypatch.setattr(moe_grouped, "SUMS_VMEM", budget)
+    blocks = moe_grouped._h_blocks(
+        -(-h // 128) * 128, D, jnp.dtype(dtype).itemsize, 2 + UNITS[unit][1])
+    assert blocks == (0 if budget is None else 2)
+    return h
+
+
+def _moe(unit, x, ws, probs, grouped: bool):
     def expert_fn(xe, fill):
         if grouped:
-            return moe_grouped.grouped_glu(xe, w1, w2, w3, fill)
-        return _einsums(xe, w1, w2, w3)
+            return _grouped(unit, xe, ws, fill)
+        return _einsums(unit, xe, ws)
 
     return ops.moe.moe_dispatch_combine(x, probs, expert_fn, C, pass_fill=True)
 
 
+@pytest.mark.parametrize("backward", list(BACKWARDS))
+@pytest.mark.parametrize("unit", list(UNITS))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("case", CASES)
-def test_forward_and_four_gradients_match_the_einsums(case, dtype):
+def test_forward_and_every_gradient_match_the_einsums(
+        monkeypatch, case, dtype, unit, backward):
+    h = _set_backward(monkeypatch, backward, unit, dtype)
     logits, fills = _case(case)
     probs = ops.moe.topk_gate_probs(jnp.asarray(logits), K)
     if fills is not None:
         np.testing.assert_array_equal(ops.moe._routes(probs, C).fill, fills)
     x = jax.random.normal(jax.random.key(2), (T, D)).astype(dtype)
-    ws = _weights(dtype)
+    ws = _weights(unit, dtype, h=h)
     dout = jax.random.normal(jax.random.key(3), (T, D))
 
     def loss(grouped):
-        def f(x, w1, w2, w3):
-            out = _moe(x, w1, w2, w3, probs, grouped)
+        def f(x, ws):
+            out = _moe(unit, x, ws, probs, grouped)
             return jnp.sum(out.astype(jnp.float32) * dout), out
-        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3), has_aux=True))
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
 
-    (_, out), grads = loss(True)(x, *ws)
-    (_, want), want_grads = loss(False)(x, *ws)
+    (_, out), (dx, dws) = loss(True)(x, ws)
+    (_, want), (want_dx, want_dws) = loss(False)(x, ws)
     # float32: both sum in float32, in another order. bfloat16: the kernels
     # round a, g, h, da, dg once where the einsums' chain rounds each
     # product's result, so they differ by roundings of the operands
     tol = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 else \
         dict(rtol=5e-2, atol=5e-2)
     f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
-    np.testing.assert_allclose(f32(out), f32(want), **tol)
-    for got, ref, name in zip(grads, want_grads, ("x", "w1", "w2", "w3")):
+    scale = max(1.0, float(np.abs(f32(want)).max()))
+    np.testing.assert_allclose(f32(out) / scale, f32(want) / scale, **tol)
+    assert set(dws) == set(ws)
+    for name, got, ref in [("x", dx, want_dx)] + [
+            (k, dws[k], want_dws[k]) for k in ws]:
         scale = max(1.0, float(np.abs(f32(ref)).max()))
         np.testing.assert_allclose(f32(got) / scale, f32(ref) / scale,
                                    err_msg=name, **tol)
 
 
+@pytest.mark.parametrize("backward", list(BACKWARDS))
+@pytest.mark.parametrize("unit", list(UNITS))
 @pytest.mark.parametrize("fills", [[0, 1, 13, 16], [8, 16, 0, 9], [0, 0, 0, 0]],
                          ids=["ragged", "tile_edges", "all_empty"])
-def test_output_and_dx_are_exactly_zero_behind_the_fill(fills):
+def test_output_and_dx_are_exactly_zero_behind_the_fill(
+        monkeypatch, fills, unit, backward):
+    h = _set_backward(monkeypatch, backward, unit, jnp.float32)
     fill = jnp.asarray(fills, jnp.int32)
     e = len(fills)
     behind = np.arange(C)[None, :] >= np.asarray(fills)[:, None]  # (E, C)
@@ -135,15 +183,15 @@ def test_output_and_dx_are_exactly_zero_behind_the_fill(fills):
     # a cotangent that is NOT zero behind the fill: dx still is, since
     # a = g = 0 there makes da = dg = 0 whatever arrives
     dye = jax.random.normal(jax.random.key(5), (e, C, D))
-    ws = _weights(e=e)
-    ye, vjp = jax.vjp(
-        lambda xe, *ws: moe_grouped.grouped_glu(xe, *ws, fill), xe, *ws)
-    dxe, *dws = vjp(dye)
+    ws = _weights(unit, e=e, h=h)
+    ye, vjp = jax.vjp(lambda xe, ws: _grouped(unit, xe, ws, fill), xe, ws)
+    dxe, dws = vjp(dye)
     assert not np.asarray(ye)[behind].any()
     assert not np.asarray(dxe)[behind].any()
-    np.testing.assert_allclose(ye, _einsums(xe, *ws), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(ye, _einsums(unit, xe, ws),
+                               rtol=2e-5, atol=2e-5)
     # an expert that holds nothing gets no weight gradient
-    for dw in dws:
+    for dw in dws.values():
         assert not np.asarray(dw)[np.asarray(fills) == 0].any()
 
 
@@ -219,6 +267,86 @@ def test_moe_layer_step_with_kernels_equals_the_einsums(monkeypatch,
         assert tiles <= total // 2
 
 
+# a rank's layer of each family that uses `HeldExpertsMoE`, as its config
+# words it, at a width of whole lanes: 96 tokens, 3 of 16 experts a token, 4
+# held; softmax and gated, sigmoid and gated (its experts a whole number of
+# lanes wide, as published), sigmoid and two matrices around a squared ReLU
+HELD = dict(hidden_size=128, num_experts=4, router_experts=16, first_expert=4,
+            moe_intermediate_size=24, dtype="float32")
+FAMILIES = {
+    "qwen3next": lambda cf: qwen3next.held_moe(qwen3next.Qwen3NextConfig(
+        **HELD, num_experts_per_tok=3, shared_expert_intermediate_size=24,
+        capacity_factor=cf)),
+    "kimi_linear": lambda cf: kimi_linear.held_moe(
+        kimi_linear.KimiLinearConfig(
+            **{**HELD, "moe_intermediate_size": 128},
+            num_experts_per_token=3, capacity_factor=cf)),
+    "nemotron_h": lambda cf: nemotron_h.held_moe(nemotron_h.NemotronHConfig(
+        **{k: v for k, v in HELD.items() if k != "num_experts"},
+        n_routed_experts=4, num_experts_per_tok=3,
+        moe_shared_expert_intermediate_size=48, capacity_factor=cf)),
+}
+
+
+def _held_step(layer, params, x):
+    def f(params, x):
+        out, mut = layer.apply({"params": params}, x, mutable=["moe_metrics"])
+        return jnp.sum(out ** 2), mut["moe_metrics"]["stats"][0]
+    return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(params, x)
+
+
+@pytest.mark.parametrize("capacity_factor", [0.25, 2.0], ids=["drops", "roomy"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_held_experts_step_with_kernels_equals_the_einsums(
+        monkeypatch, family, capacity_factor):
+    """Output, every parameter's gradient and dx; the same capacity and the
+    same drops; fewer row tiles multiplied than the layer has."""
+    layer = FAMILIES[family](capacity_factor)
+    assert layer.gated == (family != "nemotron_h")
+    x = jax.random.normal(jax.random.key(8), (2, 48, 128))
+    params = layer.init(jax.random.key(9), x)["params"]
+    (want, want_stats), want_grads = _held_step(layer, params, x)
+    _as_one_tpu(monkeypatch)
+    cap = ops.moe.expert_capacity(96, 16, 3, capacity_factor)
+    assert moe_grouped.engages(cap, 128)
+    (got, stats), grads = _held_step(layer, params, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-5),
+        grads, want_grads)
+    assert float(stats["drop_fraction"]) == float(want_stats["drop_fraction"])
+    assert (float(stats["drop_fraction"]) > 0) == (capacity_factor == 0.25)
+    assert float(want_stats["live_tile_fraction"]) == 1.0
+    # a whole number of the layer's tiles; where the slots are twice an
+    # expert's share, not all of them
+    total = 4 * cap // TILE
+    tiles = float(stats["live_tile_fraction"]) * total
+    assert tiles == pytest.approx(round(tiles)) and 0 < tiles <= total
+    if capacity_factor == 2.0:
+        assert tiles < total
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_held_experts_keep_the_einsums_at_a_ragged_capacity(monkeypatch,
+                                                            family):
+    """40 slots an expert are no whole number of 16-row tiles: on the one
+    TPU too the einsums run over every slot, and say so."""
+    layer = FAMILIES[family](2.0)
+    x = jax.random.normal(jax.random.key(8), (2, 48, 128))
+    params = layer.init(jax.random.key(9), x)["params"]
+    want, want_grads = _held_step(layer, params, x)
+    _as_one_tpu(monkeypatch)
+    monkeypatch.setattr(moe_grouped, "ROW_TILE", 16)
+    monkeypatch.setattr(
+        moe_grouped, "grouped_glu",
+        lambda *a, **k: pytest.fail("the kernels at a ragged capacity"))
+    assert ops.moe.expert_capacity(96, 16, 3, 2.0) == 40
+    got, grads = _held_step(layer, params, x)
+    assert float(got[1]["live_tile_fraction"]) == 1.0
+    jax.tree.map(np.testing.assert_array_equal, (got, grads),
+                 (want, want_grads))
+
+
 @pytest.mark.parametrize("tpu, devices, capacity, dim, want", [
     (True, 1, 64, 128, True),
     (False, 1, 64, 128, False),  # the CPU: einsums
@@ -235,7 +363,6 @@ def test_where_the_kernels_engage(monkeypatch, tpu, devices, capacity, dim,
 
 
 def test_grouped_glu_refuses_a_ragged_capacity():
-    ws = _weights(e=2)
     with pytest.raises(ValueError, match="whole tiles"):
-        moe_grouped.grouped_glu(
-            jnp.zeros((2, TILE + 1, D)), *ws, jnp.zeros((2,), jnp.int32))
+        _grouped("swiglu", jnp.zeros((2, TILE + 1, D)), _weights(e=2),
+                 jnp.zeros((2,), jnp.int32))

@@ -27,7 +27,9 @@ def setup(e, seed=0):
 
 
 def expert_fn(w1, w2):
-    return lambda xe: jnp.einsum(
+    # the held dispatch hands the experts' fills on; these einsums run over
+    # every slot
+    return lambda xe, fill=None: jnp.einsum(
         "ech,ehd->ecd", jnp.tanh(jnp.einsum("ecd,edh->ech", xe, w1)), w2)
 
 
