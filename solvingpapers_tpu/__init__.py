@@ -19,6 +19,9 @@ Layout (see SURVEY.md §7):
     configs/    typed run configs for every workload
 """
 
+from solvingpapers_tpu.metrics.trace import begin as _begin  # no JAX, no NumPy
+
+_imported = _begin("import:solvingpapers_tpu")
 __version__ = "0.1.0"
 
 _SERVE_API = ("ServeEngine", "ServeConfig", "KVSlotPool", "FIFOScheduler",
@@ -36,3 +39,6 @@ def __getattr__(name):
 
         return getattr(serve, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+_imported()

@@ -10,6 +10,7 @@ import numpy as np
 from solvingpapers_tpu.data import load_char_corpus
 from solvingpapers_tpu.data.batches import lm_batch_iterator, prefetch_batches
 from solvingpapers_tpu.configs.registry import RunConfig
+from solvingpapers_tpu.metrics.trace import run_span
 
 
 def build_model(cfg: RunConfig):
@@ -158,8 +159,8 @@ def build_image_run(cfg: RunConfig, mesh=None):
     return model, train_iter, eval_iter_fn, loss_fn_for(cfg)
 
 
-def build_char_lm_run(cfg: RunConfig, sharding=None):
-    """Returns (run_cfg_with_vocab, model, tokenizer, train_iter, eval_iter_fn).
+def _load_corpus(cfg: RunConfig):
+    """(tokenizer, train tokens, validation tokens) of `cfg.data`.
 
     data.kind 'char' builds a char vocab (gpt/gemma pipelines); 'bpe' trains
     a byte-level BPE on the corpus (the offline stand-in for the reference's
@@ -237,17 +238,27 @@ def build_char_lm_run(cfg: RunConfig, sharding=None):
         train_toks, val_toks = split_train_val(tok.encode(text))
     else:
         tok, train_toks, val_toks = load_char_corpus(path=cfg.data.get("path"))
+    return tok, train_toks, val_toks
+
+
+@run_span("build_run")
+def build_char_lm_run(cfg: RunConfig, sharding=None):
+    """Returns (run_cfg_with_vocab, model, tokenizer, train_iter, eval_iter_fn)
+    for the corpus `_load_corpus` reads."""
     block = cfg.data.get("block_size", 256)
+    bsz = cfg.train.batch_size
+    with run_span("data_open"):
+        tok, train_toks, val_toks = _load_corpus(cfg)
+        train_iter = lm_batch_iterator(train_toks, bsz, block, seed=cfg.train.seed, sharding=sharding)
+        if isinstance(train_toks, np.memmap):
+            # host-side gathers (native, GIL-releasing) overlap the device step;
+            # in-memory corpora crop device-side so there is nothing to overlap
+            train_iter = prefetch_batches(train_iter, depth=2)
     # the char vocab comes from the corpus; resize the model to match
     model_cfg = dataclasses.replace(cfg.model, vocab_size=max(tok.vocab_size, 2))
     cfg = dataclasses.replace(cfg, model=model_cfg)
-    model = build_model(cfg)
-    bsz = cfg.train.batch_size
-    train_iter = lm_batch_iterator(train_toks, bsz, block, seed=cfg.train.seed, sharding=sharding)
-    if isinstance(train_toks, np.memmap):
-        # host-side gathers (native, GIL-releasing) overlap the device step;
-        # in-memory corpora crop device-side so there is nothing to overlap
-        train_iter = prefetch_batches(train_iter, depth=2)
+    with run_span("model_build"):
+        model = build_model(cfg)
 
     def eval_iter_fn() -> Iterator[dict]:
         return lm_batch_iterator(val_toks, bsz, block, seed=10_000, sharding=sharding)

@@ -1,5 +1,8 @@
 """Jitted inference: preallocated KV/latent caches + prefill/decode loops."""
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:infer")
 from solvingpapers_tpu.infer.cache import (
     CPKVCache,
     CPLatentCache,
@@ -10,3 +13,5 @@ from solvingpapers_tpu.infer.cache import (
 )
 from solvingpapers_tpu.infer.decode import generate, generate_cp
 from solvingpapers_tpu.infer.speculative import generate_speculative  # noqa: E402,F401
+
+_imported()

@@ -10,5 +10,10 @@ themselves are row gathers in `ops/moe.py`, no kernel). Each kernel has a plain 
 (in ops/, or the model's einsums) and interpret-mode equality tests.
 """
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:kernels")
 from solvingpapers_tpu.kernels.flash_attention import flash_attention
 from solvingpapers_tpu.kernels.sharded_flash import sharded_flash_attention
+
+_imported()

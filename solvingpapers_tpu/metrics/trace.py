@@ -27,6 +27,15 @@ pieces:
   to a JSONL file for post-mortem, then keeps going (bounded by
   `max_dumps` so a pathological run cannot fill the disk).
 
+* the run's recorder, `RUN` — ONE `FlightRecorder` a process, always
+  there, on `time.perf_counter`. Start-up writes its spans to it
+  (`begin` / `run_span`: `import:<package>`, `build_run`, `init_state`,
+  `fit_first_step`, ...; `metrics/xla_obs.py` adds JAX's own `trace:` /
+  `lower:` / `compile:` events and the compile cache's counters): some
+  dozens of events a process, no switch. A span names its parent by
+  nesting; `summarize_startup` gives every second of the spans' union to
+  the innermost span that covers it, so the parts it returns add up.
+
 `summarize_trace` / `format_summary` rebuild per-request timelines from
 an exported trace (the `cli trace-summary` command): for every request
 the lifecycle spans partition its wall time exactly — queue
@@ -46,7 +55,7 @@ import statistics
 import threading
 import time
 from collections import deque
-from typing import Callable
+from typing import Callable, Iterable
 
 
 @dataclasses.dataclass(frozen=True, slots=True)
@@ -372,6 +381,141 @@ def fleet_events_to_chrome(sections) -> dict:
                 flow["bp"] = "e"
             out.append(flow)
     return {"traceEvents": out, "displayTimeUnit": "ms"}
+
+
+# ------------------------------------------------------- the run's recorder
+
+# One recorder a process, on the host clock the train loop reads. Start-up
+# and JAX's compile events (metrics/xla_obs.py) write to it whether or not
+# anything reads it: a bounded ring, an append under a lock an event.
+RUN = FlightRecorder(capacity=16384, clock=time.perf_counter)
+
+
+class _OpenSpans(threading.local):
+    """Names of the `begin` spans open on this thread, outermost first."""
+
+    def __init__(self):
+        self.names: list[str] = []
+
+
+_open_spans = _OpenSpans()
+
+
+def current_span() -> str | None:
+    """Name of the innermost `begin` span open on this thread."""
+    names = _open_spans.names
+    return names[-1] if names else None
+
+
+def begin(name: str, cat: str = "startup", **args) -> Callable[[], None]:
+    """Open a span of the run's recorder; the callable returned closes and
+    records it (name, start, duration, `parent` = the span it opened
+    inside). The two-line form for a package's `__init__.py`, whose body
+    cannot be indented under a `with`."""
+    names = _open_spans.names
+    depth, parent = len(names), (names[-1] if names else None)
+    names.append(name)
+    t0 = RUN.clock()
+
+    def end() -> None:
+        dur = RUN.clock() - t0
+        del names[depth:]  # also drops spans an exception left open inside
+        RUN.complete(name, cat, "startup", ts=t0, dur=dur, parent=parent,
+                     **args)
+
+    return end
+
+
+@contextlib.contextmanager
+def run_span(name: str, **args):
+    """`begin` as a context manager and, beside it, a
+    `jax.profiler.TraceAnnotation` of the same name: a profile of start-up
+    shows the span on the device's clock. For code that runs after JAX is
+    imported (the import is taken here, where it costs a dictionary
+    look-up)."""
+    from jax.profiler import TraceAnnotation
+
+    end = begin(name, **args)
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        end()
+
+
+# the parts of start-up, by span name (exact, or a prefix ending in ":")
+STARTUP_PARTS = {
+    "import_s": ("import:",),
+    "build_s": ("build_run", "data_open", "model_build", "create_mesh",
+                "trainer_init", "build_steps"),
+    "init_state_s": ("init_state", "init_eval_shape", "init_jit"),
+    "trace_s": ("trace:",),
+    "lower_s": ("lower:",),
+    "compile_s": ("compile:",),
+    "first_step_s": ("fit_first_step",),
+}
+
+
+def _startup_part(name: str) -> str | None:
+    for part, names in STARTUP_PARTS.items():
+        for n in names:
+            if name == n or (n.endswith(":") and name.startswith(n)):
+                return part
+    return None
+
+
+def summarize_startup(events: Iterable[TraceEvent],
+                      until: float | None = None) -> dict:
+    """Where start-up went, from the run's recorder: seconds by part
+    (`STARTUP_PARTS`), `program_s` (the union of all of them: what the
+    program itself accounts for) and the compile cache's counters.
+
+    Every instant that some span covers goes to the innermost one there
+    (the latest to start), so a part is the self time of its spans: a
+    compile inside `init_jit` is `compile_s` and not `init_state_s`, and
+    the parts add up to `program_s`. Only the process's first
+    `fit_first_step` counts (the later `fit` calls' compiles still do, as
+    `compile:` spans), and only events that ended by `until` (a reading
+    of the recorder's clock; None = all)."""
+    spans: list[tuple[float, float, str]] = []
+    first_fit = None
+    cache: dict = {}
+    for e in events:
+        if until is not None and e.ts + e.dur > until:
+            continue
+        if e.ph == "C" and e.name == "compile_cache":
+            cache = e.args or {}
+        if e.ph != "X":
+            continue
+        part = _startup_part(e.name)
+        if part is None:
+            continue
+        if part == "first_step_s":
+            if first_fit is None or e.ts < first_fit[0]:
+                first_fit = (e.ts, e.ts + e.dur, part)
+            continue
+        spans.append((e.ts, e.ts + e.dur, part))
+    if first_fit is not None:
+        spans.append(first_fit)
+    out = dict.fromkeys(STARTUP_PARTS, 0.0)
+    bounds = sorted({t for a, b, _ in spans for t in (a, b)})
+    spans.sort()
+    active: list[tuple[float, float, str]] = []
+    at = 0
+    for lo, hi in zip(bounds, bounds[1:]):
+        while at < len(spans) and spans[at][0] <= lo:
+            active.append(spans[at])
+            at += 1
+        active = [s for s in active if s[1] > lo]
+        if active:
+            # innermost: the latest start, then the earliest end
+            inner = max(active, key=lambda s: (s[0], -s[1]))
+            out[inner[2]] += hi - lo
+    out["program_s"] = sum(out.values())
+    out["cache_hits"] = int(cache.get("hits", 0))
+    out["cache_misses"] = int(cache.get("misses", 0))
+    out["cache_retrieval_s"] = float(cache.get("retrieval_s", 0.0))
+    return out
 
 
 # --------------------------------------------------------------- anomalies

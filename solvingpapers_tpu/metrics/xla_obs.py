@@ -44,9 +44,15 @@ serving stacks triage capacity and latency regressions with:
   trace via `metrics.trace.summarize_trace` (the registry emits one
   `compile` event per compilation when a recorder is attached).
 
-Everything is opt-in (`ServeConfig.xla_obs` / `TrainConfig.xla_obs`);
-with it off the engines never import this module and every hook site is
-a single `is not None` branch. With it on, program calls are fenced
+* `CompileSpans` (`compile_spans()`, one a process, always on once the
+  train engine is imported) — JAX's own `jax.monitoring` compile events
+  as `trace:` / `lower:` / `compile:` spans of the run's recorder
+  (`metrics.trace.RUN`) and the persistent cache's hits and misses as a
+  counter: nothing fenced, nothing compiled ahead of time.
+
+The registry and the ledger are opt-in (`ServeConfig.xla_obs` /
+`TrainConfig.xla_obs`); with them off every hook site is a single
+`is not None` branch. With it on, program calls are fenced
 (`block_until_ready`) so run seconds are device-true — the same
 observability-mode contract as flight-recorder tracing; what the
 fences cost is not measured on the chip.
@@ -54,6 +60,7 @@ fences cost is not measured on the chip.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import math
 import os
@@ -66,6 +73,7 @@ from typing import Any, Callable
 
 import jax
 
+from solvingpapers_tpu.metrics import trace as run_trace
 from solvingpapers_tpu.metrics.mfu import chip_peak_flops
 from solvingpapers_tpu.metrics.writer import PrometheusTextWriter
 
@@ -126,6 +134,121 @@ def device_capacity_bytes(device=None) -> int | None:
         return None
     limit = stats.get("bytes_limit")
     return int(limit) if limit else None
+
+
+# ------------------------------------------------ JAX's own compile events
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # on a hit of the persistent cache: the read and the executable's load
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# JAX reports a trace for every `jnp` function a traced program calls (they
+# lie inside the program's own `trace:` span) and for every look-up of a
+# trace it already has: thousands a model, microseconds each. Shorter ones
+# are not recorded; every `compile:` is.
+_MIN_TRACE_S = 1e-3
+
+
+class CompileSpans:
+    """JAX's compile events as spans and counters of one recorder, with no
+    fence and no ahead-of-time compile (`CompileRegistry` is that mode):
+    `trace:<fun>`, `lower:<fun>` (of a millisecond or more) and
+    `compile:<fun>` as completed spans that end where `jax.monitoring`
+    reports them (on the compiling thread, so
+    `parent` is the span of `metrics.trace.begin` open there), and the
+    persistent cache's hits, misses and seconds of retrieval as the
+    counter `compile_cache`.
+
+    `Trainer.fit` dispatches every step after a call's first inside
+    `steady(step)`: a compile on that thread while it is open is one the
+    timed steps paid for. It is counted in `recompiles_after_first_step`,
+    kept in `recompiled` (step, program; the newest 32) for the logged row
+    and the warning `fit` gives, and recorded as an instant of the
+    counter's name.
+    Evaluation, callbacks, the pipeline probe and a scan run's first tail
+    step compile outside it, as does whatever another thread compiles."""
+
+    def __init__(self, recorder):
+        self.recorder = recorder
+        self.cache = {"hits": 0, "misses": 0, "retrieval_s": 0.0}
+        self.recompiles_after_first_step = 0
+        self.recompiled: deque[tuple[int, str]] = deque(maxlen=32)
+        self._steady = threading.local()
+        self._lock = threading.Lock()
+
+    @contextlib.contextmanager
+    def steady(self, step: int | None):
+        """Compiles on this thread inside the block are recompiles at
+        `step`; none is counted where it is None."""
+        self._steady.step = step
+        try:
+            yield
+        finally:
+            self._steady.step = None
+
+    def newest(self, n: int) -> str:
+        """`<program> at step <step>` of the newest `n` recompiles."""
+        return ", ".join(f"{program} at step {step}"
+                         for step, program in list(self.recompiled)[-n:])
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        kind = _COMPILE_EVENTS.get(event)
+        if kind is None:
+            if event == _CACHE_RETRIEVAL:
+                self._count("retrieval_s", duration)
+            return
+        if kind != "compile" and duration < _MIN_TRACE_S:
+            return
+        rec, name = self.recorder, str(kw.get("fun_name", "?"))
+        rec.complete(f"{kind}:{name}", "jax", "startup",
+                     ts=rec.clock() - duration, dur=duration,
+                     parent=run_trace.current_span())
+        step = getattr(self._steady, "step", None)
+        if kind == "compile" and step is not None:
+            with self._lock:
+                self.recompiles_after_first_step += 1
+                n = self.recompiles_after_first_step
+                self.recompiled.append((step, name))
+            rec.instant("recompiles_after_first_step", "jax", "startup",
+                        count=n, step=step, program=name)
+
+    def on_event(self, event: str, **kw) -> None:
+        key = _CACHE_EVENTS.get(event)
+        if key is not None:
+            self._count(key, 1)
+
+    def _count(self, key: str, by) -> None:
+        with self._lock:
+            self.cache[key] += by
+            values = dict(self.cache)
+        self.recorder.counter("compile_cache", "jax", "startup", **values)
+
+
+_COMPILE_SPANS: CompileSpans | None = None
+_COMPILE_SPANS_LOCK = threading.Lock()
+
+
+def compile_spans() -> CompileSpans:
+    """The process's one `CompileSpans`, on the run's recorder
+    (`metrics.trace.RUN`); the first call registers it with
+    `jax.monitoring`."""
+    global _COMPILE_SPANS
+    with _COMPILE_SPANS_LOCK:
+        if _COMPILE_SPANS is None:
+            from jax import monitoring
+
+            _COMPILE_SPANS = CompileSpans(run_trace.RUN)
+            monitoring.register_event_duration_secs_listener(
+                _COMPILE_SPANS.on_duration)
+            monitoring.register_event_listener(_COMPILE_SPANS.on_event)
+    return _COMPILE_SPANS
 
 
 # process-global executable cache: (id(jitted), statics, dynamic avals)

@@ -5,6 +5,9 @@ gpt, llama3 (GQA+RoPE+SwiGLU), gemma (MQA+GeGLU), deepseekv3 (MLA+MoE+MTP),
 vit, alexnet, autoencoder/vae, kd teacher/student.
 """
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:models")
 from solvingpapers_tpu.models.layers import Attention, MLP, GLUFFN, RMSNorm, LayerNorm
 from solvingpapers_tpu.models.gpt import GPT, GPTConfig
 from solvingpapers_tpu.models.llama3 import Llama, LlamaConfig
@@ -23,3 +26,5 @@ from solvingpapers_tpu.models.kd import (
     teacher_config,
     student_config,
 )
+
+_imported()

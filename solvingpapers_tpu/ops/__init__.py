@@ -5,6 +5,9 @@ notebook: norms, RoPE (both formulations), activations, attention cores,
 losses, and samplers.
 """
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:ops")
 from solvingpapers_tpu.ops.norms import rms_norm, layer_norm, local_response_norm
 from solvingpapers_tpu.ops.rope import (
     precompute_rope,
@@ -56,3 +59,5 @@ from solvingpapers_tpu.ops.sampling import (
     min_p_mask,
     allowed_logits,
 )
+
+_imported()

@@ -17,6 +17,9 @@ backed shadow-traffic replay against a candidate config, byte-level
 stream diffing + teacher-forced agreement scoring, the config-canary
 divergence gate)."""
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:serve")
 from solvingpapers_tpu.serve.api import ApiServer, EngineLoop, serve_api
 from solvingpapers_tpu.serve.engine import ServeConfig, ServeEngine
 from solvingpapers_tpu.serve.fleet import (
@@ -85,3 +88,5 @@ __all__ = [
     "SloTracker",
     "SpecController",
 ]
+
+_imported()

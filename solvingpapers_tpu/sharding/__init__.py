@@ -9,6 +9,9 @@ shard_map + explicit collectives for ring attention / Ulysses context
 parallelism.
 """
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:sharding")
 from solvingpapers_tpu.sharding.mesh import (
     MESH_AXES,
     MeshConfig,
@@ -48,3 +51,5 @@ from solvingpapers_tpu.sharding.distributed import (
     host_batch_slice,
     host_seed,
 )
+
+_imported()

@@ -24,6 +24,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from solvingpapers_tpu.metrics.trace import run_span
+
 MESH_AXES = ("data", "fsdp", "model", "expert", "context", "pipe")
 
 # The mesh a GSPMD-partitioned model is currently tracing under (set by the
@@ -79,6 +81,7 @@ class MeshConfig:
         return tuple(sizes)
 
 
+@run_span("create_mesh")  # `jax.devices()` may be the backend's start
 def create_mesh(
     config: MeshConfig | None = None, devices: list | None = None
 ) -> Mesh:

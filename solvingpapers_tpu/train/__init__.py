@@ -1,5 +1,8 @@
 """The single training engine (L5) parameterizing every workload."""
 
+from solvingpapers_tpu.metrics.trace import begin as _begin
+
+_imported = _begin("import:train")
 from solvingpapers_tpu.train.optim import warmup_cosine, make_optimizer, OptimizerConfig
 from solvingpapers_tpu.train.state import TrainState
 from solvingpapers_tpu.train.engine import Trainer, TrainConfig, lm_loss_fn
@@ -9,3 +12,5 @@ from solvingpapers_tpu.train.objectives import (
     vae_loss_fn,
     make_kd_loss_fn,
 )
+
+_imported()

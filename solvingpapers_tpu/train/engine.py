@@ -16,10 +16,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import json
 import math
 import os
 import time
+import warnings
 from typing import Any, Callable, Iterator
 
 import jax
@@ -31,6 +33,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.checkpoint import CheckpointManager
 from solvingpapers_tpu.metrics import ConsoleWriter, MetricsWriter, hlo_cost
+from solvingpapers_tpu.metrics import trace as run_trace
+from solvingpapers_tpu.metrics.xla_obs import compile_spans
 from solvingpapers_tpu.sharding import (
     LM_RULES,
     MeshConfig,
@@ -46,7 +50,13 @@ from solvingpapers_tpu.train.state import TrainState
 # loss_fn(model, params, batch, rng, model_state, train) -> (loss, aux, new_model_state)
 LossFn = Callable[..., tuple[jax.Array, dict, Any]]
 
-_NULL_SCOPE = contextlib.nullcontext()
+# JAX's compile events go to the run's recorder from here on (every path
+# to a Trainer imports this module before anything compiles)
+_COMPILE_SPANS = compile_spans()
+# `fit` calls of the process, counted: the first one's first step is
+# start-up's last part
+_FIT_CALLS = itertools.count(1)
+
 
 def _pp_param_spec(path, _leaf) -> P:
     """shard_map in_spec for pipeline-parallel params: the stage-stacked
@@ -95,11 +105,13 @@ class TrainConfig:
     # jax.profiler trace of the steps [start, stop) counted from where
     # fit() starts: the device is fenced before the trace starts and before
     # it stops, so the window holds exactly stop - start whole executions
-    # of the train step; the loop's spans (data_wait, train_dispatch,
-    # log_fetch, eval, callback, checkpoint) are TraceAnnotations on the
-    # host plane of the same file, each step under a StepTraceAnnotation
-    # "train"; `device_scopes.json` beside the trace maps the programs'
-    # instructions to the layers of metrics/hlo_cost.LAYER_SCOPES.
+    # of the train step; the loop's spans (fit_setup, data_wait,
+    # train_dispatch, log_fetch, log_write, eval, callback, checkpoint) are
+    # TraceAnnotations on the host plane of the same file, each step under
+    # a StepTraceAnnotation "train" (they are always emitted: any profiler
+    # session, this one or a caller's own, holds them); `device_scopes.json`
+    # beside the trace maps the programs' instructions to the layers of
+    # metrics/hlo_cost.LAYER_SCOPES.
     profile_dir: str | None = None
     profile_steps: tuple[int, int] = (10, 15)
     # flight recorder (metrics/trace.py): record data-wait / step / eval /
@@ -201,8 +213,9 @@ class Trainer:
         # scoped to this trainer's key stream (init_state), not the global
         self.loss_fn = loss_fn
         self.rules = rules
-        self.mesh = mesh if mesh is not None else create_mesh(config.mesh)
-        self.tx, self.schedule = make_optimizer(config.optimizer)
+        with run_trace.run_span("trainer_init"):
+            self.mesh = mesh if mesh is not None else create_mesh(config.mesh)
+            self.tx, self.schedule = make_optimizer(config.optimizer)
         # init_fn(model, rngs, batch) -> params dict
         self.init_fn = init_fn or (
             lambda model, rngs, batch: model.init(rngs, batch["x"])["params"]
@@ -241,6 +254,7 @@ class Trainer:
 
     # ------------------------------------------------------------------ init
 
+    @run_trace.run_span("init_state")
     def init_state(self, example_batch: dict) -> TrainState:
         cfg = self.config
 
@@ -282,13 +296,19 @@ class Trainer:
             else jax.random.key(cfg.seed)
         )
         self._set_batch_shardings(example_batch)
-        abstract = jax.eval_shape(make, rng)
-        specs = param_specs(abstract, self.rules, mesh=self.mesh)
-        self._state_shardings = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, P),
-        )
-        state = jax.jit(make, out_shardings=self._state_shardings)(rng)
+        with run_trace.run_span("init_eval_shape"):
+            abstract = jax.eval_shape(make, rng)
+            specs = param_specs(abstract, self.rules, mesh=self.mesh)
+            self._state_shardings = jax.tree.map(
+                lambda s: NamedSharding(self.mesh, s), specs,
+                is_leaf=lambda x: isinstance(x, P),
+            )
+        with run_trace.run_span("init_jit"):
+            # compile (or the cache's load) and the run; fenced here so that
+            # the span holds them, where the state's first use did before
+            state = jax.block_until_ready(
+                jax.jit(make, out_shardings=self._state_shardings)(rng)
+            )
         return state
 
     def _set_batch_shardings(self, example_batch: dict) -> None:
@@ -687,6 +707,7 @@ class Trainer:
 
         return call
 
+    @run_trace.run_span("build_steps")
     def _build_steps(self):
         replicated = NamedSharding(self.mesh, P())
         if self.config.context_parallel and self.config.pipeline_parallel:
@@ -898,38 +919,41 @@ class Trainer:
             recorder = FlightRecorder()
             t_fit0 = recorder.clock()
 
-        # with a profile or the recorder asked for, every section the loop
-        # spends host time in is a jax.profiler.TraceAnnotation, so that it
-        # lands in the profiler's own file on the device operations' clock
-        annotate = bool(cfg.profile_dir or cfg.trace_path)
-
+        # every section the loop spends host time in is a
+        # jax.profiler.TraceAnnotation: whoever runs a profiler session
+        # (`profile_dir` here, or a caller that knows nothing of it) finds
+        # them in the profiler's own file on the device operations' clock;
+        # with no session an annotation is the profiler's own no-op
         @contextlib.contextmanager
-        def _annotated(name, kw):
-            with jax.profiler.TraceAnnotation(name, **kw):
-                if recorder is None:
-                    yield
-                else:
-                    with recorder.span(name, "train", "train", **kw):
-                        yield
+        def _recorded(name, kw):
+            with jax.profiler.TraceAnnotation(name, **kw), \
+                    recorder.span(name, "train", "train", **kw):
+                yield
 
         def _span(name, **kw):
             """The one instrumented-section helper: a profiler annotation
-            (and the recorder's span beside it) when tracing is on, a
-            shared no-op context when it is off — always one branch."""
-            return _annotated(name, kw) if annotate else _NULL_SCOPE
+            and, with `trace_path`, the recorder's span beside it."""
+            if recorder is None:
+                return jax.profiler.TraceAnnotation(name, **kw)
+            return _recorded(name, kw)
 
         def _step_scope(step_num):
-            if annotate:
-                return jax.profiler.StepTraceAnnotation(
-                    "train", step_num=step_num
-                )
-            return _NULL_SCOPE
+            return jax.profiler.StepTraceAnnotation("train", step_num=step_num)
 
         # host seconds since the last logged row: waiting for the batch
         # iterator, and blocked on the device (the fetch at the log cadence
         # and the fences before eval, callbacks and checkpoints)
         wait_s = 0.0
         blocked_s = 0.0
+        # what an untraced run can still say of one slow step: the longest
+        # single dispatch since the last row, and the longest stretch from
+        # the end of one dispatch to the start of the next less what the
+        # loop spent blocked on the device in it (data, logging, eval, a
+        # collector's pause), each with the step it was dispatching
+        dispatch_max = (0.0, 0)
+        gap_max = (0.0, 0)
+        t_dispatched = None  # perf_counter at the last dispatch's return
+        blocked_since_dispatch = 0.0
 
         def _next(it):
             nonlocal wait_s
@@ -941,10 +965,14 @@ class Trainer:
 
         def _fence():
             """Wait for the steps in flight (not counted as loop time)."""
-            nonlocal blocked_s
             t0 = time.perf_counter()
             jax.device_get(metrics["train_loss"])
-            blocked_s += time.perf_counter() - t0
+            _blocked(time.perf_counter() - t0)
+
+        def _blocked(seconds):
+            nonlocal blocked_s, blocked_since_dispatch
+            blocked_s += seconds
+            blocked_since_dispatch += seconds
 
         def _stop_profile() -> float:
             """Close the profile between two steps of the device and leave
@@ -963,162 +991,171 @@ class Trainer:
                 json.dump(scopes, f)
             return time.perf_counter() - t0
 
-        if state is None:
-            first = _next(batch_iter)
-            state = self.init_state(first)
-        else:
-            first = _next(batch_iter) if self._batch_shardings is None else None
-            if first is not None:
-                self._set_batch_shardings(first)
-        if self._train_step is None:
-            self._build_steps()
+        # what comes before the loop (state, steps, observatories, the
+        # checkpoint's restore) has a name in a profile too
+        with jax.profiler.TraceAnnotation("fit_setup"):
+            if state is None:
+                first = _next(batch_iter)
+                state = self.init_state(first)
+            else:
+                first = _next(batch_iter) if self._batch_shardings is None else None
+                if first is not None:
+                    self._set_batch_shardings(first)
+            if self._train_step is None:
+                self._build_steps()
 
-        if (cfg.xla_obs or cfg.mesh_obs) and self._registry is None:
-            from solvingpapers_tpu.metrics.xla_obs import (
-                CompileRegistry,
-                HBMLedger,
-                pytree_device_bytes,
-            )
-
-            # mesh_obs implies the compile registry (the collective
-            # ledger reads compiled HLO) with per-program HLO parsing on
-            self._registry = CompileRegistry(trace=recorder,
-                                             collectives=cfg.mesh_obs)
-            self._ledger = HBMLedger()
-            # the lambdas close over the loop variable `state`, so the
-            # gauges follow the live TrainState across step rebinding;
-            # PER-DEVICE bytes (shard_shape), not global — capacity is a
-            # per-chip number and fsdp/pipe-sharded pools must not book
-            # their full global size against it
-            self._ledger.register(
-                "params", lambda: pytree_device_bytes(state.params)
-            )
-            self._ledger.register(
-                "opt_state", lambda: pytree_device_bytes(state.opt_state)
-            )
-            self._ledger.temp_fn = self._registry.max_temp_bytes
-        if cfg.mesh_obs and self._mesh_obs is None:
-            from solvingpapers_tpu.metrics.mesh_obs import (
-                MeshObservatory,
-                PipelineScheduleInfo,
-            )
-            from solvingpapers_tpu.sharding import mesh_axis_sizes
-
-            sched = None
-            mcfg = getattr(self.model, "cfg", None)
-            if cfg.pipeline_parallel and mcfg is not None:
-                sched = PipelineScheduleInfo(
-                    n_stages=mesh_axis_sizes(self.mesh).get("pipe", 1),
-                    n_microbatches=getattr(mcfg, "n_microbatches", 1),
-                    n_virtual=getattr(mcfg, "virtual_stages", 1),
-                    schedule=cfg.pp_schedule,
+            if (cfg.xla_obs or cfg.mesh_obs) and self._registry is None:
+                from solvingpapers_tpu.metrics.xla_obs import (
+                    CompileRegistry,
+                    HBMLedger,
+                    pytree_device_bytes,
                 )
-            self._mesh_obs = MeshObservatory(
-                mesh=self.mesh, registry=self._registry, trace=recorder,
-                schedule=sched,
-            )
-        # registry/observatory persist across fit() calls but the
-        # recorder is per-run: re-attach so a resumed fit's compile and
-        # mesh events land in ITS trace, not the first run's dead ring
-        if self._registry is not None:
-            self._registry.trace = recorder
-        if self._mesh_obs is not None:
-            self._mesh_obs.attach_trace(recorder)
-        # observability modes fence every dispatch so step walls are
-        # device-true; _obs_clock is the shared time base
-        _fenced = recorder is not None or self._mesh_obs is not None
-        _obs_clock = (
-            recorder.clock if recorder is not None
-            else self._mesh_obs.clock if self._mesh_obs is not None
-            else None
-        )
-        # live status endpoint for the duration of fit(); last_row is
-        # mutated at every log write so /metrics and /statusz always
-        # serve the newest row without re-deriving device values
-        last_row = {"step": int(jax.device_get(state.step)), "metrics": {}}
-        if cfg.status_port is not None:
-            from solvingpapers_tpu.metrics.http import StatusServer
 
-            def _statusz() -> dict:
-                d = {
-                    "train": {"step": last_row["step"],
-                              "steps_total": cfg.steps},
-                    "metrics": last_row["metrics"],
-                }
-                if self._registry is not None:
-                    d["compile"] = self._registry.snapshot()
-                if self._ledger is not None:
-                    d["mem"] = self._ledger.snapshot()
-                if self._mesh_obs is not None:
-                    d["mesh"] = self._mesh_obs.snapshot()
-                return d
+                # mesh_obs implies the compile registry (the collective
+                # ledger reads compiled HLO) with per-program HLO parsing on
+                self._registry = CompileRegistry(trace=recorder,
+                                                 collectives=cfg.mesh_obs)
+                self._ledger = HBMLedger()
+                # the lambdas close over the loop variable `state`, so the
+                # gauges follow the live TrainState across step rebinding;
+                # PER-DEVICE bytes (shard_shape), not global — capacity is a
+                # per-chip number and fsdp/pipe-sharded pools must not book
+                # their full global size against it
+                self._ledger.register(
+                    "params", lambda: pytree_device_bytes(state.params)
+                )
+                self._ledger.register(
+                    "opt_state", lambda: pytree_device_bytes(state.opt_state)
+                )
+                self._ledger.temp_fn = self._registry.max_temp_bytes
+            if cfg.mesh_obs and self._mesh_obs is None:
+                from solvingpapers_tpu.metrics.mesh_obs import (
+                    MeshObservatory,
+                    PipelineScheduleInfo,
+                )
+                from solvingpapers_tpu.sharding import mesh_axis_sizes
 
-            def _metrics_fn() -> tuple[int, dict]:
-                m = dict(last_row["metrics"])
-                if self._registry is not None:
-                    m.update(self._registry.gauges())
-                    m.update(self._ledger.gauges())
-                if self._mesh_obs is not None:
-                    m.update(self._mesh_obs.gauges())
-                return last_row["step"], m
-
-            self._status = StatusServer(
-                _statusz, _metrics_fn,
-                host=cfg.status_host, port=cfg.status_port,
-            )
-
-        ckpt = None
-        start_step = int(jax.device_get(state.step))
-        if cfg.checkpoint_dir and cfg.ckpt_every > 0:
-            ckpt = CheckpointManager(cfg.checkpoint_dir, cfg.keep_n, cfg.ckpt_every,
-                                     async_saves=cfg.async_checkpointing)
-            restored = ckpt.restore_latest(_pure_state(state))
-            if restored is not None:
-                pure, start_step = restored
-                state = _apply_pure(state, pure)
-
-        # preemption handling: SIGTERM/SIGINT request a final checkpoint at
-        # the next step boundary (the auto-resume path restores it — the
-        # workflow the reference performs by hand after Kaggle preemptions)
-        preempted = {"flag": False}
-        old_handlers = {}
-        if ckpt is not None:
-            import signal
-
-            def _on_signal(signum, frame):
-                preempted["flag"] = True
-
-            for sig in (signal.SIGTERM, signal.SIGINT):
-                try:
-                    old_handlers[sig] = signal.signal(sig, _on_signal)
-                except ValueError:  # non-main thread
-                    break
-
-        profiling = False
-        nan_debug_prev = None
-        if cfg.debug_nans:
-            nan_debug_prev = jax.config.jax_debug_nans
-            jax.config.update("jax_debug_nans", True)
-        t_prev = time.perf_counter()
-        last_log_step = start_step
-        scan_k = max(cfg.scan_steps, 1)
-        if scan_k > 1:
-            cadences = [("log_every", cfg.log_every),
-                        ("eval_every", cfg.eval_every),
-                        ("ckpt_every", cfg.ckpt_every)]
-            cadences += [
-                (f"callbacks[{i}].every", every)
-                for i, (every, _) in enumerate(callbacks or [])
-            ]
-            for nm, ev in cadences:
-                if ev > 0 and ev % scan_k:
-                    raise ValueError(
-                        f"{nm}={ev} must be a multiple of scan_steps="
-                        f"{scan_k}: the host only sees window boundaries"
+                sched = None
+                mcfg = getattr(self.model, "cfg", None)
+                if cfg.pipeline_parallel and mcfg is not None:
+                    sched = PipelineScheduleInfo(
+                        n_stages=mesh_axis_sizes(self.mesh).get("pipe", 1),
+                        n_microbatches=getattr(mcfg, "n_microbatches", 1),
+                        n_virtual=getattr(mcfg, "virtual_stages", 1),
+                        schedule=cfg.pp_schedule,
                     )
-        profile_stopped = False
-        tail_warmed = False
-        excluded_steps = 0  # steps whose wall time was excluded since last log
+                self._mesh_obs = MeshObservatory(
+                    mesh=self.mesh, registry=self._registry, trace=recorder,
+                    schedule=sched,
+                )
+            # registry/observatory persist across fit() calls but the
+            # recorder is per-run: re-attach so a resumed fit's compile and
+            # mesh events land in ITS trace, not the first run's dead ring
+            if self._registry is not None:
+                self._registry.trace = recorder
+            if self._mesh_obs is not None:
+                self._mesh_obs.attach_trace(recorder)
+            # observability modes fence every dispatch so step walls are
+            # device-true; _obs_clock is the shared time base
+            _fenced = recorder is not None or self._mesh_obs is not None
+            _obs_clock = (
+                recorder.clock if recorder is not None
+                else self._mesh_obs.clock if self._mesh_obs is not None
+                else None
+            )
+            # live status endpoint for the duration of fit(); last_row is
+            # mutated at every log write so /metrics and /statusz always
+            # serve the newest row without re-deriving device values
+            last_row = {"step": int(jax.device_get(state.step)), "metrics": {}}
+            if cfg.status_port is not None:
+                from solvingpapers_tpu.metrics.http import StatusServer
+
+                def _statusz() -> dict:
+                    d = {
+                        "train": {"step": last_row["step"],
+                                  "steps_total": cfg.steps},
+                        "metrics": last_row["metrics"],
+                    }
+                    if self._registry is not None:
+                        d["compile"] = self._registry.snapshot()
+                    if self._ledger is not None:
+                        d["mem"] = self._ledger.snapshot()
+                    if self._mesh_obs is not None:
+                        d["mesh"] = self._mesh_obs.snapshot()
+                    return d
+
+                def _metrics_fn() -> tuple[int, dict]:
+                    m = dict(last_row["metrics"])
+                    if self._registry is not None:
+                        m.update(self._registry.gauges())
+                        m.update(self._ledger.gauges())
+                    if self._mesh_obs is not None:
+                        m.update(self._mesh_obs.gauges())
+                    return last_row["step"], m
+
+                self._status = StatusServer(
+                    _statusz, _metrics_fn,
+                    host=cfg.status_host, port=cfg.status_port,
+                )
+
+            ckpt = None
+            start_step = int(jax.device_get(state.step))
+            if cfg.checkpoint_dir and cfg.ckpt_every > 0:
+                ckpt = CheckpointManager(cfg.checkpoint_dir, cfg.keep_n, cfg.ckpt_every,
+                                         async_saves=cfg.async_checkpointing)
+                restored = ckpt.restore_latest(_pure_state(state))
+                if restored is not None:
+                    pure, start_step = restored
+                    state = _apply_pure(state, pure)
+
+            # preemption handling: SIGTERM/SIGINT request a final checkpoint at
+            # the next step boundary (the auto-resume path restores it — the
+            # workflow the reference performs by hand after Kaggle preemptions)
+            preempted = {"flag": False}
+            old_handlers = {}
+            if ckpt is not None:
+                import signal
+
+                def _on_signal(signum, frame):
+                    preempted["flag"] = True
+
+                for sig in (signal.SIGTERM, signal.SIGINT):
+                    try:
+                        old_handlers[sig] = signal.signal(sig, _on_signal)
+                    except ValueError:  # non-main thread
+                        break
+
+            profiling = False
+            nan_debug_prev = None
+            if cfg.debug_nans:
+                nan_debug_prev = jax.config.jax_debug_nans
+                jax.config.update("jax_debug_nans", True)
+            t_prev = time.perf_counter()
+            last_log_step = start_step
+            scan_k = max(cfg.scan_steps, 1)
+            if scan_k > 1:
+                cadences = [("log_every", cfg.log_every),
+                            ("eval_every", cfg.eval_every),
+                            ("ckpt_every", cfg.ckpt_every)]
+                cadences += [
+                    (f"callbacks[{i}].every", every)
+                    for i, (every, _) in enumerate(callbacks or [])
+                ]
+                for nm, ev in cadences:
+                    if ev > 0 and ev % scan_k:
+                        raise ValueError(
+                            f"{nm}={ev} must be a multiple of scan_steps="
+                            f"{scan_k}: the host only sees window boundaries"
+                        )
+            profile_stopped = False
+            warmed = set()  # the step programs this call has run
+            excluded_steps = 0  # steps whose wall time was excluded since last log
+            fit_call = next(_FIT_CALLS)
+            # the process's recompiles when this call began, and at its
+            # last row
+            recompiles_0 = _COMPILE_SPANS.recompiles_after_first_step
+            recompiles_row = 0
+        first_step_scope = None  # the open `fit_first_step` span
         try:
             step = start_step
             while step < cfg.steps:
@@ -1181,25 +1218,49 @@ class Trainer:
                             "train_step_scan", self._train_step_scan
                         )
                     exclude_compile = (
-                        kk == 1 and scan_k > 1 and not tail_warmed
+                        scan_k > 1 and name not in warmed
                         and step != start_step
                     )
                     if exclude_compile:
-                        # first single-step call of a scan-windowed run (the
-                        # ragged tail or a resume re-align): _train_step has
-                        # not been traced yet, so fence and keep its compile
-                        # out of the step timing, like eval/checkpoint
+                        # a scan-windowed run's first call of its other
+                        # program (the single step of the ragged tail, or
+                        # the window after a resume's re-aligning steps): it
+                        # has not been traced yet, so fence and keep its
+                        # compile out of the step timing, like eval/checkpoint
                         _fence()
                         t_tail = time.perf_counter()
                     t_span = _obs_clock() if _fenced else 0.0
-                    with _span("train_dispatch"):
+                    if step == start_step:
+                        # start-up's last part: the call's first dispatch
+                        # (trace, lower, compile or cache load inside it)
+                        # to the fetch that fences it below
+                        first_step_scope = run_trace.run_span(
+                            "fit_first_step", fit=fit_call, step=end
+                        )
+                        first_step_scope.__enter__()
+                    t_dispatch = time.perf_counter()
+                    if t_dispatched is not None:
+                        gap = (t_dispatch - t_dispatched
+                               - blocked_since_dispatch)
+                        if gap > gap_max[0]:
+                            gap_max = (gap, end)
+                    # a program that compiles inside a timed step's dispatch
+                    # is a recompile; the call's first step and a scan run's
+                    # first tail step compile by design, out of the timing
+                    timed = step != start_step and not exclude_compile
+                    with _span("train_dispatch"), \
+                            _COMPILE_SPANS.steady(end if timed else None):
                         state, metrics = self._dispatch(
                             name, jitted, state, batch
                         )
+                    t_dispatched = time.perf_counter()
+                    blocked_since_dispatch = 0.0
+                    if t_dispatched - t_dispatch > dispatch_max[0]:
+                        dispatch_max = (t_dispatched - t_dispatch, end)
                     if _fenced:
                         t_block = time.perf_counter()
                         jax.block_until_ready(metrics)
-                        blocked_s += time.perf_counter() - t_block
+                        _blocked(time.perf_counter() - t_block)
                         d_span = _obs_clock() - t_span
                         compiled = step == start_step
                         if recorder is not None:
@@ -1224,14 +1285,24 @@ class Trainer:
                         # the step's time is excluded, so drop it from the
                         # next log row's denominator too (else step_time /
                         # tokens_per_sec overstate by the excluded step)
-                        excluded_steps += 1
-                    if kk == 1:
-                        tail_warmed = True
+                        excluded_steps += kk
+                    warmed.add(name)
+                    if step == start_step:
+                        # fence the first step so compile time never pollutes
+                        # step_time/tokens_per_sec/MFU metrics; the timed
+                        # window therefore starts at the NEXT step
+                        jax.device_get(metrics["train_loss"])
+                        first_step_scope.__exit__(None, None, None)
+                        first_step_scope = None
                 if step == start_step:
-                    # fence the first step so compile time never pollutes
-                    # step_time/tokens_per_sec/MFU metrics; the timed window
-                    # therefore starts at the NEXT step
-                    jax.device_get(metrics["train_loss"])
+                    if fit_call == 1:
+                        # what the process spent before it trained: one row
+                        with _span("log_write", step=end):
+                            writer.write(end, {
+                                f"startup/{k}": float(v) for k, v in
+                                run_trace.summarize_startup(
+                                    run_trace.RUN.events()).items()
+                            })
                     if self._mesh_obs is not None and cfg.pipeline_parallel:
                         # one-time stage probe for the bubble report,
                         # after the compile step (params live, jit warm)
@@ -1241,6 +1312,8 @@ class Trainer:
                     t_prev = time.perf_counter()
                     last_log_step = end
                     wait_s = blocked_s = 0.0
+                    dispatch_max = gap_max = (0.0, 0)
+                    t_dispatched = None
 
                 run_eval = (
                     cfg.eval_every > 0 and eval_iter_fn
@@ -1287,7 +1360,7 @@ class Trainer:
                         n_timed = max(end - last_log_step - excluded_steps, 1)
                         wall = now - t_prev
                         dt = wall / n_timed
-                        blocked_s += now - t_fetch
+                        _blocked(now - t_fetch)
                         # where the host's share of the loop went, a step:
                         # in next() of the batch iterator, and everywhere
                         # else that is not waiting for the device
@@ -1295,6 +1368,32 @@ class Trainer:
                         metrics["host_loop_ms"] = 1e3 * max(
                             wall - wait_s - blocked_s, 0.0
                         ) / n_timed
+                        metrics["dispatch_max_ms"] = 1e3 * dispatch_max[0]
+                        metrics["dispatch_max_step"] = dispatch_max[1]
+                        metrics["host_gap_max_ms"] = 1e3 * gap_max[0]
+                        metrics["host_gap_max_step"] = gap_max[1]
+                        dispatch_max = gap_max = (0.0, 0)
+                        # a long dispatch is a retrace where this counts
+                        # one, and the queue's back-pressure where not
+                        recompiles = (
+                            _COMPILE_SPANS.recompiles_after_first_step
+                            - recompiles_0
+                        )
+                        metrics["recompiles_after_first_step"] = recompiles
+                        if recompiles:
+                            metrics["recompile_last_step"] = (
+                                _COMPILE_SPANS.recompiled[-1][0]
+                            )
+                        if recompiles > recompiles_row:
+                            warnings.warn(
+                                f"compiled again inside a timed step: "
+                                + _COMPILE_SPANS.newest(
+                                    recompiles - recompiles_row
+                                ) + f" ({recompiles} this fit); a batch's or "
+                                "a given state's shape, dtype or sharding changed",
+                                stacklevel=2,
+                            )
+                        recompiles_row = recompiles
                         wait_s = blocked_s = 0.0
                         t_prev = now
                         last_log_step = end
@@ -1316,16 +1415,17 @@ class Trainer:
                                         metrics["tokens_per_sec"]
                                         * cfg.flops_per_token / peak
                                     )
-                    row = {k: float(v) for k, v in metrics.items()}
-                    if self._registry is not None:
-                        row.update(self._registry.gauges())
-                        row.update(self._ledger.gauges())
-                        self._ledger.check()
-                    if self._mesh_obs is not None:
-                        row.update(self._mesh_obs.gauges())
-                    last_row["step"] = end
-                    last_row["metrics"] = row
-                    writer.write(end, row)
+                    with _span("log_write", step=end):
+                        row = {k: float(v) for k, v in metrics.items()}
+                        if self._registry is not None:
+                            row.update(self._registry.gauges())
+                            row.update(self._ledger.gauges())
+                            self._ledger.check()
+                        if self._mesh_obs is not None:
+                            row.update(self._mesh_obs.gauges())
+                        last_row["step"] = end
+                        last_row["metrics"] = row
+                        writer.write(end, row)
 
                 if ckpt is not None and ckpt.save_every > 0 \
                         and end % ckpt.save_every == 0:
@@ -1347,6 +1447,8 @@ class Trainer:
                 _stop_profile()
                 profiling = False
         finally:
+            if first_step_scope is not None:  # the first step raised
+                first_step_scope.__exit__(None, None, None)
             if self._status is not None:
                 self._status.close()
                 self._status = None

@@ -4,7 +4,9 @@ the package).
   * each sub-package imports only from the sub-packages below it: the
     allowed sets are the arrows as they stand, and the two arrows that
     point the wrong way are named by file, so that a third cannot appear
-    unnoticed and a paid debt must be struck here;
+    unnoticed and a paid debt must be struck here; the one module every
+    part may import is `metrics/trace.py`, the run's recorder, which
+    imports nothing but the standard library (held here too);
   * nothing under `solvingpapers_tpu/` imports what stands beside it
     (the benchmark, the tools, the tests, the chip check);
   * every `solvingpapers_tpu` module that the benchmark imports exists,
@@ -43,6 +45,10 @@ BACK_ARROWS = {
     "infer": {("infer/speculative.py", "models")},
 }
 
+# below everything: the run's recorder, which start-up's spans are written to
+# from every part (each `__init__.py`'s `import:<package>`, `create_mesh`, ...)
+BOTTOM = f"{PKG}.metrics.trace"
+
 BESIDE_THE_PACKAGE = {"benchmarks", "tools", "tests", "bench", "chip_smoke"}
 
 
@@ -73,7 +79,7 @@ def package_imports(sub: str):
     for path, package in package_files(sub):
         for module, names in imports_of(path, package):
             parts = module.split(".")
-            if parts[0] != PKG:
+            if parts[0] != PKG or module == BOTTOM:
                 continue
             # `from solvingpapers_tpu import serve` names its target last
             targets = [parts[1]] if len(parts) > 1 else names
@@ -86,6 +92,22 @@ def package_imports(sub: str):
 def test_subpackage_imports_only_from_below(sub):
     upwards = {(f, t) for f, t in package_imports(sub) if t not in ALLOWED[sub]}
     assert upwards == BACK_ARROWS.get(sub, set())
+
+
+def test_the_bottom_module_imports_the_standard_library_only():
+    """At module level; a function of `metrics/trace.py` may import what
+    its caller has loaded (JAX's annotation, a formatter of `hlo_cost`)."""
+    import sys
+
+    path = REPO.joinpath(*BOTTOM.split(".")).with_suffix(".py")
+    top_level = [
+        alias.name if isinstance(node, ast.Import) else node.module
+        for node in ast.parse(path.read_text()).body
+        if isinstance(node, (ast.Import, ast.ImportFrom))
+        for alias in (node.names if isinstance(node, ast.Import) else [None])
+    ]
+    assert top_level
+    assert {m.split(".")[0] for m in top_level} <= set(sys.stdlib_module_names)
 
 
 def test_package_imports_nothing_that_stands_beside_it():
