@@ -19,7 +19,8 @@ names).
     SiLU(causal depthwise convolution of width `conv_kernel` + bias) = [x |
     B | C]; dt = softplus(dt + dt_bias), a = -exp(A_log) a head; the
     recurrence of `ops/ssd.py` (S <- exp(dt a) S + dt x B^T, y = S C + D x,
-    head h reading group h // (H / G)) in chunks of `chunk_size`; u = y *
+    head h reading group h // (H / G)) in chunks of `chunk_size`, through
+    the Pallas kernels of `kernels/ssd.py`; u = y *
     SiLU(z), then RMS-normalised over each group's d_inner / G channels,
     times a weight; `out_proj`
   * attention: q, k, v projections to `num_attention_heads` and
@@ -39,7 +40,8 @@ The residual stream is float32; products take bfloat16 operands over
 float32 weights (`dtype`); the router's product, the steps, the decays and
 the state are float32. Not here: dense `-` layers, decode caches (ROADMAP
 R-M7: a cache manager would hold a layer's P x N state a head and the
-convolution's last rows, with `ops.ssd.ssd_step` as the step), a balance
+convolution's last rows, with `ops.ssd.ssd_step` as the step and the
+kernels' `state=` for a prefill), a balance
 loss or an update rule for the selection bias (the source's config states
 neither).
 """

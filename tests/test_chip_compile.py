@@ -240,6 +240,55 @@ def test_gated_delta_rule_pads_narrow_heads_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
+def _compiled_ssd(one_chip, x_shape, groups, state, backward):
+    """The state-space rule's kernels compiled for the described chip: x (B,
+    S, H, P) bfloat16, B and C (B, S, groups, state) bfloat16, the step
+    float32, every argument differentiated."""
+    from solvingpapers_tpu.kernels.ssd import ssd_chunked
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    b, s, h, p = x_shape
+    args = (sds(x_shape, jnp.bfloat16), sds((b, s, h), jnp.float32),
+            sds((h,), jnp.float32), sds((b, s, groups, state), jnp.bfloat16),
+            sds((b, s, groups, state), jnp.bfloat16), sds((h,), jnp.float32),
+            sds((b, h, p, state), jnp.float32))
+
+    def loss(x, dt, a, b, c, d, entering):
+        # interpret=False: the default would ask jax.devices(), the CPU here
+        y, last = ssd_chunked(x, dt, a, b, c, d, entering, chunk=128,
+                              interpret=False)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(last)
+
+    fn = jax.grad(loss, argnums=tuple(range(7))) if backward else loss
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_ssd_rule_compiles_for_v5e(one_chip, backward):
+    """The Mamba-2 recurrence at the cell's shape (64 heads of 64 on 8
+    groups of state 128, one sequence of 16,384, chunks of 128, bfloat16 x,
+    B, C, float32 step): Mosaic takes the kernels' blocks and their VMEM (a
+    group's eight (128, 128) decay matrices of four chunks, and in the
+    backward their cotangents: 18 MiB, over the default 16), the rule is a
+    kernel call forward and one backward, and no `while` is left of it."""
+    compiled = _compiled_ssd(one_chip, (1, 16_384, 64, 64), 8, 128, backward)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + backward
+    assert " while(" not in text
+    # besides the gradients: the entering states (32 grid steps x 64 heads x
+    # 64 x 128 float32, 64 MiB) and the step's rows; nothing of size Q x Q
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2 ** 30
+
+
+def test_ssd_rule_pads_narrow_heads_for_v5e(one_chip):
+    """Heads of 48 (padded to 64: two share a tile of lanes), a state of 16
+    (padded to the 128 lanes) and a ragged length are padded, not refused;
+    the padded channels hold zeros and write nothing."""
+    compiled = _compiled_ssd(one_chip, (2, 300, 4, 48), 2, 16, True)
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
 # (experts, slots an expert, width, hidden width, gated, activation, calls)
 MOE_SHAPES = {
     # the two DeepSeekV3 cells: hidden 1,365 padded to 1,408; an expert's

@@ -149,10 +149,13 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-def _expert_kernel_passes(trainer, state, batch, kernels):
+def _kernel_passes(trainer, state, batch, kernels, layer="L_moe_experts"):
     """{kernel: the passes of the compiled step in which what it lowers to
-    appears}, every such instruction under `L_moe_experts`; and the names of
-    the step's `pallas_call`s, counted."""
+    appears}, every such instruction under `layer` (or, with `kernels` a
+    dict, under the layer it names for that kernel); and the names of the
+    step's `pallas_call`s, counted."""
+    if not isinstance(kernels, dict):
+        kernels = dict.fromkeys(kernels, layer)
     hlo_cost.register_program("jit_train_step", trainer._train_step,
                               (state, batch))
     scopes = hlo_cost.program_scopes("jit_train_step")
@@ -162,9 +165,9 @@ def _expert_kernel_passes(trainer, state, batch, kernels):
         src = hlo_cost._OP_NAME_RE.search(line)
         if src is None or m.name not in scopes:  # a parameter, a constant
             continue
-        for kernel in kernels:
+        for kernel, its_layer in kernels.items():
             if kernel in src.group("src"):
-                assert scopes[m.name].layer == "L_moe_experts", line
+                assert scopes[m.name].layer == its_layer, line
                 passes[kernel].add(scopes[m.name].pass_)
     assert not set(hlo_cost.LAYER_SCOPES + hlo_cost.KERNEL_SCOPES) & set(passes)
     calls = list(_pallas_calls(
@@ -188,7 +191,7 @@ def test_grouped_expert_kernels_keep_the_experts_scope(monkeypatch):
     trainer, batch = dsv3_trainer(remat=True, rope_dim=8, dim=128, n_layers=1)
     state = trainer.init_state(batch)
     trainer._build_steps()
-    passes, calls = _expert_kernel_passes(
+    passes, calls = _kernel_passes(
         trainer, state, batch, ("moe_glu_fwd", "moe_glu_bwd"))
     assert passes == {"moe_glu_fwd": {"fwd", "remat"}, "moe_glu_bwd": {"bwd"}}
     names = collections.Counter(c.params["name"] for c in calls)
@@ -227,14 +230,19 @@ def test_held_experts_kernels_keep_the_experts_scope(monkeypatch):
     batch = {k: np.zeros((2, cfg.block_size), np.int32) for k in "xy"}
     state = trainer.init_state(batch)
     trainer._build_steps()
-    passes, calls = _expert_kernel_passes(
-        trainer, state, batch,
-        ("moe_glu_fwd", "moe_glu_bwd_dw", "moe_glu_bwd_dx"))
+    # the state-space rule's kernels in the same step: their `name=` is no
+    # scope of the vocabulary either, so `ssm_core_ms` keeps reading them
+    passes, calls = _kernel_passes(trainer, state, batch, {
+        **dict.fromkeys(("moe_glu_fwd", "moe_glu_bwd_dw", "moe_glu_bwd_dx"),
+                        "L_moe_experts"),
+        "ssd_fwd": "L_ssm_core", "ssd_bwd": "L_ssm_core"})
     assert passes == {"moe_glu_fwd": {"fwd", "remat"},
-                      "moe_glu_bwd_dw": {"bwd"}, "moe_glu_bwd_dx": {"bwd"}}
+                      "moe_glu_bwd_dw": {"bwd"}, "moe_glu_bwd_dx": {"bwd"},
+                      "ssd_fwd": {"fwd", "remat"}, "ssd_bwd": {"bwd"}}
     names = collections.Counter(c.params["name"] for c in calls)
     assert names == {"moe_glu_fwd": 2, "moe_glu_bwd_dw": 1,
-                     "moe_glu_bwd_dx": 1}, names  # fwd, remat
+                     "moe_glu_bwd_dx": 1,  # fwd, remat
+                     "ssd_fwd": 2, "ssd_bwd": 1}, names  # the Mamba-2 layer
 
 
 def test_program_scopes_knows_only_registered_programs():

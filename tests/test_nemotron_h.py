@@ -18,6 +18,7 @@
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -381,6 +382,19 @@ def test_train_step_names_the_new_layers():
         assert {s.pass_ for s in top if s.layer == layer} >= {"bwd", "remat"}
     assert not layers & {"L_gdn_proj", "L_gdn_core", "L_kda_proj",
                          "L_kda_core", "L_dense_ffn"}
+    # the rule's kernels carry a `name=` that is no layer: their time stays
+    # the rule's own scope's, the forward kernel's in the step and again in
+    # the layer's remat, the backward kernel's in the backward pass
+    seen = {}
+    for m in re.finditer(
+            r"%?([\w.\-]+) = [^\n]*op_name=\"[^\"]*(ssd_(?:fwd|bwd))", text):
+        if m.group(1) not in scopes:  # a constant
+            continue
+        s = scopes[m.group(1)]
+        assert s.layer == "L_ssm_core", m.group(0)
+        if s.top_level:
+            seen.setdefault(m.group(2), set()).add(s.pass_)
+    assert seen == {"ssd_fwd": {"fwd", "remat"}, "ssd_bwd": {"bwd"}}
     covered = sum(s.layer is not None for s in top) / len(top)
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
 

@@ -1,15 +1,18 @@
 """The Mamba-2 state-space recurrence of `ops/ssd.py` on the CPU: the
-chunked form against the rule token by token (`ssd_recurrent`) and against
-its quadratic dual written out here, values and gradients, in float32 and
-with bfloat16 operands; a sequence that is no whole number of chunks; heads
-that share their group's B and C; a step so large that the decay underflows;
-the state handed from one call to the next; the gated norm."""
+chunked form (the Pallas kernels of `kernels/ssd.py`, interpreted) against
+the rule token by token (`ssd_recurrent`) and against its quadratic dual
+written out here, values and every gradient, in float32 and with bfloat16
+operands; a sequence that is no whole number of chunks or of grid steps;
+heads that share their group's B and C, and heads that share a tile of
+lanes; a step so large that the decay underflows; the state handed from one
+call to the next and differentiated; the gated norm."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from solvingpapers_tpu.kernels import ssd as kernel
 from solvingpapers_tpu.ops import gated_delta, ssd
 
 pytestmark = pytest.mark.fast
@@ -17,9 +20,9 @@ pytestmark = pytest.mark.fast
 B, S, H, P, G, N = 2, 50, 8, 4, 2, 16
 
 
-def inputs(seed=0, seq=S, dtype=jnp.float32, heads=H, groups=G):
+def inputs(seed=0, seq=S, dtype=jnp.float32, heads=H, groups=G, width=P):
     k = jax.random.split(jax.random.key(seed), 6)
-    x = jax.random.normal(k[0], (B, seq, heads, P)).astype(dtype)
+    x = jax.random.normal(k[0], (B, seq, heads, width)).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(k[1], (B, seq, heads)) - 1.0)
     a = -jnp.exp(jax.random.uniform(k[2], (heads,), minval=-2.0, maxval=2.7))
     b = jax.random.normal(k[3], (B, seq, groups, N)).astype(dtype)
@@ -57,23 +60,29 @@ def objective(fn, **kw):
     return f
 
 
-@pytest.mark.parametrize("chunk, segment", [(8, 16), (16, 16), (8, 64)])
-def test_chunked_equals_recurrent_equals_dual_float32(chunk, segment):
-    """50 tokens: no whole number of chunks of 8 or 16; 8 heads on 2
-    groups."""
+@pytest.mark.parametrize("chunk, a_step", [(8, 2), (16, 1), (8, 8)])
+def test_chunked_equals_recurrent_equals_dual_float32(
+        chunk, a_step, monkeypatch):
+    """50 tokens: no whole number of chunks of 8 or 16, nor of grid steps of
+    2 chunks (four steps, the last half padding), of 1 (four steps) or of 8
+    (one step of 7 chunks); 8 heads on 2 groups."""
+    monkeypatch.setattr(kernel, "CHUNKS_A_STEP", a_step)
     args = inputs()
     y_rec, s_rec = ssd.ssd_recurrent(*args)
-    y, s = ssd.ssd_chunked(*args, chunk=chunk, segment=segment)
+    y, s = ssd.ssd_chunked(*args, chunk=chunk)
     assert y.shape == (B, S, H, P) and s.shape == (B, H, P, N)
     close(y, y_rec)
     close(s, s_rec)
     close(dual(*args), y_rec)
 
 
-def test_gradients_equal_the_recurrent_and_the_dual_ones():
+def test_gradients_equal_the_recurrent_and_the_dual_ones(monkeypatch):
+    """dS crosses chunks inside a grid step and grid steps (2 chunks a
+    step, 50 tokens: four steps)."""
+    monkeypatch.setattr(kernel, "CHUNKS_A_STEP", 2)
     args = inputs(seed=1)
     want = jax.grad(objective(ssd.ssd_recurrent), argnums=range(6))(*args)
-    got = jax.grad(objective(ssd.ssd_chunked, chunk=8, segment=16),
+    got = jax.grad(objective(ssd.ssd_chunked, chunk=8),
                    argnums=range(6))(*args)
     by_dual = jax.grad(objective(dual), argnums=range(6))(*args)
     for g, w, q in zip(got, want, by_dual):
@@ -86,12 +95,12 @@ def test_bfloat16_operands_stay_near_the_float32_rule():
     gradients within bfloat16's rounding of the float32 recurrence on the
     same (rounded) inputs."""
     args = inputs(seed=2, dtype=jnp.bfloat16)
-    y, state = ssd.ssd_chunked(*args, chunk=8, segment=16)
+    y, state = ssd.ssd_chunked(*args, chunk=8)
     assert y.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     y_rec, s_rec = ssd.ssd_recurrent(*args)
     close(y.astype(jnp.float32), y_rec, 2e-2)
     close(state, s_rec, 2e-2)
-    got = jax.grad(objective(ssd.ssd_chunked, chunk=8, segment=16),
+    got = jax.grad(objective(ssd.ssd_chunked, chunk=8),
                    argnums=(0, 1, 3))(*args)
     want = jax.grad(objective(ssd.ssd_recurrent), argnums=(0, 1, 3))(*args)
     for g, w in zip(got, want):
@@ -118,14 +127,14 @@ def test_a_step_that_underflows_the_decay_gives_zeros_not_nan():
     x, dt, a, b, c, d = inputs(seed=4)
     dt = jnp.full_like(dt, 250.0)
     a = jnp.full_like(a, -16.0)
-    y, state = ssd.ssd_chunked(x, dt, a, b, c, None, chunk=8, segment=16)
+    y, state = ssd.ssd_chunked(x, dt, a, b, c, None, chunk=8)
     assert bool(jnp.all(jnp.isfinite(y))) and bool(
         jnp.all(jnp.isfinite(state)))
     rep = H // G
     own = dt[..., None] * x * jnp.sum(
         jnp.repeat(b, rep, 2) * jnp.repeat(c, rep, 2), -1, keepdims=True)
     close(y, own)
-    grads = jax.grad(objective(ssd.ssd_chunked, chunk=8, segment=16),
+    grads = jax.grad(objective(ssd.ssd_chunked, chunk=8),
                      argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c, d)
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
 
@@ -133,13 +142,12 @@ def test_a_step_that_underflows_the_decay_gives_zeros_not_nan():
 def test_state_handed_from_one_call_to_the_next():
     args = inputs(seed=5, seq=48)
     x, dt, a, b, c, d = args
-    whole, s_whole = ssd.ssd_chunked(*args, chunk=8, segment=16)
+    whole, s_whole = ssd.ssd_chunked(*args, chunk=8)
     cut = 20  # not a chunk's edge
     first, s_first = ssd.ssd_chunked(x[:, :cut], dt[:, :cut], a, b[:, :cut],
-                                     c[:, :cut], d, chunk=8, segment=16)
+                                     c[:, :cut], d, chunk=8)
     rest, s_rest = ssd.ssd_chunked(x[:, cut:], dt[:, cut:], a, b[:, cut:],
-                                   c[:, cut:], d, state=s_first, chunk=8,
-                                   segment=16)
+                                   c[:, cut:], d, state=s_first, chunk=8)
     close(jnp.concatenate([first, rest], 1), whole)
     close(s_rest, s_whole)
     # and one token at a time, as a decode step would
@@ -153,10 +161,56 @@ def test_state_handed_from_one_call_to_the_next():
 
 def test_shapes_that_do_not_fit_are_refused():
     x, dt, a, b, c, d = inputs()
-    with pytest.raises(ValueError, match="whole number of chunks"):
-        ssd.ssd_chunked(x, dt, a, b, c, d, chunk=8, segment=20)
+    with pytest.raises(ValueError, match="state .* must be a head's"):
+        ssd.ssd_chunked(x, dt, a, b, c, d, chunk=8,
+                        state=jnp.zeros((B, H, N, P)))
     with pytest.raises(ValueError, match="one step a head"):
         ssd.ssd_chunked(x, dt[..., :4], a, b, c, d)
+
+
+@pytest.mark.parametrize("a_step", [1, 4])
+def test_every_gradient_with_a_state_handed_in(a_step, monkeypatch):
+    """x, dt, a, b, c, d and the state an earlier call left, with a
+    cotangent on the state that leaves too: the backward's carry starts
+    from it and ends as d(state)."""
+    monkeypatch.setattr(kernel, "CHUNKS_A_STEP", a_step)
+    args = inputs(seed=6, seq=37)
+    state = jax.random.normal(jax.random.key(7), (B, H, P, N))
+
+    def both(fn, **kw):
+        def f(*args7):
+            y, last = fn(*args7[:6], state=args7[6], **kw)
+            return jnp.sum(jnp.sin(y)) + jnp.sum(jnp.cos(last))
+        return f
+
+    want = jax.grad(both(ssd.ssd_recurrent), argnums=range(7))(*args, state)
+    got = jax.grad(both(ssd.ssd_chunked, chunk=8), argnums=range(7))(
+        *args, state)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        close(g, w, 5e-5)  # float32's rounding of e^L where |L| is tens
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+def test_heads_that_share_a_tile_of_lanes(dtype, tol):
+    """Heads of 64 sit two to a tile of 128 lanes: their score matrices side
+    by side multiply x's tile with each head's columns down a diagonal. 4
+    heads of 64 on 2 groups, values, state and gradients."""
+    args = inputs(seed=8, seq=21, dtype=dtype, heads=4, width=64)
+    assert kernel._Plan(8, 1, 2, 64, True).hp == 2
+    y, state = ssd.ssd_chunked(*args, chunk=8)
+    y_rec, s_rec = ssd.ssd_recurrent(*args)
+    close(y.astype(jnp.float32), y_rec, tol)
+    close(state, s_rec, tol)
+    got = jax.grad(objective(ssd.ssd_chunked, chunk=8),
+                   argnums=range(6))(*args)
+    want = jax.grad(objective(ssd.ssd_recurrent), argnums=range(6))(*args)
+    for g, w in zip(got, want):
+        rel = float(jnp.linalg.norm(g.astype(jnp.float32) - w)
+                    / jnp.linalg.norm(w))
+        assert rel < 50 * tol, rel
 
 
 def test_gate_then_group_norm_is_not_norm_then_gate():
