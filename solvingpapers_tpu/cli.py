@@ -125,6 +125,14 @@ def cmd_train(args) -> int:
         cfg, model, tok, train_iter, eval_iter_fn = build_char_lm_run(
             cfg, sharding=batch_sharding(mesh, context=cp)
         )
+        if cfg.model_family == "ouro" and not cfg.train.flops_per_token:
+            # the row's `mfu`: a looped model's weights count once a USE
+            # (T passes of the layers, T heads), not once
+            from solvingpapers_tpu.metrics.mfu import looped_flops_per_token
+
+            cfg = dataclasses.replace(cfg, train=dataclasses.replace(
+                cfg.train, flops_per_token=looped_flops_per_token(
+                    cfg.model, cfg.data.get("block_size", 256))))
         trainer = Trainer(
             model, cfg.train, loss_fn=loss_fn_for(cfg),
             init_fn=init_fn_for(cfg), mesh=mesh, rules=rules_for(cfg),
@@ -407,6 +415,13 @@ def _serve_model(args, *, quiet_random_init: bool = False):
               "its recurrent layers (Gated DeltaNet, Kimi Delta Attention, "
               "Mamba-2) keep recurrent state, and no cache manager here "
               "holds that yet (ROADMAP R-M7); `cli train` runs it",
+              file=sys.stderr)
+        return 2
+    if cfg.model_family == "ouro":
+        print("serving is unsupported for the ouro family: a looped model "
+              "keeps keys and values a (pass, layer) and may leave the loop "
+              "at a gate threshold, and no cache manager or decode step "
+              "here does that yet (ROADMAP R-M15); `cli train` runs it",
               file=sys.stderr)
         return 2
     if getattr(cfg.model, "context_parallel", False):

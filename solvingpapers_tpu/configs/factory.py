@@ -43,6 +43,10 @@ def build_model(cfg: RunConfig):
         from solvingpapers_tpu.models.nemotron_h import NemotronH
 
         return NemotronH(cfg.model)
+    if fam == "ouro":
+        from solvingpapers_tpu.models.ouro import Ouro
+
+        return Ouro(cfg.model)
     if fam == "gpt_pipe":
         from solvingpapers_tpu.models.gpt_pipe import GPTPipe
 
@@ -88,7 +92,7 @@ def loss_fn_for(cfg: RunConfig):
         vae_loss_fn,
     )
     from solvingpapers_tpu.train.objectives import (
-        dsv3_loss_fn, kimi_linear_loss_fn, qwen3next_loss_fn,
+        dsv3_loss_fn, kimi_linear_loss_fn, ouro_loss_fn, qwen3next_loss_fn,
     )
 
     return {
@@ -103,6 +107,7 @@ def loss_fn_for(cfg: RunConfig):
         "kimi_linear": kimi_linear_loss_fn,
         # the same objective: cross-entropy alone, head and loss in chunks
         "nemotron_h": kimi_linear_loss_fn,
+        "ouro": ouro_loss_fn,
         "vit": classification_loss_fn,
         "alexnet": classification_loss_fn,
         "kd": classification_loss_fn,

@@ -13,7 +13,7 @@ from solvingpapers_tpu.train.optim import OptimizerConfig
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     name: str
-    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | nemotron_h | vit | alexnet | ae | vae | kd
+    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | nemotron_h | ouro | vit | alexnet | ae | vae | kd
     model: Any
     train: TrainConfig
     data: dict = dataclasses.field(default_factory=dict)
@@ -761,6 +761,46 @@ def _nemotron3_nano_30b_a3b() -> RunConfig:
         notes="published widths; run through a cut (experts held, layers, "
               "vocabulary slice), see "
               "benchmarks/configs/nemotron3_nano_ep16.json",
+    )
+
+
+@register("ouro_2p6b")
+def _ouro_2p6b() -> RunConfig:
+    """Ouro-2.6B at its published size
+    (huggingface.co/ByteDance/Ouro-2.6B config.json): 48 sandwich-normed
+    layers (16 heads on 16 of width 128, RoPE theta 1e6 over the whole
+    width, SwiGLU 5632), hidden 2048, vocabulary 49,152 untied, the whole
+    stack run `total_ut_steps` = 4 times with the same weights, a final
+    norm, the head and an exit gate after every pass. 2.67B parameters,
+    42.7 GB of training state at 16 bytes: more than one chip holds; what
+    runs is a cut in depth, one pipeline stage's layers
+    (benchmarks/configs/ouro_2p6b_pp6.json sets `num_hidden_layers`).
+    Training only: no decode cache a (pass, layer) yet (ROADMAP R-M15).
+
+    The job (assumed, the source states none): two sequences of 4,096
+    tokens a step, AdamW 3e-4 beta=(0.9, 0.95) wd 0.1 clip 1.0, 100 steps
+    of warm-up -> cosine to 0.1*max; the loss's entropy weight 0.1; remat a
+    layer application."""
+    from solvingpapers_tpu.models.ouro import OuroConfig
+
+    return RunConfig(
+        name="ouro_2p6b",
+        model_family="ouro",
+        model=OuroConfig(),
+        train=TrainConfig(
+            steps=10_000, batch_size=2, log_every=50, eval_every=500,
+            eval_batches=4, ckpt_every=1000,
+            optimizer=OptimizerConfig(
+                name="adamw", max_lr=3e-4, warmup_steps=100,
+                total_steps=10_000, b1=0.9, b2=0.95, weight_decay=0.1,
+                grad_clip=1.0,
+            ),
+            tokens_per_step=8_192,
+        ),
+        data={"kind": "bpe", "path": None, "block_size": 4_096,
+              "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
+        notes="published widths; run through a cut in depth (one pipeline "
+              "stage's layers), see benchmarks/configs/ouro_2p6b_pp6.json",
     )
 
 

@@ -389,7 +389,9 @@ def format_anatomy(anatomy: dict) -> str:
 # norms and gate; its causal convolution; the chunked gated delta rule.
 # `L_kda_*` are the same three of a Kimi Delta Attention layer (the decay a
 # key channel is made in `L_kda_proj`); `L_dense_ffn` a dense SwiGLU layer
-# with its norm and residual add.
+# with its norm and residual add. `L_exit_gate` is a looped model's exit
+# gate: the gate's product, the exit distribution, the loss's weighted sum
+# and entropy.
 LAYER_SCOPES = (
     "L_embed",
     "L_attn_proj",
@@ -412,6 +414,7 @@ LAYER_SCOPES = (
     "L_moe_stats",
     "L_loss_head",
     "L_optimizer",
+    "L_exit_gate",
 )
 # `name=` of the three `pallas_call`s of kernels/flash_attention.py
 KERNEL_SCOPES = ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")

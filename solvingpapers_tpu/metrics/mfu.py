@@ -63,6 +63,22 @@ def transformer_flops_per_token(
     return mult * n_active_params + attn
 
 
+def looped_flops_per_token(cfg, seq_len: int, training: bool = True) -> float:
+    """Operations a token of a looped decoder (`models/ouro.py`'s config):
+    its `num_hidden_layers` layers are applied in each of `total_ut_steps`
+    passes and the head (with the exit gate's row) after each, so the
+    weights count once a USE, not once: 6 * uses (2 for inference), plus
+    causal attention's two products over half the sequence a layer
+    application. Recomputation under remat is not counted."""
+    d, n, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
+    layer = (d * (n + 2 * cfg.num_key_value_heads) * hd + n * hd * d
+             + 3 * d * cfg.intermediate_size)
+    uses = cfg.total_ut_steps * cfg.num_hidden_layers
+    weights = uses * layer + cfg.total_ut_steps * (cfg.vocab_size * d + d)
+    scores = uses * n * 2 * hd * seq_len / 2.0
+    return (6.0 if training else 2.0) * (weights + scores)
+
+
 def mfu(tokens_per_sec: float, flops_per_token: float, n_chips: int = 1, device=None) -> float:
     """Model FLOP utilization, or NaN when it cannot be computed
     honestly (unknown chip peak, non-finite inputs, zero peak) — NaN

@@ -53,7 +53,9 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
-from solvingpapers_tpu.models.layers import apply_flash_attention
+from solvingpapers_tpu.models.layers import (
+    apply_flash_attention, blocked_swiglu,
+)
 from solvingpapers_tpu.models.qwen3next import (
     HeldExpertsMoE, _a_log_init, _by_blocks,
 )
@@ -327,14 +329,8 @@ class FFNBlock(nn.Module):
             w_gate = self.param("mlp_gate", _INIT, shape).astype(dt)
             w_up = self.param("mlp_up", _INIT, shape).astype(dt)
             w_down = self.param("mlp_down", _INIT, shape[::-1]).astype(dt)
-
-            def block(x):
-                h = ops.rms_norm(x, norm_w, cfg.rms_norm_eps).astype(dt)
-                h = ops.silu(h @ w_gate) * (h @ w_up)
-                return x + (h @ w_down).astype(jnp.float32)
-
-            with jax.named_scope("L_dense_ffn"):
-                return _by_blocks(block, kda.SEGMENT, x)
+            return blocked_swiglu(x, norm_w, w_gate, w_up, w_down,
+                                  eps=cfg.rms_norm_eps, block=kda.SEGMENT)
         with jax.named_scope("L_moe_gate"):
             h = ops.rms_norm(x, norm_w, cfg.rms_norm_eps)
         h = held_moe(cfg, name="moe")(h)

@@ -464,3 +464,41 @@ def maybe_remat(block_cls, remat: bool, caches) -> type:
     if remat and caches is None:
         return nn.remat(block_cls, prevent_cse=False, static_argnums=(4,))
     return block_cls
+
+
+def _by_blocks(fn, block: int, *arrays):
+    """`fn` over blocks of `block` tokens of (B, S, ...) arrays, each block
+    rematerialised in the backward pass (`lax.map` of a checkpointed body):
+    what `fn` keeps for its backward is then a block's, not the
+    sequence's. `fn` is per token; S not a multiple of `block` runs whole."""
+    b, s = arrays[0].shape[:2]
+    if s <= block or s % block:
+        return fn(*arrays)
+    split = lambda a: jnp.moveaxis(  # noqa: E731
+        a.reshape((b, s // block, block) + a.shape[2:]), 1, 0)
+    join = lambda a: jnp.moveaxis(a, 0, 1).reshape(  # noqa: E731
+        (b, s) + a.shape[3:])
+    out = jax.lax.map(lambda xs: jax.checkpoint(fn)(*xs),
+                      tuple(split(a) for a in arrays))
+    return jax.tree.map(join, out)
+
+
+def blocked_swiglu(x, norm_w, w_gate, w_up, w_down, *, eps: float,
+                   block: int, post_norm_w=None):
+    """x + SwiGLU(Norm(x)) block by block under the scope `L_dense_ffn`:
+    the dense feed-forward half of a layer (Kimi-Linear's leading layer,
+    every Ouro layer). x (B, S, D) float32; the matrices already in the
+    compute dtype. With `post_norm_w` the sub-block's output is normed
+    before the add (Ouro's sandwich)."""
+    dt = w_gate.dtype
+
+    def ffn(x):
+        h = ops.rms_norm(x, norm_w, eps).astype(dt)
+        h = ops.silu(h @ w_gate) * (h @ w_up)
+        y = (h @ w_down).astype(jnp.float32)
+        if post_norm_w is not None:
+            y = ops.rms_norm(y, post_norm_w, eps)
+        return x + y
+
+    with jax.named_scope("L_dense_ffn"):
+        return _by_blocks(ffn, block, x)

@@ -46,7 +46,7 @@ from flax import linen as nn
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels import moe_grouped
 from solvingpapers_tpu.models.layers import (
-    GLUFFN, MLP, apply_flash_attention,
+    GLUFFN, MLP, _by_blocks, apply_flash_attention,
 )
 from solvingpapers_tpu.ops import gated_delta
 
@@ -178,23 +178,6 @@ class GatedAttention(nn.Module):
 def _a_log_init(key, shape, dtype=jnp.float32):
     """log U(0, 16), the low end held off zero so the log stays finite."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
-
-
-def _by_blocks(fn, block: int, *arrays):
-    """`fn` over blocks of `block` tokens of (B, S, ...) arrays, each block
-    rematerialised in the backward pass (`lax.map` of a checkpointed body):
-    what `fn` keeps for its backward is then a block's, not the
-    sequence's. `fn` is per token; S not a multiple of `block` runs whole."""
-    b, s = arrays[0].shape[:2]
-    if s <= block or s % block:
-        return fn(*arrays)
-    split = lambda a: jnp.moveaxis(  # noqa: E731
-        a.reshape((b, s // block, block) + a.shape[2:]), 1, 0)
-    join = lambda a: jnp.moveaxis(a, 0, 1).reshape(  # noqa: E731
-        (b, s) + a.shape[3:])
-    out = jax.lax.map(lambda xs: jax.checkpoint(fn)(*xs),
-                      tuple(split(a) for a in arrays))
-    return jax.tree.map(join, out)
 
 
 class GatedDeltaNet(nn.Module):

@@ -37,6 +37,7 @@ from solvingpapers_tpu.ops.attention import (
 from solvingpapers_tpu.ops.losses import (
     cross_entropy,
     head_cross_entropy,
+    head_nll_rows,
     distillation_loss,
     vae_loss,
     mtp_loss,

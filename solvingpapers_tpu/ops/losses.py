@@ -131,35 +131,61 @@ def _chunked_nll_sum_count(
     return tot, num
 
 
-@jax.named_scope("L_loss_head")
-def head_cross_entropy(
-    hidden: jax.Array, kernel: jax.Array, labels: jax.Array,
-    chunk_size: int = 2048,
-) -> jax.Array:
-    """Mean cross-entropy of integer labels under an untied head, head and
-    loss together a chunk of rows at a time: hidden (..., D) in the compute
-    dtype, kernel (D, V) as it is kept (float32), labels (...) int. A
+def _head_chunks(hidden, kernel, labels, chunk_size):
+    """What `head_nll_rows` and `head_cross_entropy` scan over: the rows of
+    hidden (..., D) and labels (...) in chunks of `chunk_size` (rows it
+    does not divide run as one chunk), and the body's per-row routine: a
     chunk's logits (the product in hidden's dtype, as `nn.Dense` gives
-    them, then float32) exist inside a `jax.checkpoint`ed scan body only,
-    so neither the (rows, V) logits nor their cotangent are ever whole in
-    memory (16,384 x 20,480 bfloat16: 640 MB each). The kernel is cast
-    inside the body, so its gradient adds up over the chunks in float32.
-    Rows that `chunk_size` does not divide run as one chunk."""
+    them, then float32) and from them -log softmax[label] a row. The kernel
+    (D, V), kept float32, is cast inside the scan's body, so its gradient
+    adds up over the chunks in float32."""
     d = hidden.shape[-1]
     flat, lab = hidden.reshape(-1, d), labels.reshape(-1)
     n = flat.shape[0]
     chunk = chunk_size if n % chunk_size == 0 else n
 
-    @jax.checkpoint
-    def body(tot, xs):
-        h, lb = xs
+    def nll(h, lb):
         lg = jnp.dot(h, kernel.astype(h.dtype)).astype(jnp.float32)
         picked = jnp.take_along_axis(lg, lb[:, None], axis=-1)[:, 0]
-        return tot + jnp.sum(jax.nn.logsumexp(lg, axis=-1) - picked), None
+        return jax.nn.logsumexp(lg, axis=-1) - picked
 
-    tot, _ = jax.lax.scan(
-        body, jnp.float32(0.0),
-        (flat.reshape(-1, chunk, d), lab.reshape(-1, chunk)))
+    return nll, (flat.reshape(-1, chunk, d), lab.reshape(-1, chunk)), n
+
+
+@jax.named_scope("L_loss_head")
+def head_nll_rows(
+    hidden: jax.Array, kernel: jax.Array, labels: jax.Array,
+    chunk_size: int = 2048,
+) -> jax.Array:
+    """-log softmax(hidden @ kernel)[label] of every row, float32 in the
+    shape of `labels`, under an untied head, head and loss together a chunk
+    of rows at a time: hidden (..., D) in the compute dtype, kernel (D, V)
+    as it is kept (float32), labels (...) int. A chunk's logits exist
+    inside a `jax.checkpoint`ed scan body only, so neither the (rows, V)
+    logits nor their cotangent are ever whole in memory (16,384 x 20,480
+    bfloat16: 640 MB each). For a loss that weights its rows (the looped
+    family's, by exit probabilities that take gradients themselves)."""
+    nll, chunks, _ = _head_chunks(hidden, kernel, labels, chunk_size)
+    _, rows = jax.lax.scan(
+        jax.checkpoint(lambda _, xs: (None, nll(*xs))), None, chunks)
+    return rows.reshape(labels.shape)
+
+
+@jax.named_scope("L_loss_head")
+def head_cross_entropy(
+    hidden: jax.Array, kernel: jax.Array, labels: jax.Array,
+    chunk_size: int = 2048,
+) -> jax.Array:
+    """The mean of `head_nll_rows`, summed inside the scan (a carry in
+    place of the rows: the program the two families that call it compiled
+    to before the per-row form existed)."""
+    nll, chunks, n = _head_chunks(hidden, kernel, labels, chunk_size)
+
+    @jax.checkpoint
+    def body(tot, xs):
+        return tot + jnp.sum(nll(*xs)), None
+
+    tot, _ = jax.lax.scan(body, jnp.float32(0.0), chunks)
     return tot / n
 
 

@@ -220,6 +220,53 @@ def kimi_linear_loss_fn(model, params, batch, rng, model_state, train):
     return main, aux, model_state
 
 
+@jax.named_scope("L_exit_gate")
+def exit_distribution(gate_logits: jax.Array) -> jax.Array:
+    """log p of leaving a looped model after pass t, from the T gate logits
+    (T, ...): p_t = lambda_t prod_{j<t} (1 - lambda_j) for t < T and p_T =
+    prod_{j<T} (1 - lambda_j), lambda = sigmoid(logit); in logs, so a gate
+    far from 0 keeps its gradient. The last pass's gate decides nothing."""
+    zero = jnp.zeros_like(gate_logits[:1])
+    stayed = jnp.concatenate(
+        [zero, jnp.cumsum(jax.nn.log_sigmoid(-gate_logits[:-1]), 0)], 0)
+    leave = jnp.concatenate(
+        [jax.nn.log_sigmoid(gate_logits[:-1]), zero], 0)
+    return stayed + leave
+
+
+def ouro_loss_fn(model, params, batch, rng, model_state, train):
+    """The looped family's objective (Ouro's stage I): with l_t the
+    next-token cross-entropy a token of pass t's exit and p_t the exit
+    distribution its gates give (`exit_distribution`), mean over tokens of
+    sum_t p_t l_t - beta H(p): the expected loss under the learned exit
+    distribution, and an entropy term (a KL to the uniform prior over
+    exits) that keeps the gates from collapsing onto one pass; beta is the
+    config's `exit_entropy_weight`. The T heads-with-loss run as ONE
+    chunked pass over the T x B x S rows (`ops.head_nll_rows`): the logits
+    are never whole. Logged beside the loss: the mean l_t of every pass
+    (`ce_ut<t>`), the mean entropy (`exit_entropy`, nats) and the mean
+    exit pass (`exit_mean_step`, 1..T)."""
+    (hidden, gate_logits), _ = model.apply(
+        {"params": params}, batch["x"], head=False)
+    n_ut = hidden.shape[0]
+    nll = ops.head_nll_rows(
+        hidden, params["lm_head"]["kernel"],
+        jnp.broadcast_to(batch["y"], hidden.shape[:-1]))
+    log_p = exit_distribution(gate_logits)
+    with jax.named_scope("L_exit_gate"):
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, 0)
+        loss = jnp.mean(jnp.sum(p * nll, 0)
+                        - model.cfg.exit_entropy_weight * entropy)
+        steps = jnp.arange(1, n_ut + 1, dtype=jnp.float32)
+        aux = {"exit_entropy": jnp.mean(entropy),
+               "exit_mean_step": jnp.mean(jnp.tensordot(steps, p, 1))}
+    with jax.named_scope("L_loss_head"):
+        per_pass = jnp.mean(nll.reshape(n_ut, -1), -1)
+        aux.update({f"ce_ut{t + 1}": per_pass[t] for t in range(n_ut)})
+    return loss, aux, model_state
+
+
 def make_kd_loss_fn(teacher_model, teacher_params, temperature=7.0, alpha=0.3):
     """Distillation objective with a frozen teacher (kd.py:48-68, 110-142).
 

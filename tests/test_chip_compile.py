@@ -67,6 +67,10 @@ SHAPES = {
     # width 128, sixteen query heads a key-value head, no rotation; at width
     # 128 `auto_block` hands all three kernels the 1,024-tiles
     "nemotron_gqa_32on2_16k": (1, 16_384, 32, 2, 128, 0.0),
+    # ouro_2p6b_pp6's layers: plain multi-head attention, 16 q heads on 16
+    # kv heads of width 128, two sequences of 4,096 (the one cell with
+    # batch 2; under LONG_SEQ, so the 512-tiles)
+    "ouro_mha_16on16_2x4k": (2, 4096, 16, 16, 128, 0.0),
 }
 
 
