@@ -36,6 +36,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,6 +57,13 @@ LONG_SEQ_MAX_HEAD_DIM = 128
 _SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
 )
+# The forward kernel's two results that the backward reads, as
+# `_flash_fwd` names them. A caller whose remat can afford them
+# (B*N*S*(Dv bf16 + one float32) bytes a call) keeps them with
+# `policy=jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)`
+# and the forward kernel runs once; under any other policy the names are
+# identities and the kernel runs again in the remat.
+FLASH_RESIDUALS = ("flash_o", "flash_lse")
 
 
 def _dropout_keep(shape, seed_val, block_uid, rate):
@@ -435,6 +443,10 @@ def _flash_fwd(q3, k3, v3, seed, heads, scale, causal, blocks, dropout_rate,
                interpret):
     o, lse = _fwd(q3, k3, v3, seed, heads[0], heads[1], scale, causal,
                   blocks[0], blocks[1], dropout_rate, interpret)
+    # named in the kernel's own (B*N, S, Dv) layout: what the backward's
+    # delta and the caller's o_proj both start from (FLASH_RESIDUALS)
+    o = checkpoint_name(o, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return o, (q3, k3, v3, seed, o, lse)
 
 

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
+from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.models.layers import (
     apply_flash_attention, blocked_swiglu,
 )
@@ -377,8 +378,14 @@ class KimiLinear(nn.Module):
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
                 embedding_init=_INIT, name="tok_emb",
             )(tokens)
-        layer_cls = (nn.remat(KimiLinearLayer, prevent_cse=True)
-                     if cfg.remat else KimiLinearLayer)
+        # the attention layer's flash forward is kept, not run again: its
+        # o and lse are 130 MiB at 32 heads of 16,384 tokens; a layer with
+        # no flash call has nothing named and remats whole
+        layer_cls = (nn.remat(
+            KimiLinearLayer, prevent_cse=True,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FLASH_RESIDUALS),
+        ) if cfg.remat else KimiLinearLayer)
         for i in range(cfg.num_hidden_layers):
             x = layer_cls(
                 cfg, cfg.is_attention_layer(i), cfg.is_dense_layer(i),

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
+from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.models.layers import apply_flash_attention
 from solvingpapers_tpu.models.qwen3next import HeldExpertsMoE, _by_blocks
 from solvingpapers_tpu.ops import gated_delta, ssd
@@ -364,8 +365,14 @@ class NemotronH(nn.Module):
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
                 embedding_init=_INIT, name="tok_emb",
             )(tokens)
-        layer_cls = (nn.remat(NemotronHLayer, prevent_cse=True)
-                     if cfg.remat else NemotronHLayer)
+        # the attention layer's flash forward is kept, not run again: its
+        # o and lse are 130 MiB at 32 heads of 16,384 tokens; a layer with
+        # no flash call has nothing named and remats whole
+        layer_cls = (nn.remat(
+            NemotronHLayer, prevent_cse=True,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FLASH_RESIDUALS),
+        ) if cfg.remat else NemotronHLayer)
         for i, kind in enumerate(cfg.layer_pattern):
             x = layer_cls(cfg, kind, name=f"layer_{i}")(x)
         with jax.named_scope("L_loss_head"):

@@ -5,6 +5,7 @@ interpret-mode equality tests — forward and gradients, causal and
 bidirectional, MHA and GQA/MQA head layouts.
 """
 
+import collections
 import functools
 
 import jax
@@ -14,6 +15,7 @@ import pytest
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels import flash_attention
+from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 
 # sub-minute correctness core: `pytest -m fast` is the ~4-minute gate
 pytestmark = pytest.mark.fast
@@ -294,3 +296,90 @@ def test_value_width_of_its_own_grads_match_dense(n, n_kv, dk, dv):
     for name, a, b in zip("qkv", got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+# --- the forward kernel's results survive a caller's remat (FLASH_RESIDUALS)
+
+# (q heads, kv heads, key width, value width)
+KEPT = [pytest.param(4, 4, 16, 16, id="mha"),
+        pytest.param(32, 2, 16, 16, id="gqa_32on2"),
+        pytest.param(4, 4, 24, 16, id="keys192_values128")]
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (a remat's
+    body, a custom rule's, a jit's), a kernel's own body apart."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+def _kernel_calls(fn, *args):
+    return collections.Counter(
+        eqn.params["name"]
+        for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "pallas_call")
+
+
+def _layer_like(n, n_kv, dk, dv, s=64, width=24):
+    """A layer as the families wrap one in remat: projections, the flash
+    call, an output projection; and its inputs."""
+    keys = jax.random.split(jax.random.key(11), 5)
+    x = jax.random.normal(keys[0], (1, s, width))
+    w = {"q": jax.random.normal(keys[1], (width, n * dk)) * 0.2,
+         "k": jax.random.normal(keys[2], (width, n_kv * dk)) * 0.2,
+         "v": jax.random.normal(keys[3], (width, n_kv * dv)) * 0.2,
+         "o": jax.random.normal(keys[4], (n * dv, width)) * 0.2}
+
+    def layer(w, x):
+        q = (x @ w["q"]).reshape(1, s, n, dk)
+        k = (x @ w["k"]).reshape(1, s, n_kv, dk)
+        v = (x @ w["v"]).reshape(1, s, n_kv, dv)
+        ctx = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+        return x + jnp.tanh(ctx.reshape(1, s, n * dv) @ w["o"])
+
+    return layer, w, x
+
+
+@pytest.mark.parametrize("n,n_kv,dk,dv", KEPT)
+def test_forward_kernel_runs_once_under_a_remat_that_keeps_its_results(
+        n, n_kv, dk, dv):
+    """Under `save_only_these_names(*FLASH_RESIDUALS)` the gradient of two
+    rematerialised layers holds one forward kernel a layer; under a remat
+    with no policy two (the names are identities there); the backward
+    kernels one a layer either way; output and gradients bit for bit."""
+    layer, w, x = _layer_like(n, n_kv, dk, dv)
+
+    def loss(remat):
+        two = lambda w, x: jnp.sum(remat(layer)(w, remat(layer)(w, x)) ** 2)  # noqa: E731
+        return jax.value_and_grad(two, argnums=(0, 1))
+
+    kept = loss(functools.partial(
+        jax.checkpoint, prevent_cse=True,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS)))
+    plain = loss(functools.partial(jax.checkpoint, prevent_cse=True))
+    backward = {"flash_mla_bwd_dq": 2, "flash_mla_bwd_dkv": 2}
+    assert _kernel_calls(kept, w, x) == {"flash_mla_fwd": 2, **backward}
+    assert _kernel_calls(plain, w, x) == {"flash_mla_fwd": 4, **backward}
+    got, want = jax.jit(kept)(w, x), jax.jit(plain)(w, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("n,n_kv,dk,dv", KEPT)
+def test_names_stand_in_the_forward_rule_only(n, n_kv, dk, dv):
+    """`_flash`, the primal, names nothing; differentiated, the two
+    residuals carry FLASH_RESIDUALS, in the kernel's own layout."""
+    layer, w, x = _layer_like(n, n_kv, dk, dv)
+    names = lambda fn: [  # noqa: E731
+        (eqn.params["name"], eqn.outvars[0].aval.shape)
+        for eqn in _equations(jax.make_jaxpr(fn)(w, x).jaxpr)
+        if eqn.primitive.name == "name"]
+    assert names(layer) == []
+    assert names(jax.checkpoint(layer, prevent_cse=True)) == []
+    s = x.shape[1]
+    assert sorted(names(jax.grad(lambda w, x: jnp.sum(layer(w, x))))) == [
+        ("flash_lse", (n, 1, s)), ("flash_o", (n, s, dv))]

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax import linen as nn
 
 from benchmarks.adapters import nemotron_h as adapter
 from benchmarks.drivers.train_job import Rows
@@ -358,8 +359,10 @@ def test_registry_holds_the_published_sizes_and_the_factory_builds_it():
     assert init_fn_for(small) is None
 
 
-def test_train_step_names_the_new_layers():
-    cfg = tiny(dtype="float32", remat=True)
+@pytest.mark.parametrize("use_flash", [False, True],
+                         ids=["dense_attention", "flash"])
+def test_train_step_names_the_new_layers(use_flash):
+    cfg = tiny(dtype="float32", remat=True, use_flash=use_flash)
     trainer = Trainer(
         NemotronH(cfg), TrainConfig(steps=2, batch_size=B, log_every=1),
         loss_fn=kimi_linear_loss_fn,
@@ -395,8 +398,46 @@ def test_train_step_names_the_new_layers():
         if s.top_level:
             seen.setdefault(m.group(2), set()).add(s.pass_)
     assert seen == {"ssd_fwd": {"fwd", "remat"}, "ssd_bwd": {"bwd"}}
+    # the flash kernels are scopes of their own; the layers' remat keeps
+    # the forward kernel's o and lse (FLASH_RESIDUALS), so it stands in
+    # the step alone, while the projections around it run again
+    flash = {k: {s.pass_ for s in top if s.layer == k}
+             for k in hlo_cost.KERNEL_SCOPES}
+    assert flash == ({"flash_mla_fwd": {"fwd"}, "flash_mla_bwd_dq": {"bwd"},
+                      "flash_mla_bwd_dkv": {"bwd"}} if use_flash
+                     else dict.fromkeys(hlo_cost.KERNEL_SCOPES, set()))
+    assert {s.pass_ for s in top if s.layer == "L_attn_proj"} == {
+        "fwd", "remat", "bwd"}
     covered = sum(s.layer is not None for s in top) / len(top)
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
+
+
+def test_keeping_the_flash_results_changes_no_bit_of_loss_or_gradient(
+        monkeypatch):
+    """The layers' remat with `save_only_these_names(*FLASH_RESIDUALS)`
+    against the same model under a plain `nn.remat(..., prevent_cse=True)`:
+    one forward kernel in the gradient where the plain one holds two, the
+    loss and every gradient leaf bit for bit."""
+    # published layers 0-5, M E M E M *: the sixth is the attention layer
+    cfg = tiny(dtype="float32", remat=True, use_flash=True,
+               num_hidden_layers=6)
+    _, _, tree = seeded(cfg)
+    model, b = NemotronH(cfg), batch()
+
+    def run():  # a new function a call: JAX keeps a trace by the function
+        program = jax.value_and_grad(lambda p: kimi_linear_loss_fn(
+            model, p, b, jax.random.key(0), None, True)[0])
+        return jax.jit(program)(tree), str(
+            jax.make_jaxpr(program)(tree)).count("name=flash_mla_fwd")
+
+    kept, n_kept = run()
+    remat = nn.remat
+    monkeypatch.setattr(nn, "remat", lambda cls, policy, **kw: remat(cls, **kw))
+    plain, n_plain = run()
+    assert (n_kept, n_plain) == (1, 2)  # one attention layer of the six
+    for a, c in zip(jax.tree.leaves(kept), jax.tree.leaves(plain),
+                    strict=True):
+        np.testing.assert_array_equal(a, c)
 
 
 def test_cli_serve_refuses_the_family_and_list_names_it(capsys):
