@@ -410,7 +410,8 @@ def _serve_model(args, *, quiet_random_init: bool = False):
               "export the stage-stacked params to the dense family first",
               file=sys.stderr)
         return 2
-    if cfg.model_family in ("qwen3next", "kimi_linear", "nemotron_h"):
+    if cfg.model_family in ("qwen3next", "kimi_linear", "nemotron_h",
+                            "granite_hybrid"):
         print(f"serving is unsupported for the {cfg.model_family} family: "
               "its recurrent layers (Gated DeltaNet, Kimi Delta Attention, "
               "Mamba-2) keep recurrent state, and no cache manager here "

@@ -47,6 +47,10 @@ def build_model(cfg: RunConfig):
         from solvingpapers_tpu.models.ouro import Ouro
 
         return Ouro(cfg.model)
+    if fam == "granite_hybrid":
+        from solvingpapers_tpu.models.granite_hybrid import GraniteHybrid
+
+        return GraniteHybrid(cfg.model)
     if fam == "gpt_pipe":
         from solvingpapers_tpu.models.gpt_pipe import GPTPipe
 
@@ -92,7 +96,8 @@ def loss_fn_for(cfg: RunConfig):
         vae_loss_fn,
     )
     from solvingpapers_tpu.train.objectives import (
-        dsv3_loss_fn, kimi_linear_loss_fn, ouro_loss_fn, qwen3next_loss_fn,
+        dsv3_loss_fn, granite_hybrid_loss_fn, kimi_linear_loss_fn,
+        ouro_loss_fn, qwen3next_loss_fn,
     )
 
     return {
@@ -108,6 +113,7 @@ def loss_fn_for(cfg: RunConfig):
         # the same objective: cross-entropy alone, head and loss in chunks
         "nemotron_h": kimi_linear_loss_fn,
         "ouro": ouro_loss_fn,
+        "granite_hybrid": granite_hybrid_loss_fn,
         "vit": classification_loss_fn,
         "alexnet": classification_loss_fn,
         "kd": classification_loss_fn,

@@ -13,7 +13,7 @@ from solvingpapers_tpu.train.optim import OptimizerConfig
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     name: str
-    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | nemotron_h | ouro | vit | alexnet | ae | vae | kd
+    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | nemotron_h | ouro | granite_hybrid | vit | alexnet | ae | vae | kd
     model: Any
     train: TrainConfig
     data: dict = dataclasses.field(default_factory=dict)
@@ -801,6 +801,51 @@ def _ouro_2p6b() -> RunConfig:
               "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
         notes="published widths; run through a cut in depth (one pipeline "
               "stage's layers), see benchmarks/configs/ouro_2p6b_pp6.json",
+    )
+
+
+@register("granite4_h_micro")
+def _granite4_h_micro() -> RunConfig:
+    """granite-4.0-h-micro at its published size
+    (huggingface.co/ibm-granite/granite-4.0-h-micro config.json,
+    `granitemoehybrid` with no routed experts): 40 layers, each a mixer AND
+    a dense SwiGLU of 8,192 behind scaled residual adds (0.22); 36 Mamba-2
+    mixers (64 heads of 64 that share ONE group's B and C, state 128,
+    convolution of 4 with bias, chunks of 256) and 4 attention (32 heads on
+    8 of width 64, softmax at 1/64, no positions) at layers 5, 15, 25, 35;
+    hidden 2048, vocabulary 100,352, the embedding times 12 and tied to a
+    head whose logits are divided by 8. 3.19B parameters, 51 GB of training
+    state at 16 bytes: more than one chip holds; what runs is a cut, one
+    pipeline stage's layers and a slice of the vocabulary
+    (benchmarks/configs/granite4_h_micro_pp4.json sets `num_hidden_layers`
+    and `vocab_size`; the kinds of the layers are read from the published
+    `layer_types`). Training only: no decode cache holds recurrent state
+    yet (ROADMAP R-M7).
+
+    The job (assumed, the source states none): one sequence of 8,192 tokens
+    a step, AdamW 3e-4 beta=(0.9, 0.95) wd 0.1 clip 1.0, 100 steps of
+    warm-up -> cosine to 0.1*max; remat a layer."""
+    from solvingpapers_tpu.models.granite_hybrid import GraniteHybridConfig
+
+    return RunConfig(
+        name="granite4_h_micro",
+        model_family="granite_hybrid",
+        model=GraniteHybridConfig(),
+        train=TrainConfig(
+            steps=10_000, batch_size=1, log_every=50, eval_every=500,
+            eval_batches=4, ckpt_every=1000,
+            optimizer=OptimizerConfig(
+                name="adamw", max_lr=3e-4, warmup_steps=100,
+                total_steps=10_000, b1=0.9, b2=0.95, weight_decay=0.1,
+                grad_clip=1.0,
+            ),
+            tokens_per_step=8_192,
+        ),
+        data={"kind": "bpe", "path": None, "block_size": 8_192,
+              "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
+        notes="published widths; run through a cut (one pipeline stage's "
+              "layers, a vocabulary slice), see "
+              "benchmarks/configs/granite4_h_micro_pp4.json",
     )
 
 

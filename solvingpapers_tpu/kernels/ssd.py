@@ -7,12 +7,15 @@ inside VMEM and nothing of them reaches HBM; the float32 state (a group's
 heads stacked, (R P, N)) is a VMEM scratch that rides the grid's last,
 sequential axis, as `kernels/gated_delta.py` carries the delta rules'.
 
-Forward (`ssd_fwd`), one streaming pass: grid (B, G, tiles), a grid step
-holds `nc` chunks of one group: x (tokens, R P) with the group's R heads side
-by side along the lanes as the convolution hands them, B and C (tokens, N),
-and the float32 step as rows (R, Q), the tokens along the lanes. What no
-state enters (`_system`: the running sums L, a product with a triangle of
-ones; e^L and the writing weights e^(L_Q - L_s) dt_s, made as rows and turned
+Forward (`ssd_fwd`), one streaming pass: grid (B, G K, tiles), a grid step
+holds `nc` chunks of one BLOCK of a group's heads (K blocks a group: a group
+of up to `HEADS_A_STEP` heads is one block, a wider one, 64 heads that share
+ONE group, is cut into blocks of that many, and the blocks of B and C ignore
+which block of their group reads them): x (tokens, R P) with the block's R
+heads side by side along the lanes as the convolution hands them, B and C
+(tokens, N), and the float32 step as rows (R, Q), the tokens along the
+lanes. What no state enters (`_system`: the running sums L, a product
+with a triangle of ones; e^L and the writing weights e^(L_Q - L_s) dt_s, made as rows and turned
 to columns by a product with the identity; the decays e^(L_t - L_s), masked
 to -inf BEFORE the exponential; C B^T once a group; the chunk's own part and
 the skip term) is made for the step's chunks at once; what meets the state (`_step`: C S_0^t scaled by e^L, and the state's
@@ -26,9 +29,11 @@ Backward (`ssd_bwd`), one streaming pass in reverse with dS as the carry: a
 grid step makes its chunks' sums, decays and C B^T again (`jax.vjp` of
 `_system`), walks its chunks forwards from the saved entering state and
 backwards with dS (`jax.vjp` of `_step`), and writes dx, dB and dC (summed
-over the group's heads by construction), d(dt) as rows, and what d(a), d(d)
-and d(state) add up from in blocks that stay in VMEM along the sequence. It
-is the derivative of the forward program as written, product for product.
+over the block's heads by construction; where a group has several blocks
+each writes its own in float32 and they are added outside), d(dt) as rows,
+and what d(a), d(d) and d(state) add up from in blocks that stay in VMEM
+along the sequence. It is the derivative of the forward program as written,
+product for product.
 
 Products take their operands in x's dtype and add up in float32 (the sums
 with the triangle and the identity: float32 at the highest precision, both
@@ -54,8 +59,13 @@ from solvingpapers_tpu.kernels.gated_delta import (
     F32, HI, LANES, _PARAMS, _at, _dot, _split)
 
 # chunks a grid step holds: what no state enters is made for all of them at
-# once, and the saved entering states are one a grid step
+# once, and the saved entering states are one a grid step; at most
+# `TOKENS_A_STEP` tokens of them, since a chunk's decays are (Q, Q) a head
 CHUNKS_A_STEP = 4
+TOKENS_A_STEP = 512
+# heads a grid step holds: a group's, or where a group has more (one group of
+# 64) a block of this many, with B and C read again by every block
+HEADS_A_STEP = 8
 # the backward holds what four chunks' `_system` made and its cotangents at
 # once: 18 MiB at the published widths, over the 16 the compiler grants unasked
 _BWD_PARAMS = pltpu.CompilerParams(
@@ -68,9 +78,10 @@ class _Plan(NamedTuple):
 
     chunk: int
     nc: int  # chunks a grid step
-    r: int  # heads a group
+    r: int  # heads a grid step: a group's, or a block of them
     p: int  # a head's width (padded)
     interpret: bool
+    blocks: int = 1  # blocks of heads a group
 
     @property
     def hp(self) -> int:
@@ -262,22 +273,27 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, st_ref, dy_ref,
 
 
 def _specs(plan: _Plan, n: int, tiles: int, reverse: bool):
-    """Block specs on a grid (B, G, tiles) of x or y (B, S, H P), of b or c
-    (B, S, G N), of the step's rows (B, G, chunks, R, Q), of a (G, R, 1), of
-    d (G, 1, R P), of a state a group (B, G, R P, N) and of a grid step's
-    entering state (B, G, tiles, R P, N); `reverse` walks the tiles from the
-    last to the first."""
+    """Block specs on a grid (B, G K, tiles), K blocks of R heads a group,
+    of x or y (B, S, H P), of b or c (B, S, G N), of the step's rows (B, G K,
+    chunks, R, Q), of a (G K, R, 1), of d (G K, 1, R P), of a state a block
+    (B, G K, R P, N) and of a grid step's entering state (B, G K, tiles, R P,
+    N); `reverse` walks the tiles from the last to the first. Last, of what
+    a block adds to db or dc where a group has several, (B, S, G K N)."""
     nc, q, rp = plan.nc, plan.chunk, plan.r * plan.p
     at = (lambda t: tiles - 1 - t) if reverse else (lambda t: t)
+    own = pl.BlockSpec((1, nc * q, n), lambda i, g, t: (i, at(t), g))
+    k = plan.blocks
     return (
         pl.BlockSpec((1, nc * q, rp), lambda i, g, t: (i, at(t), g)),
-        pl.BlockSpec((1, nc * q, n), lambda i, g, t: (i, at(t), g)),
+        own if k == 1 else pl.BlockSpec(
+            (1, nc * q, n), lambda i, g, t: (i, at(t), g // k)),
         pl.BlockSpec((1, 1, nc, plan.r, q),
                      lambda i, g, t: (i, g, at(t), 0, 0)),
         pl.BlockSpec((1, plan.r, 1), lambda i, g, t: (g, 0, 0)),
         pl.BlockSpec((1, 1, rp), lambda i, g, t: (g, 0, 0)),
         pl.BlockSpec((1, 1, rp, n), lambda i, g, t: (i, g, 0, 0)),
         pl.BlockSpec((1, 1, 1, rp, n), lambda i, g, t: (i, g, at(t), 0, 0)),
+        own,
     )
 
 
@@ -288,7 +304,7 @@ def _like(v):
 def _forward(plan: _Plan, x, b, c, dt, a, d, state, keep_states: bool):
     bsz, groups, rp, n = state.shape
     tiles = x.shape[1] // (plan.nc * plan.chunk)
-    xs, bs, dts, a_s, d_s, ss, sts = _specs(plan, n, tiles, False)
+    xs, bs, dts, a_s, d_s, ss, sts, _ = _specs(plan, n, tiles, False)
     out_shape, out_specs = [_like(x), _like(state)], [xs, ss]
     if keep_states:
         out_shape.append(jax.ShapeDtypeStruct(
@@ -309,7 +325,11 @@ def _forward(plan: _Plan, x, b, c, dt, a, d, state, keep_states: bool):
 
 def _backward(plan: _Plan, x, b, c, dt, a, d, states, dy, d_last):
     bsz, groups, tiles, rp, n = states.shape
-    xs, bs, dts, a_s, d_s, ss, sts = _specs(plan, n, tiles, True)
+    xs, bs, dts, a_s, d_s, ss, sts, dbs = _specs(plan, n, tiles, True)
+    # a block's own db and dc where a group has several: float32, as the
+    # sum over a group's heads is inside the kernel
+    d_b = _like(b) if plan.blocks == 1 else jax.ShapeDtypeStruct(
+        (bsz, x.shape[1], groups * n), F32)
     # d(a) and d(d) a batch row: summed over it outside
     per_row = lambda v: (  # noqa: E731
         jax.ShapeDtypeStruct((bsz,) + v.shape, F32),
@@ -319,9 +339,9 @@ def _backward(plan: _Plan, x, b, c, dt, a, d, states, dy, d_last):
         functools.partial(_bwd_kernel, plan=plan),
         grid=(bsz, groups, tiles),
         in_specs=[xs, bs, bs, dts, a_s, d_s, sts, xs, ss],
-        out_specs=[xs, bs, bs, dts, da_spec, dd_spec, ss],
-        out_shape=[_like(x), _like(b), _like(c), _like(dt), da_shape,
-                   dd_shape, _like(d_last)],
+        out_specs=[xs, dbs, dbs, dts, da_spec, dd_spec, ss],
+        out_shape=[_like(x), d_b, d_b, _like(dt), da_shape, dd_shape,
+                   _like(d_last)],
         scratch_shapes=[pltpu.VMEM((rp, n), F32)],
         compiler_params=_BWD_PARAMS,
         interpret=plan.interpret,
@@ -341,8 +361,14 @@ def _rule_fwd(plan, x, b, c, dt, a, d, state):
 
 
 def _rule_bwd(plan, res, cts):
-    *d_args, d_a, d_d, d_state = _backward(plan, *res, *cts)
-    return (*d_args, d_a.sum(0), d_d.sum(0), d_state)
+    d_x, d_b, d_c, d_dt, d_a, d_d, d_state = _backward(plan, *res, *cts)
+    if plan.blocks > 1:
+        b = res[1]
+        over_blocks = lambda v: v.reshape(  # noqa: E731
+            v.shape[:2] + (-1, plan.blocks, d_state.shape[-1])
+        ).sum(3).reshape(b.shape).astype(b.dtype)
+        d_b, d_c = over_blocks(d_b), over_blocks(d_c)
+    return (d_x, d_b, d_c, d_dt, d_a.sum(0), d_d.sum(0), d_state)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -359,31 +385,36 @@ def ssd_chunked(x, dt, a, b, c, d, state, *, chunk: int,
     there."""
     bsz, s, h, p = x.shape
     g, n = b.shape[2:]
+    # heads a grid step, and blocks of them a group
     r = h // g
+    if r > HEADS_A_STEP and r % HEADS_A_STEP == 0:
+        r = HEADS_A_STEP
+    k = h // (g * r)
     if interpret is None:
         interpret = jax.devices()[0].platform == "cpu"
-    nc = min(CHUNKS_A_STEP, -(-s // chunk))
+    nc = min(CHUNKS_A_STEP, max(1, TOKENS_A_STEP // chunk), -(-s // chunk))
     pad_s = (-s) % (nc * chunk)
     pad_n = 0 if interpret else (-n) % LANES
     wide = p if interpret else _lane_width(r, p)
     pad_p = wide - p
-    plan = _Plan(chunk, nc, r, wide, interpret)
+    plan = _Plan(chunk, nc, r, wide, interpret, k)
     widen = lambda v, w: jnp.pad(  # noqa: E731
         v, ((0, 0), (0, pad_s), (0, 0), (0, w)))
     s_all = s + pad_s
     x2 = widen(x, pad_p).reshape(bsz, s_all, h * wide)
     b2 = widen(b, pad_n).reshape(bsz, s_all, -1)
     c2 = widen(c, pad_n).reshape(bsz, s_all, -1)
-    # the step as rows, the tokens along the lanes: (B, G, chunks, R, Q)
+    # the step as rows, the tokens along the lanes: (B, G K, chunks, R, Q)
+    gk = g * k
     rows = jnp.pad(dt.astype(F32), ((0, 0), (0, pad_s), (0, 0))).reshape(
-        bsz, s_all // chunk, chunk, g, r).transpose(0, 3, 1, 4, 2)
+        bsz, s_all // chunk, chunk, gk, r).transpose(0, 3, 1, 4, 2)
     d2 = jnp.broadcast_to(
-        d.astype(F32).reshape(g, 1, r, 1), (g, 1, r, wide)).reshape(
-            g, 1, r * wide)
+        d.astype(F32).reshape(gk, 1, r, 1), (gk, 1, r, wide)).reshape(
+            gk, 1, r * wide)
     state2 = jnp.pad(state.astype(F32), (
         (0, 0), (0, 0), (0, pad_p), (0, pad_n))).reshape(
-            bsz, g, r * wide, n + pad_n)
-    y, last = _rule(plan, x2, b2, c2, rows, a.astype(F32).reshape(g, r, 1),
+            bsz, gk, r * wide, n + pad_n)
+    y, last = _rule(plan, x2, b2, c2, rows, a.astype(F32).reshape(gk, r, 1),
                     d2, state2)
     return (y.reshape(bsz, s_all, h, wide)[:, :s, :, :p],
             last.reshape(bsz, h, wide, n + pad_n)[:, :, :p, :n])

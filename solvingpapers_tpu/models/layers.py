@@ -415,7 +415,8 @@ def apply_flash_attention(module, q, k, v, *, causal, scale=None,
     use_flash model (Attention here, DeepSeekV3's MLA, Qwen3-Next's gated
     attention at 16 heads on 2 of width 256, Kimi-Linear's latent attention
     with keys 192 and values 128 wide, Nemotron-H's 32 heads on 2 of width
-    128 with no rotation: q (B, S, n, w), k and v (B, S, n_kv, w), each
+    128 and Granite-hybrid's 32 on 8 of width 64, both with no rotation:
+    q (B, S, n, w), k and v (B, S, n_kv, w), each
     key-value head serving n / n_kv query heads): in-kernel prob
     dropout on real TPU (same Bernoulli semantics as the dense path; mask
     regenerated in the backward from the seed, never materialized); when
@@ -484,12 +485,14 @@ def _by_blocks(fn, block: int, *arrays):
 
 
 def blocked_swiglu(x, norm_w, w_gate, w_up, w_down, *, eps: float,
-                   block: int, post_norm_w=None):
+                   block: int, post_norm_w=None, scale: float | None = None):
     """x + SwiGLU(Norm(x)) block by block under the scope `L_dense_ffn`:
     the dense feed-forward half of a layer (Kimi-Linear's leading layer,
-    every Ouro layer). x (B, S, D) float32; the matrices already in the
-    compute dtype. With `post_norm_w` the sub-block's output is normed
-    before the add (Ouro's sandwich)."""
+    every Ouro layer, every Granite-hybrid layer). x (B, S, D) float32; the
+    matrices already in the compute dtype. With `post_norm_w` the
+    sub-block's output is normed before the add (Ouro's sandwich); with
+    `scale` it is multiplied by that factor in the add (Granite's
+    `residual_multiplier`)."""
     dt = w_gate.dtype
 
     def ffn(x):
@@ -498,7 +501,7 @@ def blocked_swiglu(x, norm_w, w_gate, w_up, w_down, *, eps: float,
         y = (h @ w_down).astype(jnp.float32)
         if post_norm_w is not None:
             y = ops.rms_norm(y, post_norm_w, eps)
-        return x + y
+        return x + (y if scale is None else scale * y)
 
     with jax.named_scope("L_dense_ffn"):
         return _by_blocks(ffn, block, x)

@@ -34,7 +34,7 @@ names).
     [first_expert, first_expert + n_routed_experts), through capacity slots
     with no exchange; plus the shared expert of the same form. With
     `n_group` = `topk_group` = 1 the source's group-limited choice is plain
-    top-k; wider groups are refused (ROADMAP R-M3).
+    top-k; wider groups are refused (ROADMAP R-M2).
 
 The residual stream is float32; products take bfloat16 operands over
 float32 weights (`dtype`); the router's product, the steps, the decays and
@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -134,7 +135,7 @@ class NemotronHConfig:
         if bad:
             raise ValueError(
                 f"nemotron_h: no path here for this value of {bad} "
-                "(ROADMAP R-M3, R-M11)")
+                "(ROADMAP R-M2, R-M11)")
         if len(self.layer_pattern) != self.num_hidden_layers:
             raise ValueError(
                 f"hybrid_override_pattern names "
@@ -163,8 +164,13 @@ class NemotronHConfig:
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
+    @property
+    def attention_scale(self) -> float:
+        """What multiplies q k^T before the softmax."""
+        return self.head_dim ** -0.5
 
-def _dt_bias_init(cfg: NemotronHConfig):
+
+def _dt_bias_init(cfg):
     """The inverse softplus of a step drawn log-uniformly between
     `time_step_min` and `time_step_max`, floored at `time_step_floor`."""
     lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
@@ -189,9 +195,11 @@ class Mamba2Mixer(nn.Module):
     that run block by block (`_by_blocks`): projections and step before the
     rule, gated norm and `out_proj` after it. Each stage is one loop under
     its own scope, so a device trace tells projections, convolution and rule
-    apart."""
+    apart. `cfg` is a `NemotronHConfig`, or another family's config that
+    names the same sizes (`models/granite_hybrid.py`'s: one group of 64
+    heads, chunks of 256); the residual add is the caller's."""
 
-    cfg: NemotronHConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self, x, norm_w):
@@ -258,9 +266,10 @@ class NoPEAttention(nn.Module):
     """Norm(x) -> grouped-query causal attention without positions. As in
     `Mamba2Mixer` the input norm is applied here and the per-token stages
     run block by block: the three projections before the attention product,
-    `o_proj` after it."""
+    `o_proj` after it. The softmax's scale is the config's
+    `attention_scale` (`cfg` as in `Mamba2Mixer`)."""
 
-    cfg: NemotronHConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self, x, norm_w):
@@ -287,10 +296,10 @@ class NoPEAttention(nn.Module):
         with jax.named_scope("L_attn_core"):
             if cfg.use_flash:
                 ctx = apply_flash_attention(
-                    self, q, k, v, causal=True, scale=hd ** -0.5)
+                    self, q, k, v, causal=True, scale=cfg.attention_scale)
             else:
                 ctx = ops.dot_product_attention(
-                    q, k, v, causal=True, scale=hd ** -0.5)
+                    q, k, v, causal=True, scale=cfg.attention_scale)
         with jax.named_scope("L_attn_proj"):
             return _by_blocks(lambda c: c @ w_out, ssd.SEGMENT,
                               ctx.reshape(b, s, n * hd).astype(dt))

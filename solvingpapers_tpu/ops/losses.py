@@ -158,9 +158,13 @@ def head_nll_rows(
     chunk_size: int = 2048,
 ) -> jax.Array:
     """-log softmax(hidden @ kernel)[label] of every row, float32 in the
-    shape of `labels`, under an untied head, head and loss together a chunk
-    of rows at a time: hidden (..., D) in the compute dtype, kernel (D, V)
-    as it is kept (float32), labels (...) int. A chunk's logits exist
+    shape of `labels`, head and loss together a chunk of rows at a time:
+    hidden (..., D) in the compute dtype, kernel (D, V) float32, labels
+    (...) int. The kernel is an untied head's leaf as it is kept, or a TIED
+    head's: the embedding transposed (`granite_hybrid_loss_fn`), whose
+    gradient then reaches the one leaf twice, from the lookup and, summed
+    over the chunks in float32, from here; a scale on the logits is the
+    caller's, folded into `hidden`. A chunk's logits exist
     inside a `jax.checkpoint`ed scan body only, so neither the (rows, V)
     logits nor their cotangent are ever whole in memory (16,384 x 20,480
     bfloat16: 640 MB each). For a loss that weights its rows (the looped
@@ -178,7 +182,8 @@ def head_cross_entropy(
 ) -> jax.Array:
     """The mean of `head_nll_rows`, summed inside the scan (a carry in
     place of the rows: the program the two families that call it compiled
-    to before the per-row form existed)."""
+    to before the per-row form existed). Untied or tied kernel alike, as
+    there."""
     nll, chunks, n = _head_chunks(hidden, kernel, labels, chunk_size)
 
     @jax.checkpoint

@@ -220,6 +220,23 @@ def kimi_linear_loss_fn(model, params, batch, rng, model_state, train):
     return main, aux, model_state
 
 
+def granite_hybrid_loss_fn(model, params, batch, rng, model_state, train):
+    """The Granite-hybrid family's objective: next-token cross-entropy
+    under the TIED head. The model hands back its normed hidden rows with
+    1 / `logits_scaling` folded in; the head's kernel is the embedding
+    transposed, and head and loss run together a chunk of rows at a time
+    (`ops.head_cross_entropy`), so neither the logits nor their cotangent
+    are ever whole. The embedding's gradient is the float32 sum of what the
+    lookup and the chunks of the head give."""
+    hidden, _ = model.apply({"params": params}, batch["x"], head=False)
+    with jax.named_scope("L_loss_head"):
+        kernel = params["tok_emb"]["embedding"].T
+    main = ops.head_cross_entropy(hidden, kernel, batch["y"])
+    with jax.named_scope("L_loss_head"):
+        aux = {"perplexity": jnp.exp(main)}
+    return main, aux, model_state
+
+
 @jax.named_scope("L_exit_gate")
 def exit_distribution(gate_logits: jax.Array) -> jax.Array:
     """log p of leaving a looped model after pass t, from the T gate logits

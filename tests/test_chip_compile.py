@@ -71,6 +71,10 @@ SHAPES = {
     # kv heads of width 128, two sequences of 4,096 (the one cell with
     # batch 2; under LONG_SEQ, so the 512-tiles)
     "ouro_mha_16on16_2x4k": (2, 4096, 16, 16, 128, 0.0),
+    # granite4_h_micro_pp4's attention layer: 32 q heads on 8 kv heads of
+    # width 64 (half the lanes: every block's last dimension is the
+    # array's), one sequence of 8,192: the 1,024-tiles
+    "granite_gqa_32on8_w64_8k": (1, 8192, 32, 8, 64, 0.0),
 }
 
 
@@ -244,7 +248,7 @@ def test_gated_delta_rule_pads_narrow_heads_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
-def _compiled_ssd(one_chip, x_shape, groups, state, backward):
+def _compiled_ssd(one_chip, x_shape, groups, state, backward, chunk=128):
     """The state-space rule's kernels compiled for the described chip: x (B,
     S, H, P) bfloat16, B and C (B, S, groups, state) bfloat16, the step
     float32, every argument differentiated."""
@@ -260,7 +264,7 @@ def _compiled_ssd(one_chip, x_shape, groups, state, backward):
 
     def loss(x, dt, a, b, c, d, entering):
         # interpret=False: the default would ask jax.devices(), the CPU here
-        y, last = ssd_chunked(x, dt, a, b, c, d, entering, chunk=128,
+        y, last = ssd_chunked(x, dt, a, b, c, d, entering, chunk=chunk,
                               interpret=False)
         return jnp.sum(y.astype(jnp.float32)) + jnp.sum(last)
 
@@ -283,6 +287,24 @@ def test_ssd_rule_compiles_for_v5e(one_chip, backward):
     # besides the gradients: the entering states (32 grid steps x 64 heads x
     # 64 x 128 float32, 64 MiB) and the step's rows; nothing of size Q x Q
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2 ** 30
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_ssd_rule_at_one_group_of_64_heads_compiles_for_v5e(
+        one_chip, backward):
+    """granite4_h_micro_pp4's mixer: 64 heads of 64 on ONE group of state
+    128, one sequence of 8,192, chunks of 256. A grid step is two chunks of
+    a block of eight heads (the group's 64 at once would be 64 MiB of decay
+    matrices), eight blocks read the group's B and C; Mosaic takes the
+    blocks and the backward's VMEM inside the 32 MiB the kernel asks for.
+    Besides the gradients: the entering states (16 steps x 64 heads, 32 MiB)
+    and every block's own dB and dC in float32 (64 MiB), summed outside."""
+    compiled = _compiled_ssd(one_chip, (1, 8192, 64, 64), 1, 128, backward,
+                             chunk=256)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + backward
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * 2 ** 30
 
 
 def test_ssd_rule_pads_narrow_heads_for_v5e(one_chip):

@@ -801,6 +801,7 @@ def test_balance_loss_recovers_induced_overload():
     tx = optax.adam(2e-2)
     opt_state = tx.init(params)
 
+    @jax.jit  # 120 steps op by op took minutes of the suite's time
     def step(params, opt_state, key):
         def loss_fn(p):
             loss, aux, _ = dsv3_loss_fn(model, p, batch, key, ms, True)

@@ -73,6 +73,35 @@ def test_grads_match_dense(b, sq, skv, n, n_kv, d, causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-4, atol=2e-4)
 
 
+def test_32_on_8_at_width_64_with_a_scale_of_its_own():
+    """The Granite-hybrid attention layer's shape: 32 query heads on 8
+    key-value heads (query head i reads key-value head i // 4) of width 64,
+    half the 128 lanes, and a softmax scaled by 1/64 where the default is
+    64^-0.5 = 1/8: forward and the three gradients against the dense
+    reference at that scale, and not equal to it at the default."""
+    q, k, v = make_qkv(jax.random.key(7), 1, 128, 128, 32, 8, 64)
+    mix = jax.random.normal(jax.random.key(8), (1, 128, 32, 64))
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True, **kw) * mix)
+
+    flash = functools.partial(flash_attention, interpret=True, block_q=64,
+                              block_k=64)
+    out = flash(q, k, v, causal=True, scale=1 / 64)
+    want = ops.dot_product_attention(q, k, v, causal=True, scale=1 / 64)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    assert float(jnp.max(jnp.abs(flash(q, k, v, causal=True) - want))) > 0.1
+    # a key-value head serves its four query heads and no other
+    moved = flash(q, k, v.at[:, :, 3].add(1.0), causal=True, scale=1 / 64)
+    changed = np.asarray(jnp.max(jnp.abs(moved - out), axis=(0, 1, 3)) > 1e-6)
+    assert changed.tolist() == [12 <= i < 16 for i in range(32)]
+    got = jax.grad(loss(flash, scale=1 / 64), argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(ops.dot_product_attention, scale=1 / 64),
+                   argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, ref):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
+
+
 def test_causal_seq_q_longer_than_seq_k():
     """seq_q > seq_k causal: the end-aligned mask leaves the earliest q rows
     with no visible kv. The kernel emits 0 for those rows (guarded softmax

@@ -562,10 +562,11 @@ def test_cp_pp_export_to_dense_decodes(devices):
     from jax.sharding import PartitionSpec as P
 
     toks = jnp.zeros((2, 32), jnp.int32)
-    params = jax.shard_map(
+    # jitted: op by op, the init under shard_map took minutes
+    params = jax.jit(jax.shard_map(
         lambda x: model.init({"params": jax.random.key(0)}, x)["params"],
         mesh=mesh, in_specs=P(("data",), "context"), out_specs=P(),
-    )(toks)
+    ))(toks)
     gpt, dense_params = model.to_dense(jax.device_get(params))
     assert not gpt.cfg.context_parallel
     from solvingpapers_tpu.infer import generate

@@ -49,7 +49,7 @@ def test_ring_attention_grads_flow(devices):
     def loss_dense(q, k, v):
         return jnp.sum(ops.dot_product_attention(q, k, v, causal=True) ** 2)
 
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gr, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
@@ -119,9 +119,10 @@ def test_llama_context_parallel_training_matches_dense(devices):
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return jnp.mean(nll)
 
-    l_cp, g_cp = jax.value_and_grad(
+    # jitted: op by op, the ring's shard_map took minutes of the suite's time
+    l_cp, g_cp = jax.jit(jax.value_and_grad(
         lambda p: cp_loss(p, toks, positions, targets)
-    )(params)
+    ))(params)
     l_d, g_d = jax.value_and_grad(dense_loss)(params)
     np.testing.assert_allclose(float(l_cp), float(l_d), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g_cp), jax.tree.leaves(g_d)):
@@ -250,7 +251,7 @@ def test_ring_flash_grads_match_dense(devices):
     def loss_dense(q, k, v):
         return jnp.sum(ops.dot_product_attention(q, k, v, causal=True) ** 2)
 
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gr, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -273,12 +274,12 @@ def test_cp_llama_ring_flash_forward_matches_dense(devices):
     mesh = create_mesh(MeshConfig(data=2, context=4), devices)
     toks = jax.random.randint(jax.random.key(10), (2, 128), 0, 64)
     params = dense.init({"params": jax.random.key(11)}, toks)["params"]
-    out = jax.shard_map(
+    out = jax.jit(jax.shard_map(
         lambda p, x: cp.apply({"params": p}, x)[0],
         mesh=mesh, in_specs=(P(), P(("data",), "context")),
         out_specs=P(("data",), "context", None),
         check_vma=False,  # pallas-in-scan vs the jax-0.9 vma checker
-    )(params, toks)
+    ))(params, toks)
     ref, _ = dense.apply({"params": params}, toks)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)

@@ -5,7 +5,10 @@ written out here, values and every gradient, in float32 and with bfloat16
 operands; a sequence that is no whole number of chunks or of grid steps;
 heads that share their group's B and C, and heads that share a tile of
 lanes; a step so large that the decay underflows; the state handed from one
-call to the next and differentiated; the gated norm."""
+call to the next and differentiated; one group of many heads cut into
+blocks of heads that read the same B and C (16 and 32 heads at a tiny size,
+and 64 heads of 64 at chunks of 256, the Granite-hybrid shape); the gated
+norm."""
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +60,14 @@ def objective(fn, **kw):
         out = fn(x, dt, a, b, c, d, **kw)
         y = out[0] if isinstance(out, tuple) else out
         return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+    return f
+
+
+def both_outputs(fn, **kw):
+    def f(*args7):
+        y, last = fn(*args7[:6], state=args7[6], **kw)
+        return jnp.sum(jnp.sin(y.astype(jnp.float32))) + jnp.sum(
+            jnp.cos(last))
     return f
 
 
@@ -177,15 +188,10 @@ def test_every_gradient_with_a_state_handed_in(a_step, monkeypatch):
     args = inputs(seed=6, seq=37)
     state = jax.random.normal(jax.random.key(7), (B, H, P, N))
 
-    def both(fn, **kw):
-        def f(*args7):
-            y, last = fn(*args7[:6], state=args7[6], **kw)
-            return jnp.sum(jnp.sin(y)) + jnp.sum(jnp.cos(last))
-        return f
-
-    want = jax.grad(both(ssd.ssd_recurrent), argnums=range(7))(*args, state)
-    got = jax.grad(both(ssd.ssd_chunked, chunk=8), argnums=range(7))(
+    want = jax.grad(both_outputs(ssd.ssd_recurrent), argnums=range(7))(
         *args, state)
+    got = jax.grad(both_outputs(ssd.ssd_chunked, chunk=8),
+                   argnums=range(7))(*args, state)
     for g, w in zip(got, want):
         assert g.shape == w.shape
         close(g, w, 5e-5)  # float32's rounding of e^L where |L| is tens
@@ -211,6 +217,85 @@ def test_heads_that_share_a_tile_of_lanes(dtype, tol):
         rel = float(jnp.linalg.norm(g.astype(jnp.float32) - w)
                     / jnp.linalg.norm(w))
         assert rel < 50 * tol, rel
+
+
+@pytest.mark.parametrize("heads, groups", [(16, 1), (32, 2)])
+def test_a_group_of_many_heads_runs_as_blocks_of_heads(
+        heads, groups, monkeypatch):
+    """A group of 16 heads is two blocks of `HEADS_A_STEP` = 8 on the grid,
+    both reading the group's B and C; dB and dC add up over the blocks
+    outside the kernel. 50 tokens in grid steps of two chunks of 8; values,
+    the state, and every gradient, the entering state's among them; and the
+    same numbers as eight-head groups whose B and C repeat."""
+    monkeypatch.setattr(kernel, "CHUNKS_A_STEP", 2)
+    args = inputs(seed=9, heads=heads, groups=groups)
+    state = jax.random.normal(jax.random.key(10), (B, heads, P, N))
+    y, last = ssd.ssd_chunked(*args, state=state, chunk=8)
+    y_rec, s_rec = ssd.ssd_recurrent(*args, state=state)
+    close(y, y_rec)
+    close(last, s_rec)
+    x, dt, a, b, c, d = args
+    wide = ssd.ssd_chunked(x, dt, a, jnp.repeat(b, 2, axis=2),
+                           jnp.repeat(c, 2, axis=2), d, state=state, chunk=8)
+    close(y, wide[0], 1e-6)
+    want = jax.grad(both_outputs(ssd.ssd_recurrent), argnums=range(7))(
+        *args, state)
+    got = jax.grad(both_outputs(ssd.ssd_chunked, chunk=8),
+                   argnums=range(7))(*args, state)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        close(g, w, 5e-5)
+
+
+def test_the_plans_of_the_two_state_space_cells(monkeypatch):
+    """nemotron3_nano_ep16's call (8 groups of 8 heads, chunks of 128) is
+    built as it was: four chunks a grid step, a group's eight heads, one
+    block a group. granite4_h_micro_pp4's (ONE group of 64, chunks of 256):
+    two chunks a step (512 tokens, as there), eight blocks of eight."""
+    plans = []
+
+    def rule(plan, x, *rest):
+        plans.append(plan)
+        return x, rest[-1]
+
+    monkeypatch.setattr(kernel, "_rule", rule)
+    for groups, chunk in ((8, 128), (1, 256)):
+        x, dt, a, b, c, d = inputs(seq=1024, heads=64, groups=groups,
+                                   width=64)
+        kernel.ssd_chunked(x, dt, a, b, c, d, jnp.zeros((B, 64, 64, N)),
+                           chunk=chunk, interpret=True)
+    assert plans == [kernel._Plan(128, 4, 8, 64, True, 1),
+                     kernel._Plan(256, 2, 8, 64, True, 8)]
+
+
+def test_one_group_of_64_heads_at_chunks_of_256(monkeypatch):
+    """The Granite-hybrid mixer's shape: 64 heads of 64 on ONE group of
+    state 128, chunks of 256, float32, 300 tokens in two grid steps of one
+    chunk; values, state and every gradient against the rule token by
+    token. A chunk of 256 carries running sums four times a chunk of 64's,
+    and float32 rounds e^(L_t - L_s) at 6e-8 |L|: the gradients agree to
+    3e-4 of their largest entry (1.5e-4 seen) where chunks of 8 reach
+    5e-5."""
+    monkeypatch.setattr(kernel, "TOKENS_A_STEP", 256)
+    k = jax.random.split(jax.random.key(11), 7)
+    heads, width, n, seq = 64, 64, 128, 300
+    x = jax.random.normal(k[0], (1, seq, heads, width))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (1, seq, heads)) - 1.0)
+    a = -jnp.exp(jax.random.uniform(k[2], (heads,), minval=-2.0, maxval=2.7))
+    b = jax.random.normal(k[3], (1, seq, 1, n))
+    c = jax.random.normal(k[4], (1, seq, 1, n))
+    d = jax.random.normal(k[5], (heads,))
+    state = jax.random.normal(k[6], (1, heads, width, n))
+    args = (x, dt, a, b, c, d, state)
+    (y, last), vjp = jax.vjp(
+        lambda *v: ssd.ssd_chunked(*v[:6], state=v[6], chunk=256), *args)
+    (y_rec, s_rec), vjp_rec = jax.vjp(
+        lambda *v: ssd.ssd_recurrent(*v[:6], state=v[6]), *args)
+    close(y, y_rec)
+    close(last, s_rec)
+    cts = (jnp.cos(y_rec), jnp.sin(s_rec))
+    for g, w in zip(vjp(cts), vjp_rec(cts)):
+        close(g, w, 3e-4)
 
 
 def test_gate_then_group_norm_is_not_norm_then_gate():
