@@ -14,7 +14,15 @@ is made for the step's chunks at once, so their products overlap; what
 meets the state (`_step`: four small products a value head, bf16 operands
 and float32 sums) runs chunk after chunk. Only o is written, and under
 differentiation the float32 state entering each grid step (at 16,384
-tokens, 32 value heads of 128 x 128 and 256 tokens a step: 134 MB).
+tokens, 32 value heads of 128 x 128 and 256 tokens a step: 134 MB). Those
+two are all that anything after the forward kernel reads (the caller's gate
+and output projection start from o, the backward kernel walks from the
+entering states; q, k, v and the decays are its inputs), so the
+`custom_vjp`'s forward rule names them (`DELTA_RESIDUALS`): a caller whose
+layers are rematerialised and whose slice has the room keeps them by name
+and the forward kernel stands in its step once (`models/kimi_linear.py`);
+without such a policy the names are identities and the layer's remat runs
+the kernel again (`models/qwen3next.py`, which has no room for them).
 
 The value heads of a key head sit side by side along the lanes: a
 (C, grp*C) array holds [X_0 | X_1 | ...], one C x C matrix a value head
@@ -58,6 +66,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -67,6 +76,16 @@ LANES = 128
 # chunks a grid step holds: their systems are independent, so their
 # products fill the gaps in each other's chains of dependent ones
 CHUNKS_A_STEP = 4
+# The forward kernel's two results that anything after it reads, as
+# `_rule_fwd` names them (either decay: the rule is chosen by g's rank, not
+# by the name): o, which the caller's gate and output projection start
+# from, and the float32 state entering each grid step, which the backward
+# kernel walks from. A caller whose remat can afford them (B*S*Hv*dv in
+# v's dtype and B*Hv*tiles*dk*dv float32 a call) keeps them with
+# `policy=jax.checkpoint_policies.save_only_these_names(*DELTA_RESIDUALS)`
+# and the forward kernel runs once; under any other policy the names are
+# identities and the kernel runs again in the remat.
+DELTA_RESIDUALS = ("delta_o", "delta_states")
 
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -667,6 +686,10 @@ def _rule(plan: _Plan, q, k, v, rows, g_row):
 
 def _rule_fwd(plan, q, k, v, rows, g_row):
     o, states = _forward(plan, q, k, v, rows, g_row, keep_states=True)
+    # named in the kernel's own layouts, (B, S, Hv*dv) and (B, Hk, tiles,
+    # grp, dk, dv) float32 (DELTA_RESIDUALS)
+    o = checkpoint_name(o, DELTA_RESIDUALS[0])
+    states = checkpoint_name(states, DELTA_RESIDUALS[1])
     return o, (q, k, v, rows, g_row, states)
 
 

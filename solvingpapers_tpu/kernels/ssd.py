@@ -24,6 +24,14 @@ decay and the chunk's write) runs chunk after chunk. Heads narrower than the
 and multiply x's tile with each head's columns down a diagonal, so that no
 slice starts inside a tile. Only y is written and the state after the last
 token, and under differentiation the float32 state entering each grid step.
+In training y and those entering states are all that anything after the
+forward kernel reads (the caller's gate, norm and output projection start
+from y, the backward kernel walks from the entering states, and no training
+caller reads the last state), so the `custom_vjp`'s forward rule names the
+two (`SSD_RESIDUALS`): a caller whose layers are rematerialised and whose
+slice has the room keeps them by name and the forward kernel stands in its
+step once (`models/nemotron_h.py`, `models/granite_hybrid.py`); without
+such a policy the names are identities and the remat runs the kernel again.
 
 Backward (`ssd_bwd`), one streaming pass in reverse with dS as the carry: a
 grid step makes its chunks' sums, decays and C B^T again (`jax.vjp` of
@@ -52,6 +60,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -66,6 +75,17 @@ TOKENS_A_STEP = 512
 # heads a grid step holds: a group's, or where a group has more (one group of
 # 64) a block of this many, with B and C read again by every block
 HEADS_A_STEP = 8
+# The forward kernel's two results that anything after it reads in training,
+# as `_rule_fwd` names them: y, which the caller's gate, norm and output
+# projection start from, and the float32 state entering each grid step,
+# which the backward kernel walks from. A caller whose remat can afford
+# them (B*S*H*P in x's dtype and B*H*tiles*P*N float32 a call) keeps them
+# with `policy=jax.checkpoint_policies.save_only_these_names(
+# *SSD_RESIDUALS)` and the forward kernel runs once; under any other policy
+# the names are identities and the kernel runs again in the remat. The
+# state after the last token is not named: no training caller reads it, and
+# with y and the states kept nothing in the remat asks for the call.
+SSD_RESIDUALS = ("ssd_y", "ssd_states")
 # the backward holds what four chunks' `_system` made and its cotangents at
 # once: 18 MiB at the published widths, over the 16 the compiler grants unasked
 _BWD_PARAMS = pltpu.CompilerParams(
@@ -357,6 +377,10 @@ def _rule(plan: _Plan, x, b, c, dt, a, d, state):
 def _rule_fwd(plan, x, b, c, dt, a, d, state):
     y, last, states = _forward(plan, x, b, c, dt, a, d, state,
                                keep_states=True)
+    # named in the kernel's own layouts, (B, S, H P) and (B, G K, tiles,
+    # R P, N) float32 (SSD_RESIDUALS)
+    y = checkpoint_name(y, SSD_RESIDUALS[0])
+    states = checkpoint_name(states, SSD_RESIDUALS[1])
     return (y, last), (x, b, c, dt, a, d, states)
 
 

@@ -57,6 +57,7 @@ from flax import linen as nn
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
+from solvingpapers_tpu.kernels.ssd import SSD_RESIDUALS
 from solvingpapers_tpu.models.layers import blocked_swiglu
 from solvingpapers_tpu.models.nemotron_h import Mamba2Mixer, NoPEAttention
 from solvingpapers_tpu.ops import ssd
@@ -270,13 +271,16 @@ class GraniteHybrid(nn.Module):
             embedding_init=_INIT, name="tok_emb")
         with jax.named_scope("L_embed"):
             x = emb(tokens) * cfg.embedding_multiplier
-        # as `nemotron_h`: the attention layer's flash forward is kept (o
-        # and lse, 33 MiB at 32 heads of 64 over 8,192 tokens), not run
-        # again; a Mamba-2 layer has nothing named and remats whole
+        # as `nemotron_h`: the kernels' forward runs are kept, not run
+        # again: the attention layer's flash o and lse (33 MiB at 32 heads
+        # of 64 over 8,192 tokens), a Mamba-2 layer's y and the float32
+        # state entering each of its 16 grid steps (64 + 32 MiB at 64
+        # heads of 64 x 128); the mixer's projections and the SwiGLU
+        # behind it are made again
         layer_cls = (nn.remat(
             GraniteHybridLayer, prevent_cse=True,
             policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS),
+                *FLASH_RESIDUALS, *SSD_RESIDUALS),
         ) if cfg.remat else GraniteHybridLayer)
         for i, kind in enumerate(cfg.layer_pattern):
             x = layer_cls(cfg, kind, name=f"layer_{i}")(x)

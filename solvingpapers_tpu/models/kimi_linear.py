@@ -54,6 +54,7 @@ from flax import linen as nn
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
+from solvingpapers_tpu.kernels.gated_delta import DELTA_RESIDUALS
 from solvingpapers_tpu.models.layers import (
     apply_flash_attention, blocked_swiglu,
 )
@@ -378,13 +379,16 @@ class KimiLinear(nn.Module):
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
                 embedding_init=_INIT, name="tok_emb",
             )(tokens)
-        # the attention layer's flash forward is kept, not run again: its
-        # o and lse are 130 MiB at 32 heads of 16,384 tokens; a layer with
-        # no flash call has nothing named and remats whole
+        # the kernels' forward runs are kept, not run again: the attention
+        # layer's flash o and lse (130 MiB at 32 heads of 16,384 tokens),
+        # a KDA layer's o and the float32 state entering each of its 64
+        # grid steps (128 + 128 MiB at 32 heads of 128 x 128); everything
+        # else of a layer is made again, and the dense layer's feed-forward
+        # part, with nothing named, remats whole
         layer_cls = (nn.remat(
             KimiLinearLayer, prevent_cse=True,
             policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS),
+                *FLASH_RESIDUALS, *DELTA_RESIDUALS),
         ) if cfg.remat else KimiLinearLayer)
         for i in range(cfg.num_hidden_layers):
             x = layer_cls(

@@ -58,6 +58,7 @@ from flax import linen as nn
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
+from solvingpapers_tpu.kernels.ssd import SSD_RESIDUALS
 from solvingpapers_tpu.models.layers import apply_flash_attention
 from solvingpapers_tpu.models.qwen3next import HeldExpertsMoE, _by_blocks
 from solvingpapers_tpu.ops import gated_delta, ssd
@@ -374,13 +375,16 @@ class NemotronH(nn.Module):
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
                 embedding_init=_INIT, name="tok_emb",
             )(tokens)
-        # the attention layer's flash forward is kept, not run again: its
-        # o and lse are 130 MiB at 32 heads of 16,384 tokens; a layer with
-        # no flash call has nothing named and remats whole
+        # the kernels' forward runs are kept, not run again: the attention
+        # layer's flash o and lse (130 MiB at 32 heads of 16,384 tokens),
+        # a Mamba-2 layer's y and the float32 state entering each of its 32
+        # grid steps (128 + 64 MiB at 64 heads of 64 x 128); everything
+        # else of a layer is made again, and an expert layer, with nothing
+        # named, remats whole
         layer_cls = (nn.remat(
             NemotronHLayer, prevent_cse=True,
             policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS),
+                *FLASH_RESIDUALS, *SSD_RESIDUALS),
         ) if cfg.remat else NemotronHLayer)
         for i, kind in enumerate(cfg.layer_pattern):
             x = layer_cls(cfg, kind, name=f"layer_{i}")(x)

@@ -5,7 +5,9 @@ This is the TPU-world substitute for a fake distributed backend
 8-device host mesh.
 """
 
+import collections
 import os
+import re
 
 # SPTPU_TEST_PLATFORM=tpu runs hardware-gated tests (e.g. the in-kernel
 # dropout suite — interpret-mode pltpu.prng_random_bits is a zero stub)
@@ -27,6 +29,7 @@ if _platform == "cpu":
     os.environ.setdefault("PJRT_NPROC", "12")
 
 import jax  # noqa: E402
+import numpy as np  # noqa: E402
 
 # The suite leaves JAX's persistent compilation cache off: entry points under
 # test (cli.main) point it at <repo>/.jax_cache, and tests must neither read
@@ -42,6 +45,88 @@ def devices():
     if len(devs) != 8:
         pytest.skip(f"needs the 8-virtual-device CPU mesh, have {len(devs)}")
     return devs
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (a remat's
+    body, a custom rule's, a jit's), a kernel's own body apart."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+
+def kernel_calls(fn, *args):
+    """How many `pallas_call`s of each `name=` the traced `fn` holds."""
+    return collections.Counter(
+        eqn.params["name"]
+        for eqn in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "pallas_call")
+
+
+def checkpoint_names(fn, *args):
+    """(name, shape) of every `checkpoint_name` in the traced `fn`."""
+    return sorted(
+        (eqn.params["name"], eqn.outvars[0].aval.shape)
+        for eqn in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "name")
+
+
+def two_remat_layers(layer, keep=None):
+    """`value_and_grad` over (w, x) of two stacked calls of `layer(w, x)`,
+    each under `jax.checkpoint(..., prevent_cse=True)` as the families wrap
+    a layer; with `keep`, the remat's policy saves those names only."""
+    policy = {} if keep is None else {
+        "policy": jax.checkpoint_policies.save_only_these_names(*keep)}
+    remat = jax.checkpoint(layer, prevent_cse=True, **policy)
+    return jax.value_and_grad(
+        lambda w, x: (remat(w, remat(w, x)) ** 2).sum(), argnums=(0, 1))
+
+
+def assert_same_bits(got, want):
+    """Two trees, leaf for leaf, bit for bit."""
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(a, b)
+
+
+def keep_against_plain_remat(monkeypatch, make_program, tree, kernels):
+    """A model's loss-and-gradient program under its family's `nn.remat`
+    policy against the same under a plain `nn.remat(..., prevent_cse=True)`:
+    every leaf bit for bit; returns the two programs' `pallas_call` counts of
+    `kernels`. `make_program` builds a NEW function a call: JAX keeps a
+    trace by the function."""
+    from flax import linen as nn
+
+    def run():
+        program = make_program()
+        calls = kernel_calls(program, tree)
+        return jax.jit(program)(tree), tuple(calls[k] for k in kernels)
+
+    kept, n_kept = run()
+    remat = nn.remat
+    monkeypatch.setattr(nn, "remat", lambda cls, policy, **kw: remat(cls, **kw))
+    plain, n_plain = run()
+    assert_same_bits(kept, plain)
+    return n_kept, n_plain
+
+
+def kernel_passes(text, scopes, kernels, layer):
+    """{kernel name: the passes ("fwd", "remat", "bwd") its top-level
+    instructions stand in} of a compiled step's `text`, for the Pallas
+    kernels whose `name=` matches the pattern `kernels`; `scopes` is
+    `hlo_cost.device_scopes(text)`. A kernel's `name=` is no layer: its
+    time stays the rule's own scope's, `layer`."""
+    seen = {}
+    for m in re.finditer(
+            r"%?([\w.\-]+) = [^\n]*op_name=\"[^\"]*(" + kernels + ")", text):
+        if m.group(1) not in scopes:  # a constant
+            continue
+        s = scopes[m.group(1)]
+        assert s.layer == layer, m.group(0)
+        if s.top_level:
+            seen.setdefault(m.group(2), set()).add(s.pass_)
+    return seen
 
 
 def assert_no_leaks(eng):

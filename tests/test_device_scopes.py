@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import equations
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.metrics import hlo_cost
@@ -142,16 +143,6 @@ def test_moe_rows_move_by_gather_forward_and_backward(name):
     assert bwd["L_moe_combine"] >= cfg.n_layers
 
 
-def _pallas_calls(jaxpr):
-    """Every `pallas_call` equation of a jaxpr and of the jaxprs inside it."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-            continue
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
-
-
 def _kernel_passes(trainer, state, batch, kernels, layer="L_moe_experts"):
     """{kernel: the passes of the compiled step in which what it lowers to
     appears}, every such instruction under `layer` (or, with `kernels` a
@@ -173,8 +164,9 @@ def _kernel_passes(trainer, state, batch, kernels, layer="L_moe_experts"):
                 assert scopes[m.name].layer == its_layer, line
                 passes[kernel].add(scopes[m.name].pass_)
     assert not set(hlo_cost.LAYER_SCOPES + hlo_cost.KERNEL_SCOPES) & set(passes)
-    calls = list(_pallas_calls(
-        jax.make_jaxpr(trainer._train_step)(state, batch).jaxpr))
+    calls = [eqn for eqn in equations(
+        jax.make_jaxpr(trainer._train_step)(state, batch).jaxpr)
+        if eqn.primitive.name == "pallas_call"]
     return dict(passes), calls
 
 
@@ -234,18 +226,19 @@ def test_held_experts_kernels_keep_the_experts_scope(monkeypatch):
     state = trainer.init_state(batch)
     trainer._build_steps()
     # the state-space rule's kernels in the same step: their `name=` is no
-    # scope of the vocabulary either, so `ssm_core_ms` keeps reading them
+    # scope of the vocabulary either, so `ssm_core_ms` keeps reading them;
+    # the layers' remat keeps the forward one's results (SSD_RESIDUALS)
     passes, calls = _kernel_passes(trainer, state, batch, {
         **dict.fromkeys(("moe_glu_fwd", "moe_glu_bwd_dw", "moe_glu_bwd_dx"),
                         "L_moe_experts"),
         "ssd_fwd": "L_ssm_core", "ssd_bwd": "L_ssm_core"})
     assert passes == {"moe_glu_fwd": {"fwd", "remat"},
                       "moe_glu_bwd_dw": {"bwd"}, "moe_glu_bwd_dx": {"bwd"},
-                      "ssd_fwd": {"fwd", "remat"}, "ssd_bwd": {"bwd"}}
+                      "ssd_fwd": {"fwd"}, "ssd_bwd": {"bwd"}}
     names = collections.Counter(c.params["name"] for c in calls)
     assert names == {"moe_glu_fwd": 2, "moe_glu_bwd_dw": 1,
                      "moe_glu_bwd_dx": 1,  # fwd, remat
-                     "ssd_fwd": 2, "ssd_bwd": 1}, names  # the Mamba-2 layer
+                     "ssd_fwd": 1, "ssd_bwd": 1}, names  # the Mamba-2 layer
 
 
 def test_program_scopes_knows_only_registered_programs():

@@ -5,13 +5,14 @@ interpret-mode equality tests — forward and gradients, causal and
 bidirectional, MHA and GQA/MQA head layouts.
 """
 
-import collections
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import (
+    assert_same_bits, checkpoint_names, kernel_calls, two_remat_layers)
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels import flash_attention
@@ -335,23 +336,6 @@ KEPT = [pytest.param(4, 4, 16, 16, id="mha"),
         pytest.param(4, 4, 24, 16, id="keys192_values128")]
 
 
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside it (a remat's
-    body, a custom rule's, a jit's), a kernel's own body apart."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        if eqn.primitive.name != "pallas_call":
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from _equations(sub)
-
-
-def _kernel_calls(fn, *args):
-    return collections.Counter(
-        eqn.params["name"]
-        for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)
-        if eqn.primitive.name == "pallas_call")
-
-
 def _layer_like(n, n_kv, dk, dv, s=64, width=24):
     """A layer as the families wrap one in remat: projections, the flash
     call, an output projection; and its inputs."""
@@ -380,22 +364,12 @@ def test_forward_kernel_runs_once_under_a_remat_that_keeps_its_results(
     with no policy two (the names are identities there); the backward
     kernels one a layer either way; output and gradients bit for bit."""
     layer, w, x = _layer_like(n, n_kv, dk, dv)
-
-    def loss(remat):
-        two = lambda w, x: jnp.sum(remat(layer)(w, remat(layer)(w, x)) ** 2)  # noqa: E731
-        return jax.value_and_grad(two, argnums=(0, 1))
-
-    kept = loss(functools.partial(
-        jax.checkpoint, prevent_cse=True,
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *FLASH_RESIDUALS)))
-    plain = loss(functools.partial(jax.checkpoint, prevent_cse=True))
+    kept = two_remat_layers(layer, keep=FLASH_RESIDUALS)
+    plain = two_remat_layers(layer)
     backward = {"flash_mla_bwd_dq": 2, "flash_mla_bwd_dkv": 2}
-    assert _kernel_calls(kept, w, x) == {"flash_mla_fwd": 2, **backward}
-    assert _kernel_calls(plain, w, x) == {"flash_mla_fwd": 4, **backward}
-    got, want = jax.jit(kept)(w, x), jax.jit(plain)(w, x)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
-        np.testing.assert_array_equal(a, b)
+    assert kernel_calls(kept, w, x) == {"flash_mla_fwd": 2, **backward}
+    assert kernel_calls(plain, w, x) == {"flash_mla_fwd": 4, **backward}
+    assert_same_bits(jax.jit(kept)(w, x), jax.jit(plain)(w, x))
 
 
 @pytest.mark.parametrize("n,n_kv,dk,dv", KEPT)
@@ -403,12 +377,9 @@ def test_names_stand_in_the_forward_rule_only(n, n_kv, dk, dv):
     """`_flash`, the primal, names nothing; differentiated, the two
     residuals carry FLASH_RESIDUALS, in the kernel's own layout."""
     layer, w, x = _layer_like(n, n_kv, dk, dv)
-    names = lambda fn: [  # noqa: E731
-        (eqn.params["name"], eqn.outvars[0].aval.shape)
-        for eqn in _equations(jax.make_jaxpr(fn)(w, x).jaxpr)
-        if eqn.primitive.name == "name"]
-    assert names(layer) == []
-    assert names(jax.checkpoint(layer, prevent_cse=True)) == []
+    assert checkpoint_names(layer, w, x) == []
+    assert checkpoint_names(jax.checkpoint(layer, prevent_cse=True), w, x) == []
     s = x.shape[1]
-    assert sorted(names(jax.grad(lambda w, x: jnp.sum(layer(w, x))))) == [
+    assert checkpoint_names(
+            jax.grad(lambda w, x: jnp.sum(layer(w, x))), w, x) == [
         ("flash_lse", (n, 1, s)), ("flash_o", (n, s, dv))]

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_same_bits, checkpoint_names, kernel_calls
 
 from solvingpapers_tpu.kernels import gated_delta as kernel
 from solvingpapers_tpu.ops import gated_delta as gd
@@ -175,3 +176,34 @@ def test_gated_rms_norm_and_its_backward():
         np.testing.assert_allclose(a, b, atol=1e-5)
     assert gd.gated_rms_norm(o.astype(jnp.bfloat16), z.astype(jnp.bfloat16),
                              w, 1e-6).dtype == jnp.bfloat16
+
+
+def test_the_gated_rule_offers_the_same_names_to_a_callers_remat():
+    """One decay a head, the other branch of the kernel file: its forward
+    rule names o and the entering states DELTA_RESIDUALS too, in the
+    kernel's layouts ((B, S padded, Hv dv); (B, Hk, grid steps, grp, dk,
+    dv)); a remat that keeps them holds one `gated_delta_fwd`, a remat with
+    no policy (the family's own, which has no room) two; bit for bit."""
+    seq, hv = 75, 4  # two grid steps, the second ragged
+    args = inputs(seq, jnp.float32, hv)
+    rule = lambda *a: jnp.sum(jnp.sin(gd.gated_delta_rule(*a, chunk=CHUNK)))  # noqa: E731
+    step = CHUNK * kernel.CHUNKS_A_STEP
+    tiles = -(-seq // step)
+    assert checkpoint_names(rule, *args) == []
+    assert checkpoint_names(jax.grad(rule, argnums=(0, 1, 2, 3, 4)), *args) == [
+        ("delta_o", (B, tiles * step, hv * DV)),
+        ("delta_states", (B, HK, tiles, hv // HK, DK, DV))]
+
+    def grad_of(**remat):
+        return jax.value_and_grad(
+            jax.checkpoint(rule, prevent_cse=True, **remat),
+            argnums=(0, 1, 2, 3, 4))
+
+    kept = grad_of(policy=jax.checkpoint_policies.save_only_these_names(
+        *kernel.DELTA_RESIDUALS))
+    plain = grad_of()
+    assert kernel_calls(kept, *args) == {
+        "gated_delta_fwd": 1, "gated_delta_bwd": 1}
+    assert kernel_calls(plain, *args) == {
+        "gated_delta_fwd": 2, "gated_delta_bwd": 1}
+    assert_same_bits(jax.jit(kept)(*args), jax.jit(plain)(*args))

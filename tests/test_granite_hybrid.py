@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import keep_against_plain_remat, kernel_passes
 
 from benchmarks.adapters import granite_hybrid as adapter
 from benchmarks.drivers.train_job import Rows
@@ -278,7 +279,8 @@ def test_train_step_stands_under_the_layers_the_benchmark_reads():
     trainer._build_steps()
     with hlo_cost._persistent_cache_off():
         text = trainer._train_step.lower(state, b).compile().as_text()
-    top = [s for s in hlo_cost.device_scopes(text).values() if s.top_level]
+    scopes = hlo_cost.device_scopes(text)
+    top = [s for s in scopes.values() if s.top_level]
     layers = {s.layer for s in top} - {None}
     assert layers == {
         "L_embed", "L_ssm_proj", "L_ssm_conv", "L_ssm_core", "L_attn_proj",
@@ -286,10 +288,37 @@ def test_train_step_stands_under_the_layers_the_benchmark_reads():
         *hlo_cost.KERNEL_SCOPES}
     assert layers <= set(hlo_cost.LAYER_SCOPES) | set(
         hlo_cost.KERNEL_SCOPES)
-    # the attention layer's remat keeps the flash forward's results
+    # the layers' remat keeps the forward kernels' results, the flash one's
+    # (FLASH_RESIDUALS) and the recurrence's (SSD_RESIDUALS): each stands
+    # in the step alone, while the projections around them run again
     assert {s.pass_ for s in top if s.layer == "flash_mla_fwd"} == {"fwd"}
+    assert kernel_passes(text, scopes, "ssd_(?:fwd|bwd)", "L_ssm_core") == {
+        "ssd_fwd": {"fwd"}, "ssd_bwd": {"bwd"}}
+    assert {s.pass_ for s in top if s.layer == "L_ssm_proj"} == {
+        "fwd", "remat", "bwd"}
     covered = sum(s.layer is not None for s in top) / len(top)
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
+
+
+def test_keeping_the_kernels_results_changes_no_bit_of_loss_or_gradient(
+        monkeypatch):
+    """The layers' remat with `save_only_these_names(*FLASH_RESIDUALS,
+    *SSD_RESIDUALS)` against the same model under a plain `nn.remat(...,
+    prevent_cse=True)`: one forward kernel a layer in the gradient, the
+    flash one and the recurrence's (two blocks of eight heads a call),
+    where the plain one holds two, the loss and every gradient leaf bit for
+    bit."""
+    cfg = tiny(dtype="float32", remat=True, use_flash=True,
+               num_hidden_layers=3,
+               layer_types=("mamba", "mamba", "attention"))
+    _, _, tree = seeded(cfg)
+    model, b = GraniteHybrid(cfg), batch()
+
+    n_kept, n_plain = keep_against_plain_remat(
+        monkeypatch, lambda: jax.value_and_grad(lambda p: granite_hybrid_loss_fn(
+            model, p, b, jax.random.key(0), None, True)[0]),
+        tree, ("flash_mla_fwd", "ssd_fwd", "ssd_bwd"))
+    assert (n_kept, n_plain) == ((1, 2, 2), (2, 4, 2))
 
 
 def test_cli_train_runs_the_family(monkeypatch, tmp_path):

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import (
+    assert_same_bits, checkpoint_names, kernel_calls, two_remat_layers)
 
 from solvingpapers_tpu.kernels import gated_delta as kernel
 from solvingpapers_tpu.ops import gated_delta as gd
@@ -203,3 +205,59 @@ def test_decay_made_from_a_low_rank_input_is_the_same_function():
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))))
+
+
+# --- the forward kernel's results survive a caller's remat (DELTA_RESIDUALS)
+
+def _layer_like(seq, width=12):
+    """A layer as the family wraps one in remat: projections, the rule, an
+    output projection; and its inputs."""
+    keys = jax.random.split(jax.random.key(7), 7)
+    x = jax.random.normal(keys[0], (B, seq, width))
+    sizes = {"q": H * DK, "k": H * DK, "v": H * DV, "g": H * DK, "beta": H}
+    w = {n: jax.random.normal(key, (width, m)) * 0.3
+         for key, (n, m) in zip(keys[1:], sizes.items())}
+    w["o"] = jax.random.normal(keys[6], (H * DV, width)) * 0.3
+
+    def layer(w, x):
+        heads = lambda n, d: (x @ w[n]).reshape(B, seq, H, d)  # noqa: E731
+        o = kda.kda_rule(
+            heads("q", DK), heads("k", DK), heads("v", DV),
+            -jax.nn.softplus(heads("g", DK)), jax.nn.sigmoid(x @ w["beta"]),
+            chunk=CHUNK, sub=SUB)
+        return x + jnp.tanh(o.reshape(B, seq, H * DV) @ w["o"])
+
+    return layer, w, x
+
+
+# tokens: one grid step; grid steps and a ragged tail
+KEPT = [STEP, 2 * STEP + 22]
+
+
+@pytest.mark.parametrize("seq", KEPT)
+def test_forward_kernel_runs_once_under_a_remat_that_keeps_its_results(seq):
+    """Under `save_only_these_names(*DELTA_RESIDUALS)` the gradient of two
+    rematerialised layers holds one forward kernel a layer; under a remat
+    with no policy two (the names are identities there); the backward
+    kernel one a layer either way; output and gradients bit for bit."""
+    layer, w, x = _layer_like(seq)
+    kept = two_remat_layers(layer, keep=kernel.DELTA_RESIDUALS)
+    plain = two_remat_layers(layer)
+    assert kernel_calls(kept, w, x) == {"kda_fwd": 2, "kda_bwd": 2}
+    assert kernel_calls(plain, w, x) == {"kda_fwd": 4, "kda_bwd": 2}
+    assert_same_bits(jax.jit(kept)(w, x), jax.jit(plain)(w, x))
+
+
+@pytest.mark.parametrize("seq", KEPT)
+def test_names_stand_in_the_forward_rule_only(seq):
+    """`_rule`, the primal, names nothing; differentiated, o and the
+    entering states carry DELTA_RESIDUALS, in the kernel's own layouts:
+    (B, S padded, H dv) and (B, H, grid steps, 1, dk, dv)."""
+    layer, w, x = _layer_like(seq)
+    assert checkpoint_names(layer, w, x) == []
+    assert checkpoint_names(jax.checkpoint(layer, prevent_cse=True), w, x) == []
+    tiles = -(-seq // STEP)
+    assert checkpoint_names(
+        jax.grad(lambda w, x: jnp.sum(layer(w, x))), w, x) == [
+            ("delta_o", (B, tiles * STEP, H * DV)),
+            ("delta_states", (B, H, tiles, 1, DK, DV))]

@@ -18,13 +18,12 @@
 """
 
 import dataclasses
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax import linen as nn
+from conftest import keep_against_plain_remat, kernel_passes
 
 from benchmarks.adapters import nemotron_h as adapter
 from benchmarks.drivers.train_job import Rows
@@ -385,19 +384,11 @@ def test_train_step_names_the_new_layers(use_flash):
         assert {s.pass_ for s in top if s.layer == layer} >= {"bwd", "remat"}
     assert not layers & {"L_gdn_proj", "L_gdn_core", "L_kda_proj",
                          "L_kda_core", "L_dense_ffn"}
-    # the rule's kernels carry a `name=` that is no layer: their time stays
-    # the rule's own scope's, the forward kernel's in the step and again in
-    # the layer's remat, the backward kernel's in the backward pass
-    seen = {}
-    for m in re.finditer(
-            r"%?([\w.\-]+) = [^\n]*op_name=\"[^\"]*(ssd_(?:fwd|bwd))", text):
-        if m.group(1) not in scopes:  # a constant
-            continue
-        s = scopes[m.group(1)]
-        assert s.layer == "L_ssm_core", m.group(0)
-        if s.top_level:
-            seen.setdefault(m.group(2), set()).add(s.pass_)
-    assert seen == {"ssd_fwd": {"fwd", "remat"}, "ssd_bwd": {"bwd"}}
+    # the rule's kernels stay the rule's own scope's; the layers' remat
+    # keeps the forward kernel's y and entering states (SSD_RESIDUALS), so
+    # it stands in the step alone, the backward kernel in the backward
+    assert kernel_passes(text, scopes, "ssd_(?:fwd|bwd)", "L_ssm_core") == {
+        "ssd_fwd": {"fwd"}, "ssd_bwd": {"bwd"}}
     # the flash kernels are scopes of their own; the layers' remat keeps
     # the forward kernel's o and lse (FLASH_RESIDUALS), so it stands in
     # the step alone, while the projections around it run again
@@ -414,30 +405,23 @@ def test_train_step_names_the_new_layers(use_flash):
 
 def test_keeping_the_flash_results_changes_no_bit_of_loss_or_gradient(
         monkeypatch):
-    """The layers' remat with `save_only_these_names(*FLASH_RESIDUALS)`
-    against the same model under a plain `nn.remat(..., prevent_cse=True)`:
-    one forward kernel in the gradient where the plain one holds two, the
-    loss and every gradient leaf bit for bit."""
-    # published layers 0-5, M E M E M *: the sixth is the attention layer
+    """The layers' remat with `save_only_these_names(*FLASH_RESIDUALS,
+    *SSD_RESIDUALS)` against the same model under a plain `nn.remat(...,
+    prevent_cse=True)`: one forward kernel a layer in the gradient, the
+    flash one and the recurrence's, where the plain one holds two, the loss
+    and every gradient leaf bit for bit."""
+    # published layers 0-5, M E M E M *: three Mamba-2 layers, the sixth
+    # the attention layer
     cfg = tiny(dtype="float32", remat=True, use_flash=True,
                num_hidden_layers=6)
     _, _, tree = seeded(cfg)
     model, b = NemotronH(cfg), batch()
 
-    def run():  # a new function a call: JAX keeps a trace by the function
-        program = jax.value_and_grad(lambda p: kimi_linear_loss_fn(
-            model, p, b, jax.random.key(0), None, True)[0])
-        return jax.jit(program)(tree), str(
-            jax.make_jaxpr(program)(tree)).count("name=flash_mla_fwd")
-
-    kept, n_kept = run()
-    remat = nn.remat
-    monkeypatch.setattr(nn, "remat", lambda cls, policy, **kw: remat(cls, **kw))
-    plain, n_plain = run()
-    assert (n_kept, n_plain) == (1, 2)  # one attention layer of the six
-    for a, c in zip(jax.tree.leaves(kept), jax.tree.leaves(plain),
-                    strict=True):
-        np.testing.assert_array_equal(a, c)
+    n_kept, n_plain = keep_against_plain_remat(
+        monkeypatch, lambda: jax.value_and_grad(lambda p: kimi_linear_loss_fn(
+            model, p, b, jax.random.key(0), None, True)[0]),
+        tree, ("flash_mla_fwd", "ssd_fwd", "ssd_bwd"))
+    assert (n_kept, n_plain) == ((1, 3, 3), (2, 6, 3))
 
 
 def test_cli_serve_refuses_the_family_and_list_names_it(capsys):

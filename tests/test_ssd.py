@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import (
+    assert_same_bits, checkpoint_names, kernel_calls, two_remat_layers)
 
 from solvingpapers_tpu.kernels import ssd as kernel
 from solvingpapers_tpu.ops import gated_delta, ssd
@@ -320,3 +322,71 @@ def test_gate_then_group_norm_is_not_norm_then_gate():
     np.testing.assert_allclose(
         one, u / jnp.sqrt(jnp.mean(u * u, -1, keepdims=True) + 1e-5) * w,
         atol=1e-6)
+
+
+# --- the forward kernel's results survive a caller's remat (SSD_RESIDUALS)
+
+KEPT_CHUNK = 8  # S = 50: two grid steps of four chunks, the second ragged
+
+
+def _layer_like(read_last=False, width=12):
+    """A layer as the families wrap one in remat: projections, the rule,
+    the gate-then-norm's gate and an output projection; and its inputs.
+    `read_last`: the state after the last token is read too, as no training
+    caller does."""
+    keys = jax.random.split(jax.random.key(7), 8)
+    x = jax.random.normal(keys[0], (B, S, width))
+    sizes = {"x": H * P, "z": H * P, "dt": H, "b": G * N, "c": G * N}
+    w = {n: jax.random.normal(key, (width, m)) * 0.3
+         for key, (n, m) in zip(keys[1:], sizes.items())}
+    w["o"] = jax.random.normal(keys[6], (H * P, width)) * 0.3
+    _, _, a, _, _, d = inputs()
+
+    def layer(w, x):
+        to = lambda n, *shape: (x @ w[n]).reshape(B, S, *shape)  # noqa: E731
+        y, last = ssd.ssd_chunked(
+            to("x", H, P), jax.nn.softplus(x @ w["dt"]), a, to("b", G, N),
+            to("c", G, N), d, chunk=KEPT_CHUNK)
+        u = y.reshape(B, S, H * P) * jax.nn.silu(x @ w["z"])
+        out = x + jnp.tanh(u @ w["o"])
+        return out + jnp.mean(jnp.cos(last)) if read_last else out
+
+    return layer, w, x
+
+
+@pytest.mark.parametrize("read_last, forward_calls", [
+    pytest.param(False, 2, id="last_unread"),
+    pytest.param(True, 4, id="last_read")])
+def test_forward_kernel_runs_once_under_a_remat_that_keeps_its_results(
+        read_last, forward_calls):
+    """Under `save_only_these_names(*SSD_RESIDUALS)` the gradient of two
+    rematerialised layers holds one forward kernel a layer; under a remat
+    with no policy two (the names are identities there); the backward
+    kernel one a layer either way; output and gradients bit for bit. The
+    state after the last token is not named: unread, as in training, it
+    leaves no forward call in the remat; a caller that reads it pays the
+    second run whatever it keeps (and is another program: only its calls
+    are counted)."""
+    layer, w, x = _layer_like(read_last)
+    kept = two_remat_layers(layer, keep=kernel.SSD_RESIDUALS)
+    plain = two_remat_layers(layer)
+    assert kernel_calls(kept, w, x) == {
+        "ssd_fwd": forward_calls, "ssd_bwd": 2}
+    assert kernel_calls(plain, w, x) == {"ssd_fwd": 4, "ssd_bwd": 2}
+    if not read_last:
+        assert_same_bits(jax.jit(kept)(w, x), jax.jit(plain)(w, x))
+
+
+def test_names_stand_in_the_forward_rule_only():
+    """`_rule`, the primal, names nothing; differentiated, y and the
+    entering states carry SSD_RESIDUALS, in the kernel's own layouts: (B, S
+    padded, H P) and (B, G, grid steps, R P, N); the last state no name."""
+    layer, w, x = _layer_like()
+    assert checkpoint_names(layer, w, x) == []
+    assert checkpoint_names(jax.checkpoint(layer, prevent_cse=True), w, x) == []
+    step = KEPT_CHUNK * kernel.CHUNKS_A_STEP
+    tiles = -(-S // step)
+    assert checkpoint_names(
+        jax.grad(lambda w, x: jnp.sum(layer(w, x))), w, x) == [
+            ("ssd_states", (B, G, tiles, H // G * P, N)),
+            ("ssd_y", (B, tiles * step, H * P))]
