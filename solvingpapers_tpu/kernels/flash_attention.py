@@ -41,18 +41,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BIG_NEG = -2.0**30
-# 512 measured best on v5e for the 350M study at seq <= 4k
-# (tools/scale_350m.py sweep: 128->35.9% MFU, 256->48.2%, 512->52.2%, 1024
-# q-blocks regress); _pick_block still shrinks to fit shorter sequences.
+# The two BACKWARD kernels' tiles, one size for both sides (`auto_block`):
+# 512 below LONG_SEQ, 1,024 from there on where the key width fits 128
+# lanes (tools/sweep_flash_bwd.py, v5e, 16k: 1024/1024 beats 512/512 by
+# 1.56x forward + backward on the MLA shape and 1.53x on GQA); at 256
+# lanes the compiler refuses `flash_mla_bwd_dq` in 1,024-tiles (VMEM,
+# tests/test_chip_compile.py). _pick_block still shrinks either side to
+# fit a shorter sequence.
 DEFAULT_BLOCK = 512
-# At LONG sequence the trade flips (tools/sweep_flash_bwd.py, v5e, 16k:
-# 1024/1024 beats 512/512 by 2.1x fwd / 1.56x fwd+bwd on the MLA shape and
-# 2.0x / 1.53x on GQA — more kv reuse per q tile, fewer grid steps), so
-# callers that didn't override blocks get 1024 once the sequence clears
-# this bound (VERDICT r4 ask 8: the 16k-MFU backward sweep).
 LONG_SEQ = 8192
 LONG_SEQ_BLOCK = 1024
 LONG_SEQ_MAX_HEAD_DIM = 128
+# The FORWARD kernel's own tiles (`flash_blocks`): 1,024 x 1,024 at every
+# sequence length, for keys and values up to 256 lanes wide. Measured alone
+# on a v5e, pair by pair, at the benchmark's six shapes and two shorter ones
+# (tools/sweep_flash_fwd.py; the table is in PERF.md section 6, PR 45): it
+# is the fastest pair Mosaic takes in the default scoped VMEM at all six,
+# 39% faster a call than 512 x 512 at (2, 4096, 16 on 16, 128) and at
+# (1, 16384, 32 on 32, keys 192 / values 128), 12% at (1, 16384, 16 on 2,
+# 256), and what `auto_block` already gave the three shapes at 16k and 8k
+# in 128 lanes. A grid step's cost is mostly not its products: the
+# (block_q, 1) columns m, l and alpha are broadcast over the lanes and the
+# accumulator rescaled once a KEY block, so a wider key tile spreads that
+# over more scores, until the masked half of a wider tile costs more
+# (2,048 and 4,096 wide are slower everywhere). Wider heads than the sweep
+# saw keep the backward's pair.
+FWD_BLOCK = 1024
+FWD_MAX_HEAD_DIM = 256
 
 _SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
@@ -106,6 +121,29 @@ def auto_block(seq: int, requested: int | None, head_dim: int) -> int:
     if seq >= LONG_SEQ and head_dim <= LONG_SEQ_MAX_HEAD_DIM:
         return LONG_SEQ_BLOCK
     return DEFAULT_BLOCK
+
+
+def flash_blocks(seq_q: int, seq_k: int, head_dim: int, value_dim: int,
+                 dropout_rate: float = 0.0, block_q: int | None = None,
+                 block_k: int | None = None):
+    """((block_q, block_k) of the forward kernel, (block_q, block_k) of the
+    two backward kernels) for one call, from what the call can see: the two
+    sequence lengths, the key and value widths, the dropout rate and the
+    caller's own request. The backward pair is `auto_block`'s, as ever. The
+    forward pair is its own (FWD_BLOCK x FWD_BLOCK, see the constants)
+    unless the caller named a block, which sets both pairs, or dropout is
+    on: the mask of a score tile is regenerated from `_uid`, the tile's
+    number in ITS tiling, so forward and backward must then tile alike.
+    `o` and `lse` are whole arrays in HBM: the backward reads them in its
+    own blocks whatever tiles wrote them. Either side still shrinks to a
+    divisor of its sequence (`_pick_block`, `_pick_block_q`)."""
+    backward = (_pick_block_q(seq_q, auto_block(seq_q, block_q, head_dim)),
+                _pick_block(seq_k, auto_block(seq_k, block_k, head_dim)))
+    if (block_q is not None or block_k is not None or dropout_rate > 0.0
+            or max(head_dim, value_dim) > FWD_MAX_HEAD_DIM):
+        return backward, backward
+    return (_pick_block_q(seq_q, FWD_BLOCK),
+            _pick_block(seq_k, FWD_BLOCK)), backward
 
 
 def _pick_block(seq: int, requested: int) -> int:
@@ -435,14 +473,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
 def _flash(q3, k3, v3, seed, heads, scale, causal, blocks, dropout_rate,
            interpret):
     o, _ = _fwd(q3, k3, v3, seed, heads[0], heads[1], scale, causal,
-                blocks[0], blocks[1], dropout_rate, interpret)
+                *blocks[0], dropout_rate, interpret)
     return o
 
 
 def _flash_fwd(q3, k3, v3, seed, heads, scale, causal, blocks, dropout_rate,
                interpret):
     o, lse = _fwd(q3, k3, v3, seed, heads[0], heads[1], scale, causal,
-                  blocks[0], blocks[1], dropout_rate, interpret)
+                  *blocks[0], dropout_rate, interpret)
     # named in the kernel's own (B*N, S, Dv) layout: what the backward's
     # delta and the caller's o_proj both start from (FLASH_RESIDUALS)
     o = checkpoint_name(o, FLASH_RESIDUALS[0])
@@ -470,7 +508,7 @@ def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret, res, do):
 
     dq, dk_r, dv_r = _bwd_chunk(
         q3, k3r, v3r, do, lse, delta, seed, scale=scale, causal=causal,
-        block_q=blocks[0], block_k=blocks[1], dropout_rate=dropout_rate,
+        block_q=blocks[1][0], block_k=blocks[1][1], dropout_rate=dropout_rate,
         interpret=interpret,
     )
 
@@ -595,7 +633,7 @@ def flash_attention(
     attention decompressed: keys [nope | rope] 192 wide, values 128): each
     operand crosses HBM at its own width, nothing is padded there. In VMEM a
     width that is no multiple of the 128 lanes takes the next multiple's
-    (192 sits in 256), which is what `auto_block` sizes the tiles by.
+    (192 sits in 256), what `flash_blocks` sizes both tile pairs by.
     dropout_rate > 0 applies attention-prob dropout INSIDE the kernel
     (masks regenerated from (dropout_seed, block id) in the backward — no
     (S, S) mask tensor ever exists); same Bernoulli semantics as the dense
@@ -619,16 +657,15 @@ def flash_attention(
         )
     if scale is None:
         scale = d**-0.5
-    block_q = _pick_block_q(seq_q, auto_block(seq_q, block_q, d))
-    block_k = _pick_block(seq_k, auto_block(seq_k, block_k, d))
+    dv = v.shape[3]
+    blocks = flash_blocks(seq_q, seq_k, d, dv, dropout_rate, block_q, block_k)
 
     q3 = q.transpose(0, 2, 1, 3).reshape(b * n_heads, seq_q, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, d)
-    dv = v.shape[3]
     v3 = v.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, dv)
     seed = jnp.asarray(dropout_seed, jnp.int32).reshape(1)
     o3 = _flash(
         q3, k3, v3, seed, (n_heads, n_kv), float(scale), bool(causal),
-        (block_q, block_k), float(dropout_rate), interpret,
+        blocks, float(dropout_rate), interpret,
     )
     return o3.reshape(b, n_heads, seq_q, dv).transpose(0, 2, 1, 3)

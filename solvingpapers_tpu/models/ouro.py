@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
+from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.models.layers import (
     _by_blocks, apply_flash_attention, blocked_swiglu,
 )
@@ -199,8 +200,14 @@ class Ouro(nn.Module):
                 cfg.vocab_size, d, dtype=jnp.float32,
                 embedding_init=_INIT, name="tok_emb",
             )(tokens)
-        layer_cls = (nn.remat(OuroLayer, prevent_cse=True)
-                     if cfg.remat else OuroLayer)
+        # the flash forward kernel's o and lse are kept, not made again: 32.5
+        # MiB a layer application at 16 heads of 2 x 4,096 tokens, 1.02 GiB
+        # for the 32, dead before the step's largest live set
+        layer_cls = (nn.remat(
+            OuroLayer, prevent_cse=True,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FLASH_RESIDUALS),
+        ) if cfg.remat else OuroLayer)
         # ONE set of layers, applied in every pass
         layers = [layer_cls(cfg, name=f"layer_{i}")
                   for i in range(cfg.num_hidden_layers)]

@@ -61,15 +61,17 @@ SHAPES = {
     "ragged_2016": (1, 2016, 8, 1, 128, 0.0),
     # qwen3next's gated attention: 16 q heads on 2 kv heads of width 256.
     # With the long-sequence 1024-tiles the compiler refuses the backward-dq
-    # kernel here (VMEM), so `auto_block` keeps 512 past width 128
+    # kernel here (VMEM), so `auto_block` keeps 512 past width 128 for the
+    # two backward kernels; the forward kernel takes its own 1,024-tiles
     "qwen3next_gqa_16k": (1, 16_384, 16, 2, 256, 0.0),
     # nemotron3_nano_ep16's attention layer: 32 q heads on 2 kv heads of
     # width 128, sixteen query heads a key-value head, no rotation; at width
-    # 128 `auto_block` hands all three kernels the 1,024-tiles
+    # 128 all three kernels run in 1,024-tiles (`flash_blocks`)
     "nemotron_gqa_32on2_16k": (1, 16_384, 32, 2, 128, 0.0),
     # ouro_2p6b_pp6's layers: plain multi-head attention, 16 q heads on 16
     # kv heads of width 128, two sequences of 4,096 (the one cell with
-    # batch 2; under LONG_SEQ, so the 512-tiles)
+    # batch 2; under LONG_SEQ, so 512-tiles for the backward kernels; the
+    # forward kernel's own are 1,024 x 1,024)
     "ouro_mha_16on16_2x4k": (2, 4096, 16, 16, 128, 0.0),
     # granite4_h_micro_pp4's attention layer: 32 q heads on 8 kv heads of
     # width 64 (half the lanes: every block's last dimension is the
@@ -125,12 +127,14 @@ def test_flash_with_a_value_width_of_its_own_compiles_for_v5e(
     """kimi_linear_ep32's latent attention, decompressed: 32 heads, keys 192
     wide (128 + 64, no whole number of 128-lane tiles: 256 lanes in VMEM),
     values 128, one sequence of 16,384. `auto_block` counts 192 as 256 and
-    keeps the 512-tiles; Mosaic takes the blocks of all three kernels."""
+    keeps the 512-tiles for the backward kernels, the forward kernel runs
+    in its own; Mosaic takes the blocks of all three kernels."""
     sds = lambda h, w: jax.ShapeDtypeStruct(  # noqa: E731
         (1, 16_384, h, w), jnp.bfloat16, sharding=one_chip)
-    from solvingpapers_tpu.kernels.flash_attention import auto_block
+    from solvingpapers_tpu.kernels.flash_attention import flash_blocks
 
-    assert auto_block(16_384, None, 192) == 512
+    assert flash_blocks(16_384, 16_384, 192, 128) == (
+        (1024, 1024), (512, 512))
 
     def loss(q, k, v):
         out = flash_attention(
@@ -143,6 +147,21 @@ def test_flash_with_a_value_width_of_its_own_compiles_for_v5e(
         sds(32, 192), sds(32, 192), sds(32, 128)).compile()
     assert compiled.as_text().count("tpu_custom_call") == (3 if backward
                                                            else 1)
+
+
+def test_flash_prefill_chunk_compiles_for_v5e(one_chip):
+    """The serving prefill of `dsv3_long`: a chunk of 512 queries over the
+    16,384 latent rows written so far, end-aligned causal, one shared
+    key-value head: the forward kernel alone, in a pair that is not square
+    (each side shrinks to its own length)."""
+    from solvingpapers_tpu.kernels.flash_attention import flash_blocks
+
+    assert flash_blocks(512, 16_384, 128, 128)[0] == (512, 1024)
+    sds = lambda s, h: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, s, h, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(_attend(0.0)).lower(
+        sds(512, 8), sds(16_384, 1), sds(16_384, 1)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 def _compiled_kda(one_chip, qk_shape, dv, backward):

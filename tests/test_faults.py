@@ -384,6 +384,12 @@ def test_watchdog_flags_stalled_step():
     model, params = _model()
     p0 = _prompts(1, seed=9)[0]
     plan = [dict(site="decode", kind="stall", visit=1, stall_s=0.08)]
+    # the watchdog times a step's wall clock, a compile inside it included:
+    # warm the programs first, or the test counts 2 wherever it is the first
+    # of its worker to run these shapes (xdist hands tests out by load)
+    warm = ServeEngine(model, params, _cfg())
+    warm.submit(p0, max_new_tokens=10)
+    warm.run()
     eng = ServeEngine(model, params, _cfg(
         fault_plan=plan, fault_step_deadline_s=0.04))
     h = eng.submit(p0, max_new_tokens=10)

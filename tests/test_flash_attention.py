@@ -6,17 +6,23 @@ bidirectional, MHA and GQA/MQA head layouts.
 """
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from conftest import (
-    assert_same_bits, checkpoint_names, kernel_calls, two_remat_layers)
+    assert_same_bits, checkpoint_names, equations, kernel_calls,
+    two_remat_layers)
 
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels import flash_attention
-from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
+from solvingpapers_tpu.kernels.flash_attention import (
+    FLASH_RESIDUALS, flash_blocks)
+
+# the module (the package re-exports the function under the same name)
+flash_module = sys.modules["solvingpapers_tpu.kernels.flash_attention"]
 
 # sub-minute correctness core: `pytest -m fast` is the ~4-minute gate
 pytestmark = pytest.mark.fast
@@ -383,3 +389,122 @@ def test_names_stand_in_the_forward_rule_only(n, n_kv, dk, dv):
     assert checkpoint_names(
             jax.grad(lambda w, x: jnp.sum(layer(w, x))), w, x) == [
         ("flash_lse", (n, 1, s)), ("flash_o", (n, s, dv))]
+
+
+# --- the forward kernel's tile pair is its own (`flash_blocks`)
+
+# (sq, skv, q heads, kv heads, key width, value width, forward pair,
+#  backward pair)
+PAIRS = [
+    pytest.param(256, 256, 2, 2, 32, 32, (64, 128), (64, 64),
+                 id="wider_key_tile"),
+    pytest.param(256, 256, 2, 2, 32, 32, (128, 64), (64, 64),
+                 id="wider_query_tile"),
+    pytest.param(256, 256, 2, 2, 32, 32, (32, 256), (128, 128),
+                 id="one_key_block"),
+    pytest.param(128, 256, 2, 2, 32, 32, (64, 128), (32, 64),
+                 id="end_aligned_prefill"),
+    pytest.param(192, 64, 2, 2, 32, 32, (32, 64), (64, 32),
+                 id="seq_q_longer"),
+    pytest.param(256, 256, 4, 2, 24, 16, (32, 128), (64, 32),
+                 id="gqa_value_width_of_its_own"),
+]
+
+
+def kernel_grids(fn, *args):
+    """{`name=`: grid} of the `pallas_call`s the traced `fn` holds."""
+    return {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
+            for eqn in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("sq,skv,n,n_kv,dk,dv,forward,backward", PAIRS)
+def test_forward_tiles_of_its_own_match_dense_and_the_shared_tiles(
+        monkeypatch, sq, skv, n, n_kv, dk, dv, forward, backward):
+    """The forward kernel in one tile pair, the backward kernels in another:
+    `o` and the three gradients against the dense reference, and against
+    the call whose forward runs in the backward's tiles (`o` and `lse` are
+    whole arrays: the backward reads them in its own blocks)."""
+    q, k, _ = make_qkv(jax.random.key(13), 2, sq, skv, n, n_kv, dk)
+    v = jax.random.normal(jax.random.key(14), (2, skv, n_kv, dv))
+    mix = jax.random.normal(jax.random.key(15), (2, sq, n, dv))
+    # rows that see no key (seq_q > seq_k, end-aligned) are 0 in the kernel
+    # and arbitrary in the dense reference
+    seen = (jnp.arange(sq) >= sq - skv)[None, :, None, None]
+
+    def program(fn):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(jnp.where(
+            seen, fn(q, k, v, causal=True), 0.0) * mix), argnums=(0, 1, 2))
+
+    def flash_with(pairs):
+        monkeypatch.setattr(flash_module, "flash_blocks",
+                            lambda *a, **kw: pairs)
+        # a new function a call: JAX keeps a trace by the function
+        fn = program(functools.partial(flash_attention, interpret=True))
+        return kernel_grids(fn, q, k, v), jax.jit(fn)(q, k, v)
+
+    _, shared = flash_with((backward, backward))
+    grids, got = flash_with((forward, backward))
+    want = jax.jit(program(ops.dot_product_attention))(q, k, v)
+    heads = 2 * n
+    assert grids == {
+        "flash_mla_fwd": (heads, sq // forward[0], skv // forward[1]),
+        "flash_mla_bwd_dq": (heads, sq // backward[0], skv // backward[1]),
+        "flash_mla_bwd_dkv": (heads, skv // backward[1], sq // backward[0])}
+    for other in (want, shared):
+        np.testing.assert_allclose(got[0], other[0], rtol=2e-5)
+        for name, a, b in zip("qkv", got[1], other[1]):
+            np.testing.assert_allclose(a, b, rtol=5e-5, atol=5e-5,
+                                       err_msg=name)
+    # undifferentiated, the primal runs in the forward pair too
+    out = flash_attention(q, k, v, causal=True, interpret=True)
+    ref = ops.dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(jnp.where(seen, out, 0.0),
+                               jnp.where(seen, ref, 0.0),
+                               rtol=2e-5, atol=2e-5)
+
+
+# (seq, key width, value width, forward pair, backward pair): the six cells
+# whose step holds the kernels, as `tools/sweep_flash_fwd.py` has them
+CELLS = [
+    pytest.param(4096, 128, 128, (1024, 1024), (512, 512),
+                 id="ouro_2p6b_pp6"),
+    pytest.param(16_384, 192, 128, (1024, 1024), (512, 512),
+                 id="kimi_linear_ep32"),
+    pytest.param(16_384, 128, 128, (1024, 1024), (1024, 1024),
+                 id="dsv3_long"),
+    pytest.param(16_384, 256, 256, (1024, 1024), (512, 512),
+                 id="qwen3next_ep16"),
+    pytest.param(16_384, 128, 128, (1024, 1024), (1024, 1024),
+                 id="nemotron3_nano_ep16"),
+    pytest.param(8192, 64, 64, (1024, 1024), (1024, 1024),
+                 id="granite4_h_micro_pp4"),
+]
+
+
+@pytest.mark.parametrize("seq,dk,dv,forward,backward", CELLS)
+def test_blocks_at_the_cells_shapes(seq, dk, dv, forward, backward):
+    """What the forward sweep chose for the forward kernel, and for the
+    backward kernels what `auto_block` always gave; with dropout on, or a
+    block named by the caller, ONE tiling for all three kernels (the
+    dropout mask of a tile is seeded by the tile's number in its tiling)."""
+    assert flash_blocks(seq, seq, dk, dv) == (forward, backward)
+    assert flash_blocks(seq, seq, dk, dv, 0.1) == (backward, backward)
+    assert flash_blocks(seq, seq, dk, dv, block_q=256) == (
+        (256, backward[1]),) * 2
+    assert flash_blocks(seq, seq, dk, dv, block_k=2048) == (
+        (backward[0], 2048),) * 2
+    assert flash_blocks(seq, seq, dk, dv, 0.1, 256, 128) == ((256, 128),) * 2
+
+
+def test_blocks_shrink_to_a_divisor_of_either_sequence():
+    """The forward pair shrinks like the backward's: a short or ragged
+    sequence runs in the largest power-of-two part of the asked block that
+    divides it, the query side as one block where that is no whole number
+    of lanes; end-aligned prefill gets each side from its own length; a
+    width the sweep never saw keeps the backward's pair."""
+    assert flash_blocks(256, 256, 256, 256) == ((256, 256), (256, 256))
+    assert flash_blocks(96, 96, 32, 32) == ((96, 96), (96, 96))
+    assert flash_blocks(2016, 2016, 128, 128) == ((2016, 32), (2016, 32))
+    assert flash_blocks(512, 4096, 128, 128) == ((512, 1024), (512, 512))
+    assert flash_blocks(4096, 4096, 512, 128) == ((512, 512), (512, 512))

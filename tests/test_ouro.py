@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import keep_against_plain_remat
 
 from benchmarks.adapters import ouro as adapter
 from benchmarks.drivers.train_job import Rows
@@ -444,11 +445,18 @@ def test_train_step_names_every_layer_application_the_gate_and_the_passes(
         assert {s.pass_ for s in top if s.layer == layer} >= {"fwd", "bwd"}
     assert not layers & {"L_moe_gate", "L_moe_experts", "L_ssm_core",
                          "L_gdn_core", "L_kda_core"}
-    # this family's remat has no policy (keeping 32 layer applications' o
-    # and lse, 1.02 GiB, was measured on a scratch copy and left to the
-    # next issue: PERF.md 7 (ad)): the forward kernel runs again
-    assert {s.pass_ for s in top if s.layer == "flash_mla_fwd"} == (
-        {"fwd", "remat"} if use_flash else set())
+    # the flash kernels are scopes of their own; the layers' remat keeps
+    # the forward kernel's o and lse (FLASH_RESIDUALS), so it stands in
+    # the step once a layer application, while the projections around it
+    # run again
+    flash = {k: {s.pass_ for s in top if s.layer == k}
+             for k in hlo_cost.KERNEL_SCOPES}
+    assert flash == ({"flash_mla_fwd": {"fwd"}, "flash_mla_bwd_dq": {"bwd"},
+                      "flash_mla_bwd_dkv": {"bwd"}} if use_flash
+                     else dict.fromkeys(hlo_cost.KERNEL_SCOPES, set()))
+    if use_flash:
+        assert {s.pass_ for s in top if s.layer == "L_attn_proj"} == {
+            "fwd", "remat", "bwd"}
     # the loop is unrolled: every pass's instructions carry its scope, and
     # no `while` holds a whole pass (the per-token stages' and the head's
     # loops are inside a layer's scope, each one event of that layer)
@@ -459,6 +467,26 @@ def test_train_step_names_every_layer_application_the_gate_and_the_passes(
             assert hlo_cost._SCOPE_RE.search(line), line[:200]
     covered = sum(s.layer is not None for s in top) / len(top)
     assert covered >= 0.9, f"{covered:.3f} of {len(top)} top-level instructions"
+
+
+def test_keeping_the_flash_results_changes_no_bit_of_loss_or_gradient(
+        monkeypatch):
+    """The layers' remat with `save_only_these_names(*FLASH_RESIDUALS)`
+    against the same model under a plain `nn.remat(..., prevent_cse=True)`:
+    one forward kernel a layer application in the gradient (two layers,
+    four passes) where the plain one holds two, the loss and every gradient
+    leaf bit for bit."""
+    cfg = tiny(dtype="float32", remat=True, use_flash=True)
+    _, _, tree = seeded(cfg)
+    model, b = Ouro(cfg), batch()
+    uses = cfg.total_ut_steps * cfg.num_hidden_layers
+
+    n_kept, n_plain = keep_against_plain_remat(
+        monkeypatch, lambda: jax.value_and_grad(lambda p: ouro_loss_fn(
+            model, p, b, jax.random.key(0), None, True)[0]),
+        tree, ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"))
+    assert n_kept == (uses, uses, uses)
+    assert n_plain == (2 * uses, uses, uses)
 
 
 def test_count_file_against_the_shapes_and_a_cost_analysis():
