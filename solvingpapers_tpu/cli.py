@@ -92,6 +92,7 @@ def cmd_train(args) -> int:
         init_fn_for,
         loss_fn_for,
     )
+    from solvingpapers_tpu.configs.families import FAMILIES
     from solvingpapers_tpu.metrics import ConsoleWriter, JSONLWriter, MultiWriter
     from solvingpapers_tpu.sharding import batch_sharding, create_mesh
     from solvingpapers_tpu.train import Trainer
@@ -125,13 +126,11 @@ def cmd_train(args) -> int:
         cfg, model, tok, train_iter, eval_iter_fn = build_char_lm_run(
             cfg, sharding=batch_sharding(mesh, context=cp)
         )
-        if cfg.model_family == "ouro" and not cfg.train.flops_per_token:
-            # the row's `mfu`: a looped model's weights count once a USE
-            # (T passes of the layers, T heads), not once
-            from solvingpapers_tpu.metrics.mfu import looped_flops_per_token
-
+        count_flops = FAMILIES[cfg.model_family].flops_per_token
+        if count_flops is not None and not cfg.train.flops_per_token:
+            # the row's `mfu`, where the family counts its own operations
             cfg = dataclasses.replace(cfg, train=dataclasses.replace(
-                cfg.train, flops_per_token=looped_flops_per_token(
+                cfg.train, flops_per_token=count_flops(
                     cfg.model, cfg.data.get("block_size", 256))))
         trainer = Trainer(
             model, cfg.train, loss_fn=loss_fn_for(cfg),
@@ -402,6 +401,7 @@ def _serve_model(args, *, quiet_random_init: bool = False):
     or an int exit code on a usage error."""
     from solvingpapers_tpu.configs import get_config
     from solvingpapers_tpu.configs.factory import build_char_lm_run
+    from solvingpapers_tpu.configs.families import FAMILIES
     from solvingpapers_tpu.serve.openai import extend_token_table
 
     cfg = get_config(args.config)
@@ -410,20 +410,10 @@ def _serve_model(args, *, quiet_random_init: bool = False):
               "export the stage-stacked params to the dense family first",
               file=sys.stderr)
         return 2
-    if cfg.model_family in ("qwen3next", "kimi_linear", "nemotron_h",
-                            "granite_hybrid"):
+    unservable = FAMILIES[cfg.model_family].unservable
+    if unservable is not None:
         print(f"serving is unsupported for the {cfg.model_family} family: "
-              "its recurrent layers (Gated DeltaNet, Kimi Delta Attention, "
-              "Mamba-2) keep recurrent state, and no cache manager here "
-              "holds that yet (ROADMAP R-M7); `cli train` runs it",
-              file=sys.stderr)
-        return 2
-    if cfg.model_family == "ouro":
-        print("serving is unsupported for the ouro family: a looped model "
-              "keeps keys and values a (pass, layer) and may leave the loop "
-              "at a gate threshold, and no cache manager or decode step "
-              "here does that yet (ROADMAP R-M15); `cli train` runs it",
-              file=sys.stderr)
+              f"{unservable}; `cli train` runs it", file=sys.stderr)
         return 2
     if getattr(cfg.model, "context_parallel", False):
         # params are replicated at rest: serve the dense twin, exactly

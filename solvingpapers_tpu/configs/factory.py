@@ -1,4 +1,5 @@
-"""Build model / data / trainer objects from a RunConfig."""
+"""Build model / data / trainer objects from a RunConfig; what differs by
+family is read from the family table (`configs/families.py`)."""
 
 from __future__ import annotations
 
@@ -9,117 +10,19 @@ import numpy as np
 
 from solvingpapers_tpu.data import load_char_corpus
 from solvingpapers_tpu.data.batches import lm_batch_iterator, prefetch_batches
+from solvingpapers_tpu.configs.families import FAMILIES, resolve
 from solvingpapers_tpu.configs.registry import RunConfig
 from solvingpapers_tpu.metrics.trace import run_span
 
 
 def build_model(cfg: RunConfig):
-    fam = cfg.model_family
-    if fam == "gpt":
-        from solvingpapers_tpu.models.gpt import GPT
-
-        return GPT(cfg.model)
-    if fam == "llama3":
-        from solvingpapers_tpu.models.llama3 import Llama
-
-        return Llama(cfg.model)
-    if fam == "gemma":
-        from solvingpapers_tpu.models.gemma import Gemma
-
-        return Gemma(cfg.model)
-    if fam == "deepseekv3":
-        from solvingpapers_tpu.models.deepseekv3 import DeepSeekV3
-
-        return DeepSeekV3(cfg.model)
-    if fam == "qwen3next":
-        from solvingpapers_tpu.models.qwen3next import Qwen3Next
-
-        return Qwen3Next(cfg.model)
-    if fam == "kimi_linear":
-        from solvingpapers_tpu.models.kimi_linear import KimiLinear
-
-        return KimiLinear(cfg.model)
-    if fam == "nemotron_h":
-        from solvingpapers_tpu.models.nemotron_h import NemotronH
-
-        return NemotronH(cfg.model)
-    if fam == "ouro":
-        from solvingpapers_tpu.models.ouro import Ouro
-
-        return Ouro(cfg.model)
-    if fam == "granite_hybrid":
-        from solvingpapers_tpu.models.granite_hybrid import GraniteHybrid
-
-        return GraniteHybrid(cfg.model)
-    if fam == "gpt_pipe":
-        from solvingpapers_tpu.models.gpt_pipe import GPTPipe
-
-        return GPTPipe(cfg.model)
-    if fam == "dsv3_pipe":
-        from solvingpapers_tpu.models.deepseekv3_pipe import DSV3Pipe
-
-        return DSV3Pipe(cfg.model)
-    if fam == "llama3_pipe":
-        from solvingpapers_tpu.models.llama3_pipe import LlamaPipe
-
-        return LlamaPipe(cfg.model)
-    if fam == "vit":
-        from solvingpapers_tpu.models.vit import ViT
-
-        return ViT(cfg.model)
-    if fam == "alexnet":
-        from solvingpapers_tpu.models.alexnet import AlexNet
-
-        return AlexNet(cfg.model)
-    if fam == "ae":
-        from solvingpapers_tpu.models.autoencoder import AutoEncoder
-
-        return AutoEncoder(cfg.model)
-    if fam == "vae":
-        from solvingpapers_tpu.models.autoencoder import VAE
-
-        return VAE(cfg.model)
-    if fam == "kd":
-        from solvingpapers_tpu.models.kd import MLPClassifier
-
-        return MLPClassifier(cfg.model)
-    raise ValueError(f"unknown model family {cfg.model_family!r}")
+    return resolve(FAMILIES[cfg.model_family].model)(cfg.model)
 
 
 def loss_fn_for(cfg: RunConfig):
     """Objective for a RunConfig's family (kd's teacher phase uses
     classification; its student phase is built in train.kd_pipeline)."""
-    from solvingpapers_tpu.train import (
-        classification_loss_fn,
-        lm_loss_fn,
-        reconstruction_loss_fn,
-        vae_loss_fn,
-    )
-    from solvingpapers_tpu.train.objectives import (
-        dsv3_loss_fn, granite_hybrid_loss_fn, kimi_linear_loss_fn,
-        ouro_loss_fn, qwen3next_loss_fn,
-    )
-
-    return {
-        "gpt": lm_loss_fn,
-        "gpt_pipe": lm_loss_fn,
-        "llama3": lm_loss_fn,
-        "llama3_pipe": lm_loss_fn,
-        "gemma": lm_loss_fn,
-        "deepseekv3": dsv3_loss_fn,
-        "dsv3_pipe": dsv3_loss_fn,
-        "qwen3next": qwen3next_loss_fn,
-        "kimi_linear": kimi_linear_loss_fn,
-        # the same objective: cross-entropy alone, head and loss in chunks
-        "nemotron_h": kimi_linear_loss_fn,
-        "ouro": ouro_loss_fn,
-        "granite_hybrid": granite_hybrid_loss_fn,
-        "vit": classification_loss_fn,
-        "alexnet": classification_loss_fn,
-        "kd": classification_loss_fn,
-        "ae": reconstruction_loss_fn,
-        "vae": vae_loss_fn,
-    }[cfg.model_family]
+    return resolve(FAMILIES[cfg.model_family].objective)
 
 
 def rules_for(cfg: RunConfig):
@@ -133,11 +36,8 @@ def rules_for(cfg: RunConfig):
 
 def init_fn_for(cfg: RunConfig):
     """Trainer init_fn override (None = default params-only init)."""
-    if cfg.model_family in ("deepseekv3", "dsv3_pipe"):
-        from solvingpapers_tpu.train.objectives import dsv3_init_fn
-
-        return dsv3_init_fn
-    return None
+    init_fn = FAMILIES[cfg.model_family].init_fn
+    return None if init_fn is None else resolve(init_fn)
 
 
 def build_image_run(cfg: RunConfig, mesh=None):

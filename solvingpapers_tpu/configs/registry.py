@@ -13,7 +13,7 @@ from solvingpapers_tpu.train.optim import OptimizerConfig
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     name: str
-    model_family: str  # gpt | llama3 | gemma | deepseekv3 | qwen3next | kimi_linear | nemotron_h | ouro | granite_hybrid | vit | alexnet | ae | vae | kd
+    model_family: str  # a key of `configs/families.py` `FAMILIES`
     model: Any
     train: TrainConfig
     data: dict = dataclasses.field(default_factory=dict)

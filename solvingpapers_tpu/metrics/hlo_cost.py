@@ -383,9 +383,9 @@ def format_anatomy(anatomy: dict) -> str:
 # ------------------------------------------------------- layers of a program
 
 # The layer scopes of the train step, one `jax.named_scope` at each layer
-# boundary (models/deepseekv3.py, models/qwen3next.py, models/kimi_linear.py,
-# ops/moe.py, ops/losses.py, train/engine.py). Single tokens that no Flax
-# module is named. `L_gdn_*` are a Gated DeltaNet layer's: its projections,
+# boundary (a family's models/<family>.py, models/mixers.py, models/layers.py,
+# ops/moe.py, ops/losses.py, train/objectives.py, train/engine.py; a family
+# adds one only with a new layer). Single tokens that no Flax module is named. `L_gdn_*` are a Gated DeltaNet layer's: its projections,
 # norms and gate; its causal convolution; the chunked gated delta rule.
 # `L_kda_*` are the same three of a Kimi Delta Attention layer (the decay a
 # key channel is made in `L_kda_proj`); `L_dense_ffn` a dense SwiGLU layer

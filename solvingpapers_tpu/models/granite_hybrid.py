@@ -11,7 +11,7 @@ names). With E the embedding (V, D), also the head:
   * x = `embedding_multiplier` * E[tokens]
   * layer i, of the kind `layer_types[i]` says:
       h = Norm(x; input_layernorm)
-      "mamba": m = the Mamba-2 mixer of `models/nemotron_h.py`
+      "mamba": m = the Mamba-2 mixer of `models/mixers.py`
         (`Mamba2Mixer`: `in_proj` to [z | xBC | dt], the causal depthwise
         convolution with bias and SiLU, the recurrence of `ops/ssd.py`
         through the Pallas kernels, y * SiLU(z) normed, `out_proj`), here
@@ -30,7 +30,7 @@ names). With E the embedding (V, D), also the head:
     embedding, tied; 1 / `logits_scaling` (a power of two: exact) is folded
     into the normed rows, so that the chunked head-with-loss
     (`ops.head_cross_entropy`, `train/objectives.py`
-    `granite_hybrid_loss_fn`) takes E^T as its kernel and the logits are
+    `chunked_head_loss_fn`) takes E^T as its kernel and the logits are
     never whole. E's gradient is the float32 sum of its two uses.
   * norm: x * rsqrt(mean(x^2) + eps) * w, float32, w one at the start
 
@@ -58,8 +58,10 @@ from flax import linen as nn
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.kernels.ssd import SSD_RESIDUALS
-from solvingpapers_tpu.models.layers import blocked_swiglu
-from solvingpapers_tpu.models.nemotron_h import Mamba2Mixer, NoPEAttention
+from solvingpapers_tpu.models.layers import (
+    blocked_swiglu, remat_keeping, training_only,
+)
+from solvingpapers_tpu.models.mixers import Mamba2Mixer, NoPEAttention
 from solvingpapers_tpu.ops import ssd
 
 # every matrix starts normal(0, 0.02) (assumed: the source's config.json, as
@@ -163,8 +165,8 @@ class GraniteHybridConfig:
         """One kind a layer that runs here."""
         return self.layer_types[:self.num_hidden_layers]
 
-    # --- what `Mamba2Mixer` and `NoPEAttention` read, under the names
-    # `NemotronHConfig` gives them
+    # --- what `Mamba2Mixer` and `NoPEAttention` read (`models/mixers.py`
+    # `Mamba2Config`, `NoPEAttentionConfig`), under the names they read it by
 
     @property
     def head_dim(self) -> int:
@@ -252,20 +254,14 @@ class GraniteHybrid(nn.Module):
         return (logits, caches); with `head` False the normed hidden states
         times 1 / `logits_scaling`, (B, S, D) in the compute dtype, for a
         loss that applies the tied head itself a chunk of rows at a time
-        (`granite_hybrid_loss_fn`). Training and scoring only: the family
-        has no decode cache yet, and no dropout."""
+        (`chunked_head_loss_fn`, which takes E^T from `head_kernel`).
+        Training and scoring only: the family has no decode cache yet, and
+        no dropout."""
         cfg = self.cfg
-        if caches is not None:
-            raise NotImplementedError(
-                "granite_hybrid has no decode cache: a Mamba-2 layer keeps "
-                "recurrent state, which no cache manager here holds yet "
-                "(ROADMAP R-M7)"
-            )
-        if tokens.shape[1] > cfg.block_size:
-            raise ValueError(
-                f"sequence {tokens.shape[1]} exceeds block_size "
-                f"{cfg.block_size}"
-            )
+        training_only(
+            "granite_hybrid", cfg, tokens, caches,
+            "a Mamba-2 layer keeps recurrent state, which no cache manager "
+            "here holds yet (ROADMAP R-M7)")
         emb = nn.Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
             embedding_init=_INIT, name="tok_emb")
@@ -277,11 +273,8 @@ class GraniteHybrid(nn.Module):
         # state entering each of its 16 grid steps (64 + 32 MiB at 64
         # heads of 64 x 128); the mixer's projections and the SwiGLU
         # behind it are made again
-        layer_cls = (nn.remat(
-            GraniteHybridLayer, prevent_cse=True,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS, *SSD_RESIDUALS),
-        ) if cfg.remat else GraniteHybridLayer)
+        layer_cls = remat_keeping(
+            GraniteHybridLayer, cfg.remat, *FLASH_RESIDUALS, *SSD_RESIDUALS)
         for i, kind in enumerate(cfg.layer_pattern):
             x = layer_cls(cfg, kind, name=f"layer_{i}")(x)
         with jax.named_scope("L_loss_head"):
@@ -293,6 +286,6 @@ class GraniteHybrid(nn.Module):
                 return x, None
             return x @ emb.embedding.astype(cfg.compute_dtype).T, None
 
-    @property
-    def max_positions(self) -> int:
-        return self.cfg.block_size
+    def head_kernel(self, params) -> jax.Array:  # (D, V): E^T, the tied head
+        with jax.named_scope("L_loss_head"):
+            return params["tok_emb"]["embedding"].T

@@ -27,7 +27,7 @@ names, those of its `linear_attn_config` group prefixed `linear_`).
     is [k_n | k_r], k_r shared by all heads, NO rotation on either side
     (`mla_use_nope`); causal softmax at (d_nope + d_rope)^-0.5 through the
     flash kernels with keys 192 and values 128 wide; o_proj
-  * MoE (`HeldExpertsMoE` of models/qwen3next.py, sigmoid scoring): s =
+  * MoE (`HeldExpertsMoE` of models/mixers.py, sigmoid scoring): s =
     sigmoid(x W_r) over ALL `router_experts` in float32; the chosen are the
     top-k of s + a selection bias that takes no gradient; weights s / sum of
     the chosen s * `routed_scaling_factor`; this device computes the
@@ -56,12 +56,12 @@ from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.kernels.gated_delta import DELTA_RESIDUALS
 from solvingpapers_tpu.models.layers import (
-    apply_flash_attention, blocked_swiglu,
+    _by_blocks, blocked_swiglu, causal_attention, remat_keeping,
+    training_only,
 )
-from solvingpapers_tpu.models.qwen3next import (
-    HeldExpertsMoE, _a_log_init, _by_blocks,
-)
-from solvingpapers_tpu.ops import gated_delta, kda
+from solvingpapers_tpu.models.mixers import HeldExpertsMoE, delta_a_log_init
+from solvingpapers_tpu.ops import kda
+from solvingpapers_tpu.ops.conv import causal_depthwise_conv
 
 # every matrix starts normal(0, 0.02), the family's initializer_range
 _INIT = nn.initializers.normal(0.02)
@@ -148,10 +148,6 @@ class KimiLinearConfig:
     def compute_dtype(self) -> jnp.dtype:
         return jnp.dtype(self.dtype)
 
-    @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
-
     def is_attention_layer(self, index: int) -> bool:
         """`index` from 0; the source numbers its layers from 1."""
         return index + 1 in self.full_attn_layers
@@ -200,14 +196,8 @@ class LatentAttention(nn.Module):
 
         with jax.named_scope("L_attn_proj"):
             q, k, v = _by_blocks(before, kda.SEGMENT, x)
-        scale = (d_n + d_r) ** -0.5
-        with jax.named_scope("L_attn_core"):
-            if cfg.use_flash:
-                ctx = apply_flash_attention(
-                    self, q, k, v, causal=True, scale=scale)
-            else:
-                ctx = ops.dot_product_attention(
-                    q, k, v, causal=True, scale=scale)
+        ctx = causal_attention(self, q, k, v, scale=(d_n + d_r) ** -0.5,
+                               use_flash=cfg.use_flash)
         with jax.named_scope("L_attn_proj"):
             return _by_blocks(lambda c: c @ w_out, kda.SEGMENT,
                               ctx.reshape(b, s, n * d_v).astype(dt))
@@ -238,7 +228,7 @@ class KimiDeltaAttention(nn.Module):
                            (cfg.hidden_size, 2 * dk + h)).astype(dt)
         w_f = self.param("f_up", _INIT, (dk, n)).astype(dt)
         w_g = self.param("g_up", _INIT, (dk, n)).astype(dt)
-        a_log = self.param("A_log", _a_log_init, (h,))
+        a_log = self.param("A_log", delta_a_log_init, (h,))
         dt_bias = self.param("dt_bias", nn.initializers.ones, (h, dk))
         k_conv = cfg.short_conv_kernel_size
         conv_w = self.param(
@@ -271,7 +261,7 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("L_kda_proj"):
             qkv, f_low, o_low, beta = _by_blocks(before, kda.SEGMENT, x)
         with jax.named_scope("L_kda_conv"):
-            qkv = gated_delta.causal_depthwise_conv(qkv, conv_w, True)
+            qkv = causal_depthwise_conv(qkv, conv_w, True)
             q, k, v = (qkv[..., i * n:(i + 1) * n].reshape(b, s, h, dk)
                        for i in range(3))
         with jax.named_scope("L_kda_core"):
@@ -359,21 +349,14 @@ class KimiLinear(nn.Module):
         """(B, S) tokens -> ((B, S, V) logits, None), as the other families
         return (logits, caches); with `head` False the normed hidden states
         (B, S, D) in the compute dtype instead, for a loss that applies
-        `lm_head` itself a chunk of rows at a time (`kimi_linear_loss_fn`).
-        Training and scoring only: the family has no decode cache yet, and
-        no dropout."""
+        `lm_head` itself a chunk of rows at a time (`chunked_head_loss_fn`,
+        which takes the kernel from `head_kernel`). Training and scoring
+        only: the family has no decode cache yet, and no dropout."""
         cfg = self.cfg
-        if caches is not None:
-            raise NotImplementedError(
-                "kimi_linear has no decode cache: a KDA layer keeps "
-                "recurrent state, which no cache manager here holds yet "
-                "(ROADMAP R-M7)"
-            )
-        if tokens.shape[1] > cfg.block_size:
-            raise ValueError(
-                f"sequence {tokens.shape[1]} exceeds block_size "
-                f"{cfg.block_size}"
-            )
+        training_only(
+            "kimi_linear", cfg, tokens, caches,
+            "a KDA layer keeps recurrent state, which no cache manager here "
+            "holds yet (ROADMAP R-M7)")
         with jax.named_scope("L_embed"):
             x = nn.Embed(
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
@@ -385,11 +368,8 @@ class KimiLinear(nn.Module):
         # grid steps (128 + 128 MiB at 32 heads of 128 x 128); everything
         # else of a layer is made again, and the dense layer's feed-forward
         # part, with nothing named, remats whole
-        layer_cls = (nn.remat(
-            KimiLinearLayer, prevent_cse=True,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS, *DELTA_RESIDUALS),
-        ) if cfg.remat else KimiLinearLayer)
+        layer_cls = remat_keeping(
+            KimiLinearLayer, cfg.remat, *FLASH_RESIDUALS, *DELTA_RESIDUALS)
         for i in range(cfg.num_hidden_layers):
             x = layer_cls(
                 cfg, cfg.is_attention_layer(i), cfg.is_dense_layer(i),
@@ -407,6 +387,5 @@ class KimiLinear(nn.Module):
                 return x, None
             return lm_head(x), None
 
-    @property
-    def max_positions(self) -> int:
-        return self.cfg.block_size
+    def head_kernel(self, params) -> jax.Array:  # (D, V), the loss's to apply
+        return params["lm_head"]["kernel"]

@@ -7,6 +7,8 @@ the reference implements separately are config points here:
   * Gemma: causal MQA-grouped + RoPE (gemma/gemma.ipynb cell 8)
   * ViT: bidirectional MHA (vision transformer/ViT.ipynb cell 10)
 MLA is structurally different (latent cache) and lives in models/deepseekv3.py.
+Below them the train-only families' shared functions; the mixers more than
+one of them runs are `models/mixers.py`'s.
 
 All dense layers take a compute `dtype` (bf16 for TPU training) with f32
 params; reductions inside ops.* are f32.
@@ -412,11 +414,8 @@ def swiglu_hidden_dim(dim: int, multiplier: int = 4) -> int:
 def apply_flash_attention(module, q, k, v, *, causal, scale=None,
                           dropout_rate=0.0, deterministic=True):
     """Flash attention with the framework's dropout policy, shared by every
-    use_flash model (Attention here, DeepSeekV3's MLA, Qwen3-Next's gated
-    attention at 16 heads on 2 of width 256, Kimi-Linear's latent attention
-    with keys 192 and values 128 wide, Nemotron-H's 32 heads on 2 of width
-    128 and Granite-hybrid's 32 on 8 of width 64, both with no rotation:
-    q (B, S, n, w), k and v (B, S, n_kv, w), each
+    use_flash model (Attention here, DeepSeekV3's MLA, `causal_attention`'s
+    callers: q (B, S, n, w), k (B, S, n_kv, w), v (B, S, n_kv, w_v), each
     key-value head serving n / n_kv query heads): in-kernel prob
     dropout on real TPU (same Bernoulli semantics as the dense path; mask
     regenerated in the backward from the seed, never materialized); when
@@ -453,6 +452,17 @@ def apply_flash_attention(module, q, k, v, *, causal, scale=None,
     return kernel(q, k, v, causal=causal, scale=scale)
 
 
+def causal_attention(module, q, k, v, *, scale: float, use_flash: bool):
+    """Causal softmax attention of q (B, S, n, w) over k, v (B, S, n_kv, w)
+    under the scope `L_attn_core`, as every train-only family runs it:
+    through the flash kernels or, with `use_flash` false, the dense product."""
+    with jax.named_scope("L_attn_core"):
+        if use_flash:
+            return apply_flash_attention(
+                module, q, k, v, causal=True, scale=scale)
+        return ops.dot_product_attention(q, k, v, causal=True, scale=scale)
+
+
 def maybe_remat(block_cls, remat: bool, caches) -> type:
     """Wrap a decoder-block class in jax.checkpoint for training (trades
     recompute for HBM — dense attention at dim/seq 1024 OOMs one v5e
@@ -465,6 +475,29 @@ def maybe_remat(block_cls, remat: bool, caches) -> type:
     if remat and caches is None:
         return nn.remat(block_cls, prevent_cse=False, static_argnums=(4,))
     return block_cls
+
+
+def remat_keeping(layer_cls, remat: bool, *names: str) -> type:
+    """`layer_cls` rematerialised with `prevent_cse=True` (with `False` the
+    compiler kept the first forward: PERF.md 7 (s)) but for the arrays named
+    `names`, the results a kernel's forward rule names: which of them a
+    family has the room to keep is that family's line, with its bytes."""
+    if not remat:
+        return layer_cls
+    policy = (jax.checkpoint_policies.save_only_these_names(*names)
+              if names else None)
+    return nn.remat(layer_cls, prevent_cse=True, policy=policy)
+
+
+def training_only(family: str, cfg, tokens, caches, why: str) -> None:
+    """What the `__call__` of a family that only trains starts with: it
+    refuses a decode cache with the family's own reason `why`, and a
+    sequence past the config's `block_size`."""
+    if caches is not None:
+        raise NotImplementedError(f"{family} has no decode cache: {why}")
+    if tokens.shape[1] > cfg.block_size:
+        raise ValueError(
+            f"sequence {tokens.shape[1]} exceeds block_size {cfg.block_size}")
 
 
 def _by_blocks(fn, block: int, *arrays):

@@ -26,7 +26,7 @@ names).
   * attention: q, k, v projections to `num_attention_heads` and
     `num_key_value_heads` heads of `head_dim`, causal softmax at
     head_dim^-0.5 through the flash kernels, `o_proj`; no bias, no rotation
-  * MoE (`HeldExpertsMoE` of models/qwen3next.py, sigmoid scoring, ungated):
+  * MoE (`HeldExpertsMoE` of models/mixers.py, sigmoid scoring, ungated):
     s = sigmoid(x W_r) over ALL `router_experts` in float32; the chosen are
     the top-k of s + a selection bias that takes no gradient; weights s /
     sum of the chosen s * `routed_scaling_factor`; an expert is W_down
@@ -49,8 +49,6 @@ neither).
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +57,10 @@ from flax import linen as nn
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.kernels.ssd import SSD_RESIDUALS
-from solvingpapers_tpu.models.layers import apply_flash_attention
-from solvingpapers_tpu.models.qwen3next import HeldExpertsMoE, _by_blocks
-from solvingpapers_tpu.ops import gated_delta, ssd
+from solvingpapers_tpu.models.layers import remat_keeping, training_only
+from solvingpapers_tpu.models.mixers import (
+    HeldExpertsMoE, Mamba2Mixer, NoPEAttention,
+)
 
 # every matrix starts normal(0, 0.02), the family's initializer_range
 _INIT = nn.initializers.normal(0.02)
@@ -171,141 +170,6 @@ class NemotronHConfig:
         return self.head_dim ** -0.5
 
 
-def _dt_bias_init(cfg):
-    """The inverse softplus of a step drawn log-uniformly between
-    `time_step_min` and `time_step_max`, floored at `time_step_floor`."""
-    lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-
-    def init(key, shape, dtype=jnp.float32):
-        step = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, dtype, lo,
-                                                      hi)),
-                           cfg.time_step_floor)
-        return step + jnp.log(-jnp.expm1(-step))
-
-    return init
-
-
-def _a_log_init(key, shape, dtype=jnp.float32):
-    """log U(1, 16), the family's range for -a."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-class Mamba2Mixer(nn.Module):
-    """Norm(x) -> the Mamba-2 mixer. The input norm is applied here
-    (`norm_w` is its weight), inside the first of the two per-token stages
-    that run block by block (`_by_blocks`): projections and step before the
-    rule, gated norm and `out_proj` after it. Each stage is one loop under
-    its own scope, so a device trace tells projections, convolution and rule
-    apart. `cfg` is a `NemotronHConfig`, or another family's config that
-    names the same sizes (`models/granite_hybrid.py`'s: one group of 64
-    heads, chunks of 256); the residual add is the caller's."""
-
-    cfg: Any
-
-    @nn.compact
-    def __call__(self, x, norm_w):
-        cfg = self.cfg
-        b, s, _ = x.shape
-        h, p = cfg.mamba_num_heads, cfg.mamba_head_dim
-        g, n = cfg.n_groups, cfg.ssm_state_size
-        d_in, d_conv = cfg.d_inner, cfg.conv_dim
-        dt = cfg.compute_dtype
-        # one weight, [z | xBC | dt] by columns; each part leaves by its own
-        # product, so that no slice of the output is copied
-        w_in = self.param("in_proj", _INIT,
-                          (cfg.hidden_size, d_in + d_conv + h)).astype(dt)
-        k_conv = cfg.conv_kernel
-        conv_w = self.param(
-            "conv_w", nn.initializers.normal((3.0 * k_conv) ** -0.5),
-            (k_conv, d_conv))
-        conv_b = self.param(
-            "conv_b", nn.initializers.normal((3.0 * k_conv) ** -0.5),
-            (d_conv,))
-        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (h,))
-        a_log = self.param("A_log", _a_log_init, (h,))
-        skip = self.param("D", nn.initializers.ones, (h,))
-        w_n = self.param("norm_weight", nn.initializers.ones, (d_in,))
-        w_out = self.param("out_proj", _INIT,
-                           (d_in, cfg.hidden_size)).astype(dt)
-
-        def before(x):
-            hid = ops.rms_norm(x.astype(jnp.float32), norm_w,
-                               cfg.layer_norm_epsilon).astype(dt)
-            step = jax.nn.softplus(
-                (hid @ w_in[:, d_in + d_conv:]).astype(jnp.float32)
-                + dt_bias)
-            xbc, z = hid @ w_in[:, d_in:d_in + d_conv], hid @ w_in[:, :d_in]
-            return xbc, z, step
-
-        @jax.checkpoint
-        def conv(xbc):
-            # the bias enters BEFORE the SiLU; the backward starts again
-            # from the convolution's input, so its output is not kept
-            y = gated_delta.causal_depthwise_conv(xbc, conv_w, False)
-            return jax.nn.silu(y + conv_b.astype(y.dtype))
-
-        def after(y, z):
-            u = ssd.gate_then_group_norm(y, z, w_n, g,
-                                         cfg.layer_norm_epsilon)
-            return u @ w_out
-
-        with jax.named_scope("L_ssm_proj"):
-            xbc, z, step = _by_blocks(before, ssd.SEGMENT, x)
-        with jax.named_scope("L_ssm_conv"):
-            xbc = conv(xbc)
-            xs = xbc[..., :d_in].reshape(b, s, h, p)
-            bs = xbc[..., d_in:d_in + g * n].reshape(b, s, g, n)
-            cs = xbc[..., d_in + g * n:].reshape(b, s, g, n)
-        with jax.named_scope("L_ssm_core"):
-            y, _ = ssd.ssd_chunked(xs, step, -jnp.exp(a_log), bs, cs, skip,
-                                   chunk=cfg.chunk_size)
-        with jax.named_scope("L_ssm_proj"):
-            return _by_blocks(after, ssd.SEGMENT, y.reshape(b, s, d_in), z)
-
-
-class NoPEAttention(nn.Module):
-    """Norm(x) -> grouped-query causal attention without positions. As in
-    `Mamba2Mixer` the input norm is applied here and the per-token stages
-    run block by block: the three projections before the attention product,
-    `o_proj` after it. The softmax's scale is the config's
-    `attention_scale` (`cfg` as in `Mamba2Mixer`)."""
-
-    cfg: Any
-
-    @nn.compact
-    def __call__(self, x, norm_w):
-        cfg = self.cfg
-        b, s, d = x.shape
-        n, kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        dt = cfg.compute_dtype
-        w_q = self.param("q_proj", _INIT, (d, n * hd)).astype(dt)
-        w_k = self.param("k_proj", _INIT, (d, kv * hd)).astype(dt)
-        w_v = self.param("v_proj", _INIT, (d, kv * hd)).astype(dt)
-        w_out = self.param("o_proj", _INIT, (n * hd, d)).astype(dt)
-
-        def before(x):
-            hid = ops.rms_norm(x.astype(jnp.float32), norm_w,
-                               cfg.layer_norm_epsilon).astype(dt)
-            lead = hid.shape[:2]
-            return ((hid @ w_q).reshape(lead + (n, hd)),
-                    (hid @ w_k).reshape(lead + (kv, hd)),
-                    (hid @ w_v).reshape(lead + (kv, hd)))
-
-        with jax.named_scope("L_attn_proj"):
-            q, k, v = _by_blocks(before, ssd.SEGMENT, x)
-        with jax.named_scope("L_attn_core"):
-            if cfg.use_flash:
-                ctx = apply_flash_attention(
-                    self, q, k, v, causal=True, scale=cfg.attention_scale)
-            else:
-                ctx = ops.dot_product_attention(
-                    q, k, v, causal=True, scale=cfg.attention_scale)
-        with jax.named_scope("L_attn_proj"):
-            return _by_blocks(lambda c: c @ w_out, ssd.SEGMENT,
-                              ctx.reshape(b, s, n * hd).astype(dt))
-
-
 def held_moe(cfg: NemotronHConfig, name: str | None = None) -> HeldExpertsMoE:
     """The held-experts layer as this family's config words it."""
     return HeldExpertsMoE(
@@ -355,21 +219,14 @@ class NemotronH(nn.Module):
         """(B, S) tokens -> ((B, S, V) logits, None), as the other families
         return (logits, caches); with `head` False the normed hidden states
         (B, S, D) in the compute dtype instead, for a loss that applies
-        `lm_head` itself a chunk of rows at a time (`kimi_linear_loss_fn`).
-        Training and scoring only: the family has no decode cache yet, and
-        no dropout."""
+        `lm_head` itself a chunk of rows at a time (`chunked_head_loss_fn`,
+        which takes the kernel from `head_kernel`). Training and scoring
+        only: the family has no decode cache yet, and no dropout."""
         cfg = self.cfg
-        if caches is not None:
-            raise NotImplementedError(
-                "nemotron_h has no decode cache: a Mamba-2 layer keeps "
-                "recurrent state, which no cache manager here holds yet "
-                "(ROADMAP R-M7)"
-            )
-        if tokens.shape[1] > cfg.block_size:
-            raise ValueError(
-                f"sequence {tokens.shape[1]} exceeds block_size "
-                f"{cfg.block_size}"
-            )
+        training_only(
+            "nemotron_h", cfg, tokens, caches,
+            "a Mamba-2 layer keeps recurrent state, which no cache manager "
+            "here holds yet (ROADMAP R-M7)")
         with jax.named_scope("L_embed"):
             x = nn.Embed(
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
@@ -381,11 +238,8 @@ class NemotronH(nn.Module):
         # grid steps (128 + 64 MiB at 64 heads of 64 x 128); everything
         # else of a layer is made again, and an expert layer, with nothing
         # named, remats whole
-        layer_cls = (nn.remat(
-            NemotronHLayer, prevent_cse=True,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS, *SSD_RESIDUALS),
-        ) if cfg.remat else NemotronHLayer)
+        layer_cls = remat_keeping(
+            NemotronHLayer, cfg.remat, *FLASH_RESIDUALS, *SSD_RESIDUALS)
         for i, kind in enumerate(cfg.layer_pattern):
             x = layer_cls(cfg, kind, name=f"layer_{i}")(x)
         with jax.named_scope("L_loss_head"):
@@ -400,6 +254,5 @@ class NemotronH(nn.Module):
                 return x, None
             return lm_head(x), None
 
-    @property
-    def max_positions(self) -> int:
-        return self.cfg.block_size
+    def head_kernel(self, params) -> jax.Array:  # (D, V), the loss's to apply
+        return params["lm_head"]["kernel"]

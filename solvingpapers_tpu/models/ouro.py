@@ -46,9 +46,9 @@ from flax import linen as nn
 from solvingpapers_tpu import ops
 from solvingpapers_tpu.kernels.flash_attention import FLASH_RESIDUALS
 from solvingpapers_tpu.models.layers import (
-    _by_blocks, apply_flash_attention, blocked_swiglu,
+    _by_blocks, blocked_swiglu, causal_attention, remat_keeping,
+    training_only,
 )
-from solvingpapers_tpu.models.qwen3next import partial_rotary
 
 # every matrix starts normal(0, 0.02), the family's initializer_range
 _INIT = nn.initializers.normal(0.02)
@@ -154,15 +154,10 @@ class OuroLayer(nn.Module):
 
         with jax.named_scope("L_attn_proj"):
             q, k, v = _by_blocks(before, SEGMENT, x)
-            q = partial_rotary(q, hd, cfg.rope_theta)
-            k = partial_rotary(k, hd, cfg.rope_theta)
-        with jax.named_scope("L_attn_core"):
-            if cfg.use_flash:
-                ctx = apply_flash_attention(
-                    self, q, k, v, causal=True, scale=hd ** -0.5)
-            else:
-                ctx = ops.dot_product_attention(
-                    q, k, v, causal=True, scale=hd ** -0.5)
+            q = ops.partial_rotary(q, hd, cfg.rope_theta)
+            k = ops.partial_rotary(k, hd, cfg.rope_theta)
+        ctx = causal_attention(self, q, k, v, scale=hd ** -0.5,
+                               use_flash=cfg.use_flash)
         with jax.named_scope("L_attn_proj"):
             x = _by_blocks(after, SEGMENT,
                            ctx.reshape(b, s, n * hd).astype(dt), x)
@@ -183,17 +178,10 @@ class Ouro(nn.Module):
         a loss that applies `lm_head` itself a chunk of rows at a time
         (`ouro_loss_fn`). Training and scoring only: no decode cache."""
         cfg = self.cfg
-        if caches is not None:
-            raise NotImplementedError(
-                "ouro has no decode cache: a looped model keeps keys and "
-                "values a (pass, layer), which no cache manager here holds "
-                "yet (ROADMAP R-M15)"
-            )
-        if tokens.shape[1] > cfg.block_size:
-            raise ValueError(
-                f"sequence {tokens.shape[1]} exceeds block_size "
-                f"{cfg.block_size}"
-            )
+        training_only(
+            "ouro", cfg, tokens, caches,
+            "a looped model keeps keys and values a (pass, layer), which no "
+            "cache manager here holds yet (ROADMAP R-M15)")
         d = cfg.hidden_size
         with jax.named_scope("L_embed"):
             x = nn.Embed(
@@ -203,11 +191,7 @@ class Ouro(nn.Module):
         # the flash forward kernel's o and lse are kept, not made again: 32.5
         # MiB a layer application at 16 heads of 2 x 4,096 tokens, 1.02 GiB
         # for the 32, dead before the step's largest live set
-        layer_cls = (nn.remat(
-            OuroLayer, prevent_cse=True,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS),
-        ) if cfg.remat else OuroLayer)
+        layer_cls = remat_keeping(OuroLayer, cfg.remat, *FLASH_RESIDUALS)
         # ONE set of layers, applied in every pass
         layers = [layer_cls(cfg, name=f"layer_{i}")
                   for i in range(cfg.num_hidden_layers)]
@@ -231,7 +215,3 @@ class Ouro(nn.Module):
             return (jnp.stack(states), jnp.stack(gates)), None
         with jax.named_scope("L_loss_head"):
             return lm_head(states[-1]), None
-
-    @property
-    def max_positions(self) -> int:
-        return self.cfg.block_size

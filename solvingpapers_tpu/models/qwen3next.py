@@ -21,7 +21,8 @@ of `Qwen3NextConfig` that the source states carry the source's names).
     [q | k | v] then SiLU; beta = sigmoid(b), g = -exp(A_log) *
     softplus(a + dt_bias); the gated delta rule (ops/gated_delta.py);
     o * rsqrt(mean(o^2) + eps) * w_n a head, times SiLU(z); out_proj
-  * MoE: softmax over ALL `router_experts` in float32, top-k renormalised;
+  * MoE (`HeldExpertsMoE` of models/mixers.py, softmax scoring): softmax
+    over ALL `router_experts` in float32, top-k renormalised;
     this device computes the experts it holds, [first_expert, first_expert
     + num_experts), through capacity slots with no exchange (what the other
     experts would add is left out: one expert-parallel rank's part); plus
@@ -37,20 +38,19 @@ manager that holds recurrent state, ROADMAP R-M7).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from solvingpapers_tpu import ops
-from solvingpapers_tpu.kernels import moe_grouped
 from solvingpapers_tpu.models.layers import (
-    GLUFFN, MLP, _by_blocks, apply_flash_attention,
+    _by_blocks, causal_attention, training_only,
 )
+from solvingpapers_tpu.models.mixers import HeldExpertsMoE, delta_a_log_init
 from solvingpapers_tpu.ops import gated_delta
+from solvingpapers_tpu.ops.conv import causal_depthwise_conv
 
-HI = jax.lax.Precision.HIGHEST
 # every matrix starts as the family does: normal, initializer_range 0.02
 _INIT = nn.initializers.normal(0.02)
 
@@ -123,20 +123,6 @@ class ZeroCenteredRMSNorm(nn.Module):
         return ops.rms_norm(x.astype(jnp.float32), 1.0 + w, self.eps)
 
 
-def partial_rotary(x: jax.Array, rotary_dim: int, theta: float) -> jax.Array:
-    """Rotate-half rotary embedding on the first `rotary_dim` features of x
-    (B, S, heads, head_dim), positions 0..S-1; the rest pass through."""
-    s = x.shape[1]
-    half = rotary_dim // 2
-    freqs = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:rotary_dim]
-    rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return jnp.concatenate([rot, x32[..., rotary_dim:]], -1).astype(x.dtype)
-
-
 class GatedAttention(nn.Module):
     cfg: Qwen3NextConfig
 
@@ -157,27 +143,15 @@ class GatedAttention(nn.Module):
             v = dense(kv * hd, "v_proj")(x).reshape(b, s, kv, hd)
             q = ZeroCenteredRMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
             k = ZeroCenteredRMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
-            q = partial_rotary(q, cfg.rotary_dim, cfg.rope_theta).astype(dt)
-            k = partial_rotary(k, cfg.rotary_dim, cfg.rope_theta).astype(dt)
-        with jax.named_scope("L_attn_core"):
-            if cfg.use_flash:
-                ctx = apply_flash_attention(
-                    self, q, k, v, causal=True, scale=hd ** -0.5
-                )
-            else:
-                ctx = ops.dot_product_attention(
-                    q, k, v, causal=True, scale=hd ** -0.5
-                )
+            q = ops.partial_rotary(q, cfg.rotary_dim, cfg.rope_theta).astype(dt)
+            k = ops.partial_rotary(k, cfg.rotary_dim, cfg.rope_theta).astype(dt)
+        ctx = causal_attention(self, q, k, v, scale=hd ** -0.5,
+                               use_flash=cfg.use_flash)
         with jax.named_scope("L_attn_proj"):
             ctx = ctx.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))
             return dense(cfg.hidden_size, "o_proj")(
                 ctx.reshape(b, s, n * hd).astype(dt))
-
-
-def _a_log_init(key, shape, dtype=jnp.float32):
-    """log U(0, 16), the low end held off zero so the log stays finite."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
 
 
 class GatedDeltaNet(nn.Module):
@@ -203,7 +177,7 @@ class GatedDeltaNet(nn.Module):
                             (cfg.hidden_size, 2 * n_qk + 2 * n_v)).astype(dt)
         w_ba = self.param("in_proj_ba", _INIT,
                           (cfg.hidden_size, 2 * hv)).astype(dt)
-        a_log = self.param("A_log", _a_log_init, (hv,))
+        a_log = self.param("A_log", delta_a_log_init, (hv,))
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))
         k_conv = cfg.linear_conv_kernel_dim
         conv_w = self.param(
@@ -230,7 +204,7 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("L_gdn_proj"):
             qkv, z, g, beta = _by_blocks(before, gated_delta.SEGMENT, x)
         with jax.named_scope("L_gdn_conv"):
-            qkv = gated_delta.causal_depthwise_conv(qkv, conv_w, True)
+            qkv = causal_depthwise_conv(qkv, conv_w, True)
             q = qkv[..., :n_qk].reshape(b, s, hk, dk)
             k = qkv[..., n_qk:2 * n_qk].reshape(b, s, hk, dk)
             v = qkv[..., 2 * n_qk:].reshape(b, s, hv, dv)
@@ -239,135 +213,6 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("L_gdn_proj"):
             return _by_blocks(
                 after, gated_delta.SEGMENT, o.reshape(b, s, n_v), z)
-
-
-class HeldExpertsMoE(nn.Module):
-    """One expert-parallel rank's MoE layer: routes over all
-    `router_experts`, computes the `held` experts [first_expert,
-    first_expert + held). The routing is the family's: "softmax" scores
-    over all experts, the top_k largest, renormalised when `renorm`, a
-    shared expert behind its own sigmoid gate, and the sums the family's
-    balance loss needs; or "sigmoid" scores, the top_k largest of score +
-    a selection bias that takes no gradient (the parameter `select_bias`),
-    the scores themselves as weights, renormalised when `renorm`, times
-    `scale`, and a shared expert added as it is. An expert, and the shared
-    one, is the gated unit w3 (act(w1 x) * w2 x) or, with `gated` false,
-    the two-matrix w3 act(w1 x) (no `w2`; the shared one a plain `MLP`):
-    the `nemotron_h` family's squared-ReLU experts. On one TPU, at a
-    capacity of whole row tiles, the routed experts' unit runs as the
-    kernels of `kernels/moe_grouped.py` over the tiles of rows that hold a
-    token (`moe_grouped.engages`; the sown `live_tile_fraction` says how
-    many); everywhere else as einsums over every slot."""
-
-    router_experts: int
-    held: int
-    first_expert: int
-    top_k: int
-    expert_hidden: int
-    shared_hidden: int
-    capacity_factor: float
-    dtype: jnp.dtype
-    scoring: str = "softmax"
-    renorm: bool = True
-    scale: float = 1.0
-    gated: bool = True
-    activation: Callable[[jax.Array], jax.Array] = ops.silu
-
-    @nn.compact
-    def __call__(self, x):
-        b, s, d = x.shape
-        t = b * s
-        held, h, k, dt = self.held, self.expert_hidden, self.top_k, self.dtype
-        softmax = self.scoring == "softmax"
-        with jax.named_scope("L_moe_gate"):
-            x32 = x.reshape(t, d).astype(jnp.float32)
-            xt = x32.astype(dt)
-            logits = nn.Dense(
-                self.router_experts, use_bias=False, dtype=jnp.float32,
-                precision=HI, kernel_init=_INIT, name="gate",
-            )(x32)
-            if softmax:
-                pair_w, pair_idx, probs = ops.moe.topk_renorm_weights(
-                    logits, k, self.renorm
-                )
-            else:
-                bias = self.param("select_bias", _INIT,
-                                  (self.router_experts,))
-                pair_w, pair_idx, probs = ops.moe.topk_sigmoid_weights(
-                    logits, bias, k, self.renorm, self.scale
-                )
-        w1 = self.param("w1", _INIT, (held, d, h))
-        w2 = self.param("w2", _INIT, (held, d, h)) if self.gated else None
-        w3 = self.param("w3", _INIT, (held, h, d))
-
-        # capacity from the layer's whole width: an expert's fair share of
-        # the routed pairs is the same whichever device holds it
-        cap = ops.moe.expert_capacity(
-            t, self.router_experts, k, self.capacity_factor
-        )
-        # by what the call can see, no flag: on one TPU, with whole row
-        # tiles, the same unit over the tiles of rows that hold a token
-        # (`kernels/moe_grouped.py`); the slots behind an expert's fill are
-        # zero rows, which give zero rows either way
-        grouped = moe_grouped.engages(cap, d)
-
-        def expert_fn(xe, fill):  # (held, C, D), (held,) -> (held, C, D)
-            if grouped:
-                return moe_grouped.grouped_glu(
-                    xe, w1.astype(dt), w2 if w2 is None else w2.astype(dt),
-                    w3.astype(dt), fill, activation=self.activation)
-            a = jnp.einsum("ecd,edh->ech", xe, w1.astype(dt))
-            if self.gated:
-                g = jnp.einsum("ecd,edh->ech", xe, w2.astype(dt))
-                a = self.activation(a) * g
-            else:
-                a = self.activation(a)
-            return jnp.einsum("ech,ehd->ecd", a, w3.astype(dt))
-
-        out, held_probs = ops.moe.moe_held_dispatch_combine(
-            xt, pair_w, pair_idx, expert_fn, cap, self.first_expert, held
-        )
-        with jax.named_scope("L_moe_shared"):
-            shared = (GLUFFN if self.gated else MLP)(
-                dim=d, hidden_dim=self.shared_hidden, use_bias=False,
-                activation=self.activation, dtype=dt, name="shared_expert",
-            )(xt).astype(jnp.float32)
-            if softmax:
-                shared = jax.nn.sigmoid(nn.Dense(
-                    1, use_bias=False, dtype=jnp.float32, kernel_init=_INIT,
-                    name="shared_gate",
-                )(x32)) * shared
-            out = out.astype(jnp.float32) + shared
-
-        if self.is_mutable_collection("moe_metrics"):
-            with jax.named_scope("L_moe_stats"):
-                # over all experts, the share of tokens that chose each
-                chosen = jnp.sum(
-                    pair_idx[..., None] == jnp.arange(self.router_experts),
-                    axis=(0, 1), dtype=jnp.float32) / t
-                if softmax:
-                    # with each one's mean probability, what the family's
-                    # balance loss needs
-                    self.sow("moe_metrics", "balance", {
-                        "chosen": chosen,
-                        "prob": jnp.mean(probs, axis=0),
-                    })
-                on_held = (pair_idx >= self.first_expert) & (
-                    pair_idx < self.first_expert + held)
-                stats = ops.moe.load_balance_stats(probs, ci=chosen)
-                stats["held_pair_fraction"] = jnp.mean(
-                    on_held.astype(jnp.float32))
-            stats["drop_fraction"] = ops.moe.dispatch_drop_fraction(
-                held_probs, cap)
-            # share of the experts' row tiles that are multiplied: 1 where
-            # the einsums run over every slot
-            stats["live_tile_fraction"] = (
-                ops.moe.live_tile_fraction(
-                    held_probs, cap, moe_grouped.ROW_TILE)
-                if grouped else jnp.ones(()))
-            self.sow("moe_metrics", "stats", stats)
-        with jax.named_scope("L_moe_combine"):
-            return out.reshape(b, s, d)
 
 
 def held_moe(cfg: Qwen3NextConfig, name: str | None = None) -> HeldExpertsMoE:
@@ -437,17 +282,10 @@ class Qwen3Next(nn.Module):
         return (logits, caches). Training and scoring only: the family has
         no decode cache yet, and no dropout."""
         cfg = self.cfg
-        if caches is not None:
-            raise NotImplementedError(
-                "qwen3next has no decode cache: a Gated DeltaNet layer keeps "
-                "recurrent state, which no cache manager here holds yet "
-                "(ROADMAP R-M7)"
-            )
-        if tokens.shape[1] > cfg.block_size:
-            raise ValueError(
-                f"sequence {tokens.shape[1]} exceeds block_size "
-                f"{cfg.block_size}"
-            )
+        training_only(
+            "qwen3next", cfg, tokens, caches,
+            "a Gated DeltaNet layer keeps recurrent state, which no cache "
+            "manager here holds yet (ROADMAP R-M7)")
         with jax.named_scope("L_embed"):
             x = nn.Embed(
                 cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
@@ -466,7 +304,3 @@ class Qwen3Next(nn.Module):
                 kernel_init=nn.initializers.normal(0.02), name="lm_head",
             )(x.astype(cfg.compute_dtype))
         return logits, None
-
-    @property
-    def max_positions(self) -> int:
-        return self.cfg.block_size

@@ -14,6 +14,7 @@ from solvingpapers_tpu.ops.rope import (
     precompute_freqs_cis,
     apply_rope,
     apply_rotary_emb_complex,
+    partial_rotary,
     rope_rotation_matrix,
     sinusoidal_position_encoding,
 )

@@ -1,4 +1,4 @@
-"""The gated delta rule (Gated DeltaNet's mixer) and its causal convolution.
+"""The gated delta rule (Gated DeltaNet's mixer) and its gated norm.
 
 A head keeps a state S (d_k x d_v, zero at the sequence's start) and reads
 the sequence token by token:
@@ -18,8 +18,8 @@ channels and a chunk's system is no longer (K K^t) times a decay matrix, so
 the kernels make that system another way (sub-blocked pair sums, chosen
 there by g's rank) and share the rest.
 A g that repeats one number over the channels gives this rule back
-(tests/test_kda.py). The causal convolution and the gated norm below serve
-both.
+(tests/test_kda.py). The causal convolution in front of both rules is
+`ops/conv.py`'s.
 
 `gated_delta_rule` is the chunked form for the timed path: inside a chunk
 of C tokens the rule is a unit lower-triangular system (I + A) U = beta V -
@@ -56,59 +56,6 @@ HI = jax.lax.Precision.HIGHEST
 # called, so a test can shrink them.
 CHUNK = 64
 SEGMENT = 2048
-
-
-def _shifted_sum(x: jax.Array, w: jax.Array, back: bool) -> jax.Array:
-    """sum_j w[j] * x[t - (K-1) + j], or with `back` its transpose in t,
-    sum_j w[j] * x[t + (K-1) - j]: K slices of one padded array, one pass."""
-    k, s = w.shape[0], x.shape[1]
-    pad = (0, k - 1) if back else (k - 1, 0)
-    xp = jnp.pad(x, ((0, 0), pad, (0, 0)))
-    at = (lambda j: k - 1 - j) if back else (lambda j: j)
-    out = xp[:, at(0):at(0) + s] * w[0]
-    for j in range(1, k):
-        out = out + xp[:, at(j):at(j) + s] * w[j]
-    return out
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def causal_depthwise_conv(x: jax.Array, w: jax.Array, silu: bool = False):
-    """y_t = sum_j w[j] * x[t - (K-1) + j] a channel, zeros before the
-    start, then SiLU if `silu`. x (B, S, C), w (K, C); K shifted
-    multiply-adds, no convolution op (K is 4: an elementwise pass the
-    compiler fuses). The backward pass is written the same way and starts
-    again from x, so that it too is one pass over the sequence, and neither
-    K arrays of the sequence's size nor the convolution's output are kept.
-    There is no bias here: a caller whose convolution has one (Mamba-2,
-    `models/nemotron_h.py`) calls with `silu` False and applies SiLU(y +
-    bias) itself, under a `jax.checkpoint` of its own."""
-    y = _shifted_sum(x, w.astype(x.dtype), back=False)
-    return jax.nn.silu(y) if silu else y
-
-
-def _conv_fwd(x, w, silu):
-    return causal_depthwise_conv(x, w, silu), (x, w)
-
-
-def _conv_bwd(silu, res, dy):
-    x, w = res
-    k, s = w.shape[0], x.shape[1]
-    if silu:
-        y = _shifted_sum(x, w.astype(x.dtype), back=False).astype(jnp.float32)
-        sig = jax.nn.sigmoid(y)
-        dy = (dy.astype(jnp.float32) * sig * (1.0 + y * (1.0 - sig))).astype(
-            dy.dtype)
-    dx = _shifted_sum(dy, w.astype(dy.dtype), back=True)
-    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    dw = jnp.stack([
-        jnp.sum(xp[:, j:j + s].astype(jnp.float32) * dy.astype(jnp.float32),
-                axis=(0, 1))
-        for j in range(k)
-    ])
-    return dx.astype(x.dtype), dw.astype(w.dtype)
-
-
-causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

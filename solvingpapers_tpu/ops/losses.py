@@ -161,7 +161,7 @@ def head_nll_rows(
     shape of `labels`, head and loss together a chunk of rows at a time:
     hidden (..., D) in the compute dtype, kernel (D, V) float32, labels
     (...) int. The kernel is an untied head's leaf as it is kept, or a TIED
-    head's: the embedding transposed (`granite_hybrid_loss_fn`), whose
+    head's: the embedding transposed (`GraniteHybrid.head_kernel`), whose
     gradient then reaches the one leaf twice, from the lookup and, summed
     over the chunks in float32, from here; a scale on the logits is the
     caller's, folded into `hidden`. A chunk's logits exist

@@ -99,6 +99,21 @@ def apply_rotary_emb_complex(x: jax.Array, freqs_cis: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def partial_rotary(x: jax.Array, rotary_dim: int, theta: float) -> jax.Array:
+    """Rotate-half rotary embedding (feature j paired with j + rotary_dim / 2,
+    not `apply_rope`'s interleaved pairs) on the first `rotary_dim` features
+    of x (B, S, heads, head_dim), positions 0..S-1; the rest pass through."""
+    s = x.shape[1]
+    half = rotary_dim // 2
+    freqs = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:rotary_dim]
+    rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([rot, x32[..., rotary_dim:]], -1).astype(x.dtype)
+
+
 def rope_rotation_matrix(head_dim: int, max_seq_len: int, theta: float = 10000.0) -> jax.Array:
     """Dense (max_seq_len, head_dim, head_dim) block-diagonal rotation matrices.
 

@@ -9,6 +9,8 @@ Each reference training loop's objective as a LossFn
   * vae_loss_fn                     — variational autoencoder.ipynb cell 6
   * make_kd_loss_fn                 — kd.py:48-68 distillation objective
                                       (teacher frozen under stop_gradient)
+  * dsv3_loss_fn, qwen3next_loss_fn, chunked_head_loss_fn, ouro_loss_fn —
+    the decoder families' (which trains under which: configs/families.py)
 """
 
 from __future__ import annotations
@@ -195,45 +197,29 @@ def qwen3next_loss_fn(model, params, batch, rng, model_state, train):
     return main + cfg.router_aux_loss_coef * balance, aux, model_state
 
 
-def kimi_linear_loss_fn(model, params, batch, rng, model_state, train):
-    """Kimi-Linear objective, and the `nemotron_h` family's: next-token
-    cross-entropy and nothing else (neither source's config states a
-    balance loss; the selection bias is a parameter that takes no
-    gradient). The MoE's counters
-    (`moe_drop_fraction`, `moe_held_pair_fraction`, the load statistics)
-    ride along as the other MoE families' do. Head and loss run together in
-    chunks of rows (`ops.head_cross_entropy`): at 16,384 tokens the whole
-    logits and their cotangent, 640 MB each, were the step's memory peak."""
-    variables = {"params": params}
-    kernel = params["lm_head"]["kernel"]
-    if not train:
+def chunked_head_loss_fn(model, params, batch, rng, model_state, train):
+    """Next-token cross-entropy and nothing else (`kimi_linear`,
+    `nemotron_h`, `granite_hybrid`: no source's config states a balance
+    loss, and the selection bias takes no gradient), head and loss together
+    a chunk of rows at a time (`ops.head_cross_entropy`): at 16,384 tokens
+    the whole logits and their cotangent, 640 MB each, were the step's
+    memory peak. The model hands back its normed rows (`head=False`) and
+    names the head's kernel (`head_kernel`: `lm_head`'s, or a tied head's
+    embedding transposed, whose gradient is then the float32 sum of the
+    lookup's and the chunks'). Expert layers' counters (`moe_drop_fraction`,
+    `moe_held_pair_fraction`, the load statistics) ride along."""
+    variables, mutated = {"params": params}, {}
+    if train:
+        (hidden, _), mutated = model.apply(
+            variables, batch["x"], head=False, mutable=["moe_metrics"])
+    else:
         hidden, _ = model.apply(variables, batch["x"], head=False)
-        main = ops.head_cross_entropy(hidden, kernel, batch["y"])
-        return main, {"perplexity": jnp.exp(main)}, model_state
-    (hidden, _), mutated = model.apply(
-        variables, batch["x"], head=False, mutable=["moe_metrics"]
-    )
-    main = ops.head_cross_entropy(hidden, kernel, batch["y"])
+    # the kernel after the rows, as a tied head's transpose was traced
+    main = ops.head_cross_entropy(
+        hidden, model.head_kernel(params), batch["y"])
     with jax.named_scope("L_loss_head"):
         aux = {"perplexity": jnp.exp(main),
                **_aggregate_moe_metrics(mutated.get("moe_metrics", {}))}
-    return main, aux, model_state
-
-
-def granite_hybrid_loss_fn(model, params, batch, rng, model_state, train):
-    """The Granite-hybrid family's objective: next-token cross-entropy
-    under the TIED head. The model hands back its normed hidden rows with
-    1 / `logits_scaling` folded in; the head's kernel is the embedding
-    transposed, and head and loss run together a chunk of rows at a time
-    (`ops.head_cross_entropy`), so neither the logits nor their cotangent
-    are ever whole. The embedding's gradient is the float32 sum of what the
-    lookup and the chunks of the head give."""
-    hidden, _ = model.apply({"params": params}, batch["x"], head=False)
-    with jax.named_scope("L_loss_head"):
-        kernel = params["tok_emb"]["embedding"].T
-    main = ops.head_cross_entropy(hidden, kernel, batch["y"])
-    with jax.named_scope("L_loss_head"):
-        aux = {"perplexity": jnp.exp(main)}
     return main, aux, model_state
 
 

@@ -203,7 +203,7 @@ def test_held_experts_kernels_keep_the_experts_scope(monkeypatch):
     from solvingpapers_tpu.kernels import moe_grouped
     from solvingpapers_tpu.models.nemotron_h import NemotronH, NemotronHConfig
     from solvingpapers_tpu.ops import ssd
-    from solvingpapers_tpu.train.objectives import kimi_linear_loss_fn
+    from solvingpapers_tpu.train.objectives import chunked_head_loss_fn
 
     monkeypatch.setattr(moe_grouped, "ROW_TILE", 8)
     monkeypatch.setattr(moe_grouped, "SUMS_VMEM", 700_000)
@@ -221,7 +221,7 @@ def test_held_experts_kernels_keep_the_experts_scope(monkeypatch):
     assert cfg.hybrid_override_pattern[:2] == "ME"
     trainer = Trainer(
         NemotronH(cfg), TrainConfig(steps=2, batch_size=2, log_every=1),
-        loss_fn=kimi_linear_loss_fn, mesh=one_device_mesh())
+        loss_fn=chunked_head_loss_fn, mesh=one_device_mesh())
     batch = {k: np.zeros((2, cfg.block_size), np.int32) for k in "xy"}
     state = trainer.init_state(batch)
     trainer._build_steps()

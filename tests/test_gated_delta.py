@@ -13,6 +13,7 @@ from conftest import assert_same_bits, checkpoint_names, kernel_calls
 
 from solvingpapers_tpu.kernels import gated_delta as kernel
 from solvingpapers_tpu.ops import gated_delta as gd
+from solvingpapers_tpu.ops.conv import causal_depthwise_conv
 
 pytestmark = pytest.mark.fast
 
@@ -146,7 +147,7 @@ def test_causal_depthwise_conv_and_its_backward(silu):
         y = sum(xp[:, j:j + 11] * w[j] for j in range(4))
         return y * jax.nn.sigmoid(y) if silu else y
 
-    conv = lambda x, w: gd.causal_depthwise_conv(x, w, silu)  # noqa: E731
+    conv = lambda x, w: causal_depthwise_conv(x, w, silu)  # noqa: E731
     np.testing.assert_allclose(conv(x, w), plain(x, w), atol=1e-6)
     if not silu:
         # causal: the first output sees the first input alone, through w[-1]

@@ -42,7 +42,7 @@ from solvingpapers_tpu.ops import ssd
 from solvingpapers_tpu.sharding import MeshConfig, create_mesh
 from solvingpapers_tpu.train import Trainer
 from solvingpapers_tpu.train.engine import TrainConfig
-from solvingpapers_tpu.train.objectives import granite_hybrid_loss_fn
+from solvingpapers_tpu.train.objectives import chunked_head_loss_fn
 from solvingpapers_tpu.train.optim import OptimizerConfig
 
 pytestmark = pytest.mark.fast
@@ -98,7 +98,7 @@ def seeded(cfg, seed=5, init_std=0.2):
 
 def loss_and_grads(cfg, tree, b):
     model = GraniteHybrid(cfg)
-    fn = lambda p: granite_hybrid_loss_fn(  # noqa: E731
+    fn = lambda p: chunked_head_loss_fn(  # noqa: E731
         model, p, b, jax.random.key(0), None, True)[0]
     return jax.jit(jax.value_and_grad(fn))(tree)
 
@@ -217,7 +217,7 @@ def test_first_three_fit_steps_follow_the_reference():
     train = TrainConfig(steps=3, batch_size=B, log_every=1, eval_every=0,
                         ckpt_every=0, optimizer=opt, seed=0)
     trainer = Trainer(
-        GraniteHybrid(cfg), train, loss_fn=granite_hybrid_loss_fn,
+        GraniteHybrid(cfg), train, loss_fn=chunked_head_loss_fn,
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     batches = [batch(seed) for seed in (1, 2, 3)]
     state = trainer.init_state(batches[0])
@@ -261,7 +261,7 @@ def test_registry_holds_the_published_sizes_and_the_factory_builds_it():
     assert cfg.train.tokens_per_step == cfg.data["block_size"] == 8192
     small = dataclasses.replace(cfg, model=tiny())
     assert isinstance(build_model(small), GraniteHybrid)
-    assert loss_fn_for(small) is granite_hybrid_loss_fn
+    assert loss_fn_for(small) is chunked_head_loss_fn
     assert init_fn_for(small) is None
 
 
@@ -272,7 +272,7 @@ def test_train_step_stands_under_the_layers_the_benchmark_reads():
     cfg = tiny(dtype="float32", remat=True, use_flash=True)
     trainer = Trainer(
         GraniteHybrid(cfg), TrainConfig(steps=2, batch_size=B, log_every=1),
-        loss_fn=granite_hybrid_loss_fn,
+        loss_fn=chunked_head_loss_fn,
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     b = {k: np.asarray(v) for k, v in batch().items()}
     state = trainer.init_state(b)
@@ -315,7 +315,7 @@ def test_keeping_the_kernels_results_changes_no_bit_of_loss_or_gradient(
     model, b = GraniteHybrid(cfg), batch()
 
     n_kept, n_plain = keep_against_plain_remat(
-        monkeypatch, lambda: jax.value_and_grad(lambda p: granite_hybrid_loss_fn(
+        monkeypatch, lambda: jax.value_and_grad(lambda p: chunked_head_loss_fn(
             model, p, b, jax.random.key(0), None, True)[0]),
         tree, ("flash_mla_fwd", "ssd_fwd", "ssd_bwd"))
     assert (n_kept, n_plain) == ((1, 2, 2), (2, 4, 2))

@@ -41,7 +41,7 @@ from solvingpapers_tpu.ops import kda
 from solvingpapers_tpu.sharding import MeshConfig, create_mesh
 from solvingpapers_tpu.train import Trainer
 from solvingpapers_tpu.train.engine import TrainConfig
-from solvingpapers_tpu.train.objectives import kimi_linear_loss_fn
+from solvingpapers_tpu.train.objectives import chunked_head_loss_fn
 from solvingpapers_tpu.train.optim import OptimizerConfig
 
 pytestmark = pytest.mark.fast
@@ -106,7 +106,7 @@ def test_layer_pattern_is_read_from_the_published_lists():
     full = KimiLinearConfig()
     assert sum(full.is_attention_layer(i) for i in range(27)) == 7
     assert full.is_attention_layer(26) and not full.is_attention_layer(24)
-    assert full.qk_head_dim == 192
+    assert full.qk_nope_head_dim + full.qk_rope_head_dim == 192
 
 
 @pytest.mark.parametrize("field, value", [
@@ -133,7 +133,7 @@ def test_loss_and_gradients_match_the_reference_float32(capacity_factor):
     @jax.jit
     def program(p):
         def loss_fn(p):
-            loss, aux, _ = kimi_linear_loss_fn(model, p, b, jax.random.key(0),
+            loss, aux, _ = chunked_head_loss_fn(model, p, b, jax.random.key(0),
                                                None, True)
             return loss, aux
         return jax.value_and_grad(loss_fn, has_aux=True)(p)
@@ -169,7 +169,7 @@ def test_stages_block_by_block_equal_the_whole_sequence(monkeypatch):
     sz, w, tree = seeded(cfg)
 
     def loss_and_grads():
-        fn = lambda p: kimi_linear_loss_fn(  # noqa: E731
+        fn = lambda p: chunked_head_loss_fn(  # noqa: E731
             KimiLinear(cfg), p, b, jax.random.key(0), None, True)[0]
         return jax.jit(jax.value_and_grad(fn))(tree)
 
@@ -203,7 +203,7 @@ def test_first_three_fit_steps_follow_the_reference_and_int8_does_not():
     train = TrainConfig(steps=3, batch_size=B, log_every=1, eval_every=0,
                         ckpt_every=0, optimizer=opt, seed=0)
     trainer = Trainer(
-        KimiLinear(cfg), train, loss_fn=kimi_linear_loss_fn,
+        KimiLinear(cfg), train, loss_fn=chunked_head_loss_fn,
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     batches = [batch(seed) for seed in (1, 2, 3)]
     state = trainer.init_state(batches[0])
@@ -325,7 +325,7 @@ def test_registry_holds_the_published_sizes_and_the_factory_builds_it():
     assert m.full_attn_layers == (4, 8, 12, 16, 20, 24, 27)
     small = dataclasses.replace(cfg, model=tiny())
     assert isinstance(build_model(small), KimiLinear)
-    assert loss_fn_for(small) is kimi_linear_loss_fn
+    assert loss_fn_for(small) is chunked_head_loss_fn
     assert init_fn_for(small) is None
 
 
@@ -338,7 +338,7 @@ def test_train_step_names_the_new_layers(monkeypatch, use_flash):
     cfg = tiny(dtype="float32", remat=True, use_flash=use_flash)
     trainer = Trainer(
         KimiLinear(cfg), TrainConfig(steps=2, batch_size=B, log_every=1),
-        loss_fn=kimi_linear_loss_fn,
+        loss_fn=chunked_head_loss_fn,
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     b = {k: np.asarray(v) for k, v in batch().items()}
     state = trainer.init_state(b)
@@ -392,7 +392,7 @@ def test_keeping_the_flash_results_changes_no_bit_of_loss_or_gradient(
     model, b = KimiLinear(cfg), batch()
 
     n_kept, n_plain = keep_against_plain_remat(
-        monkeypatch, lambda: jax.value_and_grad(lambda p: kimi_linear_loss_fn(
+        monkeypatch, lambda: jax.value_and_grad(lambda p: chunked_head_loss_fn(
             model, p, b, jax.random.key(0), None, True)[0]),
         tree, ("flash_mla_fwd", "kda_fwd", "kda_bwd"))
     assert (n_kept, n_plain) == ((1, 3, 3), (2, 6, 3))

@@ -1,5 +1,6 @@
 """The package's import graph, read with `ast` (nothing here imports JAX or
-the package).
+the package, but for the family table's test, which resolves the table's
+import paths as the factory does).
 
   * each sub-package imports only from the sub-packages below it: the
     allowed sets are the arrows as they stand, and the two arrows that
@@ -7,6 +8,11 @@ the package).
     unnoticed and a paid debt must be struck here; the one module every
     part may import is `metrics/trace.py`, the run's recorder, which
     imports nothing but the standard library (held here too);
+  * inside `models/` a family's file imports the shared modules
+    (`layers.py`, `mixers.py`, `staged.py`) and no other family's file: a
+    new family, or a change to a shared mixer, opens no older family;
+  * every `model_family` of the registry has one record in the family
+    table (`configs/families.py`), and every record's paths resolve;
   * nothing under `solvingpapers_tpu/` imports what stands beside it
     (the benchmark, the tools, the tests, the chip check);
   * every `solvingpapers_tpu` module that the benchmark imports exists,
@@ -36,7 +42,8 @@ ALLOWED = {
     "models": {"infer", "kernels", "ops", "sharding"},
     "train": {"checkpoint", "metrics", "ops", "sharding"},
     "serve": {"buildinfo", "infer", "metrics", "models", "ops"},
-    "configs": {"data", "models", "sharding", "train"},
+    # `metrics`: the family table names `mfu.looped_flops_per_token`
+    "configs": {"data", "metrics", "models", "sharding", "train"},
 }
 
 # the arrows that point upwards today (ROADMAP D17, D18): (file, target)
@@ -92,6 +99,71 @@ def package_imports(sub: str):
 def test_subpackage_imports_only_from_below(sub):
     upwards = {(f, t) for f, t in package_imports(sub) if t not in ALLOWED[sub]}
     assert upwards == BACK_ARROWS.get(sub, set())
+
+
+# `models/`: what every family may import, and the three pipeline twins,
+# which import the family they stage (ROADMAP D4: one staged decoder for
+# the three would end the exception)
+SHARED_MODELS = {"__init__", "layers", "mixers", "staged"}
+PIPE_TWINS = {"gpt_pipe": "gpt", "llama3_pipe": "llama3",
+              "deepseekv3_pipe": "deepseekv3"}
+FAMILY_MODULES = sorted(
+    path.stem for path in (REPO / PKG / "models").glob("*.py")
+    if path.stem not in SHARED_MODELS)
+
+
+def models_imported_by(stem: str) -> set[str]:
+    """The modules of `models/` that `models/<stem>.py` imports."""
+    path = REPO / PKG / "models" / f"{stem}.py"
+    prefix = (PKG, "models")
+    found = set()
+    for module, names in imports_of(path, prefix):
+        parts = tuple(module.split("."))
+        if parts[:2] != prefix:
+            continue
+        # `from solvingpapers_tpu.models import ouro` names its target last
+        found.update([parts[2]] if len(parts) > 2 else names)
+    return found
+
+
+@pytest.mark.parametrize("stem", FAMILY_MODULES)
+def test_family_module_imports_no_other_family(stem):
+    assert len(FAMILY_MODULES) >= 16
+    allowed = SHARED_MODELS | {stem, PIPE_TWINS.get(stem, stem)}
+    assert models_imported_by(stem) <= allowed
+
+
+@pytest.mark.parametrize("stem", sorted(SHARED_MODELS - {"__init__"}))
+def test_shared_models_module_imports_no_family(stem):
+    assert models_imported_by(stem) <= SHARED_MODELS
+
+
+def registry_families() -> list[str]:
+    """Every `model_family="..."` that `configs/registry.py` states."""
+    tree = ast.parse((REPO / PKG / "configs" / "registry.py").read_text())
+    return sorted({
+        kw.value.value for node in ast.walk(tree)
+        if isinstance(node, ast.Call) for kw in node.keywords
+        if kw.arg == "model_family" and isinstance(kw.value, ast.Constant)})
+
+
+REGISTRY_FAMILIES = registry_families()
+
+
+@pytest.mark.parametrize("family", REGISTRY_FAMILIES)
+def test_family_of_the_registry_has_a_record_that_resolves(family):
+    from solvingpapers_tpu.configs.families import FAMILIES, resolve
+
+    assert len(REGISTRY_FAMILIES) >= 17
+    # no record without a preset either: a dead record is a dead family
+    assert sorted(FAMILIES) == REGISTRY_FAMILIES
+    record = FAMILIES[family]
+    assert isinstance(resolve(record.model), type)
+    assert callable(resolve(record.objective))
+    assert record.init_fn is None or callable(resolve(record.init_fn))
+    assert record.flops_per_token is None or callable(record.flops_per_token)
+    assert record.unservable is None or (
+        len(record.unservable.split()) >= 8 and "ROADMAP" in record.unservable)
 
 
 def test_the_bottom_module_imports_the_standard_library_only():

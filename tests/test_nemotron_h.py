@@ -33,14 +33,15 @@ from solvingpapers_tpu.configs.factory import (
     build_model, init_fn_for, loss_fn_for,
 )
 from solvingpapers_tpu.metrics import hlo_cost
+from solvingpapers_tpu.models.mixers import Mamba2Mixer, NoPEAttention
 from solvingpapers_tpu.models.nemotron_h import (
-    Mamba2Mixer, NemotronH, NemotronHConfig, NoPEAttention, held_moe,
+    NemotronH, NemotronHConfig, held_moe,
 )
 from solvingpapers_tpu.ops import ssd
 from solvingpapers_tpu.sharding import MeshConfig, create_mesh
 from solvingpapers_tpu.train import Trainer
 from solvingpapers_tpu.train.engine import TrainConfig
-from solvingpapers_tpu.train.objectives import kimi_linear_loss_fn
+from solvingpapers_tpu.train.objectives import chunked_head_loss_fn
 from solvingpapers_tpu.train.optim import OptimizerConfig
 
 pytestmark = pytest.mark.fast
@@ -163,7 +164,7 @@ def test_loss_and_gradients_match_the_reference_float32(capacity_factor):
     @jax.jit
     def program(p):
         def loss_fn(p):
-            loss, aux, _ = kimi_linear_loss_fn(model, p, b, jax.random.key(0),
+            loss, aux, _ = chunked_head_loss_fn(model, p, b, jax.random.key(0),
                                                None, True)
             return loss, aux
         return jax.value_and_grad(loss_fn, has_aux=True)(p)
@@ -197,7 +198,7 @@ def test_stages_block_by_block_equal_the_whole_sequence(monkeypatch):
     _, _, tree = seeded(cfg)
 
     def loss_and_grads():
-        fn = lambda p: kimi_linear_loss_fn(  # noqa: E731
+        fn = lambda p: chunked_head_loss_fn(  # noqa: E731
             NemotronH(cfg), p, b, jax.random.key(0), None, True)[0]
         return jax.jit(jax.value_and_grad(fn))(tree)
 
@@ -225,7 +226,7 @@ def test_first_three_fit_steps_follow_the_reference():
     train = TrainConfig(steps=3, batch_size=B, log_every=1, eval_every=0,
                         ckpt_every=0, optimizer=opt, seed=0)
     trainer = Trainer(
-        NemotronH(cfg), train, loss_fn=kimi_linear_loss_fn,
+        NemotronH(cfg), train, loss_fn=chunked_head_loss_fn,
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     batches = [batch(seed) for seed in (1, 2, 3)]
     state = trainer.init_state(batches[0])
@@ -354,7 +355,7 @@ def test_registry_holds_the_published_sizes_and_the_factory_builds_it():
     assert cfg.train.optimizer.name == "adamw"
     small = dataclasses.replace(cfg, model=tiny())
     assert isinstance(build_model(small), NemotronH)
-    assert loss_fn_for(small) is kimi_linear_loss_fn
+    assert loss_fn_for(small) is chunked_head_loss_fn
     assert init_fn_for(small) is None
 
 
@@ -364,7 +365,7 @@ def test_train_step_names_the_new_layers(use_flash):
     cfg = tiny(dtype="float32", remat=True, use_flash=use_flash)
     trainer = Trainer(
         NemotronH(cfg), TrainConfig(steps=2, batch_size=B, log_every=1),
-        loss_fn=kimi_linear_loss_fn,
+        loss_fn=chunked_head_loss_fn,
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     b = {k: np.asarray(v) for k, v in batch().items()}
     state = trainer.init_state(b)
@@ -418,7 +419,7 @@ def test_keeping_the_flash_results_changes_no_bit_of_loss_or_gradient(
     model, b = NemotronH(cfg), batch()
 
     n_kept, n_plain = keep_against_plain_remat(
-        monkeypatch, lambda: jax.value_and_grad(lambda p: kimi_linear_loss_fn(
+        monkeypatch, lambda: jax.value_and_grad(lambda p: chunked_head_loss_fn(
             model, p, b, jax.random.key(0), None, True)[0]),
         tree, ("flash_mla_fwd", "ssd_fwd", "ssd_bwd"))
     assert (n_kept, n_plain) == ((1, 3, 3), (2, 6, 3))

@@ -32,9 +32,9 @@ from solvingpapers_tpu.configs.factory import (
 from solvingpapers_tpu.metrics import hlo_cost
 from solvingpapers_tpu.models.qwen3next import (
     Qwen3Next, Qwen3NextConfig, ZeroCenteredRMSNorm, held_moe,
-    partial_rotary,
 )
 from solvingpapers_tpu.ops import gated_delta
+from solvingpapers_tpu.ops.rope import partial_rotary
 from solvingpapers_tpu.sharding import MeshConfig, create_mesh
 from solvingpapers_tpu.train import Trainer
 from solvingpapers_tpu.train.engine import TrainConfig
