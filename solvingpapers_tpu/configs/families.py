@@ -29,6 +29,10 @@ _RECURRENT = (
     "its recurrent layers (Gated DeltaNet, Kimi Delta Attention, Mamba-2) "
     "keep recurrent state, and no cache manager here holds that yet "
     "(ROADMAP R-M7)")
+_SELECTED = (
+    "its attention reads only the keys a lightning indexer picks for each "
+    "query, and here no cache holds the indexer's keys beside the paged "
+    "keys and values and no decode step selects (ROADMAP R-M13)")
 _LOOPED = (
     "a looped model keeps keys and values a (pass, layer) and may leave the "
     "loop at a gate threshold, and no cache manager or decode step here does "
@@ -65,6 +69,8 @@ FAMILIES: dict[str, Family] = {
                          unservable=_RECURRENT),
     "granite_hybrid": Family(f"{_MODELS}.granite_hybrid:GraniteHybrid",
                              _CHUNKED, unservable=_RECURRENT),
+    "keye_vl": Family(f"{_MODELS}.keye_vl:KeyeVL",
+                      f"{_OBJECTIVES}:keye_vl_loss_fn", unservable=_SELECTED),
     # a looped model's weights count once a USE (T passes of the layers, T
     # heads), not once
     "ouro": Family(f"{_MODELS}.ouro:Ouro", f"{_OBJECTIVES}:ouro_loss_fn",

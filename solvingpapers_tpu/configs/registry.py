@@ -849,6 +849,50 @@ def _granite4_h_micro() -> RunConfig:
     )
 
 
+@register("keye_vl2_30b_a3b")
+def _keye_vl2_30b_a3b() -> RunConfig:
+    """Keye-VL-2.0-30B-A3B's language model at its published size
+    (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B config.json, `KeyeVL2`;
+    text tokens only, the vision tower is not built): 48 layers, every one
+    grouped-query attention (32 heads on 4 of width 128, q- and k-norm,
+    rotary at theta 1e7) over the 2,048 keys a lightning indexer (16 heads
+    of 64 on one key head) picks for each query, then 128 experts of width
+    768 with 8 a token behind a softmax router and no shared expert; hidden
+    2048, vocabulary 151,936. 30.6B parameters: more than one chip holds;
+    what runs is a cut of it, one expert-parallel rank's share of a few
+    layers (benchmarks/configs/keye_vl2_ep8.json sets `num_hidden_layers`,
+    `num_experts` / `num_local_experts`, the experts held, and `vocab_size`;
+    the router keeps its 128 outputs). Training only: no cache holds the
+    indexer's keys and no decode step selects (ROADMAP R-M13).
+
+    The job (assumed, the source states none): the SPARSE training stage,
+    indexer and model together (cross-entropy + 0.001 x balance + the
+    indexer's KL, a mean over layers); one sequence of 16,384 tokens a step,
+    AdamW 3e-4 beta=(0.9, 0.95) wd 0.1 clip 1.0, 100 steps of warm-up ->
+    cosine to 0.1*max; capacity factor 2; remat a layer."""
+    from solvingpapers_tpu.models.keye_vl import KeyeVLConfig
+
+    return RunConfig(
+        name="keye_vl2_30b_a3b",
+        model_family="keye_vl",
+        model=KeyeVLConfig(),
+        train=TrainConfig(
+            steps=10_000, batch_size=1, log_every=50, eval_every=500,
+            eval_batches=4, ckpt_every=1000,
+            optimizer=OptimizerConfig(
+                name="adamw", max_lr=3e-4, warmup_steps=100,
+                total_steps=10_000, b1=0.9, b2=0.95, weight_decay=0.1,
+                grad_clip=1.0,
+            ),
+            tokens_per_step=16_384,
+        ),
+        data={"kind": "bpe", "path": None, "block_size": 16_384,
+              "bpe_vocab_size": 32_000, "synthetic_chars": 2_000_000},
+        notes="published widths; run through a cut (experts held, layers, "
+              "vocabulary slice), see benchmarks/configs/keye_vl2_ep8.json",
+    )
+
+
 @register("dsv3_mtp")
 def _dsv3_mtp() -> RunConfig:
     """The flagship with multi-token prediction ENABLED (2 extra heads,

@@ -415,6 +415,10 @@ LAYER_SCOPES = (
     "L_loss_head",
     "L_optimizer",
     "L_exit_gate",
+    "L_dsa_index",
+    "L_dsa_select",
+    "L_dsa_attend",
+    "L_dsa_loss",
 )
 # `name=` of the three `pallas_call`s of kernels/flash_attention.py
 KERNEL_SCOPES = ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")

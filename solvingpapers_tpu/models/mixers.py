@@ -1,6 +1,7 @@
 """The sequence mixers and the expert layer that more than one family runs
-(L2): `HeldExpertsMoE` (`qwen3next`, `kimi_linear`, `nemotron_h`; each words
-it from its own config in its `held_moe`), `Mamba2Mixer` and `NoPEAttention`
+(L2): `HeldExpertsMoE` (`qwen3next`, `kimi_linear`, `nemotron_h`,
+`keye_vl`; each words it from its own config in its `held_moe`),
+`Mamba2Mixer` and `NoPEAttention`
 (`nemotron_h`, `granite_hybrid`; what they read of a family's config is
 `Mamba2Config` / `NoPEAttentionConfig`), `delta_a_log_init` (`qwen3next`'s
 Gated DeltaNet, `kimi_linear`'s KDA). A family's file imports from here and
@@ -46,11 +47,13 @@ class HeldExpertsMoE(nn.Module):
     balance loss needs; or "sigmoid" scores, the top_k largest of score +
     a selection bias that takes no gradient (the parameter `select_bias`),
     the scores themselves as weights, renormalised when `renorm`, times
-    `scale`, and a shared expert added as it is. An expert, and the shared
-    one, is the gated unit w3 (act(w1 x) * w2 x) or, with `gated` false,
-    the two-matrix w3 act(w1 x) (no `w2`; the shared one a plain `MLP`):
-    the `nemotron_h` family's squared-ReLU experts. On one TPU, at a
-    capacity of whole row tiles, the routed experts' unit runs as the
+    `scale`, and a shared expert added as it is. `shared_hidden` 0 is a
+    layer with NO shared expert (`keye_vl`): neither its leaves nor its
+    gate's exist, and the scope `L_moe_shared` holds nothing. An expert,
+    and the shared one, is the gated unit w3 (act(w1 x) * w2 x) or, with
+    `gated` false, the two-matrix w3 act(w1 x) (no `w2`; the shared one a
+    plain `MLP`): the `nemotron_h` family's squared-ReLU experts. On one
+    TPU, at a capacity of whole row tiles, the routed experts' unit runs as the
     kernels of `kernels/moe_grouped.py` over the tiles of rows that hold a
     token (`moe_grouped.engages`; the sown `live_tile_fraction` says how
     many); everywhere else as einsums over every slot."""
@@ -123,17 +126,22 @@ class HeldExpertsMoE(nn.Module):
         out, held_probs = ops.moe.moe_held_dispatch_combine(
             xt, pair_w, pair_idx, expert_fn, cap, self.first_expert, held
         )
-        with jax.named_scope("L_moe_shared"):
-            shared = (GLUFFN if self.gated else MLP)(
-                dim=d, hidden_dim=self.shared_hidden, use_bias=False,
-                activation=self.activation, dtype=dt, name="shared_expert",
-            )(xt).astype(jnp.float32)
-            if softmax:
-                shared = jax.nn.sigmoid(nn.Dense(
-                    1, use_bias=False, dtype=jnp.float32, kernel_init=_INIT,
-                    name="shared_gate",
-                )(x32)) * shared
-            out = out.astype(jnp.float32) + shared
+        if self.shared_hidden:
+            with jax.named_scope("L_moe_shared"):
+                shared = (GLUFFN if self.gated else MLP)(
+                    dim=d, hidden_dim=self.shared_hidden, use_bias=False,
+                    activation=self.activation, dtype=dt,
+                    name="shared_expert",
+                )(xt).astype(jnp.float32)
+                if softmax:
+                    shared = jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=jnp.float32,
+                        kernel_init=_INIT, name="shared_gate",
+                    )(x32)) * shared
+                out = out.astype(jnp.float32) + shared
+        else:
+            with jax.named_scope("L_moe_combine"):
+                out = out.astype(jnp.float32)
 
         if self.is_mutable_collection("moe_metrics"):
             with jax.named_scope("L_moe_stats"):

@@ -9,7 +9,8 @@ Each reference training loop's objective as a LossFn
   * vae_loss_fn                     — variational autoencoder.ipynb cell 6
   * make_kd_loss_fn                 — kd.py:48-68 distillation objective
                                       (teacher frozen under stop_gradient)
-  * dsv3_loss_fn, qwen3next_loss_fn, chunked_head_loss_fn, ouro_loss_fn —
+  * dsv3_loss_fn, qwen3next_loss_fn, chunked_head_loss_fn, keye_vl_loss_fn,
+    ouro_loss_fn —
     the decoder families' (which trains under which: configs/families.py)
 """
 
@@ -166,13 +167,26 @@ def dsv3_loss_fn(model, params, batch, rng, model_state, train):
     return loss, aux, new_ms
 
 
+def _balance_loss(raw, router_experts: int):
+    """The held-experts families' load-balancing loss from what
+    `HeldExpertsMoE` sows a layer ("balance": `chosen`, `prob`): E * sum_e
+    F_e P_e with F the share of tokens that chose an expert and P its mean
+    router probability, both over the tokens of all layers together."""
+    with jax.named_scope("L_moe_stats"):
+        is_balance = lambda x: isinstance(x, dict) and "chosen" in x  # noqa: E731
+        layers = [b for b in jax.tree.leaves(raw, is_leaf=is_balance)
+                  if is_balance(b)]
+        chosen = jnp.mean(jnp.stack([b["chosen"] for b in layers]), axis=0)
+        prob = jnp.mean(jnp.stack([b["prob"] for b in layers]), axis=0)
+        return router_experts * jnp.sum(chosen * prob)
+
+
 def qwen3next_loss_fn(model, params, batch, rng, model_state, train):
     """Qwen3-Next objective: next-token cross-entropy plus
-    `router_aux_loss_coef` times the family's load-balancing loss, E *
-    sum_e F_e P_e with F the share of tokens that chose an expert and P its
-    mean router probability, both over the tokens of all layers together.
-    The MoE's counters (`moe_drop_fraction`, `moe_held_pair_fraction`, the
-    load statistics) ride along as the DeepSeekV3 family's do."""
+    `router_aux_loss_coef` times the family's load-balancing loss
+    (`_balance_loss`). The MoE's counters (`moe_drop_fraction`,
+    `moe_held_pair_fraction`, the load statistics) ride along as the
+    DeepSeekV3 family's do."""
     cfg = model.cfg
     variables = {"params": params}
     if not train:
@@ -186,15 +200,27 @@ def qwen3next_loss_fn(model, params, batch, rng, model_state, train):
     main = ops.cross_entropy(logits, batch["y"])
     with jax.named_scope("L_loss_head"):
         aux = {"perplexity": jnp.exp(main), **_aggregate_moe_metrics(raw)}
-    with jax.named_scope("L_moe_stats"):
-        is_balance = lambda x: isinstance(x, dict) and "chosen" in x  # noqa: E731
-        layers = [b for b in jax.tree.leaves(raw, is_leaf=is_balance)
-                  if is_balance(b)]
-        chosen = jnp.mean(jnp.stack([b["chosen"] for b in layers]), axis=0)
-        prob = jnp.mean(jnp.stack([b["prob"] for b in layers]), axis=0)
-        balance = cfg.router_experts * jnp.sum(chosen * prob)
+    balance = _balance_loss(raw, cfg.router_experts)
     aux["balance_loss"] = balance
     return main + cfg.router_aux_loss_coef * balance, aux, model_state
+
+
+def _chunked_head(model, params, batch, train, collections):
+    """The chunked head-with-loss of `chunked_head_loss_fn`: (mean
+    cross-entropy, its aux, what the model sowed into `collections`)."""
+    variables, mutated = {"params": params}, {}
+    if train:
+        (hidden, _), mutated = model.apply(
+            variables, batch["x"], head=False, mutable=list(collections))
+    else:
+        hidden, _ = model.apply(variables, batch["x"], head=False)
+    # the kernel after the rows, as a tied head's transpose was traced
+    main = ops.head_cross_entropy(
+        hidden, model.head_kernel(params), batch["y"])
+    with jax.named_scope("L_loss_head"):
+        aux = {"perplexity": jnp.exp(main),
+               **_aggregate_moe_metrics(mutated.get("moe_metrics", {}))}
+    return main, aux, mutated
 
 
 def chunked_head_loss_fn(model, params, batch, rng, model_state, train):
@@ -208,19 +234,40 @@ def chunked_head_loss_fn(model, params, batch, rng, model_state, train):
     embedding transposed, whose gradient is then the float32 sum of the
     lookup's and the chunks'). Expert layers' counters (`moe_drop_fraction`,
     `moe_held_pair_fraction`, the load statistics) ride along."""
-    variables, mutated = {"params": params}, {}
-    if train:
-        (hidden, _), mutated = model.apply(
-            variables, batch["x"], head=False, mutable=["moe_metrics"])
-    else:
-        hidden, _ = model.apply(variables, batch["x"], head=False)
-    # the kernel after the rows, as a tied head's transpose was traced
-    main = ops.head_cross_entropy(
-        hidden, model.head_kernel(params), batch["y"])
-    with jax.named_scope("L_loss_head"):
-        aux = {"perplexity": jnp.exp(main),
-               **_aggregate_moe_metrics(mutated.get("moe_metrics", {}))}
+    main, aux, _ = _chunked_head(model, params, batch, train,
+                                 ("moe_metrics",))
     return main, aux, model_state
+
+
+def keye_vl_loss_fn(model, params, batch, rng, model_state, train):
+    """The selected-attention family's objective: the chunked
+    head-with-loss of `chunked_head_loss_fn`, plus `router_aux_loss_coef`
+    times the held-experts balance loss (`_balance_loss`, as
+    `qwen3next_loss_fn`), plus the lightning indexer's own loss, the mean
+    over the layers of the `index_kl` each layer sows (`models/keye_vl.py`;
+    the weight 1 / layers is this repo's). Two parameter groups see
+    different losses: the indexer's three matrices a layer take their
+    gradient from the KL alone (its inputs and its target are detached and
+    the selection passes none), every other weight from the other two terms
+    alone. Logged beside the MoE's counters: `dsa_index_kl` (nats, a layer)
+    and `dsa_selected_fraction` (selected pairs over causal pairs; 1.0 when
+    nothing is left out)."""
+    main, aux, mutated = _chunked_head(model, params, batch, train,
+                                       ("moe_metrics", "dsa_metrics"))
+    if not train:
+        return main, aux, model_state
+    balance = _balance_loss(mutated["moe_metrics"], model.cfg.router_experts)
+    with jax.named_scope("L_dsa_loss"):
+        is_stats = lambda x: isinstance(x, dict) and "index_kl" in x  # noqa: E731
+        layers = [s for s in jax.tree.leaves(mutated["dsa_metrics"],
+                                             is_leaf=is_stats) if is_stats(s)]
+        index_kl = jnp.mean(jnp.stack([s["index_kl"] for s in layers]))
+        aux.update(
+            balance_loss=balance, dsa_index_kl=index_kl,
+            dsa_selected_fraction=jnp.mean(jnp.stack(
+                [s["selected_fraction"] for s in layers])))
+    return (main + model.cfg.router_aux_loss_coef * balance + index_kl,
+            aux, model_state)
 
 
 @jax.named_scope("L_exit_gate")

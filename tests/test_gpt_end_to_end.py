@@ -133,9 +133,12 @@ def test_cached_decode_equals_full_forward():
     np.testing.assert_array_equal(np.asarray(out[:, :5]), np.asarray(prompt))
 
     # reference: greedy loop recomputing the full prefix each step (no cache)
+    # (one compiled forward a prefix length, not one program an operation)
+    forward = jax.jit(lambda toks: model.apply(
+        {"params": params}, toks, deterministic=True))
     toks = prompt
     for _ in range(8):
-        logits, _ = model.apply({"params": params}, toks, deterministic=True)
+        logits, _ = forward(toks)
         nxt = jnp.argmax(logits[:, -1], axis=-1)
         toks = jnp.concatenate([toks, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(toks))

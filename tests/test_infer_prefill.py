@@ -28,10 +28,12 @@ DSV3_TINY = DeepSeekV3Config(
 
 
 def _full_forward_decode(model, variables, prompt, n):
+    # one compiled forward a prefix length, not one program an operation
+    forward = jax.jit(
+        lambda v, toks: model.apply(v, toks, deterministic=True)[0])
     toks = prompt
     for _ in range(n):
-        out = model.apply(variables, toks, deterministic=True)
-        logits = out[0]
+        logits = forward(variables, toks)
         toks = jnp.concatenate(
             [toks, jnp.argmax(logits[:, -1], -1)[:, None]], axis=1
         )

@@ -40,9 +40,12 @@ def test_cached_decode_equals_full_forward():
     params = model.init({"params": rng}, prompt)["params"]
 
     out = generate(model, params, prompt, rng, max_new_tokens=8)
+    # one compiled forward a prefix length, not one program an operation
+    forward = jax.jit(lambda toks: model.apply(
+        {"params": params}, toks, deterministic=True))
     toks = prompt
     for _ in range(8):
-        logits, _ = model.apply({"params": params}, toks, deterministic=True)
+        logits, _ = forward(toks)
         toks = jnp.concatenate([toks, jnp.argmax(logits[:, -1], -1)[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(toks))
 

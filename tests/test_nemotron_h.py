@@ -230,7 +230,10 @@ def test_first_three_fit_steps_follow_the_reference():
         mesh=create_mesh(MeshConfig(), devices=jax.devices()[:1]))
     batches = [batch(seed) for seed in (1, 2, 3)]
     state = trainer.init_state(batches[0])
-    state = state.replace(params=jax.tree.map(jnp.array, tree))
+    # placed as the step keeps them: the step then compiles once, not
+    # again at step 2 for another sharding of the same state
+    state = state.replace(params=jax.device_put(
+        jax.tree.map(jnp.array, tree), trainer._state_shardings.params))
     rows = Rows()
     state = trainer.fit(iter(batches), None, writer=rows, state=state)
     logged = [r for r in rows.rows if "train_loss" in r]
