@@ -15,6 +15,18 @@ has no custom kernels to port (SURVEY.md §0). This kernel family provides:
     backward kernels — no (S, S) mask tensor ever exists (validated by the
     linearity identity in tests/test_flash_dropout_tpu.py; interpret-mode
     prng is a zero stub, so dropout tests are hardware-gated)
+  * a selection mask (PR 48): `flash_attention(..., mask=)` takes a (B, Sq,
+    Skv) array, one for all heads of a batch row, a byte a pair, and the
+    forward and both backward kernels read a (block_q, block_k) tile of it
+    beside K and V and join it to the causal test before the softmax
+    (`_seen`, one definition for the three); `selected_probs`, a fourth,
+    forward-only kernel, makes the heads' mean probability a pair from the
+    forward's log-sum-exp (`ops/dsa.py`'s target for its indexer). No tile
+    is SKIPPED for a mask: only causality skips tiles (`_live`). The four
+    are named `flash_masked_*`, not `flash_mla_*` (`_name`). `mask=None`
+    is a Python branch and traces to the program there was before. Still
+    not taken: a window, sinks, additive biases, a soft cap, segment ids
+    (ROADMAP R-M13).
 
 Numerics reference: ops.dot_product_attention (tests/test_flash_attention.py
 asserts forward and gradient equality in interpret mode).
@@ -68,10 +80,37 @@ LONG_SEQ_MAX_HEAD_DIM = 128
 # saw keep the backward's pair.
 FWD_BLOCK = 1024
 FWD_MAX_HEAD_DIM = 256
+# The pairs of a call that has a selection mask, whose (block_q, block_k)
+# int8 tile sits in VMEM beside K and V, and the scoped VMEM such a call
+# asks for. Measured on a v5e at (1, 16384, 32 on 4, 128), keye_vl2_ep8's
+# call (tools/sweep_flash_fwd.py, tools/sweep_flash_bwd.py with `--vmem-mib
+# 64`; the tables are in PERF.md section 6, PR 48). Forward: 1,024 x 1,024
+# (18.9 ms a call, 59% of its roofline; 2,048 x 1,024 the same, every other
+# pair slower) fits Mosaic's default 16 MiB. Backward: 1,024 x 1,024 again
+# (64.5 ms forward + backward in the backward sweep's count, 70.0 at 512 x
+# 1,024, the fastest pair the default takes: `flash_masked_bwd_dkv` at 1,024
+# x 1,024 passes it by 16 KiB), so the masked calls raise the limit; the
+# v5e has 128 MiB. `selected_probs` holds a float32 OUTPUT tile besides:
+# 2,048 x 2,048 (9.0 ms a call against 10.6 at 1,024 x 1,024, the fastest
+# the default takes). Heads wider than the 128 lanes the sweep saw keep
+# DEFAULT_BLOCK.
+MASKED_FWD_BLOCKS = (1024, 1024)
+MASKED_BWD_BLOCKS = (1024, 1024)
+PROBS_BLOCKS = (2048, 2048)
+MASKED_VMEM_BYTES = 64 << 20
 
-_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary")
-)
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
+
+
+def _params(mask, semantics=_SEMANTICS):
+    """A call's compiler parameters: a masked call asks for
+    MASKED_VMEM_BYTES of scoped VMEM, any other takes Mosaic's default."""
+    if mask is None:
+        return pltpu.CompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=MASKED_VMEM_BYTES)
+
+
 # The forward kernel's two results that the backward reads, as
 # `_flash_fwd` names them. A caller whose remat can afford them
 # (B*N*S*(Dv bf16 + one float32) bytes a call) keeps them with
@@ -125,7 +164,7 @@ def auto_block(seq: int, requested: int | None, head_dim: int) -> int:
 
 def flash_blocks(seq_q: int, seq_k: int, head_dim: int, value_dim: int,
                  dropout_rate: float = 0.0, block_q: int | None = None,
-                 block_k: int | None = None):
+                 block_k: int | None = None, mask=None):
     """((block_q, block_k) of the forward kernel, (block_q, block_k) of the
     two backward kernels) for one call, from what the call can see: the two
     sequence lengths, the key and value widths, the dropout rate and the
@@ -136,14 +175,26 @@ def flash_blocks(seq_q: int, seq_k: int, head_dim: int, value_dim: int,
     number in ITS tiling, so forward and backward must then tile alike.
     `o` and `lse` are whole arrays in HBM: the backward reads them in its
     own blocks whatever tiles wrote them. Either side still shrinks to a
-    divisor of its sequence (`_pick_block`, `_pick_block_q`)."""
+    divisor of its sequence (`_pick_block`, `_pick_block_q`). `mask` is the
+    call's selection mask or None: a call that has one holds a (block_q,
+    block_k) tile of it beside K and V, and where nobody named a block its
+    pairs are MASKED_FWD_BLOCKS and MASKED_BWD_BLOCKS (see the constants)."""
+    fit = lambda pair: (_pick_block_q(seq_q, pair[0]),  # noqa: E731
+                        _pick_block(seq_k, pair[1]))
+    named = block_q is not None or block_k is not None
+    if mask is not None and not named:
+        wide = max(head_dim, value_dim) > LONG_SEQ_MAX_HEAD_DIM
+        default = (DEFAULT_BLOCK, DEFAULT_BLOCK)
+        backward = fit(default if wide else MASKED_BWD_BLOCKS)
+        if wide or dropout_rate > 0.0:
+            return backward, backward
+        return fit(MASKED_FWD_BLOCKS), backward
     backward = (_pick_block_q(seq_q, auto_block(seq_q, block_q, head_dim)),
                 _pick_block(seq_k, auto_block(seq_k, block_k, head_dim)))
-    if (block_q is not None or block_k is not None or dropout_rate > 0.0
+    if (named or dropout_rate > 0.0
             or max(head_dim, value_dim) > FWD_MAX_HEAD_DIM):
         return backward, backward
-    return (_pick_block_q(seq_q, FWD_BLOCK),
-            _pick_block(seq_k, FWD_BLOCK)), backward
+    return fit((FWD_BLOCK, FWD_BLOCK)), backward
 
 
 def _pick_block(seq: int, requested: int) -> int:
@@ -206,12 +257,56 @@ def _first_live_jb(kb, block_q, block_k, offset):
     return jnp.maximum(kb * block_k - offset, 0) // block_q
 
 
+def _seen(s, jb, kb, offset, causal, mask_ref):
+    """A score tile with every pair the softmax may not see at BIG_NEG: the
+    pairs past the (end-aligned) diagonal when `causal`, the pairs whose
+    byte of the caller's selection mask is 0 when there is one. One
+    definition shared by fwd/dq/dkv, as `_live` is; with neither, `s` as it
+    came."""
+    block_q, block_k = s.shape
+    seen = None
+    if causal:
+        rows = jb * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = cols <= rows + offset
+    if mask_ref is not None:
+        chosen = mask_ref[0, :, :].astype(jnp.int32) != 0
+        seen = chosen if seen is None else seen & chosen
+    return s if seen is None else jnp.where(seen, s, BIG_NEG)
+
+
+def _operand(mask) -> tuple:
+    return () if mask is None else (mask,)
+
+
+def _name(kernel: str, mask) -> str:
+    """A kernel's `name=`: what a device trace and `metrics/hlo_cost.py`
+    know it by. The three unmasked kernels' names are a vocabulary there
+    (`KERNEL_SCOPES`: their time is read as the flash kernels', whatever
+    layer calls them); a masked call's are not, so its time is its
+    caller's layer scope's (`ops/dsa.py`: `L_dsa_attend`)."""
+    return f"flash_mla_{kernel}" if mask is None else f"flash_masked_{kernel}"
+
+
+def _mask_spec(mask, block_q, block_k, index) -> tuple:
+    """The selection mask's BlockSpec, if there is a mask: one (block_q,
+    block_k) tile of a batch row for all its heads. `index` maps a grid
+    step to (batch row, query block, key block) and clamps a dead causal
+    step as its kernel's K or Q index does, so that no tile is fetched to
+    be ignored."""
+    if mask is None:
+        return ()
+    return (pl.BlockSpec((1, block_q, block_k), index),)
+
+
 # --------------------------------------------------------------------- forward
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, offset,
+def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, *rest, scale, causal, offset,
                 dropout_rate, num_qb, num_kb):
+    # rest: [the selection mask's tile,] o, lse, then the scratch m, l, acc
+    mask_ref = rest[0] if len(rest) == 6 else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[-5:]
     # q_ref: (1, block_q, D) resident across the kv sweep; k_ref/v_ref:
     # (1, block_k, D) for this kv step. `offset` end-aligns the causal mask
     # when seq_q != seq_k (ops.attention.causal_mask semantics: query i
@@ -239,16 +334,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
-        if causal:
-            rows = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(cols <= rows + offset, s, BIG_NEG)
+        s = _seen(s, j, kb, offset, causal, mask_ref)
         m_i, l_i, acc = m_scr[...], l_scr[...], acc_scr[...]
         m_new = jnp.maximum(m_i, jnp.max(s, axis=1, keepdims=True))
+        if mask_ref is not None:
+            # a row may have no chosen key in this tile, or in none: with
+            # its maximum held above BIG_NEG the unseen pairs' exp
+            # underflows to 0 (no unit mass for them, as below), on a
+            # (block_q, 1) column and not a select a score; a row with no
+            # key at all then keeps l == 0 and meets _finish's guard
+            m_new = jnp.maximum(m_new, BIG_NEG * 0.5)
         if causal and offset < 0:
             # seq_q > seq_k end-aligned causal only (e.g. a single-q-block
             # fallback): rows with r + offset < 0 see NO key in any block,
@@ -297,9 +392,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
 
 
 def _fwd(q3, k3, v3, seed, n_heads, n_kv, scale, causal, block_q, block_k,
-         dropout_rate, interpret):
-    """q3: (B*N, S, D); k3: (B*Nkv, Skv, D); v3: (B*Nkv, Skv, Dv).
-    Returns (o (B*N, S, Dv), lse)."""
+         dropout_rate, interpret, mask=None):
+    """q3: (B*N, S, D); k3: (B*Nkv, Skv, D); v3: (B*Nkv, Skv, Dv); mask:
+    None or (B, S, Skv) int8. Returns (o (B*N, S, Dv), lse)."""
     bn, seq_q, d = q3.shape
     seq_k, dv = k3.shape[1], v3.shape[2]
     group = n_heads // n_kv
@@ -331,6 +426,8 @@ def _fwd(q3, k3, v3, seed, n_heads, n_kv, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), kv_index),
             pl.BlockSpec((1, block_k, dv), kv_index),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            *_mask_spec(mask, block_q, block_k, lambda i, j, kb: (
+                i // n_heads, j, kv_index(i, j, kb)[1])),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda i, j, kb: (i, j, 0)),
@@ -345,18 +442,20 @@ def _fwd(q3, k3, v3, seed, n_heads, n_kv, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=_SEMANTICS,
+        compiler_params=_params(mask),
         interpret=interpret,
-        name="flash_mla_fwd",
-    )(q3, k3, v3, seed)
+        name=_name("fwd", mask),
+    )(q3, k3, v3, seed, *_operand(mask))
 
 
 # -------------------------------------------------------------------- backward
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
-                   dq_ref, dq_scr, *, scale, causal, offset, dropout_rate,
-                   num_qb, num_kb):
+                   *rest, scale, causal, offset, dropout_rate, num_qb, num_kb):
+    # rest: [the selection mask's tile,] dq, then the scratch
+    mask_ref = rest[0] if len(rest) == 3 else None
+    dq_ref, dq_scr = rest[-2:]
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
     i = pl.program_id(0)
@@ -380,10 +479,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        if causal:
-            rows = j * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= rows + offset, s, BIG_NEG)
+        s = _seen(s, j, kb, offset, causal, mask_ref)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -405,9 +501,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, offset,
-                    dropout_rate, num_qb, num_kb):
-    # grid is (bn, kv-blocks, q-blocks): the q axis is the sequential carry
+                    *rest, scale, causal, offset, dropout_rate, num_qb,
+                    num_kb):
+    # grid is (bn, kv-blocks, q-blocks): the q axis is the sequential carry;
+    # rest: [the selection mask's tile,] dk, dv, then the two scratch
+    mask_ref = rest[0] if len(rest) == 5 else None
+    dk_ref, dv_ref, dk_scr, dv_scr = rest[-4:]
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
     i = pl.program_id(0)
@@ -432,10 +531,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        if causal:
-            rows = jb * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= rows + offset, s, BIG_NEG)
+        s = _seen(s, jb, kb, offset, causal, mask_ref)
         p = jnp.exp(s - lse)  # (bq, bk)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -464,32 +560,126 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seed_ref,
         dv_ref[0, :, :] = dv_scr[...].astype(dv_ref.dtype)
 
 
+# --------------------------------------------- the heads' mean probabilities
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, *, scale, causal,
+                  offset, n_heads):
+    # grid (B, q-blocks, kv-blocks, heads), the heads the sequential carry:
+    # p_ref, a float32 (1, block_q, block_k) tile, stays in VMEM over them
+    block_q, block_k = p_ref.shape[1:]
+    j = pl.program_id(1)
+    kb = pl.program_id(2)
+    h = pl.program_id(3)
+
+    @pl.when(h == 0)
+    def _init():
+        p_ref[...] = jnp.zeros(p_ref.shape, p_ref.dtype)
+
+    @pl.when(_live(j, kb, block_q, block_k, offset, causal))
+    def _step():
+        # the forward kernel's scores, operation for operation: lse is theirs
+        q = q_ref[0, :, :].astype(jnp.float32) * scale
+        s = jax.lax.dot_general(
+            q, k_ref[0, :, :].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        s = _seen(s, j, kb, offset, causal, mask_ref)
+        p_ref[0, :, :] += jnp.exp(s - lse_ref[0, 0, :][:, None])
+
+    @pl.when(h == n_heads - 1)
+    def _finish():
+        p_ref[...] = p_ref[...] * (1.0 / n_heads)
+
+
+def selected_probs(q, k, lse, mask, *, causal: bool = False,
+                   scale: float | None = None, block_q: int | None = None,
+                   block_k: int | None = None,
+                   interpret: bool | None = None) -> jax.Array:
+    """mean over the N heads of exp(q_h . k_g(h) * scale - lse_h) at the pairs
+    `mask` (and `causal`) let through, 0 elsewhere: (B, Sq, Skv) float32,
+    the heads' mean attention probability, from the `lse` that
+    `flash_attention(..., mask=mask, return_lse=True)` returned for the same
+    q, k, mask, causal and scale. Forward only: a fourth kernel beside the
+    three, QK^T and the exponential without the values, never the (N, Sq,
+    Skv) probabilities in HBM. No gradient is defined (the caller's use is a
+    detached target)."""
+    b, seq_q, n_heads, d = q.shape
+    seq_k, n_kv = k.shape[1], k.shape[2]
+    group = n_heads // n_kv
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
+    if scale is None:
+        scale = d**-0.5
+    block_q = _pick_block_q(seq_q, block_q or PROBS_BLOCKS[0])
+    block_k = _pick_block(seq_k, block_k or PROBS_BLOCKS[1])
+    offset = seq_k - seq_q
+    q3, k3, lse3, mask = jax.lax.stop_gradient((
+        q.transpose(0, 2, 1, 3).reshape(b * n_heads, seq_q, d),
+        k.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, d),
+        lse.reshape(b * n_heads, 1, seq_q), mask.astype(jnp.int8)))
+
+    def key_block(j, kb):  # clamped on a dead causal step, as `_fwd`'s
+        if causal:
+            kb = jnp.minimum(kb, _last_live_kb(j, block_q, block_k, offset))
+        return kb
+
+    return pl.pallas_call(
+        functools.partial(_probs_kernel, scale=float(scale),
+                          causal=bool(causal), offset=offset,
+                          n_heads=n_heads),
+        grid=(b, seq_q // block_q, seq_k // block_k, n_heads),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d),
+                         lambda i, j, kb, h: (i * n_heads + h, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j, kb, h: (
+                i * n_kv + h // group, key_block(j, kb), 0)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda i, j, kb, h: (i * n_heads + h, 0, j)),
+            pl.BlockSpec((1, block_q, block_k),
+                         lambda i, j, kb, h: (i, j, key_block(j, kb))),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, block_k),
+                               lambda i, j, kb, h: (i, j, kb)),
+        out_shape=_sds((b, seq_q, seq_k), jnp.float32, q3),
+        compiler_params=_params(mask, (
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_masked_probs",
+    )(q3, k3, lse3, mask)
+
+
 # ------------------------------------------------------------------ public API
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
-def _flash(q3, k3, v3, seed, heads, scale, causal, blocks, dropout_rate,
-           interpret):
-    o, _ = _fwd(q3, k3, v3, seed, heads[0], heads[1], scale, causal,
-                *blocks[0], dropout_rate, interpret)
-    return o
-
-
-def _flash_fwd(q3, k3, v3, seed, heads, scale, causal, blocks, dropout_rate,
-               interpret):
+def _flash(q3, k3, v3, seed, mask, heads, scale, causal, blocks, dropout_rate,
+           interpret, with_lse):
+    # mask: None (no leaf: the call traces as it did before there was one)
+    # or (B, Sq, Sk) int8
     o, lse = _fwd(q3, k3, v3, seed, heads[0], heads[1], scale, causal,
-                  *blocks[0], dropout_rate, interpret)
+                  *blocks[0], dropout_rate, interpret, mask)
+    return (o, lse) if with_lse else o
+
+
+def _flash_fwd(q3, k3, v3, seed, mask, heads, scale, causal, blocks,
+               dropout_rate, interpret, with_lse):
+    o, lse = _fwd(q3, k3, v3, seed, heads[0], heads[1], scale, causal,
+                  *blocks[0], dropout_rate, interpret, mask)
     # named in the kernel's own (B*N, S, Dv) layout: what the backward's
     # delta and the caller's o_proj both start from (FLASH_RESIDUALS)
     o = checkpoint_name(o, FLASH_RESIDUALS[0])
     lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
-    return o, (q3, k3, v3, seed, o, lse)
+    return ((o, lse) if with_lse else o), (q3, k3, v3, seed, mask, o, lse)
 
 
-def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret, res, do):
-    q3, k3, v3, seed, o, lse = res
+def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret,
+               with_lse, res, do):
+    q3, k3, v3, seed, mask, o, lse = res
+    if with_lse:  # lse is handed out for reading: its cotangent is dropped
+        do = do[0]
     n_heads, n_kv = heads
     bn = q3.shape[0]
     seq_k = k3.shape[1]
@@ -509,7 +699,7 @@ def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret, res, do):
     dq, dk_r, dv_r = _bwd_chunk(
         q3, k3r, v3r, do, lse, delta, seed, scale=scale, causal=causal,
         block_q=blocks[1][0], block_k=blocks[1][1], dropout_rate=dropout_rate,
-        interpret=interpret,
+        interpret=interpret, mask=mask,
     )
 
     if group > 1:  # reduce repeated-head grads back to kv heads
@@ -518,17 +708,19 @@ def _flash_bwd(heads, scale, causal, blocks, dropout_rate, interpret, res, do):
             b, n_kv, group, seq_k, x.shape[2]
         ).sum(axis=2).reshape(b * n_kv, seq_k, x.shape[2])
         dk_r, dv_r = fold(dk_r), fold(dv_r)
-    # seed is integer-typed: no cotangent
-    return dq, dk_r.astype(k3.dtype), dv_r.astype(v3.dtype), None
+    # seed and mask are integer-typed: no cotangent
+    return dq, dk_r.astype(k3.dtype), dv_r.astype(v3.dtype), None, None
 
 
 def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
-               block_q, block_k, dropout_rate, interpret):
+               block_q, block_k, dropout_rate, interpret, mask=None):
     """dq/dk/dv pallas sweeps for one (q, kv) pair with kv already repeated
     to q heads. Shared by the full backward above and the ring-flash
     backward (sharding/ring_attention.py), which runs it once per rotating
-    kv chunk with the GLOBAL lse/delta. v3r and do are Dv wide, as dv is."""
+    kv chunk with the GLOBAL lse/delta. v3r and do are Dv wide, as dv is.
+    `mask`: None or the forward's (B, S, Skv) int8 selection mask."""
     bn, seq_q, d = q3.shape
+    n_heads = 1 if mask is None else bn // mask.shape[0]
     seq_k, dv = k3r.shape[1], v3r.shape[2]
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
@@ -554,14 +746,16 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
             pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            *_mask_spec(mask, block_q, block_k, lambda i, j, kb: (
+                i // n_heads, j, kv_index_rep(i, j, kb)[1])),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
         out_shape=_sds(q3.shape, q3.dtype, q3),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_SEMANTICS,
+        compiler_params=_params(mask),
         interpret=interpret,
-        name="flash_mla_bwd_dq",
-    )(q3, k3r, v3r, do, lse, delta, seed)
+        name=_name("bwd_dq", mask),
+    )(q3, k3r, v3r, do, lse, delta, seed, *_operand(mask))
 
     def q_index(i, kb, jb):
         # mirror clamp for the dkv sweep: q blocks before the diagonal are
@@ -588,6 +782,8 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
             pl.BlockSpec((1, 1, block_q), q_row_index),
             pl.BlockSpec((1, 1, block_q), q_row_index),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            *_mask_spec(mask, block_q, block_k, lambda i, kb, jb: (
+                i // n_heads, q_index(i, kb, jb)[1], kb)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda i, kb, jb: (i, kb, 0)),
@@ -601,10 +797,10 @@ def _bwd_chunk(q3, k3r, v3r, do, lse, delta, seed, *, scale, causal,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
-        compiler_params=_SEMANTICS,
+        compiler_params=_params(mask),
         interpret=interpret,
-        name="flash_mla_bwd_dkv",
-    )(q3, k3r, v3r, do, lse, delta, seed)
+        name=_name("bwd_dkv", mask),
+    )(q3, k3r, v3r, do, lse, delta, seed, *_operand(mask))
 
     return dq, dk_r, dv_r
 
@@ -624,9 +820,11 @@ def flash_attention(
     dropout_rate: float = 0.0,
     dropout_seed: jax.Array | int = 0,
     interpret: bool | None = None,
-) -> jax.Array:
+    mask: jax.Array | None = None,
+    return_lse: bool = False,
+):
     """Flash attention over BSNH tensors (drop-in for ops.dot_product_attention
-    when there is no cache/explicit mask).
+    when there is no cache).
 
     q: (B, Sq, N, D); k: (B, Skv, Nkv, D); v: (B, Skv, Nkv, Dv) with
     N % Nkv == 0; returns (B, Sq, N, Dv). Dv may differ from D (latent
@@ -638,6 +836,15 @@ def flash_attention(
     (masks regenerated from (dropout_seed, block id) in the backward — no
     (S, S) mask tensor ever exists); same Bernoulli semantics as the dense
     reference, different random stream.
+    mask: a selection mask (B, Sq, Skv), one for all heads of a batch row,
+    nonzero where a query may see a key (bool or int8; it crosses HBM as
+    int8, a byte a pair, tile by tile); with `causal` a pair must pass
+    both. A row with no key comes out 0. It takes no gradient. None (the
+    default) is a Python branch: the call traces to what it was before
+    there was a mask. A window, sinks, biases and segment ids are not taken.
+    return_lse: also return the rows' log-sum-exp (B, N, Sq) float32, for
+    reading only (it passes no gradient): exp(q . k * scale - lse) is a
+    pair's probability (`selected_probs`).
     """
     b, seq_q, n_heads, d = q.shape
     seq_k, n_kv = k.shape[1], k.shape[2]
@@ -658,14 +865,24 @@ def flash_attention(
     if scale is None:
         scale = d**-0.5
     dv = v.shape[3]
-    blocks = flash_blocks(seq_q, seq_k, d, dv, dropout_rate, block_q, block_k)
+    if mask is not None:
+        if mask.shape != (b, seq_q, seq_k):
+            raise ValueError(f"mask {mask.shape} is not (B, Sq, Skv) = "
+                             f"{(b, seq_q, seq_k)}")
+        mask = mask.astype(jnp.int8)
+    blocks = flash_blocks(seq_q, seq_k, d, dv, dropout_rate, block_q, block_k,
+                          mask)
 
     q3 = q.transpose(0, 2, 1, 3).reshape(b * n_heads, seq_q, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, d)
     v3 = v.transpose(0, 2, 1, 3).reshape(b * n_kv, seq_k, dv)
     seed = jnp.asarray(dropout_seed, jnp.int32).reshape(1)
     o3 = _flash(
-        q3, k3, v3, seed, (n_heads, n_kv), float(scale), bool(causal),
-        blocks, float(dropout_rate), interpret,
+        q3, k3, v3, seed, mask, (n_heads, n_kv), float(scale), bool(causal),
+        blocks, float(dropout_rate), interpret, bool(return_lse),
     )
-    return o3.reshape(b, n_heads, seq_q, dv).transpose(0, 2, 1, 3)
+    if return_lse:
+        o3, lse = o3
+        lse = jax.lax.stop_gradient(lse).reshape(b, n_heads, seq_q)
+    out = o3.reshape(b, n_heads, seq_q, dv).transpose(0, 2, 1, 3)
+    return (out, lse) if return_lse else out

@@ -26,7 +26,8 @@ rotation.
     KL(p_t || softmax over the selected keys of I[t]) with p_t the 32
     heads' mean probability, detached; a mean over tokens, sown a layer
     (`dsa_metrics`: `index_kl`, beside `selected_fraction`, selected pairs
-    over causal pairs) for `train/objectives.py` `keye_vl_loss_fn`, which
+    over causal pairs, and `live_tile_fraction`, the backward kernels'
+    causal tiles that hold a selected pair) for `train/objectives.py` `keye_vl_loss_fn`, which
     adds the layers' mean to the loss. It moves `indexer_*` and nothing
     else; the cross-entropy and the balance term move everything else
   * MoE (`HeldExpertsMoE` of models/mixers.py, softmax scoring, NO shared
@@ -55,6 +56,7 @@ from solvingpapers_tpu.models.layers import (
 )
 from solvingpapers_tpu.models.mixers import HeldExpertsMoE
 from solvingpapers_tpu.ops import dsa
+from solvingpapers_tpu.sharding import get_ambient_mesh
 
 # every matrix starts as the family does: normal, initializer_range 0.02
 _INIT = nn.initializers.normal(0.02)
@@ -170,24 +172,25 @@ class SelectedAttention(nn.Module):
 
         with jax.named_scope("L_attn_proj"):
             q, k, v = _by_blocks(before, SEGMENT, x)
-            # k, v (and the indexer's key) stay float32 up to the block that
-            # multiplies with them: their gradients add up over the query
-            # blocks in float32
+            # the indexer's key stays float32 up to the block that multiplies
+            # with it (its gradient adds up over the query blocks in
+            # float32); k and v are cast once, where the kernels take them
             q = ops.partial_rotary(q, hd, cfg.rope_theta).astype(dt)
             k = ops.partial_rotary(k, hd, cfg.rope_theta)
-            v = v.astype(f32)
         with jax.named_scope("L_dsa_index"):
             qi, ki, wi = _by_blocks(indexer, SEGMENT, x)
             qi = ops.partial_rotary(qi.astype(f32), di, cfg.rope_theta
                                     ).astype(dt)
             ki = ops.partial_rotary(ki.astype(f32)[:, :, None, :], di,
                                     cfg.rope_theta)[:, :, 0, :]
-        ctx, kl, selected = dsa.selected_attention(
-            q, k, v, qi, ki, wi, topk=cfg.topk, scale=hd ** -0.5)
+        ctx, kl, selected, live_tiles = dsa.selected_attention(
+            q, k, v, qi, ki, wi, topk=cfg.topk, scale=hd ** -0.5,
+            mesh=get_ambient_mesh())
         with jax.named_scope("L_dsa_loss"):
             self.sow("dsa_metrics", "stats", {
                 "index_kl": kl / (b * s),
                 "selected_fraction": selected / (b * s * (s + 1) / 2),
+                "live_tile_fraction": live_tiles,
             })
         with jax.named_scope("L_attn_proj"):
             return _by_blocks(lambda c: c @ w_o, SEGMENT,
@@ -245,9 +248,9 @@ class KeyeVL(nn.Module):
                 embedding_init=_INIT, name="tok_emb",
             )(tokens)
         # the selection and the attention over it are kept, not made again:
-        # a layer's masks (140 MiB at 16,384 tokens), its attention output
-        # (128 MiB) and the two sums; everything else of a layer is made
-        # again in the backward pass
+        # a layer's mask (256 MiB at 16,384 tokens), the forward kernel's
+        # output (128 MiB) and log-sum-exp, and the two sums; everything
+        # else of a layer is made again in the backward pass
         layer_cls = remat_keeping(KeyeVLLayer, cfg.remat, *dsa.DSA_RESIDUALS)
         for i in range(cfg.num_hidden_layers):
             x = layer_cls(cfg, name=f"layer_{i}")(x)

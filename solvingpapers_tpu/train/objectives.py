@@ -250,8 +250,10 @@ def keye_vl_loss_fn(model, params, batch, rng, model_state, train):
     gradient from the KL alone (its inputs and its target are detached and
     the selection passes none), every other weight from the other two terms
     alone. Logged beside the MoE's counters: `dsa_index_kl` (nats, a layer)
-    and `dsa_selected_fraction` (selected pairs over causal pairs; 1.0 when
-    nothing is left out)."""
+    `dsa_selected_fraction` (selected pairs over causal pairs; 1.0 when
+    nothing is left out) and `dsa_live_tile_fraction` (of the attention's
+    backward kernels' causal tiles, those that hold a selected pair: what a
+    kernel that skipped the others would still run)."""
     main, aux, mutated = _chunked_head(model, params, batch, train,
                                        ("moe_metrics", "dsa_metrics"))
     if not train:
@@ -264,8 +266,8 @@ def keye_vl_loss_fn(model, params, batch, rng, model_state, train):
         index_kl = jnp.mean(jnp.stack([s["index_kl"] for s in layers]))
         aux.update(
             balance_loss=balance, dsa_index_kl=index_kl,
-            dsa_selected_fraction=jnp.mean(jnp.stack(
-                [s["selected_fraction"] for s in layers])))
+            **{f"dsa_{name}": jnp.mean(jnp.stack([s[name] for s in layers]))
+               for name in ("selected_fraction", "live_tile_fraction")})
     return (main + model.cfg.router_aux_loss_coef * balance + index_kl,
             aux, model_state)
 

@@ -25,8 +25,14 @@ if _platform == "cpu":
     # keeps the eighth participant off the pool for good, and XLA aborts the
     # process after 40 s ("Termination timeout for all reduce"). It needs a
     # loaded host to run that far ahead — six xdist workers are one. Size the
-    # pool past the device count so a collective can always be joined.
-    os.environ.setdefault("PJRT_NPROC", "12")
+    # pool past the device count so a collective can always be joined. And
+    # past TWICE the device count since PR 48: the CPU client runs
+    # independent parts of a step at once, and keye_vl's indexer loss is a
+    # side branch of its layer since the attention left its loops (its
+    # gradients' all-reduce ran beside the expert layer's all-gather, six
+    # devices parked two threads each, twelve, and the other two never got
+    # one: `test_cli_train_runs_the_family...` aborted two runs in three).
+    os.environ.setdefault("PJRT_NPROC", "24")
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402

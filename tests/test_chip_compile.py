@@ -149,6 +149,42 @@ def test_flash_with_a_value_width_of_its_own_compiles_for_v5e(
                                                            else 1)
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_with_a_selection_mask_compiles_for_v5e(one_chip, backward):
+    """keye_vl2_ep8's attention over the indexer's keys (`ops/dsa.py`): 32
+    heads on 4 of width 128, one sequence of 16,384, a (1, S, S) int8
+    selection mask whose (block_q, block_k) tile sits in VMEM beside K and
+    V in the forward kernel and both backward kernels, and `selected_probs`
+    (the heads' mean probability, a float32 output tile) from the
+    forward's log-sum-exp: Mosaic takes all four in the pairs
+    `flash_blocks` and `PROBS_BLOCKS` give them, at the scoped VMEM the
+    masked calls ask for."""
+    from solvingpapers_tpu.kernels.flash_attention import (
+        MASKED_BWD_BLOCKS, MASKED_FWD_BLOCKS, flash_blocks, selected_probs)
+
+    sds = lambda dtype, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    mask = sds(jnp.int8, 1, 16_384, 16_384)
+    assert flash_blocks(16_384, 16_384, 128, 128, mask=mask) == (
+        MASKED_FWD_BLOCKS, MASKED_BWD_BLOCKS)
+
+    def loss(q, k, v, mask):
+        out, lse = flash_attention(q, k, v, causal=True, mask=mask,
+                                   return_lse=True, interpret=False)
+        probs = selected_probs(q, k, lse, mask, causal=True, interpret=False)
+        assert probs.shape == (1, 16_384, 16_384)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(probs[:, :, :128])
+
+    # the value too: the probabilities pass no gradient
+    fn = jax.value_and_grad(loss, argnums=(0, 1, 2)) if backward else loss
+    compiled = jax.jit(fn).lower(
+        sds(jnp.bfloat16, 1, 16_384, 32, 128),
+        sds(jnp.bfloat16, 1, 16_384, 4, 128),
+        sds(jnp.bfloat16, 1, 16_384, 4, 128), mask).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (4 if backward
+                                                           else 2)
+
+
 def test_flash_prefill_chunk_compiles_for_v5e(one_chip):
     """The serving prefill of `dsv3_long`: a chunk of 512 queries over the
     16,384 latent rows written so far, end-aligned causal, one shared

@@ -6,6 +6,8 @@ bidirectional, MHA and GQA/MQA head layouts.
 """
 
 import functools
+import hashlib
+import re
 import sys
 
 import jax
@@ -17,9 +19,10 @@ from conftest import (
     two_remat_layers)
 
 from solvingpapers_tpu import ops
+from solvingpapers_tpu.metrics import hlo_cost
 from solvingpapers_tpu.kernels import flash_attention
 from solvingpapers_tpu.kernels.flash_attention import (
-    FLASH_RESIDUALS, flash_blocks)
+    FLASH_RESIDUALS, flash_blocks, selected_probs)
 
 # the module (the package re-exports the function under the same name)
 flash_module = sys.modules["solvingpapers_tpu.kernels.flash_attention"]
@@ -36,48 +39,183 @@ def make_qkv(key, b, sq, skv, n, n_kv, d, dtype=jnp.float32):
     return q, k, v
 
 
+def selection(key, b, sq, skv, share=0.3):
+    """A selection mask (B, Sq, Skv), one for all heads: `share` of the
+    pairs at random, and every query's own (end-aligned) key, so that no
+    row is empty under the causal mask either."""
+    own = jnp.arange(skv)[None, :] == jnp.arange(sq)[:, None] + (skv - sq)
+    return (jax.random.uniform(key, (b, sq, skv)) < share) | own
+
+
 CASES = [
-    # (b, sq, skv, n, n_kv, d, causal)
-    pytest.param(2, 128, 128, 4, 4, 64, True, id="mha_causal"),
-    pytest.param(2, 128, 128, 4, 4, 64, False, id="mha_bidir"),
-    pytest.param(2, 128, 128, 4, 2, 32, True, id="gqa_causal"),
-    pytest.param(1, 128, 128, 4, 1, 32, True, id="mqa_causal"),
-    pytest.param(1, 256, 256, 2, 2, 64, True, id="multiblock_causal"),
-    pytest.param(1, 64, 256, 2, 2, 32, False, id="cross_qkv_lens"),
+    # (b, sq, skv, n, n_kv, d, causal, with a selection mask)
+    pytest.param(2, 128, 128, 4, 4, 64, True, False, id="mha_causal"),
+    pytest.param(2, 128, 128, 4, 4, 64, False, False, id="mha_bidir"),
+    pytest.param(2, 128, 128, 4, 2, 32, True, False, id="gqa_causal"),
+    pytest.param(1, 128, 128, 4, 1, 32, True, False, id="mqa_causal"),
+    pytest.param(1, 256, 256, 2, 2, 64, True, False, id="multiblock_causal"),
+    pytest.param(1, 64, 256, 2, 2, 32, False, False, id="cross_qkv_lens"),
     # end-aligned causal mask: query i sees kv <= i + (skv - sq)
-    pytest.param(1, 64, 256, 2, 2, 32, True, id="cross_qkv_lens_causal"),
-    pytest.param(2, 128, 192, 4, 2, 32, True, id="cross_gqa_causal"),
+    pytest.param(1, 64, 256, 2, 2, 32, True, False,
+                 id="cross_qkv_lens_causal"),
+    pytest.param(2, 128, 192, 4, 2, 32, True, False, id="cross_gqa_causal"),
+    # a selection mask beside the causal one (`ops/dsa.py`'s call): 4 heads
+    # on 1, a batch row's mask shared by its heads; then a span's geometry,
+    # its queries the last 64 of 256 keys
+    pytest.param(2, 128, 128, 4, 1, 32, True, True, id="selected_4on1"),
+    pytest.param(2, 64, 256, 4, 2, 32, True, True, id="selected_span"),
+    pytest.param(1, 128, 128, 2, 2, 32, False, True, id="selected_bidir"),
 ]
 
 
-@pytest.mark.parametrize("b,sq,skv,n,n_kv,d,causal", CASES)
-def test_forward_matches_dense(b, sq, skv, n, n_kv, d, causal):
+@pytest.mark.parametrize("b,sq,skv,n,n_kv,d,causal,selected", CASES)
+def test_forward_matches_dense(b, sq, skv, n, n_kv, d, causal, selected):
     q, k, v = make_qkv(jax.random.key(0), b, sq, skv, n, n_kv, d)
-    out = flash_attention(q, k, v, causal=causal, interpret=True, block_q=64, block_k=64)
-    ref = ops.dot_product_attention(q, k, v, causal=causal)
+    mask = selection(jax.random.key(9), b, sq, skv) if selected else None
+    out = jax.jit(functools.partial(
+        flash_attention, causal=causal, interpret=True, block_q=64,
+        block_k=64, mask=mask))(q, k, v)
+    ref = ops.dot_product_attention(
+        q, k, v, None if mask is None else mask[:, None], causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize(
-    "b,sq,skv,n,n_kv,d,causal",
-    [CASES[0], CASES[2], CASES[4], CASES[1], CASES[6]],
+    "b,sq,skv,n,n_kv,d,causal,selected",
+    [CASES[0], CASES[2], CASES[4], CASES[1], CASES[6], *CASES[8:]],
 )
-def test_grads_match_dense(b, sq, skv, n, n_kv, d, causal):
+def test_grads_match_dense(b, sq, skv, n, n_kv, d, causal, selected):
     q, k, v = make_qkv(jax.random.key(1), b, sq, skv, n, n_kv, d)
+    mask = selection(jax.random.key(9), b, sq, skv) if selected else None
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, causal=causal, interpret=True,
-                            block_q=64, block_k=64)
+                            block_q=64, block_k=64, mask=mask)
         return jnp.sum(o * jnp.cos(o))
 
     def loss_dense(q, k, v):
-        o = ops.dot_product_attention(q, k, v, causal=causal)
+        o = ops.dot_product_attention(
+            q, k, v, None if mask is None else mask[:, None], causal=causal)
         return jnp.sum(o * jnp.cos(o))
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-4, atol=2e-4)
+
+
+def _stripped_jaxpr(fn, *args) -> str:
+    """A traced program's text with what differs between two checkouts of
+    one program taken out: function addresses and source locations."""
+    text = str(jax.make_jaxpr(fn)(*args))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    return re.sub(r"[\w/.-]+\.py:\d+", "", text)
+
+
+def test_no_mask_traces_to_the_program_it_was_before_there_was_one():
+    """`mask=None` is a Python branch: forward and backward of an unmasked
+    call (GQA, a value width of its own, end-aligned causal; and the
+    bidirectional forward in the resolver's own tiles) trace to the jaxpr
+    PR 47's tree traced, operand for operand and equation for equation (its
+    text's SHA-256, taken on that tree with this function): no mask
+    operand, no `where` for one, the same `in_specs`. The six benchmark
+    cells that run these kernels unmasked rest on it. And a mask does
+    change the trace (the digest is of something)."""
+    q = jnp.zeros((2, 128, 4, 32))
+    k = jnp.zeros((2, 256, 2, 32))
+    v = jnp.zeros((2, 256, 2, 16))
+
+    def step(mask=None):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True, block_q=64, mask=mask)),
+            argnums=(0, 1, 2))
+
+    digest = lambda text: hashlib.sha256(text.encode()).hexdigest()  # noqa: E731
+    assert digest(_stripped_jaxpr(step(), q, k, v)) == (
+        "10e4038275e214a658ab213a5992df84009e04099c798bbbb82a3ebf9ad69582")
+    assert digest(_stripped_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=False, interpret=True), q, k, v)) == (
+        "7285f4d67f389c3ad4f2d88ac8a48775d7cf7952fc7ce6462d79b3cdb7de46bb")
+    masked = _stripped_jaxpr(step(jnp.ones((2, 128, 256), bool)), q, k, v)
+    assert masked != _stripped_jaxpr(step(), q, k, v)
+    assert "i8[2,128,256]" in masked
+    # the masked kernels go by names of their own, outside the vocabulary
+    # that reads a device trace's flash kernels as a layer of theirs: their
+    # time is the scope's that calls them (`ops/dsa.py`: `L_dsa_attend`)
+    names = set(kernel_grids(step(jnp.ones((2, 128, 256), bool)), q, k, v))
+    assert names == {"flash_masked_fwd", "flash_masked_bwd_dq",
+                     "flash_masked_bwd_dkv"}
+    assert set(kernel_grids(step(), q, k, v)) == set(hlo_cost.KERNEL_SCOPES)
+
+
+@pytest.mark.parametrize("sq,skv", [(128, 128), (64, 256)],
+                         ids=["square", "span"])
+def test_a_mask_of_all_ones_is_the_unmasked_call_bit_for_bit(sq, skv):
+    q, k, v = make_qkv(jax.random.key(2), 2, sq, skv, 4, 2, 32)
+    mix = jax.random.normal(jax.random.key(3), (2, sq, 4, 32))
+
+    def program(mask):
+        return jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, causal=True, interpret=True, block_q=64,
+                            block_k=64, mask=mask) * mix), argnums=(0, 1, 2)))
+
+    assert_same_bits(program(jnp.ones((2, sq, skv), jnp.int8))(q, k, v),
+                     program(None)(q, k, v))
+
+
+def test_a_row_whose_mask_is_empty_comes_out_zero_and_passes_no_gradient():
+    """Rows 0..31 select no key at all, and rows 32..63 none in the first
+    key tile: the empty rows are 0, not a mean of the values, their
+    gradients 0, and every other row is the dense reference's."""
+    q, k, v = make_qkv(jax.random.key(4), 1, 128, 128, 2, 1, 32)
+    rows = jnp.arange(128)[:, None]
+    mask = selection(jax.random.key(5), 1, 128, 128)
+    mask = mask & (rows >= 32) & ((rows >= 64) | (jnp.arange(128) >= 32))
+    mask = mask.at[:, 32:64, 40].set(True)
+    flash = functools.partial(flash_attention, causal=True, interpret=True,
+                              block_q=32, block_k=32, mask=mask)
+    out = jax.jit(flash)(q, k, v)
+    ref = ops.dot_product_attention(q, k, v, mask[:, None], causal=True)
+    assert float(jnp.max(jnp.abs(out[:, :32]))) == 0.0
+    np.testing.assert_allclose(out[:, 32:], ref[:, 32:], rtol=2e-5, atol=2e-5)
+    seen = rows[None, :, :, None] >= 32
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))),
+                           (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.where(seen, jnp.sin(
+        ops.dot_product_attention(*a, mask[:, None], causal=True)), 0.0)),
+        (0, 1, 2)))(q, k, v)
+    assert float(jnp.max(jnp.abs(got[0][:, :32]))) == 0.0
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("b,sq,skv,n,n_kv,d,causal,selected", CASES[8:])
+def test_heads_mean_probability_matches_the_dense_softmax(
+        b, sq, skv, n, n_kv, d, causal, selected):
+    """`selected_probs` from the forward kernel's log-sum-exp: the mean over
+    the heads of the dense reference's softmax at the pairs the masks let
+    through, 0 elsewhere, every row summing to 1; in tiles of its own."""
+    q, k, v = make_qkv(jax.random.key(6), b, sq, skv, n, n_kv, d)
+    mask = selection(jax.random.key(9), b, sq, skv)
+    @jax.jit
+    def program(q, k, v):
+        _, lse = flash_attention(q, k, v, causal=causal, interpret=True,
+                                 block_q=64, block_k=64, mask=mask,
+                                 return_lse=True)
+        return lse, selected_probs(q, k, lse, mask, causal=causal,
+                                   interpret=True, block_q=32, block_k=128)
+
+    lse, got = program(q, k, v)
+    assert lse.shape == (b, n, sq) and lse.dtype == jnp.float32
+    seen = mask[:, None]
+    if causal:
+        seen = seen & ops.causal_mask(sq, skv)
+    scores = jnp.einsum("bqnh,bknh->bnqk", q, ops.repeat_kv(k, n // n_kv))
+    want = jnp.mean(jax.nn.softmax(
+        jnp.where(seen, scores * d ** -0.5, -1e30), axis=-1), axis=1)
+    np.testing.assert_allclose(got, jnp.where(seen[:, 0], want, 0.0),
+                               rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(jnp.sum(got, -1), 1.0, rtol=1e-5)
 
 
 def test_32_on_8_at_width_64_with_a_scale_of_its_own():
@@ -495,6 +633,30 @@ def test_blocks_at_the_cells_shapes(seq, dk, dv, forward, backward):
     assert flash_blocks(seq, seq, dk, dv, block_k=2048) == (
         (backward[0], 2048),) * 2
     assert flash_blocks(seq, seq, dk, dv, 0.1, 256, 128) == ((256, 128),) * 2
+
+
+def test_blocks_of_a_call_that_has_a_selection_mask():
+    """`flash_blocks` sees the mask operand (an array or its shape, never a
+    flag) and gives the call the pairs the masked sweeps chose at
+    keye_vl2_ep8's shape; a span's geometry and a short sequence shrink
+    them to divisors; a named block and dropout set ONE tiling as without
+    a mask; heads wider than the sweep saw keep the default block."""
+    mask = jax.ShapeDtypeStruct((1, 16_384, 16_384), jnp.int8)
+    assert flash_blocks(16_384, 16_384, 128, 128, mask=mask) == (
+        (1024, 1024), (1024, 1024))
+    assert flash_blocks(2048, 16_384, 128, 128, mask=mask) == (
+        (1024, 1024), (1024, 1024))
+    assert flash_blocks(64, 64, 8, 8, mask=mask) == ((64, 64), (64, 64))
+    assert flash_blocks(16_384, 16_384, 128, 128, 0.1, mask=mask) == (
+        (1024, 1024),) * 2
+    assert flash_blocks(16_384, 16_384, 128, 128, block_q=256,
+                        mask=mask) == ((256, 1024),) * 2
+    assert flash_blocks(16_384, 16_384, 256, 256, mask=mask) == (
+        (512, 512),) * 2
+    # and the unmasked call's backward pair is not the masked one's below
+    # LONG_SEQ
+    assert flash_blocks(4096, 4096, 128, 128)[1] == (512, 512)
+    assert flash_blocks(4096, 4096, 128, 128, mask=mask)[1] == (1024, 1024)
 
 
 def test_blocks_shrink_to_a_divisor_of_either_sequence():

@@ -86,19 +86,8 @@ def seeded(cfg, seed=5, init_std=0.2):
 
 
 def program_masks(qi, w, ki, topk):
-    """`dsa.selection_masks` put together as (B, S, S)."""
-    b, s = qi.shape[:2]
-    full = jnp.zeros((b, s, s), bool)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    for (start, end, block), m in zip(dsa.spans(s),
-                                      dsa.selection_masks(qi, w, ki, topk)):
-        if m is None:
-            m = jnp.broadcast_to(causal[start:end, :end],
-                                 (b, end - start, end))
-        else:
-            m = jnp.moveaxis(m, 0, 1).reshape(b, end - start, end)
-        full = full.at[:, start:end, :end].set(m)
-    return full
+    """`dsa.selection_mask`, (B, S, S), as booleans."""
+    return dsa.selection_mask(qi, w, ki, topk) > 0
 
 
 @pytest.mark.parametrize("ties", [False, True])
@@ -141,12 +130,30 @@ def test_topk_past_the_sequence_is_plain_causal_attention():
     qi = jax.random.normal(keys[3], (B, S, j, d))
     ki = jax.random.normal(keys[4], (B, S, d))
     w = jax.random.normal(keys[5], (B, S, j))
-    out, kl, count = jax.jit(lambda *a: dsa.selected_attention(
+    out, kl, count, live = jax.jit(lambda *a: dsa.selected_attention(
         *a, topk=S, scale=wd ** -0.5))(q, k, v, qi, ki, w)
     want = ops.dot_product_attention(q, k, v, causal=True, scale=wd ** -0.5)
     np.testing.assert_allclose(out, want, atol=2e-5)
     assert float(count) == B * S * (S + 1) / 2
     assert float(kl) > 0.0  # the indexer still has its target
+
+
+def test_live_tile_fraction_counts_the_causal_tiles_that_hold_a_pair(
+        both_sides):
+    """64 queries by 64 keys in tiles of 16 x 32: six causal tiles (the
+    second key tile begins at key 32, past the first two query tiles). Every
+    query selecting key 0 alone leaves four of them live, one pair more in
+    the second key tile five, the causal mask all six; a batch row counts
+    for itself. The objective logs the layers' mean in the attention's
+    backward tiling (one tile at this length: 1.0)."""
+    only_first = jnp.zeros((B, S, S), jnp.int8).at[:, :, 0].set(1)
+    live = jax.jit(dsa.live_tile_fraction, static_argnums=(1, 2))
+    assert float(jnp.mean(live(only_first, 16, 32))) == pytest.approx(4 / 6)
+    one_more = only_first.at[0, 40, 35].set(1)
+    np.testing.assert_allclose(live(one_more, 16, 32), [5 / 6, 4 / 6])
+    causal = jnp.broadcast_to(jnp.tril(jnp.ones((S, S), jnp.int8)), (B, S, S))
+    assert live(causal, 16, 32).tolist() == [1.0] * B
+    assert float(both_sides["aux"]["dsa_live_tile_fraction"]) == 1.0
 
 
 @pytest.fixture(scope="module")
